@@ -28,11 +28,6 @@ KNOBS = {
         "defaults": ["'1'"],
         "read": True, "set": False,
     },
-    'RLT_BENCH_CACHE_MAX_AGE': {
-        "modules": ['bench'],
-        "defaults": ['86400'],
-        "read": True, "set": False,
-    },
     'RLT_BENCH_COMPILE_SWEEP': {
         "modules": ['bench'],
         "defaults": ["'1'"],
@@ -115,19 +110,6 @@ KNOBS = {
         "modules": ['bench'],
         "read": False, "set": False,
     },
-    'RLT_BENCH_PROBE_OK_TTL': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_PROBE_TIMEOUT': {
-        "modules": ['bench', 'bench_prober'],
-        "defaults": ["'600'"],
-        "read": False, "set": True,
-    },
-    'RLT_BENCH_PROBE_TTL': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
     'RLT_BENCH_REMAT_SWEEP': {
         "modules": ['bench'],
         "defaults": ["'1'"],
@@ -205,8 +187,8 @@ KNOBS = {
         "read": True, "set": False,
     },
     'RLT_BENCH_TIMEOUT': {
-        "modules": ['bench', 'bench_prober'],
-        "read": False, "set": True,
+        "modules": ['bench'],
+        "read": False, "set": False,
     },
     'RLT_BENCH_ZERO_SWEEP': {
         "modules": ['bench'],
@@ -224,7 +206,6 @@ KNOBS = {
     },
     'RLT_CHIPS_PER_HOST': {
         "modules": ['launchers.ray_launcher'],
-        "defaults": ["'4'"],
         "read": True, "set": False,
     },
     'RLT_CKPT_EVERY_N_STEPS': {
@@ -305,10 +286,6 @@ KNOBS = {
         "modules": ['bench', 'ops.attention'],
         "read": False, "set": False,
     },
-    'RLT_FORCE_JAX_PLATFORM': {
-        "modules": ['runtime.actor_boot', 'runtime.api', 'runtime.zygote'],
-        "read": True, "set": True,
-    },
     'RLT_GLOBAL_RANK': {
         "modules": ['launchers.ray_launcher', 'runtime.elastic', 'runtime.faults', 'strategies.ray_strategies', 'utils.common'],
         "defaults": ["'0'"],
@@ -380,10 +357,6 @@ KNOBS = {
         "modules": ['ops.paged_attention'],
         "read": False, "set": False,
     },
-    'RLT_PALLAS_INTERPRET': {
-        "modules": ['ops.attention', 'ops.paged_attention'],
-        "read": True, "set": False,
-    },
     'RLT_PARTITION_RULES': {
         "modules": ['strategies.base'],
         "read": True, "set": False,
@@ -407,10 +380,6 @@ KNOBS = {
     'RLT_PREFETCH_DEPTH': {
         "modules": ['strategies.base'],
         "read": True, "set": False,
-    },
-    'RLT_PROBE_INCIDENT_COOLDOWN_S': {
-        "modules": ['observability.incidents'],
-        "read": False, "set": False,
     },
     'RLT_PROFILE_AT_STEP': {
         "modules": ['observability.profiler'],
@@ -474,10 +443,6 @@ KNOBS = {
     'RLT_TELEMETRY_RING': {
         "modules": ['observability.trace'],
         "read": False, "set": False,
-    },
-    'RLT_TEST_ON_TPU': {
-        "modules": ['bench_prober'],
-        "read": False, "set": True,
     },
     'RLT_TRACE_SAMPLE': {
         "modules": ['observability.reqtrace'],

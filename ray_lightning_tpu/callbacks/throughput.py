@@ -19,7 +19,9 @@ from ray_lightning_tpu import observability as _obs
 from ray_lightning_tpu.callbacks.base import Callback
 from ray_lightning_tpu.utils.common import rank_zero_warn
 
-# Peak bf16 matmul TFLOP/s per chip for common TPU generations (public specs).
+# Peak bf16 matmul TFLOP/s per chip, keyed by a substring of the
+# ``device_kind`` JAX reports (vendor documentation; the v5e reports
+# "TPU v5 lite"). A chip that is not here is an error, not a default.
 _PEAK_TFLOPS = {
     "v4": 275.0,
     "v5e": 197.0,
@@ -27,8 +29,6 @@ _PEAK_TFLOPS = {
     "v5p": 459.0,
     "v6e": 918.0,
 }
-_DEFAULT_PEAK_TFLOPS = 197.0
-_CPU_ESTIMATE_TFLOPS = 0.1  # so tests on CPU produce finite MFU numbers
 
 PEAK_TFLOPS_ENV = "RLT_PEAK_TFLOPS"
 
@@ -37,11 +37,12 @@ TRAIN_MFU_METRIC = "rlt_train_mfu"
 TOKENS_PER_CHIP_METRIC = "rlt_tokens_per_sec_per_chip"
 
 
-def detect_peak_tflops() -> float:
-    """Peak bf16 TFLOP/s per chip. ``RLT_PEAK_TFLOPS`` overrides detection
-    (the only correct source for chips this table doesn't know); a chip
-    missing from the table falls back with a warning instead of silently
-    reporting v5e-calibrated MFU."""
+def detect_peak_tflops() -> Optional[float]:
+    """Peak bf16 TFLOP/s per chip, or None on the CPU: a utilization is a
+    device metric and is "not measured" there (callers leave it out).
+    ``RLT_PEAK_TFLOPS`` overrides detection (the only correct source for
+    chips this table doesn't know); an accelerator missing from the table
+    raises instead of reporting MFU against another chip's peak."""
     override = os.environ.get(PEAK_TFLOPS_ENV)
     if override:
         try:
@@ -56,20 +57,16 @@ def detect_peak_tflops() -> float:
                 "%s is not a number: %r; ignoring", PEAK_TFLOPS_ENV, override
             )
     dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "").lower()
     if dev.platform == "cpu":
-        return _CPU_ESTIMATE_TFLOPS
+        return None
+    kind = getattr(dev, "device_kind", "").lower()
     for key, tflops in _PEAK_TFLOPS.items():
         if key in kind:
             return tflops
-    rank_zero_warn(
-        "unknown accelerator %r: assuming %.0f peak TFLOP/s for MFU; set "
-        "%s to the chip's real peak",
-        kind,
-        _DEFAULT_PEAK_TFLOPS,
-        PEAK_TFLOPS_ENV,
+    raise ValueError(
+        f"unknown accelerator {kind!r}: no peak TFLOP/s on record for MFU; "
+        f"set {PEAK_TFLOPS_ENV} to the chip's real peak"
     )
-    return _DEFAULT_PEAK_TFLOPS
 
 
 class ThroughputMonitor(Callback):
@@ -182,9 +179,10 @@ class ThroughputMonitor(Callback):
             out["tokens_per_sec_per_chip"] = (
                 global_batch * self.tokens_per_sample / step_time / n_chips
             )
-        if self.flops_per_sample:
+        peak_tflops = detect_peak_tflops() if self.flops_per_sample else None
+        if peak_tflops:
             achieved = global_batch * self.flops_per_sample / step_time / n_chips
-            out["train_mfu"] = achieved / (detect_peak_tflops() * 1e12)
+            out["train_mfu"] = achieved / (peak_tflops * 1e12)
         return out
 
     def on_train_end(self, trainer, module) -> None:

@@ -168,6 +168,48 @@ def partition_host_chips(num_workers_on_host: int, chips_per_host: int) -> List[
     ]
 
 
+# chips of one host as the TPU runtime lays them out (x runs fastest in
+# the chip numbering): v5e/v6e hosts hold 1, 4 (2x2) or 8 (2x4) chips,
+# v4/v5p hosts 4 (2x2)
+_HOST_CHIP_GRID = {1: (1, 1), 4: (2, 2), 8: (2, 4)}
+_TPU_PROCESS_BASE_PORT = 8476
+
+
+def host_process_envs(
+    num_workers_on_host: int, chips_per_host: int
+) -> List[Dict[str, str]]:
+    """Per-worker env that makes N workers on ONE host N processes of one
+    TPU slice. ``TPU_VISIBLE_CHIPS`` alone gives each process a private
+    one-process slice (one global device, no cross-process collectives);
+    the runtime forms the group only when every process also knows the
+    process grid, its place in it and its peers' ports — the variables
+    below are the ones it reads for that."""
+    if chips_per_host not in _HOST_CHIP_GRID:
+        raise ValueError(
+            f"no known chip layout for a host of {chips_per_host} chips "
+            f"(known: {sorted(_HOST_CHIP_GRID)})"
+        )
+    chip_ids = partition_host_chips(num_workers_on_host, chips_per_host)
+    gx, gy = _HOST_CHIP_GRID[chips_per_host]
+    per = chips_per_host // num_workers_on_host
+    # a worker's consecutive chips fill rows of the host grid
+    px = min(per, gx)
+    py = per // px
+    ports = [_TPU_PROCESS_BASE_PORT + i for i in range(num_workers_on_host)]
+    addresses = ",".join(f"localhost:{p}" for p in ports)
+    return [
+        {
+            "TPU_VISIBLE_CHIPS": chip_ids[i],
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": f"{px},{py},1",
+            "TPU_PROCESS_BOUNDS": f"{gx // px},{gy // py},1",
+            "TPU_PROCESS_ADDRESSES": addresses,
+            "TPU_PROCESS_PORT": str(ports[i]),
+            "CLOUD_TPU_TASK_ID": str(i),
+        }
+        for i in range(num_workers_on_host)
+    ]
+
+
 def _wrapping_function(
     global_rank: int,
     num_workers: int,
@@ -548,16 +590,22 @@ class RayLauncher:
         if any("TPU" in d for d in demands) and any(
             len(idxs) > 1 for idxs in workers_by_node.values()
         ):
+            if len(workers_by_node) > 1:
+                raise ValueError(
+                    "workers share TPU hosts across several hosts: place "
+                    "one worker per host there (it drives all of the "
+                    "host's chips), or keep the whole group on one host"
+                )
+            # the driver can count only its own host's chips (node 0)
             chips = strategy.chips_per_host or int(
-                os.environ.get("RLT_CHIPS_PER_HOST", "4")
+                os.environ.get("RLT_CHIPS_PER_HOST")
+                or (rt.local_tpu_chips() if assignments[0] == 0 else 0)
+                or 4
             )
-            for idxs in workers_by_node.values():
-                if len(idxs) == 1:
-                    continue
-                for local_idx, chip_ids in zip(
-                    idxs, partition_host_chips(len(idxs), chips)
-                ):
-                    per_actor_env[local_idx]["TPU_VISIBLE_CHIPS"] = chip_ids
+            for rank, chip_env in zip(
+                workers_by_node[assignments[0]], host_process_envs(n, chips)
+            ):
+                per_actor_env[rank].update(chip_env)
 
         import secrets as _secrets
 

@@ -73,16 +73,13 @@ class RayExecutor:
             platforms = (
                 jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS") or ""
             )
-            if "cpu" in str(platforms).split(","):
-                # the default CPU backend refuses multiprocess computations;
-                # gloo is the transport that makes cross-process CPU
-                # collectives real (the test-path stand-in for ICI/DCN)
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo"
-                    )
-                except Exception:  # older jaxlib without the option
-                    pass
+            if str(platforms).split(",")[0] == "cpu":
+                # CPU is the platform the step runs on (not the fallback
+                # behind "tpu,cpu"): the default CPU backend refuses
+                # multiprocess computations; gloo is the transport that
+                # makes cross-process CPU collectives real (the test-path
+                # stand-in for ICI/DCN)
+                jax.config.update("jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(
                 coordinator_address=coordinator,
                 num_processes=num_processes,

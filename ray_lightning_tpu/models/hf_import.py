@@ -1,8 +1,8 @@
 """Import HuggingFace Llama checkpoints into the native param pytree.
 
 The flagship family is bit-compatible with the HF Llama architecture
-(half-split "rotate_half" rope, RMSNorm, SwiGLU MLP, GQA), so a weight
-relayout is all an import needs: torch ``[out, in]`` projections
+(half-split "rotate_half" rope, RMSNorm, SwiGLU MLP, GQA), so a change
+of weight layout is all an import needs: torch ``[out, in]`` projections
 transpose to our ``[in, out]``, per-layer tensors stack into the
 ``[L, ...]`` scanned leaves, and the config fields map one-to-one.
 Logit parity against ``transformers``' own forward is tested

@@ -17,7 +17,7 @@ event fires into one self-contained bundle under
   per-rank trace tails,
 - ``<source>.json`` — one file per registered snapshot source (arbiter
   ledger, membership ledger, request-journal summary, ...),
-- optional extra text attachments (e.g. a bench probe's log tail).
+- optional extra text attachments.
 
 Bundles are deduplicated per kind with a cooldown (``RLT_INCIDENT_COOLDOWN_S``)
 and the directory is pruned oldest-first past ``RLT_INCIDENT_MAX_BUNDLES``,
@@ -41,26 +41,16 @@ MAX_BUNDLES_ENV = "RLT_INCIDENT_MAX_BUNDLES"
 MAX_BUNDLES_DEFAULT = 16
 COOLDOWN_ENV = "RLT_INCIDENT_COOLDOWN_S"
 COOLDOWN_DEFAULT = 60.0
-# Probe-failure bundles dedup CROSS-RUN (the in-memory per-kind cooldown
-# above cannot: record_probe_failure builds a fresh recorder per bench
-# invocation, so every rerun of a persistently-broken native probe used
-# to mint a new bundle until the cap pruned real incidents). The newest
-# existing bench_probe_failed bundle's directory timestamp gates the
-# next one instead.
-PROBE_COOLDOWN_ENV = "RLT_PROBE_INCIDENT_COOLDOWN_S"
-PROBE_COOLDOWN_DEFAULT = 3600.0
 # Trailing flight-record bytes frozen into each bundle.
 EVENT_WINDOW_BYTES = 256 * 1024
 
 INCIDENTS_CAPTURED_METRIC = "rlt_incidents_captured_total"
 INCIDENTS_SUPPRESSED_METRIC = "rlt_incidents_suppressed_total"
-BENCH_PROBE_FAILURES_METRIC = "rlt_bench_probe_failures_total"
 
 # Flight-record event kinds that trip a capture. Fault verdicts and the
 # crash/relaunch path come from the supervisor/launcher; slo_breach from
 # the SLO monitor; arbiter_rollback from failed chip transfers; the
-# anomaly_* kinds from observability.anomaly; bench_probe_failed from the
-# bench orchestrator.
+# anomaly_* kinds from observability.anomaly.
 INCIDENT_EVENT_KINDS = frozenset({
     "crash",
     "hang",
@@ -68,7 +58,6 @@ INCIDENT_EVENT_KINDS = frozenset({
     "slo_breach",
     "arbiter_rollback",
     "elastic_grow_failed",
-    "bench_probe_failed",
     "anomaly_step_time",
     "anomaly_itl_p99",
     "anomaly_straggler",
@@ -90,15 +79,6 @@ def cooldown_s() -> float:
         return max(0.0, float(os.environ.get(COOLDOWN_ENV, COOLDOWN_DEFAULT)))
     except ValueError:
         return COOLDOWN_DEFAULT
-
-
-def probe_cooldown_s() -> float:
-    try:
-        return max(0.0, float(
-            os.environ.get(PROBE_COOLDOWN_ENV, PROBE_COOLDOWN_DEFAULT)
-        ))
-    except ValueError:
-        return PROBE_COOLDOWN_DEFAULT
 
 
 def _slug(kind: str) -> str:
@@ -306,58 +286,3 @@ def load_bundle(path: str) -> Dict[str, Any]:
             out["files"][name] = {"error": "unreadable"}
     return out
 
-
-def record_probe_failure(
-    run_dir: str, error: str, log_tail: str = ""
-) -> Optional[str]:
-    """Bench satellite: land a ``bench_probe_failed`` event in the flight
-    record, bump ``rlt_bench_probe_failures_total``, and capture an
-    incident bundle carrying the probe's log tail — so a timed-out native
-    probe is a first-class incident instead of a buried ``detail.error``
-    string. Standalone (no aggregator required): appends to the run
-    dir's ``events.jsonl`` directly."""
-    from . import aggregator as _aggregator  # late: avoids import cycle
-
-    try:
-        os.makedirs(run_dir, exist_ok=True)
-    except OSError:
-        return None
-    events_path = os.path.join(run_dir, _aggregator.EVENTS_FILE)
-    event = {"ts": time.time(), "event": "bench_probe_failed", "error": str(error)}
-    writer = _reqtrace.JsonlWriter(events_path)
-    try:
-        writer.write(event)
-    finally:
-        writer.close()
-    reg = _metrics_registry()
-    reg.counter(BENCH_PROBE_FAILURES_METRIC).inc()
-    # cross-run dedup: each bench invocation builds a fresh recorder, so
-    # the recorder's in-memory cooldown can never see a PREVIOUS run's
-    # bundle — gate on the newest on-disk bench_probe_failed bundle
-    # instead (its dirname timestamp is the capture time). The flight-
-    # record event and the failure counter above always land; only the
-    # duplicate bundle is suppressed.
-    window = probe_cooldown_s()
-    if window > 0:
-        newest = max(
-            (b["ts"] or 0 for b in list_bundles(run_dir)
-             if b["kind"] == "bench_probe_failed"),
-            default=None,
-        )
-        if newest is not None and time.time() - newest < window:
-            reg.counter(
-                INCIDENTS_SUPPRESSED_METRIC, kind="bench_probe_failed"
-            ).inc()
-            return None
-    rec = IncidentRecorder(run_dir, registry=reg, events_path=events_path)
-    return rec.maybe_capture(
-        "bench_probe_failed",
-        event=event,
-        attachments={"probe_log.txt": log_tail or "(no probe output captured)\n"},
-    )
-
-
-def _metrics_registry():
-    from . import metrics as _metrics
-
-    return _metrics.get_registry()

@@ -149,7 +149,6 @@ HELP: Dict[str, str] = {
     "rlt_anomaly_events_total": "Anomaly detector firings per detector.",
     "rlt_incidents_captured_total": "Incident bundles written per triggering kind.",
     "rlt_incidents_suppressed_total": "Incident captures suppressed by the per-kind cooldown.",
-    "rlt_bench_probe_failures_total": "Native bench backend probes that failed or timed out.",
     "rlt_tenant_requests_total": "Serving requests accepted per tenant (post quota/shed admission).",
     "rlt_tenant_completions_total": "Serving completions per tenant and finish reason.",
     "rlt_tenant_quota_rejected_total": "Requests refused by the tenant's token-bucket quota (distinct from shed).",

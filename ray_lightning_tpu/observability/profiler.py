@@ -85,8 +85,9 @@ _metrics.set_help(
     "measured step time, labeled by program",
 )
 
-# peak HBM bandwidth per chip, GB/s (vendor specs; same spirit as the
-# peak-TFLOPs table in callbacks/throughput.py)
+# peak HBM bandwidth per chip, GB/s (vendor specs; keyed like the
+# peak-TFLOPs table in callbacks/throughput.py, and like it an unknown
+# chip is an error)
 _PEAK_HBM_GBPS = {
     "v4": 1228.0,
     "v5e": 819.0,
@@ -94,35 +95,42 @@ _PEAK_HBM_GBPS = {
     "v5p": 2765.0,
     "v6e": 1640.0,
 }
-_DEFAULT_PEAK_GBPS = 819.0
-# rough DDR estimate so CPU smoke runs produce finite rooflines
-_CPU_PEAK_GBPS = 10.0
 
 
-def detect_peak_bandwidth_gbps() -> float:
-    """Best-effort peak HBM bandwidth (GB/s) for the local device kind.
-
-    ``RLT_PEAK_GBPS`` overrides; unknown TPU generations fall back to a
-    conservative default, CPU gets a token DDR estimate."""
+def detect_peak_bandwidth_gbps() -> Optional[float]:
+    """Peak HBM bandwidth (GB/s) of the local device kind, or None on the
+    CPU ("not measured": callers leave roofline shares out).
+    ``RLT_PEAK_GBPS`` overrides; an accelerator missing from the table
+    raises."""
     env = os.environ.get(PEAK_GBPS_ENV)
     if env:
         try:
             return float(env)
         except ValueError:
             pass
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = getattr(dev, "device_kind", "").lower()
-        if dev.platform != "tpu":
-            return _CPU_PEAK_GBPS
-        for key, gbps in _PEAK_HBM_GBPS.items():
-            if key in kind:
-                return gbps
-    except Exception:
-        return _CPU_PEAK_GBPS
-    return _DEFAULT_PEAK_GBPS
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    kind = getattr(dev, "device_kind", "").lower()
+    for key, gbps in _PEAK_HBM_GBPS.items():
+        if key in kind:
+            return gbps
+    raise ValueError(
+        f"unknown accelerator {kind!r}: no peak HBM bandwidth on record; "
+        f"set {PEAK_GBPS_ENV} to the chip's real peak"
+    )
+
+
+def _detect_peaks() -> Optional[Tuple[float, float]]:
+    """(peak FLOP/s, peak bytes/s) of the local chip, or None on the CPU."""
+    from ray_lightning_tpu.callbacks.throughput import detect_peak_tflops
+
+    tflops, gbps = detect_peak_tflops(), detect_peak_bandwidth_gbps()
+    if tflops is None or gbps is None:
+        return None
+    return tflops * 1e12, gbps * 1e9
 
 
 def cost_analysis_enabled() -> bool:
@@ -289,11 +297,17 @@ def roofline(
         peak_tflops = detect_peak_tflops()
     if peak_gbps is None:
         peak_gbps = detect_peak_bandwidth_gbps()
-    peak_flops_s = peak_tflops * 1e12
-    peak_bytes_s = peak_gbps * 1e9
     intensity = (
         report.flops / report.bytes_accessed if report.bytes_accessed else float("inf")
     )
+    if peak_tflops is None or peak_gbps is None:
+        # no chip, no peaks: the program's own counts, no machine verdict
+        return {
+            "arithmetic_intensity": round(intensity, 4),
+            "verdict": "not measured",
+        }
+    peak_flops_s = peak_tflops * 1e12
+    peak_bytes_s = peak_gbps * 1e9
     balance = peak_flops_s / peak_bytes_s
     out: Dict[str, Any] = {
         "arithmetic_intensity": round(intensity, 4),
@@ -334,8 +348,9 @@ def publish_cost_report(
             from ray_lightning_tpu.callbacks.throughput import detect_peak_tflops
 
             peak_tflops = detect_peak_tflops()
-        mfu = report.flops / step_time_s / (peak_tflops * 1e12)
-        reg.gauge(COST_MFU_METRIC, program=report.program).set(round(mfu, 6))
+        if peak_tflops:
+            mfu = report.flops / step_time_s / (peak_tflops * 1e12)
+            reg.gauge(COST_MFU_METRIC, program=report.program).set(round(mfu, 6))
 
 
 # ------------------------------------------------------------------ #
@@ -620,35 +635,30 @@ class FleetProfiler:
         """Per-step breakdown sub-spans on an "attribution" track."""
         rec = self._recorder
         rep = self._train_report()
-        if rec is None or rep is None or duration_s <= 0:
+        peaks = _detect_peaks()
+        if rec is None or rep is None or duration_s <= 0 or peaks is None:
             return
-        try:
-            from ray_lightning_tpu.callbacks.throughput import detect_peak_tflops
-
-            peak_flops_s = detect_peak_tflops() * 1e12
-            peak_bytes_s = detect_peak_bandwidth_gbps() * 1e9
-            compute_s = min(rep.flops / peak_flops_s, duration_s)
-            collective_s = min(
-                rep.collective_bytes / peak_bytes_s, duration_s - compute_s
-            )
-            wall = time.time() - duration_s
+        peak_flops_s, peak_bytes_s = peaks
+        compute_s = min(rep.flops / peak_flops_s, duration_s)
+        collective_s = min(
+            rep.collective_bytes / peak_bytes_s, duration_s - compute_s
+        )
+        wall = time.time() - duration_s
+        rec.add_span(
+            "attr/compute", wall, compute_s, step=step,
+            args={_trace.TRACK_ARG: "attribution"},
+        )
+        if collective_s > 0:
             rec.add_span(
-                "attr/compute", wall, compute_s, step=step,
-                args={_trace.TRACK_ARG: "attribution"},
+                "attr/collective", wall + compute_s, collective_s,
+                step=step, args={_trace.TRACK_ARG: "attribution"},
             )
-            if collective_s > 0:
-                rec.add_span(
-                    "attr/collective", wall + compute_s, collective_s,
-                    step=step, args={_trace.TRACK_ARG: "attribution"},
-                )
-            other = duration_s - compute_s - collective_s
-            if other > 0:
-                rec.add_span(
-                    "attr/other", wall + compute_s + collective_s, other,
-                    step=step, args={_trace.TRACK_ARG: "attribution"},
-                )
-        except Exception:
-            pass
+        other = duration_s - compute_s - collective_s
+        if other > 0:
+            rec.add_span(
+                "attr/other", wall + compute_s + collective_s, other,
+                step=step, args={_trace.TRACK_ARG: "attribution"},
+            )
 
     def attribution(
         self,
@@ -660,13 +670,12 @@ class FleetProfiler:
         n = max(1, len(samples))
         mean = sum(samples) / n
         out: Dict[str, Any] = {"steps": len(samples), "step_time_s": round(mean, 6)}
-        try:
-            from ray_lightning_tpu.callbacks.throughput import detect_peak_tflops
-
-            peak_flops_s = detect_peak_tflops() * 1e12
-            peak_bytes_s = detect_peak_bandwidth_gbps() * 1e9
-        except Exception:
+        peaks = _detect_peaks()
+        if peaks is None:
+            # no chip: the measured step time stands alone — a split
+            # against assumed peaks would be a device metric made up
             return out
+        peak_flops_s, peak_bytes_s = peaks
         rep = self._train_report()
         compute_s = rep.flops / peak_flops_s if rep else 0.0
         collective_s = rep.collective_bytes / peak_bytes_s if rep else 0.0

@@ -151,7 +151,7 @@ def _ring_modes(my, t, sp):
 # (i, 2sp-1-i) of the sequence (2sp half-chunks total): per ring step
 # EVERY device then has exactly 2 active (quarter-sized) sub-blocks —
 # perfectly balanced, ~2x faster at large sp. Rope is applied BEFORE
-# attention, so the relayout is invisible outside this op: q/k/v are
+# attention, so the layout change is invisible outside this op: q/k/v are
 # transformed in, the output transformed back, and positions/loss/rope
 # never see it.
 # --------------------------------------------------------------------- #

@@ -18,7 +18,7 @@ Every float param leaf with ``size >= min_shard_size`` ("big" leaf) is
 flattened, zero-padded to a multiple of :data:`PAD_UNIT` (256 — world
 size must divide it, which keeps the padded GLOBAL shapes identical
 across elastic resizes so sharded optimizer state hands off between
-worlds without relayout), and viewed as ``[n, c]``: rank ``r`` owns row
+worlds without a change of layout), and viewed as ``[n, c]``: rank ``r`` owns row
 ``r``. Consecutive big leaves are packed into *gather groups* of
 ``gather_group_size`` leaves; each group's shards concatenate into one
 ``[sum_c]`` vector so a group costs ONE all-gather.

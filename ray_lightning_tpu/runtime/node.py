@@ -12,7 +12,7 @@ Protocol: the agent is itself an actor served by
 host's routable interface and authenticated by a shared authkey (hex via
 ``--authkey-hex``/``RLT_NODE_AUTHKEY`` or a file). Actors it spawns bind
 ``0.0.0.0`` and are dialed *directly* by the driver at ``node_ip:port`` —
-the agent is control-plane only; no data relays through it.
+the agent is control-plane only; no data passes through it.
 """
 from __future__ import annotations
 

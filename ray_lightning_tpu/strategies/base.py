@@ -253,9 +253,10 @@ class Strategy:
     def xla_cache_dir(self) -> Optional[str]:
         """Directory of the persistent XLA compile/executable cache shared
         by the driver and every worker it spawns (see
-        ``runtime/compile_cache.py``). Constructor argument wins; otherwise
-        the ``RLT_XLA_CACHE_DIR`` env var; otherwise a per-user
-        platformdirs default. ``"0"``/``"off"`` disables (returns None)."""
+        ``runtime/compile_cache.py``). ``JAX_COMPILATION_CACHE_DIR`` wins
+        when set; then the constructor argument; then the
+        ``RLT_XLA_CACHE_DIR`` env var; otherwise ``<checkout>/.xla_cache``.
+        ``"0"``/``"off"`` disables (returns None)."""
         from ray_lightning_tpu.runtime.compile_cache import resolve_cache_dir
 
         return resolve_cache_dir(self._xla_cache_dir)

@@ -461,3 +461,103 @@ def test_relaunch_e2e_third_process_still_warm(tmp_path):
     for o in outs[1:]:
         assert o["stats"]["misses"] == 0 and o["stats"]["disk_hits"] == 1
         assert o["out"] == outs[0]["out"]
+
+
+# --------------------------------------------------------------------- #
+# where the cache lives: placed from outside, else fixed in the checkout
+# --------------------------------------------------------------------- #
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize(
+    "jax_var,rlt_var,explicit,want",
+    [
+        # JAX's own variable wins over everything below it
+        ("/placed", None, None, "/placed"),
+        ("/placed", "/rlt", "/ctor", "/placed"),
+        ("/placed", "0", "off", "/placed"),
+        # then the constructor argument, then the RLT variable
+        (None, "/rlt", "/ctor", "/ctor"),
+        (None, "/rlt", None, "/rlt"),
+        # unset: one fixed place in the checkout — no temp dir, pid or date
+        (None, None, None, os.path.join(_CHECKOUT, ".xla_cache")),
+        # explicit off switches
+        (None, "0", None, None),
+        (None, "/rlt", "off", None),
+    ],
+)
+def test_cache_dir_resolution(monkeypatch, jax_var, rlt_var, explicit, want):
+    for name, value in ((cc.JAX_CACHE_DIR_ENV, jax_var), (cc.XLA_CACHE_DIR_ENV, rlt_var)):
+        if value is None:
+            monkeypatch.delenv(name, raising=False)
+        else:
+            monkeypatch.setenv(name, value)
+    assert cc.resolve_cache_dir(explicit) == want
+    assert cc.CompileCache(cache_dir=explicit).cache_dir == want
+
+
+def test_placed_cache_dir_is_never_moved_by_code(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set JAX has read its own variable:
+    no code path may point ``jax_compilation_cache_dir`` anywhere else —
+    here, nowhere at all."""
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda name, value: updates.append((name, value))
+    )
+    monkeypatch.setenv(cc.JAX_CACHE_DIR_ENV, str(tmp_path / "placed"))
+    assert cc.configure_jax_persistent_cache("/ignored") == str(tmp_path / "placed")
+    assert "jax_compilation_cache_dir" not in dict(updates)
+
+    # unset: exactly one update, to the resolved dir
+    del updates[:]
+    monkeypatch.delenv(cc.JAX_CACHE_DIR_ENV)
+    want = str(tmp_path / "xla")  # the fixture's RLT_XLA_CACHE_DIR
+    assert cc.configure_jax_persistent_cache() == want
+    assert [v for n, v in updates if n == "jax_compilation_cache_dir"] == [want]
+    assert os.path.isdir(want)
+
+    # disabled: JAX's cache is left alone
+    del updates[:]
+    monkeypatch.setenv(cc.XLA_CACHE_DIR_ENV, "off")
+    assert cc.configure_jax_persistent_cache() is None and updates == []
+
+
+def test_worker_env_carries_only_an_explicit_cache_dir(monkeypatch):
+    """Workers inherit JAX_COMPILATION_CACHE_DIR / RLT_XLA_CACHE_DIR and
+    resolve like the driver; only a ctor-level dir has to travel."""
+    import ray_lightning_tpu as rlt
+
+    monkeypatch.delenv(cc.JAX_CACHE_DIR_ENV, raising=False)
+    assert cc.XLA_CACHE_DIR_ENV not in rlt.RayStrategy(num_workers=2).worker_env()
+    env = rlt.RayStrategy(num_workers=2, xla_cache_dir="/ctor").worker_env()
+    assert env[cc.XLA_CACHE_DIR_ENV] == "/ctor"
+    env = rlt.RayStrategy(num_workers=2, xla_cache_dir="off").worker_env()
+    assert env[cc.XLA_CACHE_DIR_ENV] == "0"
+    # placed from outside: the strategy reports that dir, whatever it was given
+    monkeypatch.setenv(cc.JAX_CACHE_DIR_ENV, "/placed")
+    assert rlt.RayStrategy(num_workers=2, xla_cache_dir="/ctor").xla_cache_dir == "/placed"
+    assert cc.JAX_CACHE_DIR_ENV not in rlt.RayStrategy(num_workers=2).worker_env()
+
+
+def test_actor_process_writes_its_cache_where_it_is_placed(tmp_path):
+    """A fresh interpreter with only JAX's variable set: both layers — JAX's
+    persistent cache and the .rltx entries — land there and nowhere else."""
+    placed = tmp_path / "placed"
+    code = (
+        "import os, jax, jax.numpy as jnp\n"
+        "from ray_lightning_tpu.runtime import compile_cache as cc\n"
+        "d = cc.configure_jax_persistent_cache()\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "cc.get_cache().get_or_compile(jax.jit(lambda x: jnp.tanh(x) * 2), jnp.ones((8, 8)), program='p')\n"
+        "assert jax.config.jax_compilation_cache_dir == d == os.environ['JAX_COMPILATION_CACHE_DIR'], d\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != cc.XLA_CACHE_DIR_ENV}
+    env.update({cc.JAX_CACHE_DIR_ENV: str(placed), "JAX_PLATFORMS": "cpu",
+                "PYTHONPATH": _CHECKOUT, "HOME": str(tmp_path / "home")})
+    subprocess.run([sys.executable, "-c", code], env=env, cwd=str(tmp_path),
+                   check=True, timeout=240)
+    names = os.listdir(placed)
+    assert any(n.endswith(".rltx") for n in names), names
+    assert any(n.endswith("-cache") or "jit_" in n for n in names), names
+    # nothing of ours under a user cache dir (third parties write there)
+    assert not os.path.exists(tmp_path / "home" / ".cache" / "ray_lightning_tpu")

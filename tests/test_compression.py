@@ -437,7 +437,6 @@ def test_two_process_dcn_reduction(tmp_path):
             + [p for p in (os.environ.get("PYTHONPATH"),) if p]
         ),
     }
-    env.pop("RLT_TEST_ON_TPU", None)
     script = _WORKER % {"port": port}
     procs = [
         subprocess.Popen(

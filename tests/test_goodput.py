@@ -253,55 +253,6 @@ def test_incident_cooldown_dedup_and_prune(tmp_path):
     assert bundles[-1]["kind"] == "crash"
 
 
-def test_record_probe_failure_is_a_first_class_incident(tmp_path):
-    run_dir = str(tmp_path / "telemetry")
-    incidents_mod.record_probe_failure(
-        run_dir, "timeout after 600s", log_tail="last stderr line"
-    )
-    events = [json.loads(ln) for ln in open(os.path.join(run_dir, "events.jsonl"))]
-    assert events[-1]["event"] == "bench_probe_failed"
-    bundles = incidents_mod.list_bundles(run_dir)
-    assert len(bundles) == 1 and bundles[0]["kind"] == "bench_probe_failed"
-    tail = open(os.path.join(bundles[0]["path"], "probe_log.txt")).read()
-    assert "last stderr line" in tail
-    reg = metrics_mod.get_registry()
-    assert any(
-        name == incidents_mod.BENCH_PROBE_FAILURES_METRIC and m.value >= 1
-        for (name, _), m in reg.items()
-    )
-
-
-def test_probe_failure_bundles_dedup_across_runs(tmp_path, monkeypatch):
-    """Satellite regression: each bench invocation builds a FRESH
-    recorder, so the in-memory cooldown can't dedup a persistently
-    broken probe across runs — the newest on-disk bundle's timestamp
-    must gate the next capture instead."""
-    run_dir = str(tmp_path / "telemetry")
-    # first run captures; the second (fresh-process semantics: this call
-    # builds its own recorder) lands inside the default 1h window
-    assert incidents_mod.record_probe_failure(run_dir, "boom 1") is not None
-    assert incidents_mod.record_probe_failure(run_dir, "boom 2") is None
-    assert len(incidents_mod.list_bundles(run_dir)) == 1
-    # the flight record still carries BOTH failures
-    events = [
-        json.loads(ln)
-        for ln in open(os.path.join(run_dir, "events.jsonl"))
-    ]
-    assert [e["event"] for e in events[-2:]] == ["bench_probe_failed"] * 2
-
-    # 0 disables the cross-run gate (the per-kind in-memory cooldown
-    # still applies within one recorder, but this is a new one)
-    monkeypatch.setenv(incidents_mod.PROBE_COOLDOWN_ENV, "0")
-    assert incidents_mod.record_probe_failure(run_dir, "boom 3") is not None
-    assert len(incidents_mod.list_bundles(run_dir)) == 2
-
-    # an aged-out bundle stops gating: shrink the window under the
-    # bundle's age instead of faking directory timestamps
-    monkeypatch.setenv(incidents_mod.PROBE_COOLDOWN_ENV, "0.0001")
-    time.sleep(0.01)
-    assert incidents_mod.record_probe_failure(run_dir, "boom 4") is not None
-
-
 # --------------------------------------------------------------------- #
 # anomaly detection
 # --------------------------------------------------------------------- #
@@ -657,7 +608,6 @@ def test_new_observability_metrics_have_doc_rows():
         anomaly_mod.ANOMALY_EVENTS_METRIC,
         incidents_mod.INCIDENTS_CAPTURED_METRIC,
         incidents_mod.INCIDENTS_SUPPRESSED_METRIC,
-        incidents_mod.BENCH_PROBE_FAILURES_METRIC,
     ):
         assert name in rows, f"{name} missing from the docs metric table"
         assert name in emitted, f"{name} lost its emission site"

@@ -135,7 +135,6 @@ def node_agent():
     authkey = secrets.token_bytes(16)
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["RLT_FORCE_JAX_PLATFORM"] = "cpu"
     proc = subprocess.Popen(
         [
             sys.executable, "-m", "ray_lightning_tpu.runtime.node",
@@ -433,3 +432,47 @@ def test_client_mode_fit(node_agent, tmp_root):
         # don't leave a client-mode runtime (0-CPU local node + soon-dead
         # agent) behind for later tests
         rt.shutdown()
+
+
+def test_host_process_envs_form_one_process_grid():
+    """Four one-chip workers on a 2x2 host: disjoint chips, one process
+    grid, each process its own port and task id (checked on four v5e chips:
+    with TPU_VISIBLE_CHIPS alone every process is a one-device slice)."""
+    from ray_lightning_tpu.launchers.ray_launcher import host_process_envs
+
+    envs = host_process_envs(4, 4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    assert len({e["TPU_PROCESS_ADDRESSES"] for e in envs}) == 1
+    ports = [e["TPU_PROCESS_PORT"] for e in envs]
+    assert len(set(ports)) == 4
+    assert envs[0]["TPU_PROCESS_ADDRESSES"] == ",".join(
+        f"localhost:{p}" for p in ports
+    )
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+
+
+@pytest.mark.parametrize(
+    "workers,chips,per_process,grid",
+    [
+        (2, 4, "2,1,1", "1,2,1"),  # each worker one row of the 2x2
+        (2, 8, "2,2,1", "1,2,1"),  # each worker half of the 2x4
+        (4, 8, "2,1,1", "1,4,1"),
+        (8, 8, "1,1,1", "2,4,1"),
+        (1, 1, "1,1,1", "1,1,1"),
+    ],
+)
+def test_host_process_envs_split_the_host_grid(workers, chips, per_process, grid):
+    from ray_lightning_tpu.launchers.ray_launcher import host_process_envs
+
+    envs = host_process_envs(workers, chips)
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {per_process}
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {grid}
+
+
+def test_host_process_envs_refuse_an_unknown_host():
+    from ray_lightning_tpu.launchers.ray_launcher import host_process_envs
+
+    with pytest.raises(ValueError, match="no known chip layout"):
+        host_process_envs(3, 6)

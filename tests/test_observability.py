@@ -403,16 +403,28 @@ def test_detect_peak_tflops_env_override(monkeypatch):
 
     monkeypatch.setenv("RLT_PEAK_TFLOPS", "123.5")
     assert detect_peak_tflops() == 123.5
+    # a bad override is ignored, and the CPU has no peak: "not measured"
     monkeypatch.setenv("RLT_PEAK_TFLOPS", "not-a-number")
-    assert detect_peak_tflops() == 0.1  # CPU estimate, override ignored
+    assert detect_peak_tflops() is None
     monkeypatch.setenv("RLT_PEAK_TFLOPS", "-3")
-    assert detect_peak_tflops() == 0.1
+    assert detect_peak_tflops() is None
 
 
-def test_throughput_monitor_publishes_gauges():
+def test_throughput_monitor_publishes_gauges(monkeypatch):
     from ray_lightning_tpu.callbacks.throughput import ThroughputMonitor
 
     obs.enable()
+    assert ThroughputMonitor(flops_per_sample=1e9).summary(None) == {}
+    mon = ThroughputMonitor(flops_per_sample=1e9)
+    mon._times = [0.1]
+    mon._batch_size = 8
+
+    class _T:
+        world_size = 1
+
+    # on the CPU a utilization is not measured: no MFU key, no gauge
+    assert "train_mfu" not in mon.summary(_T())
+    monkeypatch.setenv("RLT_PEAK_TFLOPS", "197")
     mon = ThroughputMonitor(flops_per_sample=1e9)
     mon._times = [0.1]
     mon._batch_size = 8

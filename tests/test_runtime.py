@@ -79,3 +79,68 @@ def test_queue_tunnel(counter_actor):
         assert q.empty()
     finally:
         q.shutdown()
+
+
+def _fake_host(monkeypatch, functions, nodes):
+    """A host whose PCI functions (``vendor:device``) and device nodes are
+    the given lists."""
+    import builtins
+    import fnmatch
+    import io
+
+    from ray_lightning_tpu.runtime import api
+
+    files = {}
+    for i, function in enumerate(functions):
+        vendor, device = function.split(":")
+        files[f"/sys/bus/pci/devices/{i}/vendor"] = vendor
+        files[f"/sys/bus/pci/devices/{i}/device"] = device
+
+    def fake_glob(pattern):
+        if pattern.startswith("/sys/bus/pci"):
+            return sorted(f for f in files if f.endswith("/vendor"))
+        return [n for n in nodes if fnmatch.fnmatchcase(n, pattern)]
+
+    real_open = builtins.open
+    monkeypatch.setattr(api.glob, "glob", fake_glob)
+    monkeypatch.setattr(
+        builtins, "open",
+        lambda path, *a, **k: io.StringIO(files[path] + "\n")
+        if path in files else real_open(path, *a, **k),
+    )
+
+
+_V5E, _V4, _GVNIC = "0x1ae0:0x0063", "0x1ae0:0x005e", "0x1ae0:0x0042"
+
+
+@pytest.mark.parametrize(
+    "functions,nodes,chips",
+    [
+        # the one-chip v5e machine: four TPU functions, one VFIO group
+        ([_V5E] * 4 + ["0x8086:0x1237"], ["/dev/vfio/0", "/dev/vfio/vfio"], 1),
+        ([_V5E] * 4, [f"/dev/vfio/{i}" for i in range(4)] + ["/dev/vfio/vfio"], 4),
+        ([_V4] * 4, [f"/dev/accel{i}" for i in range(4)], 4),
+        # VFIO groups of something else are not chips: another vendor's, or
+        # a Google function that is no TPU (every GCE host has a gVNIC)
+        (["0x10de:0x2330", "0x8086:0x1237"], ["/dev/vfio/0"], 0),
+        ([_GVNIC], ["/dev/vfio/0"], 0),
+        ([_GVNIC, _V5E], ["/dev/vfio/0", "/dev/vfio/1"], 1),
+        ([], [], 0),
+    ],
+    ids=["v5e-1", "v5e-4-vfio", "v4-accel", "other-vfio", "gvnic-vfio",
+         "gvnic-beside-a-chip", "bare"],
+)
+def test_tpu_chips_are_read_from_the_host_not_the_environment(
+    monkeypatch, functions, nodes, chips
+):
+    """The TPU resource must not hinge on how JAX_PLATFORMS is spelled, nor
+    on asking jax (the driver would take the chip from its workers)."""
+    from ray_lightning_tpu.runtime import api
+
+    _fake_host(monkeypatch, functions, nodes)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert api.local_tpu_chips() == chips
+    assert api._local_default_resources() == ({"TPU": 1.0} if chips else {})
+    # and the old spelling no longer conjures a chip
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert api.local_tpu_chips() == chips

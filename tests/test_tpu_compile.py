@@ -1,0 +1,213 @@
+"""What the chip's compiler accepts, checked without the chip.
+
+The TPU compiler is installed here and compiles for a v5e that is described,
+not attached (``on-chip-measurement`` guide, section 2): every Pallas kernel
+of the train and serve main paths at real widths, and the data-parallel train
+step on the 2x2 mesh. Interpret mode on the CPU cannot see what these see — a
+block not aligned to the (8,128) tiling, a scalar store to VMEM, a kernel
+GSPMD cannot partition — and each of those stopped a program before its
+first chip run.
+
+The topology is described inside a module fixture, never at import (one
+process at a time may load the TPU library; see the guide), every compile
+runs in this process, and JAX's persistent cache is off around them: a
+compile for a described chip is written to it but cannot be read back.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+import bench
+from ray_lightning_tpu.models.llama import (
+    LlamaConfig,
+    init_params,
+    lm_loss,
+    shardings_for_mesh,
+)
+from ray_lightning_tpu.ops import paged_attention as pa
+from ray_lightning_tpu.ops.attention import attention
+from ray_lightning_tpu.ops.rmsnorm import _rmsnorm_pallas
+
+SMALL = LlamaConfig.small()
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _custom_calls(fn, *shapes, sharding):
+    """Compile ``fn`` for the described chip; count its Mosaic kernels."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call")
+
+
+# q [B, Hq, S, hd] / kv [B, Hkv, S, hd] of LlamaConfig.small() at batch 8
+_Q = ((8, SMALL.n_heads, SMALL.max_seq, SMALL.head_dim), jnp.bfloat16)
+_KV = ((8, SMALL.n_kv_heads, SMALL.max_seq, SMALL.head_dim), jnp.bfloat16)
+
+
+def _flash(window=None):
+    return lambda q, k, v: attention(
+        q, k, v, causal=True, impl="flash", interpret=False, window=window
+    )
+
+
+@pytest.mark.parametrize(
+    "name,fn,shapes,kernels",
+    [
+        ("fwd", _flash(), (_Q, _KV, _KV), 1),
+        (
+            "fwd+bwd",
+            jax.grad(lambda q, k, v: _flash()(q, k, v).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2)),
+            (_Q, _KV, _KV),
+            3,  # forward, dQ, dK/dV
+        ),
+        ("window", _flash(window=512), (_Q, _KV, _KV), 1),
+        # head_dim 64 is padded to the 128-lane tile inside the op
+        ("head_dim64", _flash(), (((8, 12, 512, 64), jnp.bfloat16),) * 3, 1),
+    ],
+    ids=["fwd", "fwd+bwd", "window", "head_dim64"],
+)
+def test_flash_attention_compiles(one_chip, name, fn, shapes, kernels):
+    assert _custom_calls(fn, *shapes, sharding=one_chip) == kernels
+
+
+@pytest.mark.parametrize(
+    "blocks", bench.FLASH_BLOCK_CANDIDATES, ids=lambda b: f"{b[0]}x{b[1]}"
+)
+def test_autotune_block_candidates_compile(one_chip, blocks):
+    """``bench.py`` times fwd+bwd at each of these and a refused one fails
+    its run, so each is compiled here first."""
+    def loss(q, k, v):
+        return attention(
+            q, k, v, causal=True, impl="flash", interpret=False,
+            block_q=blocks[0], block_k=blocks[1],
+        ).astype(jnp.float32).sum()
+
+    n = _custom_calls(
+        jax.grad(loss, argnums=(0, 1, 2)), _Q, _KV, _KV, sharding=one_chip
+    )
+    assert n == 3
+
+
+@pytest.mark.parametrize(
+    "shape", [(8 * 2048, 2048), (8, 2048, 2048)], ids=["2d", "3d"]
+)
+def test_rmsnorm_compiles(one_chip, shape):
+    n = _custom_calls(
+        lambda x, w: _rmsnorm_pallas(x, w, 1e-6),
+        (shape, jnp.bfloat16), ((shape[-1],), jnp.bfloat16), sharding=one_chip,
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_paged_decode_attention_compiles(one_chip, block_size):
+    slots, hkv, group, hd = 8, SMALL.n_kv_heads, SMALL.n_heads // SMALL.n_kv_heads, SMALL.head_dim
+    n = _custom_calls(
+        lambda q, k, v, bt, pos: pa.paged_decode_attention(
+            q, k, v, bt, pos, interpret=False
+        ),
+        ((slots, hkv, group, hd), jnp.bfloat16),
+        ((1024, hkv, block_size, hd), jnp.bfloat16),
+        ((1024, hkv, block_size, hd), jnp.bfloat16),
+        ((slots, 2048 // block_size), jnp.int32),
+        ((slots,), jnp.int32),
+        sharding=one_chip,
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("vocab", [32000, 128256])
+@pytest.mark.parametrize("rows", [8, 32], ids=["slots", "slots_x_k"])
+def test_greedy_sampler_compiles(one_chip, rows, vocab, dtype):
+    """[num_slots, V] and the speculative verify's [num_slots*K, V]; neither
+    vocab is a multiple of the kernel's block (the ragged tail is masked)."""
+    n = _custom_calls(
+        lambda x: pa.fused_greedy_sample(x, interpret=False),
+        ((rows, vocab), dtype), sharding=one_chip,
+    )
+    assert n == 1
+
+
+def test_single_row_sampler_compiles(one_chip):
+    n = _custom_calls(
+        lambda x: pa.fused_greedy_sample(x, interpret=False),
+        ((1, 32000), jnp.float32), sharding=one_chip,
+    )
+    assert n == 1
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("vocab", [32000, 128256])
+def test_temperature_sampler_compiles(one_chip, vocab, dtype):
+    """The gumbel noise is drawn in the logits' dtype, as ``categorical``
+    draws it, so bf16 is a program of its own."""
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    logits = jax.ShapeDtypeStruct((8, vocab), dtype, sharding=one_chip)
+    text = (
+        jax.jit(lambda x, k: pa.fused_sample(x, k, 0.8, interpret=False))
+        .lower(logits, key).compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize(
+    "axes",
+    [{"dp": 4}, {"dp": 2, "tp": 2}, {"fsdp": 4}, {"dp": 2, "sp": 2}],
+    ids=["dp4", "dp2-tp2", "fsdp4", "dp2-sp2"],
+)
+def test_sharded_train_step_compiles_on_the_mesh(topo, monkeypatch, axes):
+    """``lm_loss`` and its gradient at small's widths (depth cut to 2) over
+    the four chips, parameters placed as ``shardings_for_mesh`` places them.
+    The ops (``ops/attention.py``, ``ops/rmsnorm.py``) ask ``jax.devices()``
+    whether their kernels are native, so the test answers with the described
+    chips; the model wraps them per shard on every mesh and asks nothing.
+    GSPMD cannot partition a Mosaic kernel: without the wrap in
+    ``models/llama.py`` this compile is refused."""
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    cfg = dataclasses.replace(SMALL, n_layers=2)
+    mesh = Mesh(np.array(topo.devices).reshape(tuple(axes.values())), tuple(axes))
+    params = jax.tree_util.tree_map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(0)),
+        shardings_for_mesh(cfg, mesh),
+    )
+    rows = tuple(a for a in ("dp", "fsdp") if a in axes)
+    tokens = jax.ShapeDtypeStruct(
+        (32, cfg.max_seq), jnp.int32,
+        sharding=NamedSharding(mesh, P(rows, "sp" if "sp" in axes else None)),
+    )
+    text = (
+        jax.jit(jax.grad(lambda p, t: lm_loss(p, t, cfg, mesh)[0]))
+        .lower(params, tokens).compile().as_text()
+    )
+    assert text.count("tpu_custom_call") > 0
+    assert "all-reduce" in text or "reduce-scatter" in text  # grads cross chips
