@@ -64,6 +64,19 @@ class TrainerState:
         return {"fn": self.fn or "", "status": self.status}
 
 
+def _spanned_pulls(batches):
+    """``batches``, with every pull of the next item inside an
+    ``rlt.train.input_wait`` span: the time the loop waits for its input."""
+    it = iter(batches)
+    while True:
+        with obs.phase_span("rlt.train.input_wait"):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
 @dataclass
 class _EpochAggregator:
     """Accumulates per-batch on_epoch metrics as device scalars; reduces at
@@ -933,8 +946,8 @@ class Trainer:
         )
         # distinct program name: its cost report (and the profiler's
         # collective attribution) must not collide with "train_step"
-        return _compile_cache.wrap(
-            jax.jit(mapped, donate_argnums=(0, 1)), "zero_train_step"
+        return _compile_cache.jit_program(
+            mapped, "zero_train_step", donate_argnums=(0, 1)
         )
 
     # ------------------------------------------------------------------ #
@@ -1146,8 +1159,8 @@ class Trainer:
                     new_params = optax.apply_updates(params, updates)
                 return new_params, new_opt_state, {"loss": loss}
 
-        return _compile_cache.wrap(
-            jax.jit(train_step, donate_argnums=(0, 1)), program
+        return _compile_cache.jit_program(
+            train_step, program, donate_argnums=(0, 1)
         )
 
     def _stack_ef_residual(self, opt_state):
@@ -1262,8 +1275,8 @@ class Trainer:
         # first dispatch resolves through the shared executable cache:
         # an elastic resize back to a seen topology, or a relaunch on a
         # warm cache dir, skips XLA entirely (runtime/compile_cache.py)
-        return _compile_cache.wrap(
-            jax.jit(mapped, donate_argnums=(0, 1)), "train_step"
+        return _compile_cache.jit_program(
+            mapped, "train_step", donate_argnums=(0, 1)
         )
 
     # ------------------------------------------------------------------ #
@@ -1338,8 +1351,8 @@ class Trainer:
             with matmul_precision_scope(mp):
                 return _step_body(params, opt_state, batch, rng_root, step)
 
-        return _compile_cache.wrap(
-            jax.jit(train_step, donate_argnums=(0, 1)), "train_step"
+        return _compile_cache.jit_program(
+            train_step, "train_step", donate_argnums=(0, 1)
         )
 
     def _build_alternating_train_step(self):
@@ -1406,8 +1419,8 @@ class Trainer:
             )
             return params, tuple(new_states), logs_all
 
-        return _compile_cache.wrap(
-            jax.jit(train_step, donate_argnums=(0, 1)), "train_step"
+        return _compile_cache.jit_program(
+            train_step, "train_step", donate_argnums=(0, 1)
         )
 
     def _build_eval_step(self, phase: str):
@@ -1432,7 +1445,7 @@ class Trainer:
                     logs.setdefault(k, jnp.asarray(v))
             return logs
 
-        return _compile_cache.wrap(jax.jit(eval_step), f"{phase}_step")
+        return _compile_cache.jit_program(eval_step, f"{phase}_step")
 
     # ------------------------------------------------------------------ #
     # fit implementation (runs on driver, or inside a worker actor)
@@ -2201,8 +2214,9 @@ class Trainer:
         # pulling the next batch: input wait until the body reclassifies
         if led is not None:
             led.enter("input_wait")
-        for batch_idx, batch, device_batch in self._prefetch_shard(
-            train_loader, limit_train
+        # rlt.train.*: the step's phases on the profiler's clock
+        for batch_idx, batch, device_batch in _spanned_pulls(
+            self._prefetch_shard(train_loader, limit_train)
         ):
             if led is not None:
                 led.enter(
@@ -2215,17 +2229,20 @@ class Trainer:
                 if prof is not None:
                     prof.before_step(self.global_step, device_batch)
             self._health_tick(train=True)
-            self._cb("on_train_batch_start", batch, batch_idx)
-            self._params, self._opt_state, logs = train_step(
-                self._params,
-                self._opt_state,
-                device_batch,
-                self._rng_root,
-                np.int32(self.global_step),
-            )
-            batch_size = self._batch_size_of(batch)
-            self._record_train_logs(logs, aggregator, batch_size)
-            self._cb("on_train_batch_end", logs, batch, batch_idx)
+            with obs.phase_span("rlt.train.callbacks", hook="batch_start"):
+                self._cb("on_train_batch_start", batch, batch_idx)
+            with obs.phase_span("rlt.train.step", step=self.global_step):
+                self._params, self._opt_state, logs = train_step(
+                    self._params,
+                    self._opt_state,
+                    device_batch,
+                    self._rng_root,
+                    np.int32(self.global_step),
+                )
+                batch_size = self._batch_size_of(batch)
+                self._record_train_logs(logs, aggregator, batch_size)
+            with obs.phase_span("rlt.train.callbacks", hook="batch_end"):
+                self._cb("on_train_batch_end", logs, batch, batch_idx)
             self.global_step += 1
             n_batches += 1
             if rec is not None or prof is not None:

@@ -31,6 +31,7 @@ from .trace import (  # noqa: F401  (re-exported API)
     get_recorder,
     maybe_enable_from_env,
     merge_traces,
+    phase_span,
     span,
 )
 
@@ -50,6 +51,7 @@ __all__ = [
     "maybe_enable_from_env",
     "merge_traces",
     "metrics",
+    "phase_span",
     "profiler",
     "registry",
     "reqtrace",

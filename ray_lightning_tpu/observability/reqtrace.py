@@ -267,7 +267,8 @@ class RequestTrace:
     __slots__ = (
         "request_id", "prompt_len", "max_new_tokens", "replica",
         "submitted_wall", "_submitted", "_admitted", "_first_deferred",
-        "deferred_ticks", "prefill_s", "_prefill_done", "_first_token",
+        "deferred_ticks", "prefill_s", "prefill_synced", "_prefill_done",
+        "_first_token",
         "_last_token", "tokens", "token_stamps", "slot",
         "hbm_bytes_in_use", "retries", "hop", "parent_rid",
         "origin_replica", "pool", "ctx_components", "ctx_sent_wall",
@@ -314,6 +315,7 @@ class RequestTrace:
         self._first_deferred: Optional[float] = None
         self.deferred_ticks = 0
         self.prefill_s: Optional[float] = None
+        self.prefill_synced = True
         self._prefill_done: Optional[float] = None
         self._first_token: Optional[float] = None
         self._last_token: Optional[float] = None
@@ -339,9 +341,20 @@ class RequestTrace:
             if stats:
                 self.hbm_bytes_in_use = sum(s["bytes_in_use"] for s in stats)
 
-    def prefilled(self, duration_s: float) -> None:
+    def prefilled(
+        self,
+        duration_s: float,
+        done_at: Optional[float] = None,
+        synced: bool = True,
+    ) -> None:
+        """The prefill took ``duration_s`` and was done at ``done_at`` (now,
+        when omitted). ``synced`` says what kind of instant that is: the
+        tick's sampling sync, the first time the host knows the device has
+        finished the prefill, or (False) only the enqueue, on a tick that
+        waited for nothing."""
         self.prefill_s = float(duration_s)
-        self._prefill_done = time.perf_counter()
+        self.prefill_synced = bool(synced)
+        self._prefill_done = time.perf_counter() if done_at is None else done_at
 
     def token(self) -> None:
         now = time.perf_counter()
@@ -531,7 +544,8 @@ class RequestTrace:
                 "req/prefill",
                 self._wall(self._prefill_done - self.prefill_s),
                 self.prefill_s,
-                args={trace.TRACK_ARG: track, "prompt_len": self.prompt_len},
+                args={trace.TRACK_ARG: track, "prompt_len": self.prompt_len,
+                      "synced": self.prefill_synced},
             )
         if self._first_token is not None:
             end = self._last_token or self._first_token

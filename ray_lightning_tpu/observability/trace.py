@@ -21,6 +21,7 @@ by the skew estimated from heartbeat send/receive timestamps
 from __future__ import annotations
 
 import os
+import sys
 import time
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
@@ -177,6 +178,47 @@ def span(name: str, step: Optional[int] = None, **args):
     if rec is None:
         return NOOP_SPAN
     return _Span(rec, name, step, args or None)
+
+
+class _Both:
+    """A profiler annotation and a ring span opened and closed together."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, ring_span: _Span):
+        self._ann = ann
+        self._span = ring_span
+
+    def __enter__(self) -> "_Both":
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._span.__exit__(exc_type, exc, tb)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def phase_span(name: str, **args):
+    """``with phase_span("rlt.serve.schedule"): ...``: a layer boundary
+    of the engine tick or the train step, on the profiler's clock.
+
+    Where JAX is already imported in this process the span is a
+    ``jax.profiler.TraceAnnotation(name, **args)``: it lands in the host
+    plane of a ``jax.profiler`` trace beside the device's events, and is
+    inert (one object built) while no trace is being taken. JAX is never
+    imported from here, so a launcher's parent stays off it. While the ring
+    recorder is on, the same name and arguments are recorded there too.
+    Names start with ``rlt.``; arguments are ints or short strings."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return span(name, **args)
+    ann = jax.profiler.TraceAnnotation(name, **args)
+    rec = _recorder
+    if rec is None:
+        return ann
+    return _Both(ann, _Span(rec, name, None, args or None))
 
 
 def event(name: str, step: Optional[int] = None, **args) -> None:

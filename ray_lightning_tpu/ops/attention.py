@@ -402,6 +402,7 @@ def _flash_fwd(q, k, v, causal, scale, interpret, blocks=None, window=None):
             pltpu.VMEM((bq, 128), jnp.float32),  # running sum (lane-replicated)
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -440,6 +441,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, interpret, blocks=None,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dK/dV: grid over KV heads with the GQA group folded into the
@@ -473,6 +475,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, interpret, blocks=None,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

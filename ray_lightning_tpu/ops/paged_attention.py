@@ -200,6 +200,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hkv, gp, hd), jnp.float32),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q, k_cache, v_cache)
     return out[:, :, :group] if gp != group else out
@@ -280,6 +281,7 @@ def _blockwise_argmax(x: jnp.ndarray, noise: Optional[jnp.ndarray],
             pltpu.VMEM((bb, 1), jnp.int32),    # its index
         ],
         interpret=interpret,
+        name="fused_argmax" if noise is None else "fused_sample",
     )(*args)
     return out[:, 0]
 

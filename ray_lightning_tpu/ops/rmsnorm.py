@@ -45,6 +45,7 @@ def _rmsnorm_pallas(x, weight, eps: float, block_rows: int = 256):
             (block_rows, dim), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((rows, dim), x.dtype),
+        name="rmsnorm",
     )(x2, weight)
     return out.reshape(orig_shape)
 

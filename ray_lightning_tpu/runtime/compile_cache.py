@@ -42,6 +42,7 @@ the disk layer stays on there.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import os
@@ -693,3 +694,20 @@ def wrap(fn, program: str, cache: Optional[CompileCache] = None):
     if not enabled():
         return fn
     return CachedProgram(fn, program, cache=cache)
+
+
+def jit_program(fn, program: str, **jit_kwargs):
+    """``jax.jit(fn, **jit_kwargs)`` under the program's label, then
+    :func:`wrap`. The label is the one place that names a compiled program:
+    the cache's metrics carry it, and the module is ``jit_<program>``, which
+    is what a device trace's ``XLA Modules`` line and the HLO show. The name
+    is a constant of the call site (never a counter or an id), so the lowered
+    text, and with it every cache key, is the same from run to run."""
+    import jax
+
+    @functools.wraps(fn)
+    def labelled(*args):
+        return fn(*args)
+
+    labelled.__name__ = labelled.__qualname__ = program
+    return wrap(jax.jit(labelled, **jit_kwargs), program)

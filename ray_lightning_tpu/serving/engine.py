@@ -442,6 +442,15 @@ class InferenceEngine:
             # the mean accepted-tokens-per-slot-tick the bench reports
             "accepted_tokens": 0,
             "spec_row_ticks": 0,
+            # loop-time sums, in seconds (sums only: a reader subtracts the
+            # value before its window from the value after). tick_s is the
+            # wall time of step(), sync_wait_s the part of it spent waiting
+            # for the sampled tokens, loop_wait_s the loop thread's time
+            # between ticks: tick_s + loop_wait_s is that thread's wall time
+            "ticks": 0,
+            "tick_s": 0.0,
+            "sync_wait_s": 0.0,
+            "loop_wait_s": 0.0,
         }
         self._build_compiled()
 
@@ -604,22 +613,22 @@ class InferenceEngine:
                 sampled = sample(logits.reshape(S * K, V), key).reshape(S, K)
                 return sampled.astype(jnp.int32), cache["k"], cache["v"]
 
-            self._prefill_fn = _compile_cache.wrap(
-                jax.jit(_with_precision(prefill_into_paged)), "serve_prefill"
+            self._prefill_fn = _compile_cache.jit_program(
+                _with_precision(prefill_into_paged), "serve_prefill"
             )
-            self._decode_fn = _compile_cache.wrap(
-                jax.jit(_with_precision(
+            self._decode_fn = _compile_cache.jit_program(
+                _with_precision(
                     decode_verify_paged if spec_k > 0 else decode_paged
-                )), "serve_decode"
+                ), "serve_decode"
             )
         else:
-            self._prefill_fn = _compile_cache.wrap(
-                jax.jit(_with_precision(prefill_into)), "serve_prefill"
+            self._prefill_fn = _compile_cache.jit_program(
+                _with_precision(prefill_into), "serve_prefill"
             )
-            self._decode_fn = _compile_cache.wrap(
-                jax.jit(_with_precision(
+            self._decode_fn = _compile_cache.jit_program(
+                _with_precision(
                     decode_verify if spec_k > 0 else decode
-                )), "serve_decode"
+                ), "serve_decode"
             )
 
     def _program_specs(self):
@@ -835,7 +844,21 @@ class InferenceEngine:
         """Run one scheduler tick: up to N prefills + one batched decode.
 
         Returns ``{"prefills": int, "decoded": int, "completed": [ids]}``.
-        Call from a single thread only (the loop thread, or the test)."""
+        Call from a single thread only (the loop thread, or the test).
+
+        The tick and its phases are ``rlt.serve.*`` spans on the profiler's
+        clock (``observability.phase_span``), and its wall time and its one
+        wait for the device are summed into ``stats`` (``ticks``, ``tick_s``,
+        ``sync_wait_s``) whether or not anything is being traced."""
+        t0 = time.perf_counter()
+        try:
+            with _obs.phase_span("rlt.serve.tick", tick=self._ticks + 1):
+                return self._run_tick()
+        finally:
+            self.stats["ticks"] += 1
+            self.stats["tick_s"] += time.perf_counter() - t0
+
+    def _run_tick(self) -> Dict[str, Any]:
         import jax
         import jax.numpy as jnp
 
@@ -847,29 +870,33 @@ class InferenceEngine:
         # and dies, which is exactly the replica death the journal and
         # breakers must recover from
         _faults.fire_serve_tick_faults(self.replica_index, self._ticks)
-        self._process_export_actions()
-        self._process_imports()
-        self._evict_expired_slots()
-        plan = self.scheduler.tick()
+        with _obs.phase_span("rlt.serve.schedule"):
+            self._process_export_actions()
+            self._process_imports()
+            self._evict_expired_slots()
+            plan = self.scheduler.tick()
         ecfg = self.engine_config
         ck, cv = self.pool.cache["k"], self.pool.cache["v"]
 
         new_exports: List[str] = []
+        # (trace, dispatch start, dispatch end) of this tick's prefills:
+        # their duration is known at the tick's sync, not at the enqueue
+        prefill_traces: List[tuple] = []
         paged = self.kv_layout == "paged"
         for req, slot in plan.prefills:
-            self._admit_seq += 1
-            fspec = _faults.serve_request_fault(
-                self.replica_index, self._admit_seq
-            )
-            if fspec is not None and fspec.kind == "drop-stream":
-                self._drop_stream[req.request_id] = max(
-                    1, int(fspec.arg or 1)
+            with _obs.phase_span("rlt.serve.prefill", prompt_len=req.prompt_len):
+                self._admit_seq += 1
+                fspec = _faults.serve_request_fault(
+                    self.replica_index, self._admit_seq
                 )
-            padded = np.zeros((1, ecfg.max_prompt_len), np.int32)
-            padded[0, : req.prompt_len] = req.tokens
-            tr = req.trace
-            t0 = time.perf_counter() if tr is not None else 0.0
-            with _obs.span("serve_prefill", prompt_len=req.prompt_len):
+                if fspec is not None and fspec.kind == "drop-stream":
+                    self._drop_stream[req.request_id] = max(
+                        1, int(fspec.arg or 1)
+                    )
+                padded = np.zeros((1, ecfg.max_prompt_len), np.int32)
+                padded[0, : req.prompt_len] = req.tokens
+                tr = req.trace
+                t0 = time.perf_counter() if tr is not None else 0.0
                 if paged:
                     wt = self.pool.prompt_write_table(
                         slot.index, self._n_prompt_blocks
@@ -883,25 +910,25 @@ class InferenceEngine:
                         self.params, ck, cv, jnp.asarray(padded),
                         jnp.int32(slot.index),
                     )
-            if tr is not None:
-                tr.prefilled(time.perf_counter() - t0)
-            slot.pos = req.prompt_len - 1
-            slot.pending_token = req.tokens[-1]
-            if self._speculate_k > 0:
-                self._history[req.request_id] = list(req.tokens)
-            if self._role == "prefill":
-                # park the slot for migration: pin its prefix chains NOW
-                # (engine thread — serialized with every other allocator
-                # op) so a sibling release can't drop them to refcount 0
-                # and have them evicted while the shipment is in flight
-                slot.export_pending = True
-                pinned = self.pool.allocator.pin_request(req.request_id)
-                self._exports[req.request_id] = {
-                    "slot": slot.index, "pinned": pinned,
-                    "prompt": tuple(req.tokens),
-                }
-                new_exports.append(req.request_id)
-            self.stats["prefills"] += 1
+                if tr is not None:
+                    prefill_traces.append((tr, t0, time.perf_counter()))
+                slot.pos = req.prompt_len - 1
+                slot.pending_token = req.tokens[-1]
+                if self._speculate_k > 0:
+                    self._history[req.request_id] = list(req.tokens)
+                if self._role == "prefill":
+                    # park the slot for migration: pin its prefix chains NOW
+                    # (engine thread — serialized with every other allocator
+                    # op) so a sibling release can't drop them to refcount 0
+                    # and have them evicted while the shipment is in flight
+                    slot.export_pending = True
+                    pinned = self.pool.allocator.pin_request(req.request_id)
+                    self._exports[req.request_id] = {
+                        "slot": slot.index, "pinned": pinned,
+                        "prompt": tuple(req.tokens),
+                    }
+                    new_exports.append(req.request_id)
+                self.stats["prefills"] += 1
 
         # export-pending slots are parked: their KV is in flight to a
         # decode replica, so this engine must not decode them — not even
@@ -934,125 +961,112 @@ class InferenceEngine:
 
         completed: List[str] = []
         K = self._speculate_k
-        if decode_slots and K > 0:
-            # speculative tick: every row carries its pending token plus
-            # up to K-1 prompt-lookup proposals; rows with no proposal
-            # (or at the end of their budget) ride the same fixed-shape
-            # program with padded columns that are sampled and discarded
-            token = np.zeros((self.pool.num_slots, K), np.int32)
-            pos = np.zeros((self.pool.num_slots,), np.int32)
-            proposals: Dict[int, List[int]] = {}
-            for slot in decode_slots:
-                rid = slot.request_id
-                # budget: a row may deliver at most `remaining` tokens
-                # this tick, so propose at most remaining-1 — also what
-                # keeps every speculative write inside the blocks the
-                # paged allocator reserved at admission
-                remaining = slot.max_new_tokens - slot.generated
-                props = ngram_propose(
-                    self._history.get(rid, ()), min(K - 1, remaining - 1)
+        if decode_slots:
+            rows = len(decode_slots)
+            with _obs.phase_span("rlt.serve.decode_prep", rows=rows):
+                # speculative tick (K > 0): every row carries its pending
+                # token plus up to K-1 prompt-lookup proposals; rows with no
+                # proposal (or at the end of their budget) ride the same
+                # fixed-shape program with padded columns that are sampled
+                # and discarded
+                token = np.zeros(
+                    (self.pool.num_slots, K) if K > 0 else (self.pool.num_slots,),
+                    np.int32,
                 )
-                proposals[slot.index] = props
-                if paged:
-                    # on-demand growth must cover the deepest speculative
-                    # write position, not just slot.pos (a host-side
-                    # table-value change, never a shape change)
-                    self.pool.ensure_writable(
-                        slot, upto_pos=slot.pos + len(props)
-                    )
-                token[slot.index, 0] = slot.pending_token
-                for j, p in enumerate(props):
-                    token[slot.index, 1 + j] = p
-                pos[slot.index] = slot.pos
-            self._rng, sub = jax.random.split(self._rng)
-            with _obs.span("serve_decode"):
-                if paged:
-                    sampled, ck, cv = self._decode_fn(
-                        self.params, ck, cv, jnp.asarray(token),
-                        jnp.asarray(pos),
-                        jnp.asarray(block_tables), sub,
-                    )
-                else:
-                    sampled, ck, cv = self._decode_fn(
-                        self.params, ck, cv, jnp.asarray(token),
-                        jnp.asarray(pos), sub,
-                    )
-                sampled_host = np.asarray(sampled)  # the per-step sync point
-            now = time.perf_counter()
-            reg = _obs.registry()
-            for slot in decode_slots:
-                rid = slot.request_id
-                if rid is None:
-                    # released mid-step (re-entrant shutdown from an
-                    # on_token callback): nothing to deliver
-                    continue
-                out = sampled_host[slot.index]
-                props = proposals.get(slot.index, [])
-                # greedy accept: out[j] is the model's token AFTER
-                # consuming proposals[:j]; the first mismatch both ends
-                # the accepted prefix AND contributes its correction —
-                # so at least one token always lands, same as k=0
-                accepted = 1
-                for j, p in enumerate(props):
-                    if int(out[j]) == int(p):
-                        accepted += 1
+                pos = np.zeros((self.pool.num_slots,), np.int32)
+                proposals: Dict[int, List[int]] = {}
+                for slot in decode_slots:
+                    if K > 0:
+                        # budget: a row may deliver at most `remaining`
+                        # tokens this tick, so propose at most remaining-1 —
+                        # also what keeps every speculative write inside the
+                        # blocks the paged allocator reserved at admission
+                        remaining = slot.max_new_tokens - slot.generated
+                        props = ngram_propose(
+                            self._history.get(slot.request_id, ()),
+                            min(K - 1, remaining - 1),
+                        )
+                        proposals[slot.index] = props
+                        token[slot.index, 0] = slot.pending_token
+                        for j, p in enumerate(props):
+                            token[slot.index, 1 + j] = p
                     else:
-                        break
-                before = self.stats["tokens_out"]
-                for j in range(accepted):
-                    if not self._deliver_token(
-                        slot, rid, int(out[j]), now, reg, completed
-                    ):
-                        break
-                delivered = int(self.stats["tokens_out"] - before)
-                self.stats["spec_row_ticks"] += 1
-                self.stats["accepted_tokens"] += delivered
-                if delivered > 0 and reg is not None:
-                    reg.histogram(
-                        "rlt_serve_accepted_tokens",
-                        bounds=ACCEPTED_BOUNDS,
-                    ).observe(float(delivered), exemplar=rid)
-            self.stats["decode_steps"] += 1
-            self.stats["busy_slot_steps"] += len(decode_slots)
-        elif decode_slots:
-            token = np.zeros((self.pool.num_slots,), np.int32)
-            pos = np.zeros((self.pool.num_slots,), np.int32)
-            for slot in decode_slots:
+                        props = ()
+                        token[slot.index] = slot.pending_token
+                    if paged:
+                        # on-demand growth: the block holding the deepest
+                        # write position (slot.pos, or the last speculative
+                        # one) must be physical before the compiled scatter
+                        # writes it (a host-side table-value change, never a
+                        # shape change)
+                        self.pool.ensure_writable(
+                            slot, upto_pos=slot.pos + len(props)
+                        )
+                    pos[slot.index] = slot.pos
+                self._rng, sub = jax.random.split(self._rng)
+                inputs = [jnp.asarray(token), jnp.asarray(pos)]
                 if paged:
-                    # on-demand growth: the block holding slot.pos must be
-                    # physical before the compiled scatter writes it (a
-                    # host-side table-value change, never a shape change)
-                    self.pool.ensure_writable(slot)
-                token[slot.index] = slot.pending_token
-                pos[slot.index] = slot.pos
-            self._rng, sub = jax.random.split(self._rng)
-            with _obs.span("serve_decode"):
-                if paged:
-                    sampled, ck, cv = self._decode_fn(
-                        self.params, ck, cv, jnp.asarray(token),
-                        jnp.asarray(pos),
-                        jnp.asarray(block_tables), sub,
-                    )
-                else:
-                    sampled, ck, cv = self._decode_fn(
-                        self.params, ck, cv, jnp.asarray(token),
-                        jnp.asarray(pos), sub,
-                    )
+                    inputs.append(jnp.asarray(block_tables))
+            with _obs.phase_span("rlt.serve.decode_dispatch"):
+                sampled, ck, cv = self._decode_fn(
+                    self.params, ck, cv, *inputs, sub
+                )
+            t_sync = time.perf_counter()
+            with _obs.phase_span(
+                "rlt.serve.sample_sync", prefills=len(plan.prefills)
+            ):
                 sampled_host = np.asarray(sampled)  # the per-step sync point
             now = time.perf_counter()
-            reg = _obs.registry()
-            for slot in decode_slots:
-                rid = slot.request_id
-                if rid is None:
-                    # released mid-step (re-entrant shutdown from an
-                    # on_token callback): nothing to deliver
-                    continue
-                self._deliver_token(
-                    slot, rid, int(sampled_host[slot.index]), now, reg,
-                    completed,
-                )
+            self.stats["sync_wait_s"] += now - t_sync
+            # the first instant the host knows this tick's prefills are done
+            for tr, t0, _ in prefill_traces:
+                tr.prefilled(now - t0, done_at=now)
+            with _obs.phase_span("rlt.serve.deliver", rows=rows):
+                reg = _obs.registry()
+                for slot in decode_slots:
+                    rid = slot.request_id
+                    if rid is None:
+                        # released mid-step (re-entrant shutdown from an
+                        # on_token callback): nothing to deliver
+                        continue
+                    if K == 0:
+                        self._deliver_token(
+                            slot, rid, int(sampled_host[slot.index]), now,
+                            reg, completed,
+                        )
+                        continue
+                    out = sampled_host[slot.index]
+                    # greedy accept: out[j] is the model's token AFTER
+                    # consuming proposals[:j]; the first mismatch both ends
+                    # the accepted prefix AND contributes its correction —
+                    # so at least one token always lands, same as k=0
+                    accepted = 1
+                    for j, p in enumerate(proposals.get(slot.index, [])):
+                        if int(out[j]) == int(p):
+                            accepted += 1
+                        else:
+                            break
+                    before = self.stats["tokens_out"]
+                    for j in range(accepted):
+                        if not self._deliver_token(
+                            slot, rid, int(out[j]), now, reg, completed
+                        ):
+                            break
+                    delivered = int(self.stats["tokens_out"] - before)
+                    self.stats["spec_row_ticks"] += 1
+                    self.stats["accepted_tokens"] += delivered
+                    if delivered > 0 and reg is not None:
+                        reg.histogram(
+                            "rlt_serve_accepted_tokens",
+                            bounds=ACCEPTED_BOUNDS,
+                        ).observe(float(delivered), exemplar=rid)
             self.stats["decode_steps"] += 1
-            self.stats["busy_slot_steps"] += len(decode_slots)
+            self.stats["busy_slot_steps"] += rows
+        else:
+            # no decode, so no sync this tick (a prefill-role replica whose
+            # slots are all parked): all the host knows is the enqueue
+            for tr, t0, t1 in prefill_traces:
+                tr.prefilled(t1 - t0, done_at=t1, synced=False)
 
         self.pool.cache = {"k": ck, "v": cv}
         if new_exports:
@@ -1557,20 +1571,35 @@ class InferenceEngine:
     def _loop(self) -> None:
         led = self._goodput
         while True:
-            with self._work:
-                while not self.scheduler.has_work():
-                    if self._pending_imports or self._export_actions:
-                        break  # migration work needs a tick even when idle
-                    if self._stop_when_idle:
-                        return
-                    if led is not None:
-                        led.enter("idle")
-                    self._work.wait(timeout=0.05)
+            # everything between two ticks is loop wait, so that tick_s +
+            # loop_wait_s is this thread's wall time
+            t_wait = time.perf_counter()
+            try:
+                with self._work:
+                    if not self._tick_due():
+                        with _obs.phase_span("rlt.serve.wait_work"):
+                            while not self._tick_due():
+                                if self._stop_when_idle:
+                                    return
+                                if led is not None:
+                                    led.enter("idle")
+                                self._work.wait(timeout=0.05)
+            finally:
+                self.stats["loop_wait_s"] += time.perf_counter() - t_wait
             try:
                 self.step()
             except Exception as e:  # fail every in-flight request loudly
                 self._fail_all(e)
                 return
+
+    def _tick_due(self) -> bool:
+        """Under ``self._work``: the scheduler has work, or migration work
+        needs a tick even when it is idle."""
+        return bool(
+            self.scheduler.has_work()
+            or self._pending_imports
+            or self._export_actions
+        )
 
     def _fail_all(self, error: BaseException) -> None:
         self.failed = error

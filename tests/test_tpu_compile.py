@@ -211,3 +211,150 @@ def test_sharded_train_step_compiles_on_the_mesh(topo, monkeypatch, axes):
     )
     assert text.count("tpu_custom_call") > 0
     assert "all-reduce" in text or "reduce-scatter" in text  # grads cross chips
+
+
+# --------------------------------------------------------------------- #
+# names that reach the device trace: a kernel's custom call is the HLO
+# instruction %<name>.N whatever scope it was traced in, and that
+# instruction's name is what a trace's XLA Ops line shows
+# --------------------------------------------------------------------- #
+def _kernel_instructions(text):
+    """Kernels of the compiled program's Mosaic custom calls, as the
+    benchmark reads them off an instruction's name."""
+    import re
+
+    from benchmarks.program_trace import kernel_of
+
+    return sorted(
+        kernel_of("custom-call %" + m.group(1))
+        for m in re.finditer(
+            r"%(\S+) = [^\n]*custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"",
+            text,
+        )
+    )
+
+
+def _compiled_text(fn, *shapes, sharding):
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_loss(q, k, v):
+    return _flash()(q, k, v).astype(jnp.float32).sum()
+
+
+def _in_a_closure_named_wrapped(fn):
+    def wrapped(*args):
+        return fn(*args)
+
+    return wrapped
+
+
+_PAGED = (
+    ((8, 4, 2, 128), jnp.bfloat16),
+    ((256, 4, 16, 128), jnp.bfloat16),
+    ((256, 4, 16, 128), jnp.bfloat16),
+    ((8, 16), jnp.int32),
+    ((8,), jnp.int32),
+)
+
+
+@pytest.mark.parametrize(
+    "fn,shapes,names",
+    [
+        (_flash(), (_Q, _KV, _KV), ["flash_fwd"]),
+        (
+            # no checkpoint round the kernels: the compiler wraps the names
+            # in the transformations (%jvp_flash_fwd_.1), which kernel_of
+            # takes off again
+            jax.grad(_flash_loss, argnums=(0, 1, 2)),
+            (_Q, _KV, _KV),
+            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
+        ),
+        (
+            jax.grad(jax.checkpoint(_flash_loss), argnums=(0, 1, 2)),
+            (_Q, _KV, _KV),
+            ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"],
+        ),
+        (
+            _in_a_closure_named_wrapped(jax.jit(_flash())),
+            (_Q, _KV, _KV),
+            ["flash_fwd"],
+        ),
+        (
+            lambda x, w: _rmsnorm_pallas(x, w, 1e-6),
+            (((8 * 2048, 2048), jnp.bfloat16), ((2048,), jnp.bfloat16)),
+            ["rmsnorm"],
+        ),
+        (
+            lambda q, k, v, bt, pos: pa.paged_decode_attention(
+                q, k, v, bt, pos, interpret=False
+            ),
+            _PAGED,
+            ["paged_decode_attention"],
+        ),
+        (
+            lambda x: pa.fused_greedy_sample(x, interpret=False),
+            (((8, 32000), jnp.float32),),
+            ["fused_argmax"],
+        ),
+    ],
+    ids=["flash_fwd", "flash_bwd", "checkpoint", "inner_jit_in_wrapped",
+         "rmsnorm", "paged_decode", "argmax"],
+)
+def test_kernels_keep_their_names_in_the_compiled_program(
+    one_chip, fn, shapes, names
+):
+    text = _compiled_text(fn, *shapes, sharding=one_chip)
+    assert _kernel_instructions(text) == names
+
+
+def test_temperature_sampler_is_named_fused_sample(one_chip):
+    key = jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip)
+    logits = jax.ShapeDtypeStruct((8, 32000), jnp.float32, sharding=one_chip)
+    text = (
+        jax.jit(lambda x, k: pa.fused_sample(x, k, 0.8, interpret=False))
+        .lower(logits, key).compile().as_text()
+    )
+    assert _kernel_instructions(text) == ["fused_sample"]
+
+
+def test_engine_programs_carry_their_labels_and_kernel_names(
+    one_chip, topo, monkeypatch
+):
+    """The paged engine's two programs compiled for the chip are the modules
+    ``jit_serve_prefill`` / ``jit_serve_decode``, and the decode program's
+    Mosaic calls are the named kernels: once a layer the paged attention,
+    the norms, the sampler. The engine is built on the CPU with the kernel
+    knob forced on; the ops ask ``jax.devices()`` at trace time whether to
+    interpret, so the test answers with the described chip there."""
+    from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
+
+    monkeypatch.setenv("RLT_PAGED_KERNEL", "1")
+    cfg = LlamaConfig(
+        vocab_size=2048, dim=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        ffn_dim=512, max_seq=256, remat=False,
+    )
+    engine = InferenceEngine(
+        init_params(jax.random.key(0), cfg), cfg,
+        EngineConfig(num_slots=4, max_prompt_len=64, max_len=128,
+                     kv_layout="paged"),
+    )
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    texts = {}
+    for name, fn, args in engine._program_specs():
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            args,
+        )
+        texts[name] = fn.lower(*shapes).compile().as_text()
+    assert texts["serve_prefill"].startswith("HloModule jit_serve_prefill")
+    assert texts["serve_decode"].startswith("HloModule jit_serve_decode")
+    decode = _kernel_instructions(texts["serve_decode"])
+    assert set(decode) == {"paged_decode_attention", "rmsnorm", "fused_argmax"}
+    assert "wrapped" not in " ".join(decode)
+    assert set(_kernel_instructions(texts["serve_prefill"])) <= {
+        "flash_fwd", "rmsnorm"}
