@@ -458,9 +458,13 @@ def decode_step_paged(
     path. ``None`` (default) defers to ``paged_kernel_enabled()``
     (env ``RLT_PAGED_KERNEL``; off on CPU unless forced, so the default
     CPU path stays byte-identical to the pre-kernel implementation).
-    The kernel's flash-style accumulation reorders float adds, so logits
-    agree to float tolerance and greedy tokens agree exactly — the
-    parity the serving tests pin.
+    The gather path materializes [B, Hkv, max_blocks * block_size, hd]
+    a layer whatever the rows hold; the kernel reads each row's live
+    pages only, whole ``[Hkv, bs, hd]`` pages a copy and a few hundred
+    tokens a step, so its time follows the live context and not
+    ``num_slots * max_len``. Its flash-style accumulation reorders float
+    adds (per group of pages), so logits agree to float tolerance and
+    greedy tokens agree exactly — the parity the serving tests pin.
     """
     from ray_lightning_tpu.ops.paged_attention import (
         paged_decode_attention,

@@ -1,16 +1,43 @@
 """Pallas TPU kernels for the paged serving decode hot path.
 
-``decode_step_paged`` (models/generation.py) is memory-bound: per step it
-gathers every row's referenced KV blocks into logical order
-(``k_cache[block_tables]`` — a full [B, Hkv, C, hd] materialization) and
-then runs a masked matvec that reads most of that gather exactly once.
-The fused kernel here walks the block table IN-KERNEL instead: the table
-and the row positions ride in as scalar-prefetch operands, each grid
-step DMAs one physical block directly from the paged cache, and a
-flash-style online softmax accumulates the attention output — the
-gathered intermediate never exists, and blocks past a row's position are
-neither computed (``pl.when``) nor fetched (the index map clamps to the
-last active block, re-referencing the resident block so the DMA elides).
+``decode_step_paged`` (models/generation.py) attends, a layer, one query
+position of every row over that row's pages of the paged pool. Its lax
+path gathers every row's whole table into logical order
+(``k_cache[block_tables]`` — a full [B, Hkv, C, hd] materialization,
+whatever the rows hold) and runs a masked matvec over it. The fused
+kernel here walks the block table IN-KERNEL instead: the table and the
+row positions ride in as scalar-prefetch operands, the pools stay in
+HBM, and a flash-style online softmax accumulates the output, so the
+gathered intermediate never exists.
+
+How it walks is what its time depends on. A grid step costs a fixed
+0.2-0.35 us on the chip whether it does anything or not, and a copy
+costs about as much to issue as 4 KB costs to move, so the kernel is
+built to take few steps and large copies, and none for dead context:
+
+- the grid is one step a row; inside it a loop runs over the row's live
+  groups of ``P`` table columns only (``pos[b] // (P * bs) + 1`` of
+  them), ``P`` chosen from the static shapes (``_pages_per_step``) so
+  that a group is about 256 tokens;
+- the pool keeps a physical page as one contiguous ``[Hkv, bs, hd]``
+  slab, so one hand-started copy brings K of a page for ALL KV heads and
+  one brings V; a group is ``2 * P`` such copies into one half of a
+  double buffer, and the next group's — at a row's end the next row's
+  first group's — are in flight while this one is computed;
+- a group is computed for all KV heads at once (head-batched matmuls
+  over ``[Hkv, P * bs, hd]``), scores, probabilities and accumulator in
+  float32; positions past ``pos[b]`` are masked to -inf and their V rows
+  selected to zero, so nothing past ``pos[b]`` reaches the result,
+  whatever the pages there hold.
+
+Measured on a v5e at the serve cells' shapes (32 rows, 8 KV heads of 4
+queries, head 128, bf16 pages of 16, 160 columns; PERF.md, PR 24): 0.11
+ms a layer for 24 live rows of a chat mix, 0.46 ms for 32 rows of 2,250
+tokens (79 % of what the K/V bytes alone take at 819 GB/s). The kernel
+it replaces took a grid step per (row, head, page) of the table's width
+— 40,960 a layer at those shapes, over 90 % of them dead — and 7.4 and
+15.9 ms for the same two mixes: it was bound by step overhead, not by
+bytes.
 
 Also here: a fused top-of-logits sampling kernel. Greedy sampling is a
 blockwise argmax over the vocab (per-row running max + first-max index
@@ -70,58 +97,133 @@ def paged_kernel_enabled() -> bool:
 # --------------------------------------------------------------------- #
 # fused paged decode attention
 # --------------------------------------------------------------------- #
+# tokens of one row a group of pages holds (P * block_size): long enough
+# that a group's matmuls and its 2 * P copies outweigh its fixed cost,
+# short enough that a 250-token row or a free slot wastes little of the
+# one group it has. On the v5e at the serve cells' shapes 128 / 256 / 512
+# / 1024 took 0.12 / 0.11 / 0.15 / 0.24 ms a layer on a chat mix and
+# 0.56 / 0.46 / 0.46 / 0.52 on 32 long rows (PERF.md, PR 24)
+_STEP_TOKENS = 256
+# both K and V, double-buffered; a quarter of the v5e's default 16 MiB
+# scoped VMEM, so no raised vmem_limit_bytes
+_KV_SCRATCH_BYTES = 4 * 1024 * 1024
+
+
+def _pages_per_step(n_cols, hkv, bs, hd, dtype) -> int:
+    """Table columns (pages) a step fetches and computes: about
+    ``_STEP_TOKENS`` tokens, no wider than the table, and small enough
+    that the four page buffers fit ``_KV_SCRATCH_BYTES``."""
+    page_bytes = hkv * bs * hd * jnp.dtype(dtype).itemsize
+    p = max(1, min(n_cols, _STEP_TOKENS // bs))
+    while p > 1 and 4 * p * page_bytes > _KV_SCRATCH_BYTES:
+        p //= 2
+    return p
+
+
 def _paged_decode_kernel(
-    bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_scr, m_scr, l_scr,
-    *, scale, block_size, n_blocks,
+    bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+    k_buf, v_buf, sems, slot_ref, acc_scr, m_scr, l_scr,
+    *, scale, block_size, n_cols,
 ):
+    """One row a grid step: walk the row's live page groups, the next
+    group's (or the next row's first group's) copies in flight while this
+    one is computed for all KV heads at once."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    n_rows = pl.num_programs(0)
+    hkv, pages = k_buf.shape[1:3]
+    step_tokens = pages * block_size
     pos_b = pos_ref[b]
+    # groups holding at least one valid position; group 0 always does
+    n_live = pos_b // step_tokens + 1
 
-    @pl.when(j == 0)
-    def _init():
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
+    def copies(row, group, half):
+        """The 2 * P page copies of one (row, group) into buffer ``half``:
+        a page is one contiguous [Hkv, bs, hd] slab of the pool, laid head
+        by head into the buffer's [Hkv, P * bs, hd]. Every page of a live
+        group is fetched (the table is trash-padded, so each column names
+        a page); columns past the table's width, where P does not divide
+        it, re-read its last column. Whatever lies past ``pos`` is masked
+        below."""
+        out = []
+        for i in range(pages):
+            page = bt_ref[row, jnp.minimum(group * pages + i, n_cols - 1)]
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[page], k_buf.at[half, :, i], sems.at[0, half]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[half, :, i], sems.at[1, half]))
+        return out
 
-    # a block is active when it holds at least one valid position; its
-    # first position (j * bs) valid means every row of the score block
-    # has a finite column, so -inf masking stays nan-safe
-    @pl.when(j * block_size <= pos_b)
-    def _update():
-        q = q_ref[:].astype(jnp.float32)  # [Gp, hd]
-        ks = k_ref[:].astype(jnp.float32)  # [bs, hd]
-        vs = v_ref[:].astype(jnp.float32)
+    @pl.when(b == 0)
+    def _prime():
+        slot_ref[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+    def group_step(g, half):
+        other = 1 - half
+
+        # what is computed next: this row's next live group, else the
+        # next row's first one
+        row_ends = g + 1 == n_live
+
+        @pl.when(jnp.logical_or(~row_ends, b + 1 < n_rows))
+        def _prefetch():
+            for c in copies(
+                jnp.where(row_ends, jnp.minimum(b + 1, n_rows - 1), b),
+                jnp.where(row_ends, 0, g + 1),
+                other,
+            ):
+                c.start()
+
+        for c in copies(b, g, half):
+            c.wait()
+
+        ks = k_buf[half].reshape(hkv, step_tokens, -1)
+        vs = v_buf[half].reshape(hkv, step_tokens, -1)
+        # the queries are values of the pool's dtype (the rope returns
+        # its input's dtype), so every product is exact
         s = (
             jax.lax.dot_general(
-                q, ks, (((1,), (1,)), ((), ())),
+                q_ref[:].astype(ks.dtype), ks,
+                (((2,), (2,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32,
             )
             * scale
-        )  # [Gp, bs]
-        cols = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
+        )  # [Hkv, Gp, T]
+        base = g * step_tokens
+        cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        # the group's first position is valid, so every row of the score
+        # block has a finite column and -inf masking stays nan-safe
         s = jnp.where(cols <= pos_b, s, -jnp.inf)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+            l_scr[:, :, :1] * alpha + jnp.sum(p, axis=2, keepdims=True),
             l_scr.shape,
         )
+        # a page past pos // bs may hold anything, NaN bits too, and
+        # 0 * NaN is NaN: its V rows are selected to zero, not only its
+        # scores to -inf
+        rows = base + jax.lax.broadcasted_iota(jnp.int32, vs.shape, 1)
+        vs = jnp.where(rows <= pos_b, vs.astype(jnp.float32), 0.0)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, vs, (((1,), (0,)), ((), ())),
+            p, vs, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        return other
 
-    @pl.when(j == n_blocks - 1)
-    def _finalize():
-        o_ref[:] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+    slot_ref[0] = jax.lax.fori_loop(0, n_live, group_step, slot_ref[0])
+    o_ref[:] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -138,67 +240,78 @@ def paged_decode_attention(
 
     q: [B, Hkv, G, hd] (GQA-folded queries, one position per row);
     k_cache / v_cache: [N, Hkv, bs, hd] paged pools; block_tables:
-    [B, max_blocks] int32 (trash-padded); pos: [B] int32 per-row
-    positions. Returns fp32 [B, Hkv, G, hd] — the softmax(QK^T)V of each
-    row over its logical positions [0, pos[b]], identical math to the
-    gather path in ``decode_step_paged`` (flash accumulation order, so
-    float-exact only per block; token-level parity is what the serving
-    tests pin).
+    [B, max_blocks] int32 (trash-padded: every column names a page of
+    the pool); pos: [B] int32 per-row positions. Returns fp32
+    [B, Hkv, G, hd] — the softmax(QK^T)V of each row over its logical
+    positions [0, pos[b]], identical math to the gather path in
+    ``decode_step_paged`` (flash accumulation order, so float-exact only
+    per group; token-level parity is what the serving tests pin). The
+    score matmul takes its operands in the pool's dtype (exact for
+    queries that are values of that dtype, as the caller's are), scores,
+    probabilities and the accumulator are float32.
 
-    Grid is (B, Hkv, max_blocks) with the table and positions as
-    scalar-prefetch operands: the KV index map resolves logical block j
-    to ``block_tables[b, min(j, pos[b] // bs)]`` — physical gather
-    without materializing [B, Hkv, C, hd], and the clamp parks inactive
-    steps on the already-resident block so their DMA elides.
+    Grid is (B,): a step is one row, and inside it a loop runs over the
+    row's LIVE page groups only (``pos[b] // (P * bs) + 1`` of them, P
+    from ``_pages_per_step``). The pools stay in HBM; a group is 2 * P
+    hand-started copies of whole ``[Hkv, bs, hd]`` pages, addressed from
+    the scalar-prefetched table, into one half of a double buffer, while
+    the other half is computed for all KV heads. Nothing past the last
+    live group is fetched, and nothing past ``pos[b]`` reaches the
+    result: the kernel's time follows the live context, not
+    ``B * max_blocks``.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, hkv, group, hd = q.shape
     bs = k_cache.shape[2]
-    n_blocks = block_tables.shape[1]
+    n_cols = block_tables.shape[1]
     if interpret is None:
         interpret = _interpret_default()
     scale = sm_scale if sm_scale is not None else 1.0 / np.sqrt(hd)
-    # pad the GQA group up to the sublane tile so tiny models (G < 8)
-    # keep TPU-legal shapes; padded rows compute masked garbage that is
-    # sliced off below
-    gp = max(group, 8)
+    # pad the GQA group up to the pool dtype's sublane tile (8 rows of
+    # float32, 16 of bfloat16) so the query block cast to that dtype is
+    # a TPU-legal matmul operand; padded rows compute masked garbage
+    # that is sliced off below
+    sublanes = 32 // jnp.dtype(k_cache.dtype).itemsize
+    gp = -(-group // sublanes) * sublanes
     if gp != group:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    pages = _pages_per_step(n_cols, hkv, bs, hd, k_cache.dtype)
 
-    def kv_idx(b, h, j, bt_ref, pos_ref):
-        jj = jnp.minimum(j, pos_ref[b] // bs)
-        return bt_ref[b, jj], h, 0, 0
-
+    row_block = pl.BlockSpec(
+        (None, hkv, gp, hd), lambda b, bt_ref, pos_ref: (b, 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, hkv, n_blocks),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec(
-                (None, None, gp, hd),
-                lambda b, h, j, bt_ref, pos_ref: (b, h, 0, 0),
-            ),
-            pl.BlockSpec((None, None, bs, hd), kv_idx),
-            pl.BlockSpec((None, None, bs, hd), kv_idx),
+            row_block,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (None, None, gp, hd),
-            lambda b, h, j, bt_ref, pos_ref: (b, h, 0, 0),
-        ),
+        out_specs=row_block,
         scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),   # acc
-            pltpu.VMEM((gp, 128), jnp.float32),  # running max (lane-repl.)
-            pltpu.VMEM((gp, 128), jnp.float32),  # running sum (lane-repl.)
+            pltpu.VMEM((2, hkv, pages, bs, hd), k_cache.dtype),
+            pltpu.VMEM((2, hkv, pages, bs, hd), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),       # (K | V, buffer half)
+            pltpu.SMEM((1,), jnp.int32),           # half the next wait reads
+            pltpu.VMEM((hkv, gp, hd), jnp.float32),   # acc
+            pltpu.VMEM((hkv, gp, 128), jnp.float32),  # running max (lane-repl.)
+            pltpu.VMEM((hkv, gp, 128), jnp.float32),  # running sum (lane-repl.)
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel,
-            scale=scale, block_size=bs, n_blocks=n_blocks,
+            scale=scale, block_size=bs, n_cols=n_cols,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hkv, gp, hd), jnp.float32),
+        # rows run in order: the double buffer is handed from row to row
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),

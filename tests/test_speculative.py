@@ -328,6 +328,28 @@ def test_paged_kernel_env_knob():
             assert pa.paged_kernel_enabled() is False
 
 
+def _gather_softmax_reference(q, k_cache, v_cache, tables, pos):
+    """Plain gather + mask + softmax in float32 numpy: the logical cache
+    rows of every row of the batch, whatever page they sit in. A V row
+    past ``pos`` is taken as zero, as its probability is."""
+    q, tables, pos = np.asarray(q, np.float32), np.asarray(tables), np.asarray(pos)
+    k_cache = np.asarray(k_cache.astype(jnp.float32))
+    v_cache = np.asarray(v_cache.astype(jnp.float32))
+    B, Hkv, _, hd = q.shape
+    C = tables.shape[1] * k_cache.shape[2]
+    # [B, maxb, Hkv, bs, hd] -> [B, Hkv, C, hd]
+    kg = k_cache[tables].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, C, hd)
+    vg = v_cache[tables].transpose(0, 2, 1, 3, 4).reshape(B, Hkv, C, hd)
+    mask = (np.arange(C)[None, :] <= pos[:, None])[:, None, :]
+    vg = np.where(mask[..., None], vg, 0.0)
+    with np.errstate(invalid="ignore"):
+        s = np.einsum("bhgd,bhtd->bhgt", q, kg) / np.sqrt(hd)
+    s = np.where(mask[:, :, None, :], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("bhgt,bhtd->bhgd", p, vg)
+
+
 def test_paged_decode_attention_matches_lax_gather():
     """The Pallas kernel vs the plain gather+softmax reference: same
     argmax everywhere, logits equal to float tolerance (online-softmax
@@ -349,25 +371,118 @@ def test_paged_decode_attention_matches_lax_gather():
     out = pa.paged_decode_attention(
         q, k_cache, v_cache, tables, pos, interpret=True
     )
-
-    # lax reference: gather the logical cache rows, mask, softmax
-    C = maxb * bs
-    phys = np.asarray(tables)
-    kg = np.asarray(k_cache)[phys].transpose(0, 2, 1, 3, 4).reshape(
-        B, Hkv, C, hd
-    )  # [B, maxb, Hkv, bs, hd] -> [B, Hkv, C, hd]
-    vg = np.asarray(v_cache)[phys].transpose(0, 2, 1, 3, 4).reshape(
-        B, Hkv, C, hd
-    )
-    qn = np.asarray(q)
-    s = np.einsum("bhgd,bhtd->bhgt", qn, kg) / np.sqrt(hd)
-    mask = np.arange(C)[None, :] <= np.asarray(pos)[:, None]
-    s = np.where(mask[:, None, None, :], s, -np.inf)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    p /= p.sum(-1, keepdims=True)
-    ref = np.einsum("bhgt,bhtd->bhgd", p, vg)
-
+    ref = _gather_softmax_reference(q, k_cache, v_cache, tables, pos)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
+
+
+# (block_size, G, Hkv, hd, pool dtype, table width): every block size,
+# group size, head count and pool dtype the issue names, with widths that
+# the pages of a step do and do not divide
+_PAGED_CASES = [
+    (8, 1, 1, 16, jnp.float32, 40),
+    (8, 2, 2, 16, jnp.float32, 64),
+    (8, 4, 8, 16, jnp.float32, 33),
+    (8, 8, 2, 16, jnp.float32, 4),
+    (8, 2, 1, 16, jnp.bfloat16, 40),
+    (8, 4, 2, 16, jnp.bfloat16, 32),
+    (8, 8, 8, 16, jnp.bfloat16, 7),
+    (16, 1, 2, 16, jnp.float32, 20),
+    (16, 2, 8, 16, jnp.float32, 16),
+    (16, 4, 1, 16, jnp.float32, 35),
+    (16, 8, 2, 16, jnp.float32, 3),
+    (16, 1, 8, 16, jnp.bfloat16, 19),
+    (16, 2, 1, 16, jnp.bfloat16, 32),
+    (16, 4, 8, 128, jnp.bfloat16, 24),
+    (16, 8, 2, 16, jnp.bfloat16, 17),
+    (128, 1, 1, 16, jnp.float32, 5),
+    (128, 2, 2, 16, jnp.float32, 4),
+    (128, 4, 8, 16, jnp.float32, 3),
+    (128, 8, 1, 16, jnp.float32, 1),
+    (128, 1, 2, 16, jnp.bfloat16, 3),
+    (128, 2, 8, 16, jnp.bfloat16, 5),
+    (128, 4, 2, 128, jnp.bfloat16, 4),
+    (128, 8, 8, 16, jnp.bfloat16, 2),
+]
+
+
+@pytest.mark.parametrize(
+    "bs,G,Hkv,hd,dtype,width", _PAGED_CASES,
+    ids=[
+        f"bs{c[0]}-g{c[1]}-h{c[2]}-d{c[3]}-{jnp.dtype(c[4]).name}-w{c[5]}"
+        for c in _PAGED_CASES
+    ],
+)
+def test_paged_decode_attention_cases(bs, G, Hkv, hd, dtype, width):
+    """One batch a case, its rows at the edges of a page and of a step's
+    group of pages: ``pos`` of 0, ``bs-1``, ``bs``, ``P*bs-1``, ``P*bs``
+    and the table's last position, two rows that share physical pages,
+    and a free slot (``pos`` 0, every column the trash page). Every table
+    column past a row's last live page names a page filled with NaN — in
+    the last live group and in the dead groups alike — so a finite result
+    equal to the reference shows no page past ``pos // bs`` reached it.
+    Inside the last live page the columns past ``pos`` hold finite
+    values, as they do in the engine."""
+    rng = np.random.default_rng(bs * 1000 + G * 100 + Hkv * 10 + width)
+    P = pa._pages_per_step(width, Hkv, bs, hd, dtype)
+    last = width * bs - 1
+    edges = sorted({
+        min(p, last)
+        for p in (0, bs - 1, bs, P * bs - 1, P * bs, last // 2, last)
+    })
+    shared = last // 2  # the two sharing rows: same pages, other queries
+    pos = np.asarray([0] + edges + [shared, shared], np.int32)
+    B = len(pos)
+    n_pages = 2 * width + 2
+    trash, poison = n_pages - 2, n_pages - 1
+    tables = np.full((B, width), poison, np.int32)
+    tables[0] = trash
+    for b in range(1, B - 2):
+        live = pos[b] // bs + 1
+        tables[b, :live] = rng.permutation(trash)[:live]
+    live = shared // bs + 1
+    tables[B - 2, :live] = tables[B - 1, :live] = rng.permutation(trash)[:live]
+
+    def pool():
+        x = rng.standard_normal((n_pages, Hkv, bs, hd)).astype(np.float32)
+        x[poison] = np.nan
+        return jnp.asarray(x).astype(dtype)
+
+    k_cache, v_cache = pool(), pool()
+    # queries that are values of the pool's dtype, as the engine's are
+    q = jnp.asarray(
+        rng.standard_normal((B, Hkv, G, hd)), jnp.float32
+    ).astype(dtype).astype(jnp.float32)
+
+    out = np.asarray(pa.paged_decode_attention(
+        q, k_cache, v_cache, jnp.asarray(tables), jnp.asarray(pos),
+        interpret=True,
+    ))
+    assert out.shape == (B, Hkv, G, hd) and out.dtype == np.float32
+    assert np.isfinite(out).all()
+    ref = _gather_softmax_reference(q, k_cache, v_cache, tables, pos)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    # the sharing rows read the same pages with their own queries
+    assert not np.allclose(out[B - 2], out[B - 1])
+
+
+@pytest.mark.parametrize(
+    "width,Hkv,bs,hd,dtype,pages",
+    [
+        (160, 8, 16, 128, jnp.bfloat16, 16),   # the serve cells
+        (128, 4, 16, 128, jnp.bfloat16, 16),
+        (16, 4, 128, 128, jnp.bfloat16, 2),
+        (4, 2, 8, 16, jnp.float32, 4),         # no wider than the table
+        (64, 2, 8, 16, jnp.float32, 32),
+        (16, 8, 128, 256, jnp.float32, 1),     # the buffers' budget binds
+    ],
+)
+def test_pages_per_step_follows_the_static_shapes(
+    width, Hkv, bs, hd, dtype, pages
+):
+    got = pa._pages_per_step(width, Hkv, bs, hd, dtype)
+    assert got == pages
+    page_bytes = Hkv * bs * hd * jnp.dtype(dtype).itemsize
+    assert got == 1 or 4 * got * page_bytes <= pa._KV_SCRATCH_BYTES
 
 
 def test_decode_step_paged_kernel_vs_lax_token_parity(model):

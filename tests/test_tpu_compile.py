@@ -127,21 +127,80 @@ def test_rmsnorm_compiles(one_chip, shape):
     assert n == 1
 
 
-@pytest.mark.parametrize("block_size", [16, 128])
-def test_paged_decode_attention_compiles(one_chip, block_size):
-    slots, hkv, group, hd = 8, SMALL.n_kv_heads, SMALL.n_heads // SMALL.n_kv_heads, SMALL.head_dim
-    n = _custom_calls(
-        lambda q, k, v, bt, pos: pa.paged_decode_attention(
-            q, k, v, bt, pos, interpret=False
-        ),
-        ((slots, hkv, group, hd), jnp.bfloat16),
-        ((1024, hkv, block_size, hd), jnp.bfloat16),
-        ((1024, hkv, block_size, hd), jnp.bfloat16),
-        ((slots, 2048 // block_size), jnp.int32),
-        ((slots,), jnp.int32),
-        sharding=one_chip,
+def _paged_shapes(slots, hkv, group, hd, block_size, pages, columns,
+                  q_dtype=jnp.bfloat16):
+    """q, K pool, V pool, block table, positions of one decode call."""
+    pool = ((pages, hkv, block_size, hd), jnp.bfloat16)
+    return (
+        ((slots, hkv, group, hd), q_dtype), pool, pool,
+        ((slots, columns), jnp.int32), ((slots,), jnp.int32),
     )
+
+
+_SMALL_HEADS = (SMALL.n_kv_heads, SMALL.n_heads // SMALL.n_kv_heads, SMALL.head_dim)
+# what the serve cells hand the kernel (benchmarks/workloads/serve-*.json,
+# benchmarks/configs/): 32 slots, 8 KV heads of 4 queries and 128 wide as
+# float32, bf16 pools of 2401 (chat) and 1281 (batch) pages of 16,
+# max_len 2560 = 160 table columns
+_CELL_PAGED = {
+    "chat-cell": _paged_shapes(32, 8, 4, 128, 16, 2401, 160, jnp.float32),
+    "batch-cell": _paged_shapes(32, 8, 4, 128, 16, 1281, 160, jnp.float32),
+}
+_PAGED_SHAPES = {
+    "small-16": _paged_shapes(8, *_SMALL_HEADS, 16, 1024, 2048 // 16),
+    "small-128": _paged_shapes(8, *_SMALL_HEADS, 128, 1024, 2048 // 128),
+    **_CELL_PAGED,
+}
+
+
+def _paged_decode(q, k, v, bt, pos):
+    return pa.paged_decode_attention(q, k, v, bt, pos, interpret=False)
+
+
+@pytest.mark.parametrize("shapes", list(_PAGED_SHAPES), ids=list(_PAGED_SHAPES))
+def test_paged_decode_attention_compiles(one_chip, shapes):
+    n = _custom_calls(_paged_decode, *_PAGED_SHAPES[shapes], sharding=one_chip)
     assert n == 1
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _pallas_calls(inner)
+
+
+@pytest.mark.parametrize("shapes", list(_CELL_PAGED), ids=list(_CELL_PAGED))
+def test_paged_decode_attention_grid_and_scratch_at_the_cells_shapes(
+    one_chip, shapes
+):
+    """The call as it is lowered at the serve cells' shapes: a grid of at
+    most 1,000 steps a layer (the grid of one step per (row, head, page)
+    had 32 x 8 x 160 = 40,960, most of them dead), and VMEM scratch that
+    fits the v5e's default scoped limit of 16 MiB beside the pipelined
+    query and output blocks, with no ``vmem_limit_bytes`` raised. The
+    compile of these shapes for the described chip (the test above) is
+    what holds the kernel to the limit; the sum says by how much."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in _CELL_PAGED[shapes]
+    ]
+    (call,) = _pallas_calls(jax.make_jaxpr(_paged_decode)(*args).jaxpr)
+    mapping = call.params["grid_mapping"]
+    assert call.params["name"] == "paged_decode_attention"
+    assert int(np.prod(mapping.grid)) <= 1000
+    assert call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes is None
+    vmem = sum(
+        int(np.prod(ref.shape)) * ref.dtype.itemsize
+        for ref in mapping.scratch_avals
+        if "vmem" in str(ref.memory_space).lower()
+    )
+    _, hkv, _, hd = _CELL_PAGED[shapes][0][0]
+    blocks = 2 * 2 * hkv * 16 * hd * 4  # q and out blocks, double-buffered
+    assert 0 < vmem and vmem + blocks <= 8 * 2 ** 20  # half the 16 MiB limit
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
