@@ -17,6 +17,7 @@ reference logit held against the reference's best at its position.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import itertools
 import math
@@ -28,7 +29,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from benchmarks import program, stats, trace_reduce, traffic, weights
+from benchmarks import program, reference, stats, trace_reduce, traffic
 from benchmarks.correct import Check
 
 
@@ -51,14 +52,14 @@ class _Rec:
 def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
     import jax
 
-    sizes, mix, settings = cell.config, cell.traffic, cell.settings
+    sizes, mix, settings, family = cell.config, cell.traffic, cell.settings, cell.family
     ecfg = dict(settings["engine"])
     ramp_s = float(mix.get("ramp_s", 0.0))
     drain_s = float(settings.get("drain_s", 60.0))
     vocab = sizes["vocab_size"]
-    cfg = program.llama_config(sizes, max_seq=ecfg["max_len"], remat=False)
+    cfg = family.program.model_config(sizes, max_seq=ecfg["max_len"], remat=False)
     ctx.mark("program_imported")
-    params = weights.make_params_on_device(sizes, seed)
+    params = family.program.engine_params(sizes, seed)
     ctx.mark("weights_dispatched")
     jax.block_until_ready(params)
     ctx.mark("weights_made")
@@ -162,7 +163,7 @@ def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
         "generator_lag_s": lags, "ttft_s": ttft, "itl_s": itl,
         "prompt_lens": [len(r.req.prompt) for r in recs.values() if r.submitted],
         "window_s": t_close - t_open, "tokens_in_window": in_window_tokens,
-        "sizes": sizes,
+        "decode_tick_bytes": functools.partial(family.counts.decode_tick_bytes, sizes),
         "trace_path": tracer.path if tracer is not None else None,
         "finished": done,
     }
@@ -322,8 +323,6 @@ def sample_finished(done: List[_Rec], seed: int, k: int) -> List[_Rec]:
 
 
 def served_check(cell, seed: int, done: List[_Rec], quant=None) -> Check:
-    from benchmarks.reference import decoder
-
     settings = cell.settings["correct"]
     limits = settings["limits"]
     width = int(cell.settings["engine"]["max_len"])
@@ -339,12 +338,13 @@ def served_check(cell, seed: int, done: List[_Rec], quant=None) -> Check:
         tokens[i, : len(seq)] = seq
         plens.append(len(r.req.prompt))
         totals.append(len(seq))
-    logits = decoder.teacher_forced_logits(cell.config, seed, tokens)
+    logits_of = cell.family.reference.teacher_forced_logits
+    logits = logits_of(cell.config, seed, tokens)
     if quant is None:
-        gaps = decoder.served_token_gaps(logits, tokens, plens, totals)
+        gaps = reference.served_token_gaps(logits, tokens, plens, totals)
     else:  # the control: the lower precision in the program's place
-        low = decoder.teacher_forced_logits(cell.config, seed, tokens, quant=quant)
-        gaps = decoder.first_choice_gaps(logits, low, plens, totals)
+        low = logits_of(cell.config, seed, tokens, quant=quant)
+        gaps = reference.first_choice_gaps(logits, low, plens, totals)
         del low
     del logits
     gc.collect()

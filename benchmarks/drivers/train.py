@@ -19,7 +19,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks import flops, lm_data, program, trace_reduce
+from benchmarks import lm_data, program, trace_reduce
 from benchmarks.correct import Check
 
 TRACE_STEPS = 3  # steps under the profiler in a --trace 1 run
@@ -27,7 +27,7 @@ MIN_STEP_S = 0.2  # sizes the row buffer: no step of a cell is shorter
 
 
 def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
-    sizes, job, settings = cell.config, cell.traffic, cell.settings
+    sizes, job, settings, family = cell.config, cell.traffic, cell.settings, cell.family
     opt = job["optimizer"]
     seq, rows_per_chip = job["seq_len"], job["rows_per_chip"]
     warm = int(job["steps_before_window"])
@@ -37,9 +37,9 @@ def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
     rows = lm_data.rows(seed, batch * max_steps, seq, sizes["vocab_size"])
 
     ctx.mark("rows_made")
-    cfg = program.llama_config(sizes, max_seq=seq, **settings.get("model", {}))
+    cfg = family.program.model_config(sizes, max_seq=seq, **settings.get("model", {}))
     ctx.mark("program_imported")
-    module = program.make_module(cfg, sizes, seed, opt)
+    module = family.program.make_module(cfg, sizes, seed, opt)
     tracer = trace_reduce.Tracer(os.path.join(ctx.scratch, "trace")) if trace else None
 
     class Probe(program.callback_base()):
@@ -67,7 +67,7 @@ def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
                 if k == 1:
                     self.first_grad = program.first_gradient_norms(trainer, opt["b1"])
                 if k == checked - 1:
-                    self.change = program.change_norms(trainer, sizes, seed)
+                    self.change = program.change_norms(trainer, family.weights, sizes, seed)
                 if k == warm:
                     ctx.window_opens()
                     self.t_open = time.perf_counter()
@@ -118,7 +118,7 @@ def run(cell, seed: int, seconds: float, trace: bool, ctx) -> Dict[str, Any]:
 
     facts: Dict[str, Any] = {
         "train_tokens_per_s": rate,
-        "flops_per_token": flops.train_flops_per_token(sizes, seq),
+        "flops_per_token": family.counts.train_flops_per_token(sizes, seq),
         "chips": cell.chips,
         "trace_path": tracer.path if tracer is not None else None,
         "first_steps": dict(first, rows=rows[: checked * batch], batch=batch, reference=want),
@@ -135,10 +135,9 @@ def reference_numbers(cell, seed: int, rows: np.ndarray, batch: int, quant=None)
     with their updates, the last for its loss): the losses, the first
     gradient's norm per leaf, the norm per leaf of the parameters' change
     before the last step."""
-    from benchmarks.reference import decoder
-
     checked = int(cell.traffic["checked_steps"])
-    ref = decoder.TrainReference(cell.config, seed, cell.traffic["optimizer"], quant=quant)
+    ref = cell.family.reference.TrainReference(
+        cell.config, seed, cell.traffic["optimizer"], quant=quant)
     losses, grad = [], {}
     for k in range(checked - 1):
         loss, norms = ref.step(rows[k * batch:(k + 1) * batch])

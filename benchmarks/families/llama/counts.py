@@ -1,4 +1,5 @@
-"""Operations the arithmetic of a configuration requires, from its shapes.
+"""Operations and bytes the Llama family's arithmetic requires, from a
+configuration's shapes.
 
 Counted here and not read from the program or from XLA's cost analysis (which
 misses the Mosaic kernels): a multiply-add is two operations; the embedding
@@ -46,3 +47,25 @@ def forward_flops(sizes: Dict[str, Any], tokens: int, active_only: bool = True) 
     """One causal forward pass over one sequence of ``tokens`` positions."""
     attn = 2.0 * sizes["num_hidden_layers"] * tokens * tokens * sizes["hidden_size"]
     return 2.0 * matmul_params(sizes, active_only) * tokens + attn
+
+
+_WIDTH = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def weight_bytes(sizes: Dict[str, Any], active_only: bool = False) -> int:
+    """Layer and head weights, each read once by a decode tick. The
+    embedding table is gathered by row and is not counted."""
+    return matmul_params(sizes, active_only) * _WIDTH[sizes.get("dtype", "bfloat16")]
+
+
+def cache_bytes_per_token(sizes: Dict[str, Any]) -> int:
+    """What one cached position costs through every layer: K and V of every
+    KV head."""
+    return (2 * sizes["num_hidden_layers"] * sizes["num_key_value_heads"]
+            * head_dim(sizes) * _WIDTH[sizes.get("dtype", "bfloat16")])
+
+
+def decode_tick_bytes(sizes: Dict[str, Any], live_context_tokens: float) -> float:
+    """What one decode tick must read: every weight once, and the cache of
+    the live context of every occupied row (their lengths summed)."""
+    return weight_bytes(sizes) + cache_bytes_per_token(sizes) * live_context_tokens

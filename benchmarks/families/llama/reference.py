@@ -1,4 +1,5 @@
-"""The plain reference: a decoder-only transformer in ``jax.numpy`` float32.
+"""The Llama family's plain reference: a decoder-only transformer in
+``jax.numpy`` float32.
 
 Written from the published description of the Mistral / Mixtral family
 (pre-norm blocks; RMSNorm; rotary embedding on the two halves of each head,
@@ -7,14 +8,11 @@ einsum; SwiGLU; for Mixtral a router that takes the softmax over all experts,
 keeps the top two and renormalises them; untied head; next-token
 cross-entropy), with no kernel, no cache and no batching tricks. It imports
 nothing of ``ray_lightning_tpu`` and takes no array the program has made:
-weights come from ``benchmarks/weights.py`` by seed, a layer at a time, in
+weights come from the family's ``weights.py`` by seed, a layer at a time, in
 bfloat16 as the configuration states and are cast to float32 here. Every
 matmul runs under ``jax.default_matmul_precision("highest")``; on a TPU a
-float32 matmul is otherwise a bfloat16 one.
-
-``quant`` is the control's hook: a function applied to both operands of
-every matmul. ``None`` is the reference; ``fp8`` puts the reference into the
-next precision below bfloat16, which a sound comparison has to refuse.
+float32 matmul is otherwise a bfloat16 one. ``quant`` is the control's hook
+(``benchmarks/reference.py``).
 
 Departures from the description, all for memory and none for the
 arithmetic: attention runs over blocks of query rows (each against all keys,
@@ -26,45 +24,17 @@ time) so that only one layer's float32 weights and gradients are alive.
 from __future__ import annotations
 
 import math
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks import weights
+from benchmarks.reference import Quant, mm, schedule
 
-Quant = Optional[Callable[[jnp.ndarray], jnp.ndarray]]
+from . import weights
+
 Q_BLOCK = 1024  # query rows scored at a time
-
-
-def fp8(x: jnp.ndarray) -> jnp.ndarray:
-    """Round to float8 e4m3 with one scale for the tensor (the largest
-    magnitude lands on 448), and back: 3 bits of mantissa where bfloat16
-    keeps 7. Done on the bits, round to nearest even, because the chip's
-    compiler folds a convert to float8 and back into nothing; below the
-    smallest normal (2**-6) the grid is the subnormals' 2**-9."""
-    x = x.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
-    y = x / scale
-    bits = jax.lax.bitcast_convert_type(y, jnp.uint32)
-    drop = 20  # 23 mantissa bits kept by float32, 3 by e4m3
-    odd = (bits >> drop) & jnp.uint32(1)
-    bits = (bits + jnp.uint32((1 << (drop - 1)) - 1) + odd) & jnp.uint32(~((1 << drop) - 1) & 0xFFFFFFFF)
-    normal = jax.lax.bitcast_convert_type(bits, jnp.float32)
-    small = jnp.round(y * 512.0) / 512.0
-    return jnp.where(jnp.abs(y) < 2.0 ** -6, small, normal) * scale
-
-
-def bf16(x: jnp.ndarray) -> jnp.ndarray:
-    """The control for a float32 configuration (the tests' tiny ones)."""
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
-
-
-def _mm(a, b, quant: Quant):
-    if quant is not None:
-        a, b = quant(a), quant(b)
-    return jnp.matmul(a, b)
 
 
 def rmsnorm(x, w, eps):
@@ -111,14 +81,14 @@ def attention(q, k, v, quant: Quant):
 
 
 def mlp(x, lp, quant: Quant):
-    gate = jax.nn.silu(_mm(x, lp["w_gate"], quant)) * _mm(x, lp["w_up"], quant)
-    return _mm(gate, lp["w_down"], quant)
+    gate = jax.nn.silu(mm(x, lp["w_gate"], quant)) * mm(x, lp["w_up"], quant)
+    return mm(gate, lp["w_down"], quant)
 
 
 def moe(x, lp, top_k: int, quant: Quant):
     """x: [N, D]. Softmax over all experts, top-k, renormalise; every expert
     on every token, weighted by its gate (zero outside the top-k)."""
-    gates = jax.nn.softmax(_mm(x, lp["moe/router"], quant), axis=-1)
+    gates = jax.nn.softmax(mm(x, lp["moe/router"], quant), axis=-1)
     vals, idx = jax.lax.top_k(gates, top_k)
     vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     n_e = gates.shape[-1]
@@ -126,8 +96,8 @@ def moe(x, lp, top_k: int, quant: Quant):
 
     def one(acc, expert):
         wg, wu, wd, col = expert
-        h = jax.nn.silu(_mm(x, wg, quant)) * _mm(x, wu, quant)
-        return acc + col[:, None] * _mm(h, wd, quant), None
+        h = jax.nn.silu(mm(x, wg, quant)) * mm(x, wu, quant)
+        return acc + col[:, None] * mm(h, wd, quant), None
 
     out, _ = jax.lax.scan(
         one, jnp.zeros_like(x),
@@ -141,16 +111,16 @@ def layer(x, lp, sizes: Dict[str, Any], quant: Quant = None):
     hd = sizes.get("head_dim") or sizes["hidden_size"] // sizes["num_attention_heads"]
     b, t, d = x.shape
     h = rmsnorm(x, lp["attn_norm"], eps)
-    q = _mm(h, lp["wq"], quant).reshape(b, t, -1, hd)
-    k = _mm(h, lp["wk"], quant).reshape(b, t, -1, hd)
-    v = _mm(h, lp["wv"], quant).reshape(b, t, -1, hd)
+    q = mm(h, lp["wq"], quant).reshape(b, t, -1, hd)
+    k = mm(h, lp["wk"], quant).reshape(b, t, -1, hd)
+    v = mm(h, lp["wv"], quant).reshape(b, t, -1, hd)
 
     def one_row(args):
         qr, kr, vr = args
         return attention(rope(qr, theta), rope(kr, theta), vr, quant)
 
     att = jax.lax.map(one_row, (q, k, v)).reshape(b, t, -1)
-    x = x + _mm(att, lp["wo"], quant)
+    x = x + mm(att, lp["wo"], quant)
     h = rmsnorm(x, lp["mlp_norm"], eps)
     if "moe/router" in lp:
         out = moe(h.reshape(b * t, d), lp, sizes["num_experts_per_tok"], quant)
@@ -182,7 +152,7 @@ def logits_fn(sizes: Dict[str, Any], seed: int, quant: Quant = None):
             x, _ = jax.lax.scan(
                 step, x, jnp.arange(sizes["num_hidden_layers"], dtype=jnp.uint32))
             x = rmsnorm(x, top["final_norm"], sizes["rms_norm_eps"])
-            return _mm(x, top["lm_head"], quant)
+            return mm(x, top["lm_head"], quant)
 
     keys = weights.seed_keys(sizes, seed)  # arguments, so every seed shares the program
     fn = jax.jit(run)
@@ -193,45 +163,9 @@ def teacher_forced_logits(sizes: Dict[str, Any], seed: int, tokens, quant: Quant
     return logits_fn(sizes, seed, quant)(jnp.asarray(tokens, jnp.int32))
 
 
-def served_token_gaps(logits, tokens, prompt_lens: Sequence[int], totals: Sequence[int]):
-    """For every served token (positions prompt_len .. total-1 of each row)
-    how far its reference logit lies below the reference's best at the
-    position that produced it. Returns a flat float32 numpy array."""
-    logits = jnp.asarray(logits)
-    best = jnp.max(logits[:, :-1], axis=-1)
-    nxt = jnp.asarray(tokens, jnp.int32)[:, 1:]
-    got = jnp.take_along_axis(logits[:, :-1], nxt[..., None], axis=-1)[..., 0]
-    gap = np.asarray(best - got)
-    out = [gap[r, p - 1: n - 1] for r, (p, n) in enumerate(zip(prompt_lens, totals))]
-    return np.concatenate(out) if out else np.zeros((0,), np.float32)
-
-
-def first_choice_gaps(ref_logits, other_logits, prompt_lens, totals):
-    """The control's reading: at each served position, the reference gap of
-    the token that ``other_logits`` puts first."""
-    ref = jnp.asarray(ref_logits)
-    pick = jnp.argmax(jnp.asarray(other_logits), axis=-1)
-    got = jnp.take_along_axis(ref, pick[..., None], axis=-1)[..., 0]
-    gap = np.asarray(jnp.max(ref, axis=-1) - got)
-    out = [gap[r, p - 1: n - 1] for r, (p, n) in enumerate(zip(prompt_lens, totals))]
-    return np.concatenate(out) if out else np.zeros((0,), np.float32)
-
-
 # ---------------------------------------------------------------------- #
 # training: the first steps of the job
 # ---------------------------------------------------------------------- #
-def schedule(opt: Dict[str, Any], count: int) -> float:
-    """Linear warm-up from 0 over ``warmup_steps`` then cosine decay to 0 at
-    ``total_steps``: the learning rate of the update number ``count`` (from
-    0), as the job states it."""
-    peak, warm = opt["lr"], opt["warmup_steps"]
-    total = max(opt["total_steps"], warm + 1)
-    if count < warm:
-        return peak * count / warm
-    frac = min(1.0, (count - warm) / (total - warm))
-    return peak * 0.5 * (1.0 + math.cos(math.pi * frac))
-
-
 class TrainReference:
     """AdamW on the next-token loss, float32 arithmetic on state kept in the
     configuration's own type (bfloat16 parameters and moments), a layer at a
@@ -274,7 +208,7 @@ class TrainReference:
                     def row(args):
                         xr, tr = args
                         h = rmsnorm(xr, norm_w, sizes["rms_norm_eps"])
-                        logits = _mm(h, head_w, quant)
+                        logits = mm(h, head_w, quant)
                         tgt = jnp.roll(tr, -1)
                         lse = jax.nn.logsumexp(logits, axis=-1)
                         nll = lse - jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]

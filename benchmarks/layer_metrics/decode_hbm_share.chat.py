@@ -1,7 +1,7 @@
-"""Bytes a decode tick must read (benchmarks/bytes.py: every weight once, K
-and V of the live context) over the median decode tick, over the chip's HBM
-bandwidth."""
-from benchmarks import bytes as hbm
+"""Bytes a decode tick must read (the family's ``counts.decode_tick_bytes``,
+which the driver hands over as a function of the live context: every weight
+once and the cache of the live rows) over the median decode tick, over the
+chip's HBM bandwidth."""
 from benchmarks.readers import decode_live_tokens, tick_ms
 
 
@@ -9,5 +9,5 @@ def read(facts):
     ms, live = tick_ms(facts, prefill=False), decode_live_tokens(facts)
     if ms is None or live is None:
         return None
-    need = hbm.decode_tick_bytes(facts["sizes"], live)
+    need = facts["decode_tick_bytes"](live)
     return 100.0 * need / (ms * 1e-3) / (facts["peaks"]["hbm_gbps"] * 1e9)

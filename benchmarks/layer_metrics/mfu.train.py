@@ -1,5 +1,6 @@
 """Model FLOP/s utilisation: operations the forward and backward require per
-token (benchmarks/flops.py) x tokens/s, over chips x the chip's bf16 peak."""
+token (the family's ``counts.train_flops_per_token``, handed over by the
+driver) x tokens/s, over chips x the chip's bf16 peak."""
 
 
 def read(facts):

@@ -5,15 +5,22 @@ A cell is one entry of ``workloads``: its ``config`` names
 ``traffic`` names ``benchmarks/traffic/<traffic>.json``, the cell's own
 settings sit in ``benchmarks/workloads/<cell>.json`` and each per-layer
 metric has a reader ``benchmarks/layer_metrics/<metric>.py`` with one
-function ``read(facts)``. A later PR adds files and manifest entries; this
-module names none of them.
+function ``read(facts)``. The configuration file names its model family
+under ``family``: ``benchmarks/families/<family>/`` holds the family's four
+pieces (``PIECES``), found by path under the manifest's own root as a reader
+is. A later PR adds files and manifest entries; this module names none of
+them.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
 import re
+import sys
+import types
+import zlib
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
@@ -21,6 +28,16 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH_DIR)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# A family's pieces, each a file ``<piece>.py`` of its directory, and what
+# the drivers and tools call of each. Beyond these the files are the family's
+# own: how many kinds of layers its tree has, what its cache holds.
+PIECES = {
+    "weights": ("seed_keys", "make_params", "make_params_on_device"),
+    "reference": ("teacher_forced_logits", "TrainReference"),
+    "counts": ("train_flops_per_token", "forward_flops", "weight_bytes",
+               "cache_bytes_per_token", "decode_tick_bytes"),
+    "program": ("model_config", "make_module", "engine_params"),
+}
 
 
 class ManifestError(ValueError):
@@ -43,10 +60,22 @@ class Metric:
 
 
 @dataclass(frozen=True)
+class Family:
+    """The four pieces of ``benchmarks/families/<name>/``, as modules. Only
+    ``program`` imports the program under test."""
+    name: str
+    weights: Any  # the seeded weights, whole and a layer at a time
+    reference: Any  # the plain reference, float32 at ``highest``
+    counts: Any  # operations and bytes from the configuration's shapes
+    program: Any  # the program's config object, module and engine parameters
+
+
+@dataclass(frozen=True)
 class Cell:
     name: str
     chips: int
     config: Dict[str, Any]  # the configuration file as it is run
+    family: Family  # the one the configuration file names
     traffic: Dict[str, Any]  # the traffic mix's parameters
     settings: Dict[str, Any]  # benchmarks/workloads/<cell>.json
     end_to_end: tuple  # Metric, those this cell reports
@@ -105,6 +134,7 @@ class Manifest:
 
     def __init__(self, root: str = ROOT):
         self.root = root
+        self._families: Dict[str, Family] = {}
         self.raw = _read_json(os.path.join(root, "BENCHMARK.json"))
         for key in ("command", "paths", "run_seconds", "configs", "workloads",
                     "end_to_end", "per_layer"):
@@ -154,7 +184,10 @@ class Manifest:
             raise ManifestError(
                 f"no cell {name!r}; BENCHMARK.json has {sorted(self.cells)}")
         w = self.cells[name]
-        config = _read_json(self._under_paths(self.configs[w["config"]]["file"]))
+        path = self._under_paths(self.configs[w["config"]]["file"])
+        config = _read_json(path)
+        if "family" not in config:
+            raise ManifestError(f"{path} names no family: there is no default")
         bench = os.path.join(self.root, "benchmarks")
         traffic = _read_json(
             os.path.join(bench, "traffic", _name(w.get("traffic"), "traffic") + ".json"))
@@ -168,8 +201,38 @@ class Manifest:
         )
         return Cell(
             name=name, chips=w["chips"], config=config,
+            family=self.family(config["family"]),
             traffic=traffic, settings=settings, end_to_end=e2e, per_layer=layer,
         )
+
+    def family(self, name: str) -> Family:
+        """The pieces of ``benchmarks/families/<name>/`` under this root,
+        imported as one package of a name of this root's own, so that a
+        family's files import each other by relative imports and two roots'
+        families of one name stay apart."""
+        if name in self._families:
+            return self._families[name]
+        where = os.path.join(self.root, "benchmarks", "families", _name(name, "family"))
+        if not os.path.isdir(where):
+            raise ManifestError(f"no family {name!r}: {where} is missing")
+        package = "benchmarks_family_%s_%08x" % (
+            re.sub(r"[^A-Za-z0-9_]", "_", name), zlib.crc32(where.encode()))
+        for loaded in [m for m in sys.modules if m == package or m.startswith(package + ".")]:
+            del sys.modules[loaded]
+        sys.modules[package] = types.ModuleType(package)
+        sys.modules[package].__path__ = [where]
+        importlib.invalidate_caches()
+        pieces = {}
+        for piece, needs in PIECES.items():
+            path = os.path.join(where, piece + ".py")
+            if not os.path.exists(path):
+                raise ManifestError(f"family {name!r} lacks its {piece}: {path} is missing")
+            pieces[piece] = importlib.import_module(f"{package}.{piece}")
+            for fn in needs:
+                if not callable(getattr(pieces[piece], fn, None)):
+                    raise ManifestError(f"{path} has no {fn}")
+        self._families[name] = Family(name=name, **pieces)
+        return self._families[name]
 
     def reader(self, metric: str) -> Callable[[Dict[str, Any]], Optional[float]]:
         """The ``read`` function of ``layer_metrics/<metric>.py``."""

@@ -1,8 +1,11 @@
-"""The one place where the benchmark touches the program under test.
+"""Where the benchmark touches the program under test, whatever the model.
 
 Everything else under ``benchmarks/`` is the yardstick and imports nothing
-of ``ray_lightning_tpu``; this module builds the system under test from a
-configuration file's sizes (HF key names) and reads its counters.
+of ``ray_lightning_tpu``, save each family's ``program.py``
+(``benchmarks/families/<family>/``), which builds the program's config
+object, its module with the seeded weights and its engine's parameters. Here:
+the trainer, the loader, the engine, its counters, and the norms read from
+the trainer's state.
 """
 from __future__ import annotations
 
@@ -12,8 +15,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from benchmarks import weights
 
 
 def cache_dir(root: str) -> str:
@@ -32,28 +33,6 @@ def cache_dir(root: str) -> str:
     return path
 
 
-def llama_config(sizes: Dict[str, Any], max_seq: int, **model: Any):
-    """The program's ``LlamaConfig`` for a configuration file's sizes."""
-    from ray_lightning_tpu.models.llama import LlamaConfig
-
-    if sizes.get("sliding_window"):
-        raise ValueError("the paged engine refuses a sliding window")
-    hd = sizes.get("head_dim") or sizes["hidden_size"] // sizes["num_attention_heads"]
-    if hd * sizes["num_attention_heads"] != sizes["hidden_size"]:
-        raise ValueError("LlamaConfig derives head_dim from hidden_size / heads")
-    return LlamaConfig(
-        vocab_size=sizes["vocab_size"], dim=sizes["hidden_size"],
-        n_layers=sizes["num_hidden_layers"], n_heads=sizes["num_attention_heads"],
-        n_kv_heads=sizes["num_key_value_heads"], ffn_dim=sizes["intermediate_size"],
-        max_seq=max_seq, rope_theta=float(sizes["rope_theta"]),
-        norm_eps=float(sizes["rms_norm_eps"]),
-        dtype=jnp.dtype(sizes.get("dtype", "bfloat16")).type,
-        n_experts=sizes.get("num_local_experts", 0),
-        expert_top_k=sizes.get("num_experts_per_tok", 2),
-        **model,
-    )
-
-
 def leaf_names(tree) -> Dict[str, Any]:
     """{"layers/wq": leaf, "layers/moe/w_up": leaf, "embed": leaf, ...}"""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
@@ -69,26 +48,6 @@ def _norms(tree) -> Dict[str, float]:
     return {k: float(v) for k, v in leaf_names(vals).items()}
 
 
-def make_module(cfg, sizes: Dict[str, Any], seed: int, opt: Dict[str, Any]):
-    """``LlamaModule`` whose weights come from the benchmark's generator
-    (so the reference can regenerate them) and whose optimizer is the one
-    ``LlamaModule`` sets: AdamW(b1 0.9, b2 0.95) under warm-up + cosine."""
-    from ray_lightning_tpu.models.llama import LlamaModule
-
-    for key, want in (("b1", 0.9), ("b2", 0.95), ("eps", 1e-8)):
-        if opt[key] != want:
-            raise ValueError(f"LlamaModule fixes {key}={want}; the job states {opt[key]}")
-
-    class SeededLlama(LlamaModule):
-        def init_params(self, rng):
-            return weights.make_params(sizes, weights.seed_keys(sizes, seed))
-
-    return SeededLlama(
-        cfg, lr=opt["lr"], warmup_steps=opt["warmup_steps"],
-        total_steps=opt["total_steps"], weight_decay=opt["weight_decay"],
-    )
-
-
 def first_gradient_norms(trainer, b1: float) -> Dict[str, float]:
     """After exactly one step Adam's first moment is (1 - b1) x the gradient
     the optimizer was given: its norm per leaf."""
@@ -102,8 +61,9 @@ def first_gradient_norms(trainer, b1: float) -> Dict[str, float]:
     return {k: v / (1.0 - b1) for k, v in _norms(found[0].mu).items()}
 
 
-def change_norms(trainer, sizes: Dict[str, Any], seed: int) -> Dict[str, float]:
-    """Norm per leaf of (the trainer's parameters now - those of the seed)."""
+def change_norms(trainer, weights, sizes: Dict[str, Any], seed: int) -> Dict[str, float]:
+    """Norm per leaf of (the trainer's parameters now - those of the seed,
+    made anew by the family's ``weights``)."""
     vals = jax.jit(lambda now, keys: jax.tree_util.tree_map(
         lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
             a.astype(jnp.float32) - b.astype(jnp.float32)))),

@@ -15,10 +15,10 @@ from __future__ import annotations
 
 import functools
 import os
-import re
 from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Sequence
 
 from benchmarks import stats, trace_reduce
+from benchmarks.trace_reduce import kernel_of  # noqa: F401  (the readers' and the tool's name for it)
 
 PREFIX = "rlt."
 TICK = "rlt.serve.tick"
@@ -26,26 +26,6 @@ SCHEDULE = "rlt.serve.schedule"
 SAMPLE_SYNC = "rlt.serve.sample_sync"
 TRAIN_STEP = "rlt.train.step"
 INPUT_WAIT = "rlt.train.input_wait"
-
-
-_INSTRUCTION = re.compile(r"^custom-call %(?P<name>.+?)(\.\d+)?$")
-_TRANSFORMS = re.compile(r"^((jvp|transpose|vmap|remat|checkpoint)_)+")
-
-
-def kernel_of(short: str) -> Optional[str]:
-    """The kernel behind ``trace_reduce.short_name``'s ``custom-call
-    %flash_fwd.3``: ``flash_fwd``; None for any other operation. The compiler
-    names a Mosaic custom call after the innermost scope of its ``op_name``,
-    which is the ``name=`` of the ``pl.pallas_call`` wrapped in the
-    transformations it was traced under: ``jax.grad`` with no
-    ``jax.checkpoint`` round it gives ``%jvp_flash_fwd_.1`` and
-    ``%transpose_jvp_flash_bwd_dq__.1``. Those wrappers are taken off."""
-    m = _INSTRUCTION.match(short)
-    if not m:
-        return None
-    name = m.group("name")
-    bare = _TRANSFORMS.sub("", name)
-    return bare.rstrip("_") if bare != name else name
 
 
 class Span(NamedTuple):
@@ -155,17 +135,14 @@ def decode_sync_ms(facts: Dict[str, Any]) -> Optional[float]:
 
 
 def kernel_share_percent(facts: Dict[str, Any], kernel: str) -> Optional[float]:
-    """Own time of the Mosaic custom calls named ``kernel`` among the reduced
-    trace's ``device_ops``, over the device's busy time. ``device_ops`` holds
-    the ten largest operations only: a kernel that is not among them reads
-    0.0, not its true (small) share."""
+    """Own time of the Mosaic custom calls named ``kernel`` over all of the
+    trace's device events (the reduced trace's ``kernels``), over the
+    device's busy time. A trace in which no such kernel ran leaves nothing
+    to read: a kernel renamed or gone shows as a missing metric, not as 0."""
     trace = facts.get("trace")
-    if not trace or not trace.get("busy_s"):
+    if not trace or not trace.get("busy_s") or kernel not in trace.get("kernels", {}):
         return None
-    head = f"custom-call %{kernel}"
-    own = sum(t for name, t in trace["device_ops"]
-              if name == head or name.startswith(head + "."))
-    return 100.0 * own / trace["busy_s"]
+    return 100.0 * trace["kernels"][kernel] / trace["busy_s"]
 
 
 def engine_host_ms_per_tick(facts: Dict[str, Any]) -> Optional[float]:

@@ -12,8 +12,9 @@ metrics with ``--trace 1``), ``device`` and, traced, ``breakdown``. Every
 number compared for ``correct`` is printed beside its limit before it.
 
 Nothing here names a cell, a model or a metric: the cell's files are found
-by the names in ``BENCHMARK.json`` (``benchmarks/loader.py``), the driver by
-the name in the cell's own settings, each per-layer metric by its reader.
+by the names in ``BENCHMARK.json`` (``benchmarks/loader.py``), the model's
+family by the name in the configuration file, the driver by the name in the
+cell's own settings, each per-layer metric by its reader.
 """
 from __future__ import annotations
 

@@ -25,9 +25,9 @@ sys.path.insert(0, ROOT)
 
 
 def lower_precision(sizes):
-    from benchmarks.reference import decoder
+    from benchmarks import reference
 
-    return decoder.bf16 if sizes.get("dtype", "bfloat16") == "float32" else decoder.fp8
+    return reference.bf16 if sizes.get("dtype", "bfloat16") == "float32" else reference.fp8
 
 
 def control_check(cell, seed, facts):

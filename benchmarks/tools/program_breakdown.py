@@ -46,7 +46,6 @@ if ROOT not in sys.path:
 from benchmarks import program_trace, stats, trace_reduce  # noqa: E402
 
 NO_SPAN = "no_rlt_span"
-MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'  # in a Pallas kernel's HLO text
 _RUN_ID = re.compile(r"\(\d+\)$")
 
 
@@ -102,9 +101,9 @@ def breakdown(path: str) -> Dict[str, Any]:
     for events in per_device.values():
         for name, own in trace_reduce.self_times(events):
             if trace_reduce._is(name, trace_reduce.MOSAIC):
-                short = trace_reduce.short_name(name)
-                kernel = program_trace.kernel_of(short) if MOSAIC_TARGET in name else None
-                (other if kernel is None else kernels)[kernel or short] += own / n
+                kernel = trace_reduce.kernel_name(name)
+                (other if kernel is None else kernels)[
+                    kernel or trace_reduce.short_name(name)] += own / n
         merged = trace_reduce.union((s, e) for s, e, _ in events)
         busy += trace_reduce._length(merged) / n
         edges = [(w0, w0)] + merged + [(w1, w1)]

@@ -2,12 +2,13 @@
 """Records the small engine trace that ``tests/bench_harness`` lays the
 program's spans against.
 
-    chiprun -- python3 benchmarks/tools/record_engine_trace.py chiprun_out/engine_trace
+    chiprun -- python3 benchmarks/tools/record_engine_trace.py <family> chiprun_out/engine_trace
     python3 benchmarks/tools/record_engine_trace.py --slim <recorded.xplane.pb> <out.xplane.pb>
 
-A tiny paged engine (two layers, heads of 128, so the chip takes its Pallas
-kernels) runs a dozen ticks with two prefills under ``jax.profiler`` with the
-benchmark's sync probes, single-threaded, so the trace holds both serving
+A tiny paged engine of the family named (two layers, heads of 128, so the
+chip takes its Pallas kernels; its sizes below are in HF key names, as a
+configuration file's are) runs a dozen ticks with two prefills under
+``jax.profiler`` with the benchmark's sync probes, single-threaded, so the trace holds both serving
 programs on ``XLA Modules``, the named kernels on ``XLA Ops`` and the
 ``rlt.serve.*`` spans on the host's plane. The Python tracer and the runtime's
 own host events are switched off to keep the file small (the benchmark's runs
@@ -32,21 +33,21 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspa
 TICKS = 12
 
 
-def main(out_dir: str) -> int:
+def main(family_name: str, out_dir: str) -> int:
     import jax
 
-    from benchmarks import program, trace_reduce
+    from benchmarks import loader, program, trace_reduce
     from benchmarks.tools import program_breakdown
+
+    family = loader.Manifest().family(family_name)
 
     sizes = {
         "hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 2,
         "num_key_value_heads": 1, "num_hidden_layers": 2, "vocab_size": 2048,
         "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "dtype": "bfloat16",
     }
-    cfg = program.llama_config(sizes, max_seq=256, remat=False)
-    from ray_lightning_tpu.models.llama import init_params
-
-    engine = program.make_engine(cfg, init_params(jax.random.key(0), cfg), {
+    cfg = family.program.model_config(sizes, max_seq=256, remat=False)
+    engine = program.make_engine(cfg, family.program.engine_params(sizes, 0), {
         "num_slots": 4, "max_prompt_len": 64, "max_len": 128, "kv_layout": "paged"})
     engine.warmup()
     engine.submit([3, 1, 4, 1, 5], max_new_tokens=2)  # every shape executed once
@@ -117,4 +118,4 @@ def slim(src: str, dst: str) -> None:
 if __name__ == "__main__":
     if sys.argv[1:2] == ["--slim"]:
         sys.exit(slim(*sys.argv[2:4]))
-    sys.exit(main(sys.argv[1] if len(sys.argv) > 1 else "chiprun_out/engine_trace"))
+    sys.exit(main(sys.argv[1], sys.argv[2] if len(sys.argv) > 2 else "chiprun_out/engine_trace"))

@@ -12,8 +12,11 @@ number in the same way. It reads the trace with nothing but JAX
   last one's end over all devices;
 - an operation's own time is its duration less that of the events nested
   directly inside it, so a loop does not count its body twice;
-- a Mosaic (Pallas) kernel shows as a custom call; the program gives its
-  kernels no names yet, so all of them are one bucket;
+- a Mosaic (Pallas) kernel shows as a custom call whose HLO text holds
+  ``MOSAIC_TARGET``, named after the ``name=`` of its ``pl.pallas_call``:
+  ``kernels`` holds every such kernel's own time by that name, over all
+  events (``device_ops`` is the ten largest operations only); ``mosaic_s``
+  is the older single bucket of everything that mentions a custom call;
 - host and device events sit about a millisecond apart on the trace's clock.
   ``Tracer.start`` therefore runs a tiny named program a few times inside
   ``bench.sync_probe`` spans: each run's device event has to end before its
@@ -36,6 +39,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MOSAIC = ("custom-call", "custom_call", "mosaic", "pallas")
+MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'  # in a Pallas kernel's HLO text
 SPAN_PREFIX = "bench."
 SYNC_SPAN = "bench.sync_probe"
 SYNC_MODULE = "jit_bench_sync_probe"
@@ -151,6 +155,33 @@ def short_name(hlo: str) -> str:
     return f"{m.group('op')} %{m.group('name')}" + (f" {kind.group(1)}" if kind else "")
 
 
+_INSTRUCTION = re.compile(r"^custom-call %(?P<name>.+?)(\.\d+)?$")
+_TRANSFORMS = re.compile(r"^((jvp|transpose|vmap|remat|checkpoint)_)+")
+
+
+def kernel_of(short: str) -> Optional[str]:
+    """The kernel behind ``short_name``'s ``custom-call
+    %flash_fwd.3``: ``flash_fwd``; None for any other operation. The compiler
+    names a Mosaic custom call after the innermost scope of its ``op_name``,
+    which is the ``name=`` of the ``pl.pallas_call`` wrapped in the
+    transformations it was traced under: ``jax.grad`` with no
+    ``jax.checkpoint`` round it gives ``%jvp_flash_fwd_.1`` and
+    ``%transpose_jvp_flash_bwd_dq__.1``. Those wrappers are taken off."""
+    m = _INSTRUCTION.match(short)
+    if not m:
+        return None
+    name = m.group("name")
+    bare = _TRANSFORMS.sub("", name)
+    return bare.rstrip("_") if bare != name else name
+
+
+def kernel_name(hlo: str) -> Optional[str]:
+    """The program's name for the Pallas kernel behind an event's HLO text
+    (``flash_fwd``, ``paged_decode_attention``); None for any other
+    operation, the compiler's own custom calls among them."""
+    return kernel_of(short_name(hlo)) if MOSAIC_TARGET in hlo else None
+
+
 def host_spans(path: str) -> List[Interval]:
     """The benchmark's host spans, moved onto the device events' clock."""
     out: List[Interval] = []
@@ -238,6 +269,7 @@ def reduce(path: str, top: int = 10) -> Dict[str, Any]:
     spans = host_spans(path)
     busy, mosaic = [], []
     ops: Dict[str, float] = defaultdict(float)
+    kernels: Dict[str, float] = defaultdict(float)
     gaps: Dict[str, float] = defaultdict(float)
     longest_gap = 0.0
     for dev, events in sorted(per_device.items()):
@@ -246,6 +278,9 @@ def reduce(path: str, top: int = 10) -> Dict[str, Any]:
         own = self_times(events)
         for name, t in own:
             ops[short_name(name)] += t / len(per_device)
+            kernel = kernel_name(name)
+            if kernel is not None:
+                kernels[kernel] += t / len(per_device)
         mosaic.append(sum(t for n, t in own if _is(n, MOSAIC)))
         edges = [(w0, w0)] + merged + [(w1, w1)]
         for (_, e0), (s1, _) in zip(edges, edges[1:]):
@@ -262,6 +297,7 @@ def reduce(path: str, top: int = 10) -> Dict[str, Any]:
         "busy_s": busy_s,
         "idle_share": 1.0 - busy_s / ((w1 - w0) * ns),
         "mosaic_s": sum(mosaic) / n * ns,
+        "kernels": {k: v * ns for k, v in kernels.items()},
         "longest_gap_s": longest_gap * ns,
         "device_ops": rank(ops),
         "idle_gaps": rank(gaps),

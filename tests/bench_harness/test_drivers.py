@@ -18,7 +18,9 @@ from benchmarks import loader, program, run, trace_reduce  # noqa: E402
 from benchmarks.drivers import serve, train  # noqa: E402
 from benchmarks.tools import control  # noqa: E402
 
-TRACE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data", "tiny_tpu.xplane.pb")
+DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+TRACES = {"train": os.path.join(DATA, "tiny_tpu.xplane.pb"),  # a train step's flash kernel
+          "serve": os.path.join(DATA, "tiny_engine_tpu.xplane.pb")}  # a paged engine's ticks
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
@@ -59,9 +61,11 @@ def test_untraced_run_reports_the_cells_end_to_end_metrics(lines, cell, metrics)
 
 @pytest.mark.parametrize("cell", ["train-tiny", "chat-tiny", "batch-tiny"])
 def test_traced_run_reports_per_layer_metrics_and_a_breakdown(manifest, monkeypatch, cell):
-    """The CPU has no device plane, so the recorded chip trace stands in for
-    the one the run took; everything else is the traced path."""
-    monkeypatch.setattr(trace_reduce, "reduce", lambda path, top=10: _recorded())
+    """The CPU has no device plane, so a recorded chip trace of the driver's
+    kind stands in for the one the run took; everything else is the traced
+    path."""
+    kind = manifest.cell(cell).settings["driver"]
+    monkeypatch.setattr(trace_reduce, "reduce", lambda path, top=10: _recorded(kind))
     line = run.execute(manifest, cell, 23, 1.0, True, tiny.DEVICE)
     assert set(line) == RESULT_KEYS | {"breakdown"}
     want = {m.name for m in manifest.cell(cell).per_layer}
@@ -74,10 +78,10 @@ def test_traced_run_reports_per_layer_metrics_and_a_breakdown(manifest, monkeypa
 _cache = {}
 
 
-def _recorded():
-    if "r" not in _cache:
-        _cache["r"] = _real_reduce(TRACE)
-    return _cache["r"]
+def _recorded(kind):
+    if kind not in _cache:
+        _cache[kind] = _real_reduce(TRACES[kind])
+    return _cache[kind]
 
 
 _real_reduce = trace_reduce.reduce
@@ -152,8 +156,7 @@ def test_serve_control_in_the_next_lower_precision_is_not_correct(manifest):
             self.tokens = []
     done = [Rec(i) for i in range(4)]
     # greedy tokens of the reference itself: a sound program's stream
-    from benchmarks.reference import decoder
-    logits_of = decoder.logits_fn(cell.config, 43)
+    logits_of = cell.family.reference.logits_fn(cell.config, 43)
     rows = np.zeros((4, 64), np.int32)
     for i, r in enumerate(done):
         rows[i, :20] = r.req.prompt

@@ -1,13 +1,14 @@
-"""flops.py and bytes.py against values worked out by hand."""
+"""The Llama family's counts (operations and bytes) against values worked
+out by hand."""
 import json
 import os
 
 import pytest
 
-from benchmarks import bytes as hbm
-from benchmarks import flops
+from benchmarks import loader
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+flops = hbm = loader.Manifest(REPO).family("llama").counts
 
 
 def sizes(name):
@@ -55,9 +56,9 @@ def test_prefill_flops_of_a_padded_prompt():
 
 @pytest.mark.parametrize("name,per_token", [("mistral-7b-d4", 16384), ("mistral-7b-d16", 65536),
                                             ("mixtral-8x7b-d4", 16384)])
-def test_kv_bytes_per_token(name, per_token):
+def test_cache_bytes_per_token(name, per_token):
     # 2 (K and V) x layers x 8 KV heads x 128 x 2 bytes
-    assert hbm.kv_bytes_per_token(sizes(name)) == per_token
+    assert hbm.cache_bytes_per_token(sizes(name)) == per_token
 
 
 def test_decode_tick_bytes_mistral_d16():

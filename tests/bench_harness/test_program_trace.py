@@ -109,15 +109,21 @@ def test_spans_of_no_trace_are_none_to_read():
     assert pt.spans("/nonexistent/trace.xplane.pb") == []
 
 
-def test_kernel_share_reads_the_named_entries_of_the_ten():
-    trace = {"busy_s": 2.0, "device_ops": [
-        ["custom-call %paged_decode_attention.23", 1.0],
-        ["custom-call %paged_decode_attention.7", 0.25],
-        ["custom-call %paged_decode_attention_v2.1", 0.5],
-        ["fusion %fusion.85 kOutput", 0.25]]}
-    assert pt.kernel_share_percent({"trace": trace}, "paged_decode_attention") == pytest.approx(62.5)
-    trace["device_ops"] = trace["device_ops"][3:]
-    assert pt.kernel_share_percent({"trace": trace}, "paged_decode_attention") == 0.0
+@pytest.fixture(scope="module")
+def engine_reduced():
+    return trace_reduce.reduce(ENGINE_TRACE)
+
+
+@pytest.mark.parametrize("kernel", ["paged_decode_attention", "rmsnorm", "flash_fwd", "fused_argmax"])
+def test_kernel_share_is_the_kernels_own_time_over_all_events(engine_reduced, broken_down, kernel):
+    """On the recorded engine trace: what the tool reads from all events,
+    whether or not the kernel is among the ten largest operations."""
+    share = pt.kernel_share_percent({"trace": engine_reduced}, kernel)
+    assert share == pytest.approx(100.0 * broken_down["kernels"][kernel] / broken_down["busy_s"])
+    assert share > 0.0
+    among_the_ten = any(pt.kernel_of(name) == kernel for name, _ in engine_reduced["device_ops"])
+    assert among_the_ten == (kernel == "paged_decode_attention")
+    assert len(engine_reduced["device_ops"]) == 10  # the breakdown stays the ten largest
 
 
 def test_counter_readers():
@@ -144,9 +150,12 @@ def test_new_readers_find_nothing_in_facts_of_a_program_without_them(manifest, m
 
 
 @pytest.mark.parametrize("metric", NEW_READERS[:2])
-def test_kernel_share_is_zero_where_the_ten_hold_no_such_name(manifest, metric):
-    reduced = trace_reduce.reduce(KERNEL_TRACE)  # a flash kernel, no paged one
-    assert manifest.reader(metric)({"trace": reduced}) == 0.0
+def test_paged_share_reads_the_recorded_kernel_and_nothing_where_there_is_none(
+        manifest, engine_reduced, metric):
+    read = manifest.reader(metric)
+    assert read({"trace": engine_reduced}) == pytest.approx(100.0 * 96.859e-6 / 377.077e-6)
+    flash_only = trace_reduce.reduce(KERNEL_TRACE)  # a flash kernel, no paged one
+    assert flash_only["kernels"] and read({"trace": flash_only}) is None  # never 0.0
 
 
 def test_span_readers_read_the_recorded_engine_trace(manifest):

@@ -1,5 +1,7 @@
-"""The plain reference against the repository's forward at tiny size, dense
-and MoE; the seeded weights; the control's float8 rounding."""
+"""The Llama family's plain reference against the repository's forward at
+tiny size, dense and MoE; its seeded weights; what the references share (the
+control's float8 rounding, the schedule, the gap arithmetic)."""
+import glob
 import os
 import sys
 
@@ -12,9 +14,11 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import tiny  # noqa: E402
 
-from benchmarks import program, weights  # noqa: E402
-from benchmarks.reference import decoder  # noqa: E402
+from benchmarks import loader, program, reference  # noqa: E402
+from benchmarks import weights as hashing  # noqa: E402
 
+FAMILY = loader.Manifest(tiny.REPO).family("llama")
+weights, decoder = FAMILY.weights, FAMILY.reference
 SIZES = {"dense": tiny.TINY_DENSE, "moe": tiny.TINY_MOE}
 
 
@@ -23,7 +27,7 @@ def test_reference_logits_match_the_programs_forward(kind):
     from ray_lightning_tpu.models.llama import forward
 
     sizes, seed = SIZES[kind], 2 ** 31 + 3
-    cfg = program.llama_config(sizes, max_seq=64, remat=False, capacity_factor=8.0)
+    cfg = FAMILY.program.model_config(sizes, max_seq=64, remat=False, capacity_factor=8.0)
     params = weights.make_params_on_device(sizes, seed)
     tokens = np.random.default_rng(0).integers(1, 512, size=(3, 64)).astype(np.int32)
     got = np.asarray(forward(params, jnp.asarray(tokens), cfg)[0])
@@ -32,8 +36,20 @@ def test_reference_logits_match_the_programs_forward(kind):
     assert np.abs(got - want).max() < 2e-4 * np.abs(want).max()  # float32 both, other summation order
 
 
-def test_reference_imports_nothing_of_the_program():
-    src = open(decoder.__file__).read() + open(weights.__file__).read()
+def _yardstick_files():
+    bench = os.path.join(tiny.REPO, "benchmarks")
+    families = glob.glob(os.path.join(bench, "families", "*", "*.py"))
+    assert len(families) >= len(loader.PIECES)
+    return sorted(f for f in families if os.path.basename(f) != "program.py") + [
+        reference.__file__, hashing.__file__]
+
+
+@pytest.mark.parametrize("path", _yardstick_files(),
+                         ids=lambda p: os.path.relpath(p, tiny.REPO))
+def test_reference_imports_nothing_of_the_program(path):
+    """Every family's weights, reference and counts, and what they share:
+    only a family's ``program.py`` may touch the program under test."""
+    src = open(path).read()
     assert "ray_lightning_tpu" not in src.replace("``ray_lightning_tpu``", "")
 
 
@@ -66,7 +82,7 @@ def test_weights_repeat_from_a_seed_and_differ_across_seeds_and_layers():
 def test_fp8_rounding_is_e4m3_bit_for_bit():
     x = np.asarray(jax.random.normal(jax.random.key(0), (256, 256), jnp.float32)) * 3.0
     x[0, :8] = [0.0, 1e-4, -1e-4, 2e-3, -2e-3, 5e-3, 0.02, -0.02]  # subnormals of the scaled grid
-    got = np.asarray(jax.jit(decoder.fp8)(x))
+    got = np.asarray(jax.jit(reference.fp8)(x))
     scale = np.abs(x).max() / 448.0
     want = (x / scale).astype(ml_dtypes.float8_e4m3fn).astype(np.float32) * scale
     assert np.allclose(got, want, rtol=3e-7, atol=0)  # the same grid; the last multiply may round apart
@@ -77,13 +93,13 @@ def test_fp8_rounding_is_e4m3_bit_for_bit():
 
 def test_schedule_is_warmup_then_cosine():
     opt = dict(tiny.OPT, lr=1.0, warmup_steps=2, total_steps=10)
-    assert [decoder.schedule(opt, c) for c in (0, 1, 2)] == [0.0, 0.5, 1.0]
-    assert decoder.schedule(opt, 6) == pytest.approx(0.5)
-    assert decoder.schedule(opt, 10) == pytest.approx(0.0, abs=1e-12)
+    assert [reference.schedule(opt, c) for c in (0, 1, 2)] == [0.0, 0.5, 1.0]
+    assert reference.schedule(opt, 6) == pytest.approx(0.5)
+    assert reference.schedule(opt, 10) == pytest.approx(0.0, abs=1e-12)
     import optax
     sched = optax.warmup_cosine_decay_schedule(0.0, 1.0, 2, 10)
     for c in range(11):
-        assert decoder.schedule(opt, c) == pytest.approx(float(sched(c)), abs=1e-6)
+        assert reference.schedule(opt, c) == pytest.approx(float(sched(c)), abs=1e-6)
 
 
 def test_served_gaps_are_read_at_the_position_that_produced_the_token():
@@ -91,11 +107,11 @@ def test_served_gaps_are_read_at_the_position_that_produced_the_token():
     logits[0, 2] = [0.0, 3.0, 1.0, 0.0]  # position 2 produces the token at 3
     logits[0, 3] = [5.0, 0.0, 0.0, 4.5]
     tokens = np.array([[1, 1, 1, 1, 3, 0]], np.int32)  # prompt of 3, served: 1, 3
-    gaps = decoder.served_token_gaps(logits, tokens, [3], [5])
+    gaps = reference.served_token_gaps(logits, tokens, [3], [5])
     assert gaps.tolist() == [0.0, 0.5]
     low = logits.copy()
     low[0, 2] = [0.0, 1.0, 3.0, 0.0]  # the lower precision puts token 2 first there
-    assert decoder.first_choice_gaps(logits, low, [3], [5]).tolist() == [2.0, 0.0]
+    assert reference.first_choice_gaps(logits, low, [3], [5]).tolist() == [2.0, 0.0]
 
 
 def test_one_compiled_program_makes_the_weights_of_every_seed():

@@ -1,6 +1,7 @@
 """A tiny copy of the benchmark in a temporary directory: the manifest and
 the data files (configurations, traffic, cells, readers) are new files and
-new entries there; the harness code is the repository's own, unedited."""
+new entries there, the families' directories are copies; the harness code is
+the repository's own, unedited."""
 from __future__ import annotations
 
 import json
@@ -11,7 +12,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 DEVICE = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}  # what a chip run reports
 
 TINY_DENSE = {
-    "source": "test", "hidden_size": 128, "intermediate_size": 256,
+    "source": "test", "family": "llama", "hidden_size": 128, "intermediate_size": 256,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 32,
     "num_hidden_layers": 2, "vocab_size": 512, "rope_theta": 10000.0,
     "rms_norm_eps": 1e-5, "sliding_window": None, "dtype": "float32",
@@ -39,7 +40,11 @@ def make_root(tmp: str) -> str:
     bench = os.path.join(root, "benchmarks")
     os.makedirs(bench)
     shutil.copytree(os.path.join(REPO, "benchmarks", "layer_metrics"),
-                    os.path.join(bench, "layer_metrics"))
+                    os.path.join(bench, "layer_metrics"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(os.path.join(REPO, "benchmarks", "families"),
+                    os.path.join(bench, "families"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(REPO, "benchmarks", "peaks.json"), bench)
     real = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     _dump(dict(TINY_DENSE, name="tiny-dense"), bench, "configs", "tiny-dense.json")
