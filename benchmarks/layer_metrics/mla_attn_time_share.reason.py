@@ -1,0 +1,8 @@
+"""Share of the device's busy time inside the latent paged decode attention
+kernel: the own time of every ``mla_paged_decode_attention`` custom call of the
+trace (the reduced trace's ``kernels``, all events), over ``busy_s``."""
+from benchmarks.program_trace import kernel_share_percent
+
+
+def read(facts):
+    return kernel_share_percent(facts, "mla_paged_decode_attention")

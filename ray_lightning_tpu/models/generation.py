@@ -740,6 +740,86 @@ def decode_step_verify(
     return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
 
 
+class LlamaServing:
+    """What ``InferenceEngine`` and the paged pool ask of a model, for the
+    Llama family. A config object answers ``cfg.serving()`` with one of
+    these, and that is the one place the engine learns its model from:
+
+    - ``layouts``: the ``kv_layout``s it serves; ``speculation``: whether it
+      has a verify step; ``counters``: names of the int32 counters its paged
+      decode step returns beside the logits (none here);
+    - ``rope_table(max_len)``: one table for prefill and decode;
+    - ``paged_block_leaves(block_size)``: the pool's device leaves, each
+      (layers, shape of one block in one layer, dtype), and
+      ``cache_bytes_per_position()`` through all of them;
+    - ``prefill_blocks(params, prompt_row, n_blocks, block_size, table)``:
+      the leaves' contents for a prompt, cut into blocks
+      ``[layers, n_blocks, ...]`` for the engine's write table;
+    - ``decode_paged(params, cache, token, pos, tables, table)`` ->
+      (logits, cache, counters or None).
+
+    A model that serves the slot layout or speculates also has
+    ``prefill_row``, ``decode_ragged`` and ``decode_verify``; one that does
+    not is refused those settings by name when the engine is built."""
+
+    name = "Llama family (models/llama.py)"
+    layouts = ("slot", "paged")
+    speculation = True
+    counters = ()
+
+    def __init__(self, cfg: LlamaConfig):
+        self.cfg = cfg
+
+    def rope_table(self, max_len: int):
+        cfg = self.cfg
+        return rope_angles(max_len, cfg.head_dim, cfg.rope_theta,
+                           scaling=cfg.rope_scaling)
+
+    def paged_block_leaves(self, block_size: int):
+        cfg = self.cfg
+        block = (cfg.n_kv_heads, block_size, cfg.head_dim)
+        return {"k": (cfg.n_layers, block, cfg.dtype),
+                "v": (cfg.n_layers, block, cfg.dtype)}
+
+    def cache_bytes_per_position(self) -> int:
+        cfg = self.cfg
+        return (2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim
+                * jnp.dtype(cfg.dtype).itemsize)
+
+    def prefill_blocks(self, params, prompt_row, n_blocks, block_size, table):
+        # the batched prefill into a scratch row, padded up to whole blocks,
+        # then the row cut into blocks [L, nb, Hkv, bs, hd]
+        cfg = self.cfg
+        row = init_kv_cache(cfg, 1, max(n_blocks * block_size, block_size))
+        _, row = prefill(params, prompt_row, cfg, row, table)
+
+        def blocks(leaf):
+            return leaf[:, 0].reshape(
+                cfg.n_layers, cfg.n_kv_heads, n_blocks, block_size, cfg.head_dim
+            ).transpose(0, 2, 1, 3, 4)
+
+        return {"k": blocks(row["k"]), "v": blocks(row["v"])}
+
+    def decode_paged(self, params, cache, token, pos, tables, table):
+        logits, cache = decode_step_paged(
+            params, cache, token, pos, tables, self.cfg, table)
+        return logits, cache, None
+
+    # the slot layout and speculation
+    def prefill_row(self, params, prompt_row, max_len: int, table):
+        """The prompt's cache as one row of the slot layout's length."""
+        _, row = prefill(params, prompt_row, self.cfg,
+                         init_kv_cache(self.cfg, 1, max_len), table)
+        return row
+
+    def decode_ragged(self, params, cache, token, pos, table):
+        return decode_step_ragged(params, cache, token, pos, self.cfg, table)
+
+    def decode_verify(self, params, cache, tokens, pos, table, block_tables=None):
+        return decode_step_verify(
+            params, cache, tokens, pos, self.cfg, table, block_tables=block_tables)
+
+
 def _sample_logits(logits, key, temperature, top_k, top_p):
     """One sampling step over [B, V] logits, jit/scan-safe (static shapes).
 

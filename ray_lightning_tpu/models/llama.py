@@ -128,6 +128,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    def serving(self):
+        """What ``InferenceEngine`` and the paged pool ask of a model."""
+        from ray_lightning_tpu.models.generation import LlamaServing
+
+        return LlamaServing(self)
+
     def to_dict(self) -> Dict[str, Any]:
         import dataclasses
 

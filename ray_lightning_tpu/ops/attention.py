@@ -369,6 +369,7 @@ def _flash_fwd(q, k, v, causal, scale, interpret, blocks=None, window=None):
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, sq, d = q.shape
+    dv = v.shape[3]  # values (and the output) may have another width than q, k
     hkv = k.shape[1]
     group = hq // hkv
     skv = k.shape[2]
@@ -386,18 +387,18 @@ def _flash_fwd(q, k, v, causal, scale, interpret, blocks=None, window=None):
         in_specs=[
             pl.BlockSpec((None, None, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((None, None, bk, d), kv_idx),
-            pl.BlockSpec((None, None, bk, d), kv_idx),
+            pl.BlockSpec((None, None, bk, dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((None, None, bq, dv), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((None, None, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),    # acc
+            pltpu.VMEM((bq, dv), jnp.float32),   # acc
             pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
             pltpu.VMEM((bq, 128), jnp.float32),  # running sum (lane-replicated)
         ],
@@ -413,6 +414,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, interpret, blocks=None,
     from jax.experimental.pallas import tpu as pltpu
 
     b, hq, sq, d = q.shape
+    d_v = v.shape[3]  # the values' own width (see _flash_fwd)
     hkv = k.shape[1]
     group = hq // hkv
     skv = k.shape[2]
@@ -432,8 +434,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, interpret, blocks=None,
         in_specs=[
             pl.BlockSpec((None, None, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((None, None, bk, d), kv_idx),
-            pl.BlockSpec((None, None, bk, d), kv_idx),
-            pl.BlockSpec((None, None, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((None, None, bk, d_v), kv_idx),
+            pl.BlockSpec((None, None, bq, d_v), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((None, None, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((None, None, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
@@ -457,22 +459,22 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, interpret, blocks=None,
         in_specs=[
             pl.BlockSpec((None, None, bq, d), q_idx),
             pl.BlockSpec((None, None, bk, d), lambda b_, h, j, t: (b_, h, j, 0)),
-            pl.BlockSpec((None, None, bk, d), lambda b_, h, j, t: (b_, h, j, 0)),
-            pl.BlockSpec((None, None, bq, d), q_idx),
+            pl.BlockSpec((None, None, bk, d_v), lambda b_, h, j, t: (b_, h, j, 0)),
+            pl.BlockSpec((None, None, bq, d_v), q_idx),
             pl.BlockSpec((None, None, bq, 1), q_idx),
             pl.BlockSpec((None, None, bq, 1), q_idx),
         ],
         out_specs=[
             pl.BlockSpec((None, None, bk, d), lambda b_, h, j, t: (b_, h, j, 0)),
-            pl.BlockSpec((None, None, bk, d), lambda b_, h, j, t: (b_, h, j, 0)),
+            pl.BlockSpec((None, None, bk, d_v), lambda b_, h, j, t: (b_, h, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, skv, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, skv, d_v), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d_v), jnp.float32),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
@@ -590,13 +592,17 @@ def attention(
     if interpret is None:
         interpret = _interpret_default()
     blocks = (block_q, block_k) if (block_q or block_k) else None
-    d_pad = _lane_pad(d)
-    if d_pad != d:
-        # scale already fixed from the true d; zero columns change nothing
-        pad = ((0, 0), (0, 0), (0, 0), (0, d_pad - d))
+    dv = v.shape[3]
+    d_pad, dv_pad = _lane_pad(d), _lane_pad(dv)
+    if d_pad != d or dv_pad != dv:
+        # scale already fixed from the true d; zero columns change nothing.
+        # q and k share one width, v (and so the output) may have another:
+        # latent attention scores 192 columns and sums values of 128
+        qk_pad = ((0, 0), (0, 0), (0, 0), (0, d_pad - d))
+        v_pad = ((0, 0), (0, 0), (0, 0), (0, dv_pad - dv))
         out = _flash_attention(
-            jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad),
+            jnp.pad(q, qk_pad), jnp.pad(k, qk_pad), jnp.pad(v, v_pad),
             causal, scale, interpret, blocks, window,
         )
-        return out[..., :d]
+        return out[..., :dv]
     return _flash_attention(q, k, v, causal, scale, interpret, blocks, window)

@@ -72,6 +72,7 @@ __all__ = [
     "fused_greedy_sample",
     "fused_sample",
     "fused_sample_supported",
+    "mla_paged_decode_attention",
     "paged_decode_attention",
     "paged_kernel_enabled",
 ]
@@ -317,6 +318,178 @@ def paged_decode_attention(
     )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
       q, k_cache, v_cache)
     return out[:, :, :group] if gp != group else out
+
+
+# --------------------------------------------------------------------- #
+# fused paged decode attention over a latent pool
+# --------------------------------------------------------------------- #
+def _mla_paged_decode_kernel(
+    bt_ref, pos_ref, q_ref, kv_hbm, o_ref,
+    kv_buf, sems, slot_ref, acc_scr, m_scr, l_scr,
+    *, scale, block_size, n_cols, v_width,
+):
+    """The walk of ``_paged_decode_kernel`` over a pool whose page is one
+    ``[bs, W]`` slab that every head reads: a position's compressed K/V
+    (``v_width`` columns, which are also the values) and its one shared
+    roped key. One copy a page, one score matmul ``[H, W] x [W, T]`` and
+    one value matmul ``[H, T] x [T, v_width]`` a group, for all heads."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    pages = kv_buf.shape[1]
+    step_tokens = pages * block_size
+    pos_b = pos_ref[b]
+    n_live = pos_b // step_tokens + 1
+
+    def copies(row, group, half):
+        out = []
+        for i in range(pages):
+            page = bt_ref[row, jnp.minimum(group * pages + i, n_cols - 1)]
+            out.append(pltpu.make_async_copy(
+                kv_hbm.at[page], kv_buf.at[half, i], sems.at[half]))
+        return out
+
+    @pl.when(b == 0)
+    def _prime():
+        slot_ref[0] = 0
+        for c in copies(0, 0, 0):
+            c.start()
+
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[:] = jnp.zeros_like(l_scr)
+
+    def group_step(g, half):
+        other = 1 - half
+        row_ends = g + 1 == n_live
+
+        @pl.when(jnp.logical_or(~row_ends, b + 1 < n_rows))
+        def _prefetch():
+            for c in copies(
+                jnp.where(row_ends, jnp.minimum(b + 1, n_rows - 1), b),
+                jnp.where(row_ends, 0, g + 1),
+                other,
+            ):
+                c.start()
+
+        for c in copies(b, g, half):
+            c.wait()
+
+        kv = kv_buf[half].reshape(step_tokens, -1)  # [T, W]
+        s = (
+            jax.lax.dot_general(
+                q_ref[:].astype(kv.dtype), kv,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * scale
+        )  # [Hp, T]
+        base = g * step_tokens
+        cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols <= pos_b, s, -jnp.inf)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape,
+        )
+        # as in the K/V kernel: rows past pos may hold anything, so the
+        # values are selected to zero there, not only the scores masked
+        vals = kv[:, :v_width]
+        rows = base + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0)
+        vals = jnp.where(rows <= pos_b, vals.astype(jnp.float32), 0.0)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p, vals, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        return other
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_live, group_step, slot_ref[0])
+    o_ref[:] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
+
+
+def mla_paged_decode_attention(
+    q: jnp.ndarray,
+    kv_cache: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    pos: jnp.ndarray,
+    *,
+    v_width: int,
+    sm_scale: float,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Decode attention of latent-attention heads over a latent paged pool.
+
+    q: [B, H, W], a row's absorbed queries (``q_nope W_kb`` beside the
+    roped columns); kv_cache: [N, bs, W], a position's normed compressed
+    K/V in its first ``v_width`` columns and its roped shared key after
+    them, one row for ALL heads. ``W`` is a multiple of the 128 lanes: the
+    chip lays a 576-wide array out 640 wide anyway and refuses a copy of a
+    576-wide slab out of it, so the caller pads both with zero columns,
+    which add nothing to a score; block_tables [B, max_blocks] int32
+    (trash-padded); pos [B] int32. Returns float32 [B, H, v_width]:
+    ``softmax(q . kv * sm_scale) @ kv[:, :v_width]`` of each row over its
+    positions [0, pos[b]]. The caller multiplies by ``W_vb`` and ``W_o``.
+
+    Same walk as :func:`paged_decode_attention` (a grid step a row, a loop
+    over the row's live groups of pages, the next group's copies in flight,
+    nothing past ``pos[b]`` fetched or summed), but a page is one
+    ``[bs, W]`` slab read once for all heads, where the K/V pool holds
+    ``2 * Hkv`` such slabs a page: what a cached position costs is ``W``
+    values a layer, and the kernel's bytes follow that.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, heads, width = q.shape
+    bs = kv_cache.shape[1]
+    n_cols = block_tables.shape[1]
+    if interpret is None:
+        interpret = _interpret_default()
+    sublanes = 32 // jnp.dtype(kv_cache.dtype).itemsize
+    hp = -(-heads // sublanes) * sublanes
+    if hp != heads:
+        q = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
+    pages = _pages_per_step(n_cols, 1, bs, width, kv_cache.dtype)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((None, hp, width), lambda b, bt_ref, pos_ref: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, hp, v_width), lambda b, bt_ref, pos_ref: (b, 0, 0)
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages, bs, width), kv_cache.dtype),
+            pltpu.SemaphoreType.DMA((2,)),          # buffer half
+            pltpu.SMEM((1,), jnp.int32),            # half the next wait reads
+            pltpu.VMEM((hp, v_width), jnp.float32),  # acc
+            pltpu.VMEM((hp, 128), jnp.float32),      # running max (lane-repl.)
+            pltpu.VMEM((hp, 128), jnp.float32),      # running sum (lane-repl.)
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _mla_paged_decode_kernel,
+            scale=sm_scale, block_size=bs, n_cols=n_cols, v_width=v_width,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, hp, v_width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
+        interpret=interpret,
+        name="mla_paged_decode_attention",
+    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), q, kv_cache)
+    return out[:, :heads] if hp != heads else out
 
 
 # --------------------------------------------------------------------- #

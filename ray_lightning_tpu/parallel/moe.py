@@ -5,6 +5,16 @@ combine einsums over an expert-sharded weight stack — XLA partitions the
 [tokens, experts, capacity] dispatch tensors into all-to-alls over the 'ep'
 axis (Switch-Transformer style). No scatter/gather, fully static shapes.
 
+Inference has two no-drop paths. :func:`moe_ffn_lossless` runs every
+expert on every token (E / k times the routed work: right for a handful of
+experts). :func:`moe_ffn_routed` computes only the routed (token, expert)
+pairs: the pairs sorted by expert, one grouped matmul a weight stack, no
+capacity, so no pair is ever dropped however uneven the routing. It takes
+the choice and the weights from its caller, so any router feeds it:
+:func:`route_sigmoid_bias` (sigmoid scores, a selection bias that does not
+enter the weights, renormalised and scaled) is the one hundreds of small
+experts are published with.
+
 The reference has no MoE (SURVEY §2c: EP absent); this is part of the
 framework's first-class parallelism surface.
 """
@@ -82,6 +92,105 @@ def moe_ffn_lossless(
         (params["w_gate"], params["w_up"], params["w_down"], w.T),
     )
     return out.reshape(b, s, d).astype(x.dtype)
+
+
+def route_sigmoid_bias(
+    xt: jnp.ndarray,
+    router: jnp.ndarray,
+    bias: jnp.ndarray,
+    top_k: int,
+    scale: float = 1.0,
+    renormalize: bool = True,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sigmoid routing with a selection bias (no groups). xt: [T, D];
+    router: [D, E] float32; bias: [E]. Scores ``s = sigmoid(x W_r)`` in
+    float32; the chosen experts are the top-k of ``s + bias``, ties to the
+    lower index (``lax.top_k``); the weights are ``s`` at the chosen ones,
+    WITHOUT the bias, over their sum (+ 1e-20) if ``renormalize``, times
+    ``scale``. Returns (idx [T, K] int32, weights [T, K] float32)."""
+    s = jax.nn.sigmoid(xt.astype(jnp.float32) @ router.astype(jnp.float32))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scale
+
+
+# rows of the sorted pairs a grid step of the grouped matmul takes. A decode
+# tick of 64 rows makes 512 pairs over 256 experts, two rows an expert, and
+# every step reads a whole [D, F] expert slab whatever rows it has: the step
+# is bound by that read, so the row tile only has to divide the pairs
+_GMM_ROWS = 128
+
+
+def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
+                   kernel: Optional[bool] = None,
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
+    """``xs[start_g : start_g + sizes[g]] @ w[g]`` for every group g, the
+    rows of ``xs`` [M, K] lying group after group; w: [G, K, N]; returns
+    float32 [M, N]. An empty group costs nothing: its weights are not read.
+
+    On the TPU (``kernel`` None: where Pallas is native) this is JAX's
+    Pallas grouped matmul (``pallas.ops.tpu.megablox``), which walks the
+    (group, row tile) pairs that hold rows and takes a whole ``[K, N]``
+    slab a step; elsewhere ``lax.ragged_dot``, which differentiates.
+    ``kernel=True`` off the TPU interprets the kernel (the tests)."""
+    on_tpu = jax.devices()[0].platform == "tpu"
+    if kernel is None:
+        kernel = on_tpu
+    if interpret is None:
+        interpret = not on_tpu
+    m, k = xs.shape
+    n = w.shape[2]
+    if not kernel or m % _GMM_ROWS:
+        return jax.lax.ragged_dot(
+            xs, w, sizes.astype(jnp.int32),
+            preferred_element_type=jnp.float32)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(xs, w, sizes.astype(jnp.int32), jnp.float32,
+               (_GMM_ROWS, k, n), interpret=interpret)
+
+
+def moe_ffn_routed(
+    params: Dict[str, Any],
+    xt: jnp.ndarray,
+    idx: jnp.ndarray,
+    weights: jnp.ndarray,
+    kernel: Optional[bool] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """No-drop MoE evaluation that computes only the routed pairs.
+
+    xt: [T, D]; idx: [T, K] int32, the experts each token is routed to;
+    weights: [T, K] float32. The T * K (token, expert) pairs are sorted by
+    expert (stable, so a token's pairs keep their order), each expert's
+    rows go through its SwiGLU by three grouped matmuls over the stacked
+    weights (``params["w_gate"|"w_up"]`` [E, D, F], ``["w_down"]``
+    [E, F, D]), and a token's K outputs are weighted and summed. There is
+    no capacity: an expert takes every row routed to it, all T * K if the
+    routing sends them there, and one that gets none is skipped.
+
+    Returns (out [T, D] in xt's dtype, sizes [E] int32: the rows each
+    expert got, which is what the serving counters are made of)."""
+    t, k = idx.shape
+    e = params["w_gate"].shape[0]
+    flat = idx.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)  # pair numbers, expert by expert
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    xs = xt[order // k]  # [T*K, D]: each pair's token
+    dt = xt.dtype
+    h = (
+        jax.nn.silu(grouped_matmul(xs, params["w_gate"], sizes, kernel))
+        * grouped_matmul(xs, params["w_up"], sizes, kernel)
+    ).astype(dt)
+    y = grouped_matmul(h, params["w_down"], sizes, kernel)  # [T*K, D] f32
+    y = y * weights.reshape(t * k)[order][:, None]
+    # back to pair order by a gather (the inverse permutation), then a
+    # token's K rows are adjacent
+    inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    out = y[inverse].reshape(t, k, -1).sum(axis=1)
+    return out.astype(dt), sizes
 
 
 def _route(xt: jnp.ndarray, router: jnp.ndarray, top_k: int, capacity: int):

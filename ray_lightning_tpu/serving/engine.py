@@ -21,11 +21,13 @@ Two KV layouts behind the same two-program contract
 - ``"slot"`` — every request owns a full-``max_len`` cache row
   (``kv_pool.KVSlotPool``); the parity baseline.
 - ``"paged"`` — requests hold fixed-size BLOCKS from one shared pool
-  (``paged_kv.PagedKVPool``): prefill writes through a per-request
+  (``paged_kv.PagedKVPool``, a tree of device leaves whose names and block
+  shapes the model states: K and V per head, or one latent row a position
+  for latent attention): prefill writes through a per-request
   write-redirect table (shared-prefix blocks land in trash, written
-  exactly once by the first request), decode gathers (k, v) through the
-  fixed-shape [num_slots, max_blocks] block table
-  (``decode_step_paged``), and block tables GROW on demand as rows
+  exactly once by the first request), decode reads each row's pages
+  through the fixed-shape [num_slots, max_blocks] block table (the
+  model's paged decode step), and block tables GROW on demand as rows
   cross block boundaries — a host-side value mutation, never a shape
   change, so both layouts hold the zero-steady-state-recompile
   contract. Admission is by block availability (scheduler back-
@@ -343,6 +345,10 @@ class InferenceEngine:
         self.engine_config = ecfg
         self.params = params
         self.kv_layout = ecfg.kv_layout
+        # the one place the engine learns its model from: prefill, the decode
+        # steps and the pool's leaves are the config object's to state
+        self._model = cfg.serving()
+        self._refuse_unserved(ecfg)
         if self.kv_layout == "paged":
             self.pool = PagedKVPool(
                 cfg,
@@ -452,7 +458,34 @@ class InferenceEngine:
             "sync_wait_s": 0.0,
             "loop_wait_s": 0.0,
         }
+        # what the model's decode step counts (routing, for one with
+        # experts), summed over decode ticks: they come back in the array
+        # of sampled tokens, so reading them costs no sync of its own
+        self.stats.update({name: 0 for name in self._model.counters})
         self._build_compiled()
+
+    def _refuse_unserved(self, ecfg: EngineConfig) -> None:
+        """Settings this model has no code for are refused here, by name,
+        not somewhere inside a tick."""
+        model = self._model
+        if ecfg.kv_layout not in model.layouts:
+            raise ValueError(
+                f"kv_layout={ecfg.kv_layout!r}: the {model.name} serves "
+                f"{' / '.join(model.layouts)} only"
+            )
+        if ecfg.resolved_speculate_k() > 0 and not model.speculation:
+            raise ValueError(
+                f"speculate_k={ecfg.resolved_speculate_k()}: the "
+                f"{model.name} has no verify step (speculate_k=0 only)"
+            )
+        if ecfg.role != "both" and tuple(
+            model.paged_block_leaves(1)
+        ) != _migration.SHIPPED_LEAVES:
+            raise ValueError(
+                f"role={ecfg.role!r}: KV migration ships K and V blocks, "
+                f"and the {model.name} caches "
+                f"{sorted(model.paged_block_leaves(1))}"
+            )
 
     # ------------------------------------------------------------------ #
     # compiled programs
@@ -461,28 +494,19 @@ class InferenceEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_lightning_tpu.models.generation import (
-            _sample_logits,
-            decode_step_paged,
-            decode_step_ragged,
-            decode_step_verify,
-            init_kv_cache,
-            prefill,
-        )
+        from ray_lightning_tpu.models.generation import _sample_logits
         from ray_lightning_tpu.ops.paged_attention import (
             fused_sample,
             fused_sample_supported,
             paged_kernel_enabled,
         )
-        from ray_lightning_tpu.ops.rope import rope_angles
-
         from ray_lightning_tpu.utils.precision import (
             matmul_precision_scope,
             parse_matmul_precision,
             round_matmul_inputs,
         )
 
-        cfg = self.cfg
+        model = self._model
         ecfg = self.engine_config
         spec_k = self._speculate_k = ecfg.resolved_speculate_k()
         # the SAME matmul-precision helper the train step applies — the
@@ -516,102 +540,80 @@ class InferenceEngine:
 
         # one table covering every position a slot can reach, shared by
         # prefill and decode so rope factors cannot diverge between them
-        table = rope_angles(
-            ecfg.max_len, cfg.head_dim, cfg.rope_theta, scaling=cfg.rope_scaling
-        )
+        table = model.rope_table(ecfg.max_len)
 
-        def prefill_into(params, cache_k, cache_v, prompt_row, slot_index):
+        def sampled_of(logits, key, counters=None):
+            """The program's first output: the sampled tokens, with the
+            model's counters behind them where it has any (one array, one
+            read-back)."""
+            shape = logits.shape[:-1]
+            flat = logits.reshape(-1, logits.shape[-1])
+            out = sample(flat, key).astype(jnp.int32).reshape(shape)
+            if counters is None:
+                return out
+            return jnp.concatenate([out, counters.astype(jnp.int32)])
+
+        def prefill_into(params, cache, prompt_row, slot_index):
             # [1, max_prompt_len] through the batched prefill into a
             # single-row scratch cache, then one dynamic_update_slice
             # drops the row into the pool at slot_index. The scratch row
             # is length max_len so shapes line up with the pool rows.
-            row = init_kv_cache(cfg, 1, ecfg.max_len)
-            _, row = prefill(params, prompt_row, cfg, row, table)
-            cache_k = jax.lax.dynamic_update_slice(
-                cache_k, row["k"], (0, slot_index, 0, 0, 0)
-            )
-            cache_v = jax.lax.dynamic_update_slice(
-                cache_v, row["v"], (0, slot_index, 0, 0, 0)
-            )
-            return cache_k, cache_v
+            row = model.prefill_row(params, prompt_row, ecfg.max_len, table)
+            return {
+                name: jax.lax.dynamic_update_slice(
+                    cache[name], row[name], (0, slot_index, 0, 0, 0)
+                )
+                for name in cache
+            }
 
-        def decode(params, cache_k, cache_v, token, pos, key):
-            logits, cache = decode_step_ragged(
-                params, {"k": cache_k, "v": cache_v}, token, pos, cfg, table
-            )
-            sampled = sample(logits, key)
-            return sampled.astype(jnp.int32), cache["k"], cache["v"]
+        def decode(params, cache, token, pos, key):
+            logits, cache = model.decode_ragged(params, cache, token, pos, table)
+            return sampled_of(logits, key), cache
 
-        def decode_verify(params, cache_k, cache_v, tokens, pos, key):
+        def decode_verify(params, cache, tokens, pos, key):
             # speculative verify: tokens is [num_slots, K] (pending token
             # + K-1 proposals), logits come back [S, K, V] and every
             # position is greedily sampled — the host accept loop keeps
             # the longest matching prefix, so any row that proposed
             # nothing degenerates to the k=0 program's math exactly
-            logits, cache = decode_step_verify(
-                params, {"k": cache_k, "v": cache_v}, tokens, pos, cfg, table
-            )
-            S, K, V = logits.shape
-            sampled = sample(logits.reshape(S * K, V), key).reshape(S, K)
-            return sampled.astype(jnp.int32), cache["k"], cache["v"]
+            logits, cache = model.decode_verify(params, cache, tokens, pos, table)
+            return sampled_of(logits, key), cache
 
         if self.kv_layout == "paged":
             bs = self.pool.block_size
-            # prompt blocks the fixed-shape prefill spans; the scratch
-            # row is padded up to a block multiple so whole blocks can
+            # prompt blocks the fixed-shape prefill spans; the prompt's
+            # rows are padded up to a block multiple so whole blocks can
             # be scattered through the write table
             n_prompt_blocks = (ecfg.max_prompt_len - 1) // bs + 1
             self._n_prompt_blocks = n_prompt_blocks
-            scratch_len = max(n_prompt_blocks * bs, bs)
 
-            def prefill_into_paged(
-                params, cache_k, cache_v, prompt_row, write_table
-            ):
-                # same batched prefill into a scratch row, then the row
-                # is cut into blocks and scattered to the PHYSICAL
-                # blocks named by write_table — shared-prefix entries
-                # point at the trash block, so a cached prefix is
-                # written exactly once (by the request that registered
-                # it), never re-written per hit
-                row = init_kv_cache(cfg, 1, scratch_len)
-                _, row = prefill(params, prompt_row, cfg, row, table)
-                L = cfg.n_layers
-                hkv = cfg.n_kv_heads
-                hd = cfg.head_dim
-                ks = row["k"][:, 0].reshape(
-                    L, hkv, n_prompt_blocks, bs, hd
-                ).transpose(0, 2, 1, 3, 4)  # [L, nb, Hkv, bs, hd]
-                vs = row["v"][:, 0].reshape(
-                    L, hkv, n_prompt_blocks, bs, hd
-                ).transpose(0, 2, 1, 3, 4)
-                cache_k = cache_k.at[:, write_table].set(
-                    ks.astype(cache_k.dtype)
+            def prefill_into_paged(params, cache, prompt_row, write_table):
+                # the model's batched prefill, its cache rows cut into
+                # blocks, scattered to the PHYSICAL blocks named by
+                # write_table — shared-prefix entries point at the trash
+                # block, so a cached prefix is written exactly once (by
+                # the request that registered it), never re-written per hit
+                blocks = model.prefill_blocks(
+                    params, prompt_row, n_prompt_blocks, bs, table
                 )
-                cache_v = cache_v.at[:, write_table].set(
-                    vs.astype(cache_v.dtype)
-                )
-                return cache_k, cache_v
+                return {
+                    name: leaf.at[:, write_table].set(
+                        blocks[name].astype(leaf.dtype)
+                    )
+                    for name, leaf in cache.items()
+                }
 
-            def decode_paged(
-                params, cache_k, cache_v, token, pos, tables, key
-            ):
-                logits, cache = decode_step_paged(
-                    params, {"k": cache_k, "v": cache_v}, token, pos,
-                    tables, cfg, table,
+            def decode_paged(params, cache, token, pos, tables, key):
+                logits, cache, counters = model.decode_paged(
+                    params, cache, token, pos, tables, table
                 )
-                sampled = sample(logits, key)
-                return sampled.astype(jnp.int32), cache["k"], cache["v"]
+                return sampled_of(logits, key, counters), cache
 
-            def decode_verify_paged(
-                params, cache_k, cache_v, tokens, pos, tables, key
-            ):
-                logits, cache = decode_step_verify(
-                    params, {"k": cache_k, "v": cache_v}, tokens, pos, cfg,
-                    table, block_tables=tables,
+            def decode_verify_paged(params, cache, tokens, pos, tables, key):
+                logits, cache = model.decode_verify(
+                    params, cache, tokens, pos, table, block_tables=tables
                 )
-                S, K, V = logits.shape
-                sampled = sample(logits.reshape(S * K, V), key).reshape(S, K)
-                return sampled.astype(jnp.int32), cache["k"], cache["v"]
+                return sampled_of(logits, key), cache
 
             self._prefill_fn = _compile_cache.jit_program(
                 _with_precision(prefill_into_paged), "serve_prefill"
@@ -640,7 +642,7 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         ecfg = self.engine_config
-        ck, cv = self.pool.cache["k"], self.pool.cache["v"]
+        cache = self.pool.cache
         prompt = jnp.zeros((1, ecfg.max_prompt_len), jnp.int32)
         if self._speculate_k > 0:
             token = jnp.zeros(
@@ -654,16 +656,16 @@ class InferenceEngine:
             wt = jnp.zeros((self._n_prompt_blocks,), jnp.int32)
             return (
                 ("serve_prefill", self._prefill_fn,
-                 (self.params, ck, cv, prompt, wt)),
+                 (self.params, cache, prompt, wt)),
                 ("serve_decode", self._decode_fn,
-                 (self.params, ck, cv, token, pos,
+                 (self.params, cache, token, pos,
                   jnp.asarray(self.pool.block_tables), key)),
             )
         return (
             ("serve_prefill", self._prefill_fn,
-             (self.params, ck, cv, prompt, jnp.int32(0))),
+             (self.params, cache, prompt, jnp.int32(0))),
             ("serve_decode", self._decode_fn,
-             (self.params, ck, cv, token, pos, key)),
+             (self.params, cache, token, pos, key)),
         )
 
     def warmup(self) -> Dict[str, int]:
@@ -876,7 +878,7 @@ class InferenceEngine:
             self._evict_expired_slots()
             plan = self.scheduler.tick()
         ecfg = self.engine_config
-        ck, cv = self.pool.cache["k"], self.pool.cache["v"]
+        cache = self.pool.cache  # the pool's device leaves, as one tree
 
         new_exports: List[str] = []
         # (trace, dispatch start, dispatch end) of this tick's prefills:
@@ -901,13 +903,13 @@ class InferenceEngine:
                     wt = self.pool.prompt_write_table(
                         slot.index, self._n_prompt_blocks
                     )
-                    ck, cv = self._prefill_fn(
-                        self.params, ck, cv, jnp.asarray(padded),
+                    cache = self._prefill_fn(
+                        self.params, cache, jnp.asarray(padded),
                         jnp.asarray(wt),
                     )
                 else:
-                    ck, cv = self._prefill_fn(
-                        self.params, ck, cv, jnp.asarray(padded),
+                    cache = self._prefill_fn(
+                        self.params, cache, jnp.asarray(padded),
                         jnp.int32(slot.index),
                     )
                 if tr is not None:
@@ -1008,8 +1010,8 @@ class InferenceEngine:
                 if paged:
                     inputs.append(jnp.asarray(block_tables))
             with _obs.phase_span("rlt.serve.decode_dispatch"):
-                sampled, ck, cv = self._decode_fn(
-                    self.params, ck, cv, *inputs, sub
+                sampled, cache = self._decode_fn(
+                    self.params, cache, *inputs, sub
                 )
             t_sync = time.perf_counter()
             with _obs.phase_span(
@@ -1018,6 +1020,13 @@ class InferenceEngine:
                 sampled_host = np.asarray(sampled)  # the per-step sync point
             now = time.perf_counter()
             self.stats["sync_wait_s"] += now - t_sync
+            counters = self._model.counters
+            if counters:  # behind the tokens, in the same array
+                for name, value in zip(
+                    counters, sampled_host[self.pool.num_slots:]
+                ):
+                    self.stats[name] += int(value)
+                sampled_host = sampled_host[: self.pool.num_slots]
             # the first instant the host knows this tick's prefills are done
             for tr, t0, _ in prefill_traces:
                 tr.prefilled(now - t0, done_at=now)
@@ -1068,7 +1077,7 @@ class InferenceEngine:
             for tr, t0, t1 in prefill_traces:
                 tr.prefilled(t1 - t0, done_at=t1, synced=False)
 
-        self.pool.cache = {"k": ck, "v": cv}
+        self.pool.cache = cache
         if new_exports:
             # publish AFTER the cache swap: the fleet's migration pump
             # snapshots block payloads from self.pool.cache, which only
@@ -1234,14 +1243,16 @@ class InferenceEngine:
             raise ValueError(
                 "kv_fingerprint requires kv_layout='paged'"
             )
-        cfg = self.cfg
+        cache = self.pool.cache
+        first = next(iter(cache.values()))
         return _migration.kv_fingerprint(
             self.kv_layout,
             self.pool.block_size,
-            (cfg.n_layers, cfg.n_kv_heads, self.pool.block_size,
-             cfg.head_dim),
-            str(self.pool.cache["k"].dtype),
+            # one block through every layer: [L, *block]
+            first.shape[:1] + first.shape[2:],
+            str(first.dtype),
             self.pool.max_len,
+            leaves=tuple(cache),
         )
 
     def drain_ready_exports(self) -> List[str]:
@@ -1484,7 +1495,7 @@ class InferenceEngine:
             vs = np.stack([shipment.block_v[j] for _, j in write], axis=1)
             ck = ck.at[:, ids].set(jnp.asarray(ks, ck.dtype))
             cv = cv.at[:, ids].set(jnp.asarray(vs, cv.dtype))
-            self.pool.cache = {"k": ck, "v": cv}
+            self.pool.cache = dict(self.pool.cache, k=ck, v=cv)
         # resume exactly where the colocated path would be after its own
         # prefill: the next decode step re-runs the last prompt token at
         # pos P-1 (idempotent KV rewrite), so the first emitted token —

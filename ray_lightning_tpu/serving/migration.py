@@ -42,6 +42,8 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 SHIPMENT_VERSION = 1
+# the pool leaves a shipment carries (``KVShipment.block_k`` / ``block_v``)
+SHIPPED_LEAVES = ("k", "v")
 
 
 class ShipmentError(RuntimeError):
@@ -72,11 +74,24 @@ def kv_fingerprint(
     block_shape: Tuple[int, ...],
     dtype: str,
     max_len: int,
+    leaves: Tuple[str, ...] = SHIPPED_LEAVES,
 ) -> str:
     """Engine/layout fingerprint: 16 hex chars over every property that
     must agree between sender and receiver for a raw block payload to be
     meaningful. Includes the format version so a protocol bump also
-    changes the fingerprint."""
+    changes the fingerprint.
+
+    ``leaves`` names the pool's device leaves. A shipment is K blocks and
+    V blocks; a pool that holds anything else (a latent pool: one
+    compressed row a position, no K or V) has no fingerprint and is
+    refused here, so neither end of a migration can be built on it."""
+    if tuple(leaves) != SHIPPED_LEAVES:
+        raise ShipmentMismatch(
+            f"a KV shipment carries the pool leaves {SHIPPED_LEAVES}; this "
+            f"pool's leaves are {tuple(leaves)} (a latent pool holds one "
+            "compressed row a position, not K and V): format version "
+            f"{SHIPMENT_VERSION} cannot ship it"
+        )
     h = hashlib.sha256()
     h.update(
         repr(

@@ -13,7 +13,11 @@ positions need:
   hash-chained prefix cache with per-block refcounts and LRU eviction
   of refcount-0 chains. Unit-testable without a device.
 - :class:`PagedKVPool` — the device-facing pool the engine drives: owns
-  the block-shaped cache arrays ([L, num_blocks, Hkv, block_size, D]),
+  the block-shaped cache arrays, a tree of leaves
+  ``[layers, num_blocks, *block]`` whose names, layer counts and block
+  shapes the MODEL states (``cfg.serving().paged_block_leaves``: K and V
+  of ``[Hkv, block_size, D]`` for the Llama family, one latent row
+  ``[block_size, W]`` a group of layers for latent attention),
   the host block-table mirror ([num_slots, max_blocks] int32 — a FIXED
   shape, which is what keeps the paged decode at zero steady-state
   recompiles), and the slot bookkeeping, delegating block policy to the
@@ -457,10 +461,14 @@ class PagedKVPool:
     :class:`~.kv_pool.KVSlotPool` (same acquire/release/occupancy
     surface, so the scheduler and engine are layout-agnostic).
 
-    One device allocation of ``num_blocks`` blocks shaped
-    [L, num_blocks, Hkv, block_size, D]; each engine slot has a row in
-    the FIXED-shape host block table [num_slots, max_blocks] (int32,
-    trash-padded) that ``decode_step_paged`` gathers (k, v) through.
+    One device allocation of ``num_blocks`` blocks, a leaf
+    ``[layers, num_blocks, *block]`` for each that the model's
+    ``paged_block_leaves`` states (what one cached position holds in one
+    layer is the model's: K and V per head, or one latent row); each
+    engine slot has a row in the FIXED-shape host block table
+    [num_slots, max_blocks] (int32, trash-padded) that the model's paged
+    decode step reads its pages through. The allocator, the tables and
+    the slots know blocks only, never what is in them.
     Admission is by block availability (the allocator's reservation
     contract), not by free slot alone — the pool can refuse a request
     while slots are free, which is the back-pressure signal the
@@ -510,14 +518,14 @@ class PagedKVPool:
         self.allocator = BlockAllocator(
             num_blocks, self.block_size, prefix_cache=prefix_cache
         )
-        shape = (
-            cfg.n_layers, num_blocks, cfg.n_kv_heads,
-            self.block_size, cfg.head_dim,
-        )
+        model = cfg.serving()
         self.cache = {
-            "k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
+            name: jnp.zeros((layers, num_blocks) + tuple(block), dtype)
+            for name, (layers, block, dtype) in model.paged_block_leaves(
+                self.block_size
+            ).items()
         }
+        self.bytes_per_position = int(model.cache_bytes_per_position())
         # host mirror of the device block tables; trash-padded so free
         # slots and unallocated tail entries write/gather harmlessly
         self.block_tables = np.full(
@@ -673,6 +681,8 @@ class PagedKVPool:
             "max_len": self.max_len,
             "occupancy": self.occupancy,
             "highwater": self.highwater,
+            # what one cached position costs through every layer
+            "bytes_per_position": self.bytes_per_position,
             "admitted_total": self.admitted_total,
             "recycled_total": self.recycled_total,
             "tenants_per_slot": {

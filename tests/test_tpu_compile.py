@@ -417,3 +417,60 @@ def test_engine_programs_carry_their_labels_and_kernel_names(
     assert "wrapped" not in " ".join(decode)
     assert set(_kernel_instructions(texts["serve_prefill"])) <= {
         "flash_fwd", "rmsnorm"}
+
+
+# ---------------------------------------------------------------------- #
+# what the latent-attention MoE cell hands its kernels
+# (benchmarks/configs/joyai-flash-d5.json, workloads/serve-mla-moe-reason.json)
+# ---------------------------------------------------------------------- #
+def test_latent_paged_decode_attention_compiles_at_the_cells_shapes(one_chip):
+    """64 slots of 32 heads, a latent row of 576 values in 640 lanes, bf16
+    pool of 8193 pages of 16, max_len 4096 = 256 table columns."""
+    def call(q, kv, bt, pos):
+        return pa.mla_paged_decode_attention(
+            q, kv, bt, pos, v_width=512, sm_scale=192 ** -0.5, interpret=False)
+
+    n = _custom_calls(
+        call, ((64, 32, 640), jnp.bfloat16), ((8193, 16, 640), jnp.bfloat16),
+        ((64, 256), jnp.int32), ((64,), jnp.int32), sharding=one_chip)
+    assert n == 1
+
+
+def test_a_576_wide_latent_page_is_what_the_chip_refuses(one_chip):
+    """Why the pool's row is padded to whole lanes: the chip lays a 576-wide
+    array out 640 wide and refuses the copy of a 576-wide slab out of it."""
+    def call(q, kv, bt, pos):
+        return pa.mla_paged_decode_attention(
+            q, kv, bt, pos, v_width=512, sm_scale=192 ** -0.5, interpret=False)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _custom_calls(
+            call, ((64, 32, 576), jnp.bfloat16), ((8193, 16, 576), jnp.bfloat16),
+            ((64, 256), jnp.int32), ((64,), jnp.int32), sharding=one_chip)
+
+
+@pytest.mark.parametrize("rows", [512, 16384], ids=["decode-tick", "padded-prefill"])
+def test_routed_expert_matmuls_compile_at_the_cells_shapes(one_chip, rows):
+    """A decode tick's 64 x 8 pairs and a padded prefill's 2048 x 8, over
+    256 experts of 2048 x 768: three grouped matmuls."""
+    from ray_lightning_tpu.parallel.moe import grouped_matmul
+
+    def call(xs, w_gate, w_up, w_down, sizes):
+        mm = lambda a, w: grouped_matmul(a, w, sizes, kernel=True, interpret=False)
+        h = (jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
+        return mm(h, w_down)
+
+    n = _custom_calls(
+        call, ((rows, 2048), jnp.bfloat16), ((256, 2048, 768), jnp.bfloat16),
+        ((256, 2048, 768), jnp.bfloat16), ((256, 768, 2048), jnp.bfloat16),
+        ((256,), jnp.int32), sharding=one_chip)
+    assert n == 3
+
+
+def test_flash_attention_with_192_wide_keys_and_128_wide_values_compiles(one_chip):
+    fn = lambda q, k, v: attention(
+        q, k, v, causal=True, sm_scale=192 ** -0.5, impl="flash", interpret=False)
+    n = _custom_calls(
+        fn, ((1, 32, 2048, 192), jnp.bfloat16), ((1, 32, 2048, 192), jnp.bfloat16),
+        ((1, 32, 2048, 128), jnp.bfloat16), sharding=one_chip)
+    assert n == 1
