@@ -287,17 +287,25 @@ def _layer_groups(params):
 
 def _stack(x, params, cfg: DeepseekConfig, block, pools=None):
     """Both groups of layers, each scanned: ``block(x, lp, experts, layer,
-    pool) -> (x, out)``; ``pools``: a leaf a group, scanned with it. Returns
-    (x, {"dense": the group's stacked outs, "moe": ...})."""
+    pool) -> (x, pool, out)``. ``pools``: a leaf a group, which rides that
+    group's scan as its CARRY beside x: the block writes its rows into the
+    leaf it is handed and hands it on, so under the caller's donation the
+    buffer that goes in is the one that comes out (scanned over, a leaf is
+    sliced a layer and stacked into a second buffer). Returns (x, {"dense":
+    the group's stacked outs, "moe": ...}, the pools as the scans left them)."""
     groups, experts = _layer_groups(params)
-    outs = {}
+    outs, carried = {}, {}
     for name, leaves in groups:
         count = jax.tree_util.tree_leaves(leaves)[0].shape[0]
-        xs = (leaves, jnp.arange(count, dtype=jnp.int32),
-              None if pools is None else pools[name])
-        x, outs[name] = jax.lax.scan(
-            lambda x, a: block(x, a[0], experts, a[1], a[2]), x, xs)
-    return x, outs
+
+        def body(carry, a):
+            x, pool, out = block(carry[0], a[0], experts, a[1], carry[1])
+            return (x, pool), out
+
+        (x, carried[name]), outs[name] = jax.lax.scan(
+            body, (x, None if pools is None else pools[name]),
+            (leaves, jnp.arange(count, dtype=jnp.int32)))
+    return x, outs, carried
 
 
 def forward(
@@ -312,11 +320,11 @@ def forward(
     def block(x, lp, experts, layer, _):
         x, _ = _attend_prefill(x, lp, cfg, cos, sin)
         x, _ = _ffn(x, lp, cfg, experts, layer)
-        return x, None
+        return x, None, None
 
     if cfg.remat:
         block = jax.checkpoint(block)
-    x, _ = _stack(params["embed"][tokens], params, cfg, block)
+    x, _, _ = _stack(params["embed"][tokens], params, cfg, block)
     return rmsnorm(x, params["final_norm"], cfg.norm_eps) @ params["lm_head"]
 
 
@@ -344,9 +352,9 @@ def prefill(params, prompt: jnp.ndarray, cfg: DeepseekConfig, table):
     def block(x, lp, experts, layer, _):
         x, latent = _attend_prefill(x, lp, cfg, cos, sin)
         x, _ = _ffn(x, lp, cfg, experts, layer)
-        return x, latent
+        return x, None, latent
 
-    x, latent = _stack(params["embed"][prompt], params, cfg, block)
+    x, latent, _ = _stack(params["embed"][prompt], params, cfg, block)
     h = rmsnorm(x[:, -1], params["final_norm"], cfg.norm_eps)
     logits = (h @ params["lm_head"]).astype(jnp.float32)
     return logits, latent
@@ -373,11 +381,16 @@ def decode_step_paged(
 ):
     """One decode step over the latent paged pool. token, pos: [B] int32;
     block_tables: [B, max_blocks]; ``cache["dense"]``, ``cache["moe"]``:
-    [layers of the group, N, bs, latent_row], a leaf a group so that each
-    scan takes and returns its own. Each row's new latent is written at ``pos`` (through
-    its table), then its heads attend positions [0, pos] in the absorbed
-    form: the kernel where Pallas is native (``kernel`` None defers to
-    ``paged_kernel_enabled()``), else a gather of the row's pages.
+    [layers of the group, N, bs, latent_row], a leaf a group, which that
+    group's scan carries (``_stack``) as ``[layers * N, bs, latent_row]``, a
+    reshape of leading axes. Each row's new latent is written at ``pos``
+    (through its table, into page ``layer * N + physical block`` of that
+    stack), then its heads attend positions [0, pos] in the absorbed form
+    over the WHOLE stack through tables offset by ``layer * N``: the kernel
+    where Pallas is native (``kernel`` None defers to
+    ``paged_kernel_enabled()``), else a gather of the row's pages. Nothing
+    is sliced out of the pool, so a caller that donates it (the engine does)
+    gets it back updated in place.
 
     Returns (logits [B, V] float32, cache, counters [3] int32 in the order
     of ``DECODE_COUNTERS``, over all B rows of the step, free slots' dummy
@@ -388,7 +401,7 @@ def decode_step_paged(
     )
 
     use_kernel = paged_kernel_enabled() if kernel is None else bool(kernel)
-    bs, row = cache["moe"].shape[2], cache["moe"].shape[3]
+    n_pages, bs, row = cache["moe"].shape[1:]
     n_cols = block_tables.shape[1]
     b = token.shape[0]
     c, s = table[0][pos], table[1][pos]  # [B, rope/2]
@@ -397,7 +410,8 @@ def decode_step_paged(
     valid = jnp.arange(n_cols * bs)[None, :] <= pos[:, None]
     pad = row - cfg.latent_width
 
-    def block(x, lp, experts, layer, layer_pool):  # layer_pool: [N, bs, row]
+    def block(x, lp, experts, layer, pool):  # pool: [layers * N, bs, row]
+        first = layer * n_pages  # this layer's pages are [first, first + N)
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q_nope, q_rope = _queries(h, lp, cfg)
         c_kv, k_r = _latent(h, lp, cfg)
@@ -405,7 +419,8 @@ def decode_step_paged(
         new = jnp.concatenate(
             [c_kv, _rope(k_r, c, s), jnp.zeros((b, pad), c_kv.dtype)], axis=-1)
         # free slots all write the trash block: duplicates there are harmless
-        layer_pool = layer_pool.at[phys, off].set(new.astype(layer_pool.dtype))
+        pool = pool.at[first + phys, off].set(new.astype(pool.dtype))
+        tables = block_tables + first
         w_kb, w_vb = _wkv_b(lp, cfg)
         q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, w_kb)
         if use_kernel:
@@ -413,10 +428,10 @@ def decode_step_paged(
                 [q_lat, q_rope, jnp.zeros((b, cfg.n_heads, pad), q_lat.dtype)],
                 axis=-1)
             u = mla_paged_decode_attention(
-                q_row, layer_pool, block_tables, pos,
+                q_row, pool, tables, pos,
                 v_width=cfg.kv_lora_rank, sm_scale=cfg.sm_scale)
         else:
-            rows = layer_pool[block_tables].reshape(b, n_cols * bs, row)
+            rows = pool[tables].reshape(b, n_cols * bs, row)
             u = absorbed_attention(q_lat, q_rope, rows, valid, cfg)
         att = jnp.einsum("bhr,rhd->bhd", u.astype(x.dtype), w_vb)
         x = x + att.reshape(b, cfg.n_heads * cfg.v_head_dim) @ lp["wo"]
@@ -426,13 +441,17 @@ def decode_step_paged(
         else:
             counters = jnp.stack(
                 [jnp.sum(sizes > 0), jnp.sum(sizes), jnp.max(sizes)]).astype(jnp.int32)
-        return x, (layer_pool, counters)
+        return x, pool, counters
 
-    x, outs = _stack(params["embed"][token], params, cfg, block, pools=cache)
+    x, counters, pools = _stack(
+        params["embed"][token], params, cfg, block,
+        pools={name: leaf.reshape((-1,) + leaf.shape[2:])
+               for name, leaf in cache.items()})
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return (logits, {name: pool for name, (pool, _) in outs.items()},
-            jnp.sum(outs["moe"][1], axis=0))
+    return (logits,
+            {name: pool.reshape(cache[name].shape) for name, pool in pools.items()},
+            jnp.sum(counters["moe"], axis=0))
 
 
 class DeepseekServing:

@@ -113,6 +113,72 @@ def _apply_rope_rows(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndar
     return out.astype(dtype)
 
 
+def _flat_pages(cache: jnp.ndarray) -> jnp.ndarray:
+    """A stacked cache ``[L, P, Hkv, T, hd]`` (P pages of T positions a
+    layer: the paged pool's blocks, or the slot layout's rows) as the decode
+    steps carry it through their layer loop: ``[L * P * Hkv, T, hd]``, a
+    reshape of leading axes and no copy."""
+    return cache.reshape((-1,) + cache.shape[3:])
+
+
+def _write_rows(
+    flat: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray, new: jnp.ndarray
+) -> jnp.ndarray:
+    """Write ``new[..., h, :]`` at position ``off[...]`` of page
+    ``page[...]`` (counted through all layers: ``layer * P + p``) of
+    ``flat`` (``_flat_pages``). new: [..., Hkv, hd]; page, off: [...] int32.
+
+    The update window is one ``[hd]`` row of the ``[L * P * Hkv, T, hd]``
+    view on purpose. Written as ``.at[page, :, off, :]`` on the five-axis
+    pool the window is ``[Hkv, hd]`` across the T axis, XLA then lays the
+    carried pool out with such a window contiguous, and the attention kernel
+    (row-major pages) gets a copy of the WHOLE pool a layer. With a one-row
+    window nothing but row-major suits the scatter, the kernel's operand and
+    the loop's carry agree, and under donation the pool is written in place.
+    Rows that name the same (page, off), as free slots all naming the trash
+    block do, may land in any order."""
+    hkv, hd = new.shape[-2:]
+    page, off = jnp.broadcast_arrays(page, off)
+    i0 = (page[..., None] * hkv + jnp.arange(hkv, dtype=page.dtype)).reshape(-1)
+    i1 = jnp.repeat(off.reshape(-1), hkv)
+    return flat.at[i0, i1].set(new.reshape(-1, hd).astype(flat.dtype))
+
+
+def _gather_pages(flat: jnp.ndarray, tables: jnp.ndarray, nkv: int) -> jnp.ndarray:
+    """Each row's pages out of the stack, in logical position order: flat
+    ``[P * Hkv, bs, hd]``, tables ``[B, cols]`` of pages counted through
+    all layers -> ``[B, Hkv, cols * bs, hd]``."""
+    bs, hd = flat.shape[1:]
+    b, cols = tables.shape
+    pages = flat.reshape(-1, nkv, bs, hd)[tables]  # [B, cols, Hkv, bs, hd]
+    return pages.transpose(0, 2, 1, 3, 4).reshape(b, nkv, cols * bs, hd)
+
+
+def _layer_rows(flat: jnp.ndarray, layer, rows: int, nkv: int) -> jnp.ndarray:
+    """One layer of the slot layout out of the stack: ``[B, Hkv, C, hd]``."""
+    return jax.lax.dynamic_slice_in_dim(
+        flat, layer * (rows * nkv), rows * nkv
+    ).reshape((rows, nkv) + flat.shape[1:])
+
+
+def _scan_layers_over_cache(layer_fn, x, layers, cache):
+    """``lax.scan`` of a decode step's layers with the cache as the loop's
+    CARRY: ``layer_fn((x, k_flat, v_flat), (lp, layer)) -> (carry, None)``,
+    k/v as ``_flat_pages`` lays them. Returns (x, the cache in its own
+    shape). A cache scanned over instead (an ``xs`` operand taken back as
+    stacked ``ys``) is sliced a layer, copied for the kernel and stacked
+    into a second buffer every step; carried, and donated by the caller's
+    jit, the buffer that goes in is the one that comes out."""
+    count = cache["k"].shape[0]
+    (x, k_flat, v_flat), _ = jax.lax.scan(
+        layer_fn,
+        (x, _flat_pages(cache["k"]), _flat_pages(cache["v"])),
+        (layers, jnp.arange(count, dtype=jnp.int32)),
+    )
+    return x, {"k": k_flat.reshape(cache["k"].shape),
+               "v": v_flat.reshape(cache["v"].shape)}
+
+
 def prefill(
     params: Dict[str, Any],
     prompt: jnp.ndarray,
@@ -366,8 +432,11 @@ def decode_step_ragged(
         keep &= positions[None, :] > pos[:, None] - cfg.sliding_window
     valid = keep[:, None, None, :]  # [B, 1, 1, C]
 
-    def layer_fn(x, inputs):
-        lp, k_cache, v_cache = inputs  # k/v: [B, Hkv, C, hd]
+    def layer_fn(carry, inputs):
+        # the cache rides the loop as its carry (see decode_step_paged):
+        # k/v [L * B * Hkv, C, hd], a layer writes its B rows in place
+        x, k_flat, v_flat = carry
+        lp, layer = inputs
         nh = lp["wq"].shape[-1] // hd
         nkv = lp["wk"].shape[-1] // hd
         group = nh // nkv
@@ -382,8 +451,10 @@ def decode_step_ragged(
         v = v.reshape(B, nkv, hd)
         q = _apply_rope_rows(q, c, s)
         k = _apply_rope_rows(k, c, s)
-        k_cache = k_cache.at[rows, :, slot, :].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, :, slot, :].set(v.astype(v_cache.dtype))
+        k_flat = _write_rows(k_flat, layer * B + rows, slot, k)
+        v_flat = _write_rows(v_flat, layer * B + rows, slot, v)
+        k_cache = _layer_rows(k_flat, layer, B, nkv)
+        v_cache = _layer_rows(v_flat, layer, B, nkv)
         qf = q.reshape(B, nkv, group, hd).astype(jnp.float32)
         logits = jnp.einsum(
             "bhgd,bhtd->bhgt", qf, k_cache.astype(jnp.float32)
@@ -404,14 +475,12 @@ def decode_step_ragged(
         else:
             gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
             x = x + gated @ lp["w_down"]
-        return x, (k_cache, v_cache)
+        return (x, k_flat, v_flat), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"])
-    )
+    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+    return logits.astype(jnp.float32), cache
 
 
 def decode_step_paged(
@@ -434,11 +503,20 @@ def decode_step_paged(
 
     Logical position ``p`` of row ``b`` lives at physical cache slot
     ``block_tables[b, p // block_size] * block_size + p % block_size``.
-    The write is a per-row scatter into (physical block, offset); the
-    read gathers each row's referenced blocks
-    (``k_cache[block_tables]``) and reshapes them back into logical
-    position order [B, Hkv, max_blocks * block_size, D], after which the
-    attention math — validity mask included — is IDENTICAL to
+
+    The pool is the layer loop's CARRY, never a scanned operand: a layer
+    sees the whole stack as ``[L * N * Hkv, bs, hd]`` (``_flat_pages``, a
+    reshape of leading axes), writes each row's new (k, v) into page
+    ``layer * N + physical block`` one ``[hd]`` row at a time
+    (``_write_rows``, which says why one row), and reads through
+    ``block_tables + layer * N``. So nothing of the pool is sliced, copied
+    or stacked: a caller that donates the cache (the engine does) gets the
+    same buffer back with B rows a layer changed, and one that does not
+    pays one copy of it on entry.
+
+    The read gathers each row's referenced blocks and reshapes them back
+    into logical position order [B, Hkv, max_blocks * block_size, D], after
+    which the attention math — validity mask included — is IDENTICAL to
     ``decode_step_ragged`` over a cache of length
     ``max_blocks * block_size``. Rows sharing prefix blocks (refcounted
     by the allocator) read the same physical (k, v) without copies;
@@ -496,8 +574,11 @@ def decode_step_paged(
     positions = jnp.arange(C)
     valid = (positions[None, :] <= pos[:, None])[:, None, None, :]
 
-    def layer_fn(x, inputs):
-        lp, k_cache, v_cache = inputs  # k/v: [N, Hkv, bs, hd]
+    n_pages = cache["k"].shape[1]
+
+    def layer_fn(carry, inputs):
+        x, k_flat, v_flat = carry  # k/v: [L * N * Hkv, bs, hd]
+        lp, layer = inputs
         nh = lp["wq"].shape[-1] // hd
         nkv = lp["wk"].shape[-1] // hd
         group = nh // nkv
@@ -512,28 +593,30 @@ def decode_step_paged(
         v = v.reshape(B, nkv, hd)
         q = _apply_rope_rows(q, c, s)
         k = _apply_rope_rows(k, c, s)
-        # per-row scatter into (physical block, offset); free slots all
+        # this layer's pages are [first, first + N) of the stack: a row's
+        # write and the tables it attends through are offset by that.
+        # Per-row scatter into (physical block, offset); free slots all
         # target the trash block — duplicate indices there are harmless
         # because trash contents are never attendable
-        k_cache = k_cache.at[phys, :, off, :].set(k.astype(k_cache.dtype))
-        v_cache = v_cache.at[phys, :, off, :].set(v.astype(v_cache.dtype))
+        first = layer * n_pages
+        k_flat = _write_rows(k_flat, first + phys, off, k)
+        v_flat = _write_rows(v_flat, first + phys, off, v)
+        # attention reads the WHOLE stack through the offset tables (a
+        # reshape of leading axes): a layer sliced out of it and handed to
+        # the kernel would be a copy of that layer
+        tables = block_tables + first
         qf = q.reshape(B, nkv, group, hd).astype(jnp.float32)
         if use_kernel:
             # fused path: the kernel walks the block table itself (the
             # table rides in as a scalar-prefetch operand), so the
             # [B, Hkv, C, hd] logical gather is never materialized
             att = paged_decode_attention(
-                qf, k_cache, v_cache, block_tables, pos
+                qf, k_flat.reshape(-1, nkv, bs, hd),
+                v_flat.reshape(-1, nkv, bs, hd), tables, pos,
             )
         else:
-            # gather each row's blocks and lay them out in logical order:
-            # [B, max_blocks, Hkv, bs, hd] -> [B, Hkv, max_blocks*bs, hd]
-            kk = k_cache[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-                B, nkv, C, hd
-            )
-            vv = v_cache[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-                B, nkv, C, hd
-            )
+            kk = _gather_pages(k_flat, tables, nkv)
+            vv = _gather_pages(v_flat, tables, nkv)
             logits = jnp.einsum(
                 "bhgd,bhtd->bhgt", qf, kk.astype(jnp.float32)
             ) / jnp.sqrt(jnp.float32(hd))
@@ -555,14 +638,12 @@ def decode_step_paged(
         else:
             gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
             x = x + gated @ lp["w_down"]
-        return x, (k_cache, v_cache)
+        return (x, k_flat, v_flat), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"])
-    )
+    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+    return logits.astype(jnp.float32), cache
 
 
 def _apply_rope_block(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
@@ -670,8 +751,12 @@ def decode_step_verify(
     keep = positions[None, None, :] <= qpos[:, :, None]
     valid = keep[:, None, None, :, :]  # [B, 1, 1, K, C]
 
-    def layer_fn(x, inputs):
-        lp, k_cache, v_cache = inputs
+    # pages a layer, as _flat_pages counts them: blocks, or the slots' rows
+    n_pages = cache["k"].shape[1]
+
+    def layer_fn(carry, inputs):
+        x, k_flat, v_flat = carry  # the cache is the loop's carry
+        lp, layer = inputs
         nh = lp["wq"].shape[-1] // hd
         nkv = lp["wk"].shape[-1] // hd
         group = nh // nkv
@@ -692,23 +777,18 @@ def decode_step_verify(
         # line up with the advanced-indexing result layout.
         kw = k.transpose(0, 2, 1, 3)
         vw = v.transpose(0, 2, 1, 3)
+        first = layer * n_pages
         if paged:
-            k_cache = k_cache.at[phys, :, off, :].set(kw.astype(k_cache.dtype))
-            v_cache = v_cache.at[phys, :, off, :].set(vw.astype(v_cache.dtype))
-            kk = k_cache[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-                B, nkv, C, hd
-            )
-            vv = v_cache[block_tables].transpose(0, 2, 1, 3, 4).reshape(
-                B, nkv, C, hd
-            )
+            k_flat = _write_rows(k_flat, first + phys, off, kw)
+            v_flat = _write_rows(v_flat, first + phys, off, vw)
+            tables = block_tables + first
+            kk = _gather_pages(k_flat, tables, nkv)
+            vv = _gather_pages(v_flat, tables, nkv)
         else:
-            k_cache = k_cache.at[rows[:, None], :, wpos, :].set(
-                kw.astype(k_cache.dtype)
-            )
-            v_cache = v_cache.at[rows[:, None], :, wpos, :].set(
-                vw.astype(v_cache.dtype)
-            )
-            kk, vv = k_cache, v_cache
+            k_flat = _write_rows(k_flat, first + rows[:, None], wpos, kw)
+            v_flat = _write_rows(v_flat, first + rows[:, None], wpos, vw)
+            kk = _layer_rows(k_flat, layer, B, nkv)
+            vv = _layer_rows(v_flat, layer, B, nkv)
         qf = q.reshape(B, nkv, group, K, hd).astype(jnp.float32)
         logits = jnp.einsum(
             "bhgqd,bhtd->bhgqt", qf, kk.astype(jnp.float32)
@@ -730,14 +810,12 @@ def decode_step_verify(
         else:
             gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
             x = x + gated @ lp["w_down"]
-        return x, (k_cache, v_cache)
+        return (x, k_flat, v_flat), None
 
-    x, (k_new, v_new) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"])
-    )
+    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
+    return logits.astype(jnp.float32), cache
 
 
 class LlamaServing:

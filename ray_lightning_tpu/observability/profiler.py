@@ -197,6 +197,11 @@ class CostReport:
     flops: float
     bytes_accessed: float
     collectives: Dict[str, Dict[str, float]] = field(default_factory=dict)
+    # from the executable's memory analysis: the bytes of donated arguments
+    # it updates in place (0 for a program that donates nothing), and the
+    # temporaries it allocates beside its arguments and outputs
+    alias_bytes: float = 0.0
+    temp_bytes: float = 0.0
 
     @property
     def collective_bytes(self) -> float:
@@ -208,6 +213,8 @@ class CostReport:
             "step_flops": self.flops,
             "step_bytes": self.bytes_accessed,
             "collective_bytes": self.collective_bytes,
+            "alias_bytes": self.alias_bytes,
+            "temp_bytes": self.temp_bytes,
             "collectives": {
                 op: dict(d) for op, d in sorted(self.collectives.items())
             },
@@ -242,11 +249,17 @@ def analyze_compiled(compiled: Any, program: str = "program") -> CostReport:
         hlo = compiled.as_text()
     except Exception:
         hlo = ""
+    try:
+        mem = compiled.memory_analysis()
+    except Exception:
+        mem = None
     return CostReport(
         program=program,
         flops=float(flat.get("flops", 0.0)),
         bytes_accessed=float(flat.get("bytes accessed", 0.0)),
         collectives=collectives_from_hlo(hlo),
+        alias_bytes=float(getattr(mem, "alias_size_in_bytes", 0) or 0),
+        temp_bytes=float(getattr(mem, "temp_size_in_bytes", 0) or 0),
     )
 
 

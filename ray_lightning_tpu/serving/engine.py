@@ -35,6 +35,14 @@ Two KV layouts behind the same two-program contract
   requests, which is what lifts resident concurrency past
   ``num_slots × max_len`` HBM.
 
+The pool is updated IN PLACE: it is donated to both programs, prefill
+scatters whole blocks into it and the decode steps carry it through their
+layer loop writing one row a slot a layer, so a tick holds one pool and a
+program moves the rows it changes, not the pool. The arrays under
+``pool.cache`` are therefore dead from each dispatch to the rebind that
+follows it; ``_pool_lock`` covers that span, for the one reader on another
+thread (``export_shipment``).
+
 After warmup (one prefill + one decode compile) the jit caches are
 flat: admission, recycling, mixed prompt lengths, EOS — none of it
 changes a device shape. ``compile_stats()`` exposes the cache sizes so
@@ -398,6 +406,14 @@ class InferenceEngine:
         self._work = rlt_condition(
             "serving.engine.InferenceEngine._work", self._state_lock
         )
+        # The pool is DONATED to every program that updates it: the arrays
+        # under self.pool.cache are dead from the dispatch until the rebind
+        # to the program's output. Both happen under this lock (_update_pool),
+        # and so does any read from another thread (export_shipment), so
+        # whoever holds it sees live arrays. Never held together with _work.
+        self._pool_lock = rlt_lock(
+            "serving.engine.InferenceEngine._pool_lock"
+        )
         self._closed = False
         self._thread: Optional[threading.Thread] = None
         self._stop_when_idle = False
@@ -615,23 +631,33 @@ class InferenceEngine:
                 )
                 return sampled_of(logits, key), cache
 
-            self._prefill_fn = _compile_cache.jit_program(
-                _with_precision(prefill_into_paged), "serve_prefill"
-            )
-            self._decode_fn = _compile_cache.jit_program(
-                _with_precision(
-                    decode_verify_paged if spec_k > 0 else decode_paged
-                ), "serve_decode"
-            )
+            def install_blocks(cache, ids, blocks):
+                # an imported shipment's blocks, written where the prefill
+                # would have written them
+                return {
+                    name: leaf.at[:, ids].set(blocks[name].astype(leaf.dtype))
+                    for name, leaf in cache.items()
+                }
+
+            # not one of the two tracked programs (a shape a block count)
+            self._install_fn = jax.jit(install_blocks, donate_argnums=(0,))
+            prefill_fn = prefill_into_paged
+            decode_fn = decode_verify_paged if spec_k > 0 else decode_paged
         else:
-            self._prefill_fn = _compile_cache.jit_program(
-                _with_precision(prefill_into), "serve_prefill"
-            )
-            self._decode_fn = _compile_cache.jit_program(
-                _with_precision(
-                    decode_verify if spec_k > 0 else decode
-                ), "serve_decode"
-            )
+            prefill_fn = prefill_into
+            decode_fn = decode_verify if spec_k > 0 else decode
+        # The pool (argument 1, behind the parameters) is donated to both:
+        # the buffer that goes in is the one that comes out, and a program
+        # writes only the rows that change (the decode steps carry the pool
+        # through their layer loop; prefill's scatter of whole blocks needs
+        # the donation alone). Undonated, each would copy the whole pool to
+        # change a few rows of it, and a tick would hold it two or three times.
+        self._prefill_fn = _compile_cache.jit_program(
+            _with_precision(prefill_fn), "serve_prefill", donate_argnums=(1,)
+        )
+        self._decode_fn = _compile_cache.jit_program(
+            _with_precision(decode_fn), "serve_decode", donate_argnums=(1,)
+        )
 
     def _program_specs(self):
         """(name, fn, dummy_args) for both serving programs, with dummy
@@ -642,7 +668,11 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         ecfg = self.engine_config
-        cache = self.pool.cache
+        # the pool by its shapes alone: its arrays are donated every tick
+        cache = {
+            name: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+            for name, leaf in self.pool.cache.items()
+        }
         prompt = jnp.zeros((1, ecfg.max_prompt_len), jnp.int32)
         if self._speculate_k > 0:
             token = jnp.zeros(
@@ -860,10 +890,33 @@ class InferenceEngine:
             self.stats["ticks"] += 1
             self.stats["tick_s"] += time.perf_counter() - t0
 
+    def _update_pool(self, run):
+        """Dispatch one program that the pool is donated to and bind the pool
+        to its output, as one step under ``_pool_lock``: ``run(cache) ->
+        (the new cache, the program's other output)``; returns the latter.
+        Nothing keeps the tree that went in, which is dead from the dispatch
+        on. A program that raises after it consumed its input leaves no
+        pool: the engine is then marked failed and no later tick runs."""
+        with self._pool_lock:
+            try:
+                cache, out = run(self.pool.cache)
+            except Exception as e:
+                gone = any(a.is_deleted() for a in self.pool.cache.values())
+                if gone and self.failed is None:
+                    self.failed = e
+                raise
+            self.pool.cache = cache
+        return out
+
     def _run_tick(self) -> Dict[str, Any]:
         import jax
         import jax.numpy as jnp
 
+        if self.failed is not None:
+            raise EngineClosed(
+                f"the engine failed ({self.failed!r}) and its KV pool went "
+                "with the program that raised: build a new engine"
+            ) from self.failed
         self._ticks += 1
         if self._goodput is not None:
             self._goodput.enter("productive_compute")
@@ -878,7 +931,6 @@ class InferenceEngine:
             self._evict_expired_slots()
             plan = self.scheduler.tick()
         ecfg = self.engine_config
-        cache = self.pool.cache  # the pool's device leaves, as one tree
 
         new_exports: List[str] = []
         # (trace, dispatch start, dispatch end) of this tick's prefills:
@@ -900,18 +952,16 @@ class InferenceEngine:
                 tr = req.trace
                 t0 = time.perf_counter() if tr is not None else 0.0
                 if paged:
-                    wt = self.pool.prompt_write_table(
+                    where = jnp.asarray(self.pool.prompt_write_table(
                         slot.index, self._n_prompt_blocks
-                    )
-                    cache = self._prefill_fn(
-                        self.params, cache, jnp.asarray(padded),
-                        jnp.asarray(wt),
-                    )
+                    ))
                 else:
-                    cache = self._prefill_fn(
-                        self.params, cache, jnp.asarray(padded),
-                        jnp.int32(slot.index),
-                    )
+                    where = jnp.int32(slot.index)
+                prompt_row = jnp.asarray(padded)
+                self._update_pool(lambda cache: (
+                    self._prefill_fn(self.params, cache, prompt_row, where),
+                    None,
+                ))
                 if tr is not None:
                     prefill_traces.append((tr, t0, time.perf_counter()))
                 slot.pos = req.prompt_len - 1
@@ -1010,9 +1060,9 @@ class InferenceEngine:
                 if paged:
                     inputs.append(jnp.asarray(block_tables))
             with _obs.phase_span("rlt.serve.decode_dispatch"):
-                sampled, cache = self._decode_fn(
+                sampled = self._update_pool(lambda cache: self._decode_fn(
                     self.params, cache, *inputs, sub
-                )
+                )[::-1])
             t_sync = time.perf_counter()
             with _obs.phase_span(
                 "rlt.serve.sample_sync", prefills=len(plan.prefills)
@@ -1077,11 +1127,10 @@ class InferenceEngine:
             for tr, t0, t1 in prefill_traces:
                 tr.prefilled(t1 - t0, done_at=t1, synced=False)
 
-        self.pool.cache = cache
         if new_exports:
-            # publish AFTER the cache swap: the fleet's migration pump
-            # snapshots block payloads from self.pool.cache, which only
-            # now holds this tick's prefill writes
+            # published once the tick is over: the fleet's migration pump
+            # reads the block payloads out of self.pool.cache
+            # (export_shipment), behind this tick's prefill writes
             with self._work:
                 self._ready_exports.extend(new_exports)
                 self._work.notify_all()
@@ -1269,10 +1318,14 @@ class InferenceEngine:
 
         Read-only and callable from the fleet's pump thread: the slot is
         export-parked (the decode filter skips it, so its blocks are
-        never written), its prefix chains were pinned at arm time, and
-        ``self.pool.cache`` arrays are immutable jax values — a
-        concurrent tick swaps the dict but never mutates the blocks this
-        slot owns. The shipment carries ALL prompt blocks (including
+        never written) and its prefix chains were pinned at arm time. The
+        pool's arrays are NOT stable values, though: every tick donates them
+        to its programs, and what ``self.pool.cache`` named a moment ago may
+        be deleted. So the blocks are gathered under ``_pool_lock``, which a
+        tick holds from each dispatch to the rebind: the gather is enqueued
+        on live arrays, behind the programs that wrote them, and a later
+        donation waits for it. The copy to the host happens outside the
+        lock. The shipment carries ALL prompt blocks (including
         source-shared ones): the receiver may not hold the chain."""
         with self._work:
             rec = self._exports.get(request_id)
@@ -1286,13 +1339,18 @@ class InferenceEngine:
         alloc = self.pool._alloc_of[rec["slot"]]
         bs = self.pool.block_size
         n_prompt_blocks = (slot.prompt_len - 1) // bs + 1
-        cache = self.pool.cache
-        block_k = []
-        block_v = []
-        for j in range(n_prompt_blocks):
-            bid = alloc.blocks[j]
-            block_k.append(np.asarray(cache["k"][:, bid]))
-            block_v.append(np.asarray(cache["v"][:, bid]))
+        ids = np.asarray(alloc.blocks[:n_prompt_blocks], np.int32)
+        with self._pool_lock:
+            if self.failed is not None:
+                raise EngineClosed(
+                    f"request {request_id!r}: the engine failed and its KV "
+                    "pool is gone"
+                ) from self.failed
+            cache = self.pool.cache
+            k, v = cache["k"][:, ids], cache["v"][:, ids]  # [L, n, ...]
+        k, v = np.asarray(k), np.asarray(v)
+        block_k = [np.ascontiguousarray(k[:, j]) for j in range(n_prompt_blocks)]
+        block_v = [np.ascontiguousarray(v[:, j]) for j in range(n_prompt_blocks)]
         prompt = self._export_prompt(request_id, slot)
         # Lineage: the parked slot's trace hands the shipment a hop
         # context (parent rid, accumulated TTFT components, send stamp)
@@ -1479,8 +1537,9 @@ class InferenceEngine:
         # install the payloads this replica does not already share: the
         # receiver's own prefix-cache hits (alloc.shared leading blocks)
         # hold identical bytes by chain-key construction, everything
-        # else gets the shipped blocks. Eager scatter, not one of the
-        # two tracked jitted programs — compile_stats stays flat.
+        # else gets the shipped blocks. A jitted scatter the pool is donated
+        # to, like the two tracked programs (so in place), but not one of
+        # them: compile_stats stays flat.
         alloc = self.pool._alloc_of[slot.index]
         bs = self.pool.block_size
         n_prompt_blocks = (len(prompt) - 1) // bs + 1
@@ -1490,12 +1549,13 @@ class InferenceEngine:
         ]
         if write:
             ids = jnp.asarray([b for b, _ in write])
-            ck, cv = self.pool.cache["k"], self.pool.cache["v"]
-            ks = np.stack([shipment.block_k[j] for _, j in write], axis=1)
-            vs = np.stack([shipment.block_v[j] for _, j in write], axis=1)
-            ck = ck.at[:, ids].set(jnp.asarray(ks, ck.dtype))
-            cv = cv.at[:, ids].set(jnp.asarray(vs, cv.dtype))
-            self.pool.cache = dict(self.pool.cache, k=ck, v=cv)
+            blocks = {
+                "k": np.stack([shipment.block_k[j] for _, j in write], axis=1),
+                "v": np.stack([shipment.block_v[j] for _, j in write], axis=1),
+            }
+            self._update_pool(
+                lambda cache: (self._install_fn(cache, ids, blocks), None)
+            )
         # resume exactly where the colocated path would be after its own
         # prefill: the next decode step re-runs the last prompt token at
         # pos P-1 (idempotent KV rewrite), so the first emitted token —
@@ -1613,7 +1673,13 @@ class InferenceEngine:
         )
 
     def _fail_all(self, error: BaseException) -> None:
-        self.failed = error
+        """Fail every queued and in-flight request with ``error`` and mark
+        the engine dead. Where a program raised, the pool is gone too (it
+        was donated to that program: ``_update_pool``), so there is nothing
+        to resume from: ``failed`` stays set, ``step()`` refuses to run
+        again, and the replica is relaunched, not restarted."""
+        if self.failed is None:
+            self.failed = error
         if self._goodput is not None:
             # the time from here until a successor engine adopts the
             # ledger is unplanned recovery, not idle
@@ -1770,7 +1836,10 @@ class InferenceEngine:
         :meth:`step` call-site shapes/dtypes, publishes the
         ``rlt_step_flops``/``rlt_step_bytes``/collective gauges labeled
         ``program=serve_prefill|serve_decode``, and returns the per-program
-        reports with analytic roofline verdicts. With the compile cache on,
+        reports with analytic roofline verdicts, the bytes each executable
+        updates in place (``alias_bytes``: the donated pool, where the
+        program's writes leave it where it is) and ``pool_bytes`` to hold
+        that against. With the compile cache on,
         the analysis reuses the cached executable (the one the serving loop
         dispatches), so this is near-free on a warm cache instead of paying
         a second compile."""
@@ -1778,6 +1847,7 @@ class InferenceEngine:
         from ray_lightning_tpu.observability import profiler as _profiler
 
         programs = self._program_specs()
+        pool_bytes = sum(int(a.nbytes) for a in self.pool.cache.values())
         out: Dict[str, Any] = {}
         reg = _obs2.registry()
         for name, fn, args in programs:
@@ -1789,5 +1859,8 @@ class InferenceEngine:
                 _profiler.publish_cost_report(reg, rep)
             d = rep.to_dict()
             d["roofline"] = _profiler.roofline(rep)
+            # beside alias_bytes: a program that updates the pool in place
+            # aliases at least this much
+            d["pool_bytes"] = pool_bytes
             out[name] = d
         return out

@@ -94,8 +94,12 @@ class Slot:
 class KVSlotPool:
     """num_slots cache rows + free-list + occupancy counters.
 
-    The pool owns the cache arrays (``self.cache``); the engine swaps
-    them after every jitted call (functional updates). Sliding-window
+    The pool owns the cache arrays (``self.cache``). The engine DONATES
+    them to every program that writes them and rebinds ``self.cache`` to
+    the program's output (``InferenceEngine._update_pool``): the same
+    buffers, updated in place, under new array objects. An array read off
+    ``self.cache`` earlier is deleted by the next tick, so keep a host copy
+    (``np.array``), not the array. Sliding-window
     configs are refused: their rolling buffers are per-POSITION-modulo
     structures and the serving path sizes every slot to ``max_len``
     (full cache) so that admit/recycle never has to reason about wrap

@@ -468,7 +468,10 @@ class PagedKVPool:
     engine slot has a row in the FIXED-shape host block table
     [num_slots, max_blocks] (int32, trash-padded) that the model's paged
     decode step reads its pages through. The allocator, the tables and
-    the slots know blocks only, never what is in them.
+    the slots know blocks only, never what is in them. The leaves
+    (``self.cache``) are donated to the engine's programs and rebound to
+    their outputs every tick, as :class:`~.kv_pool.KVSlotPool`'s are: one
+    allocation, updated in place.
     Admission is by block availability (the allocator's reservation
     contract), not by free slot alone — the pool can refuse a request
     while slots are free, which is the back-pressure signal the

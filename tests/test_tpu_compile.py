@@ -474,3 +474,118 @@ def test_flash_attention_with_192_wide_keys_and_128_wide_values_compiles(one_chi
         fn, ((1, 32, 2048, 192), jnp.bfloat16), ((1, 32, 2048, 192), jnp.bfloat16),
         ((1, 32, 2048, 128), jnp.bfloat16), sharding=one_chip)
     assert n == 1
+
+
+# ---------------------------------------------------------------------- #
+# the KV pool is updated in place: what the chip's compiler makes of the
+# engine's two programs at the serve cells' pool shapes
+# ---------------------------------------------------------------------- #
+_COPIES = ("copy", "copy-start", "copy-done", "dynamic-slice", "dynamic-update-slice")
+
+
+def _pool_sized_copies(text, leaves):
+    """Instructions of a compiled program that copy, slice or stack
+    something of a pool leaf's size: ``leaves`` are ``(shape, dtype)`` of the
+    leaves ``[L, N, *block]``; looked for are results shaped like a leaf, a
+    leaf flattened over layers (and over heads, as the decode steps carry
+    it), or one layer of either. A fusion counts by its name, which XLA
+    makes of what it fused (``copy_dynamic-update-slice_fusion``)."""
+    import re
+
+    prefix = {"bfloat16": "bf16", "float32": "f32"}
+    sized = set()
+    for shape, dtype in leaves:
+        (layers, pages), block = shape[:2], tuple(shape[2:])
+        shapes = [shape, (layers * pages,) + block, (pages,) + block, (1, pages) + block]
+        if len(block) == 3:
+            shapes += [(layers * pages * block[0],) + block[1:],
+                       (pages * block[0],) + block[1:]]
+        sized |= {f"{prefix[jnp.dtype(dtype).name]}[{','.join(map(str, s))}]"
+                  for s in shapes}
+    found = []
+    for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(", text, re.M
+    ):
+        name, result, op = m.groups()
+        if not any(s in result for s in sized):
+            continue
+        fused = op == "fusion" and any(
+            w in name.replace("_", "-") for w in ("copy", "dynamic-slice", "dynamic-update-slice"))
+        if op in _COPIES or fused:
+            found.append(f"{op} {name} {result}")
+    return found
+
+
+def test_an_undonated_scatter_copies_its_leaf_and_the_check_sees_it(one_chip):
+    """What ``_pool_sized_copies`` is for, at a small size: writing blocks
+    into a leaf that was not donated copies the leaf first; donated, the
+    scatter alone is left and the leaf is aliased."""
+    leaf = ((4, 65, 8, 16, 128), jnp.bfloat16)
+    shapes = (leaf, ((3,), jnp.int32), ((4, 3, 8, 16, 128), jnp.bfloat16))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    write = lambda pool, ids, blocks: pool.at[:, ids].set(blocks)
+    plain = jax.jit(write).lower(*args).compile()
+    assert any(c.startswith("copy ") for c in _pool_sized_copies(plain.as_text(), [leaf]))
+    assert plain.memory_analysis().alias_size_in_bytes == 0
+    given = jax.jit(write, donate_argnums=(0,)).lower(*args).compile()
+    assert _pool_sized_copies(given.as_text(), [leaf]) == []
+    assert given.memory_analysis().alias_size_in_bytes == 4 * 65 * 8 * 16 * 128 * 2
+
+
+@pytest.fixture(scope="module", params=["serve-dense-chat", "serve-mla-moe-reason"])
+def cell_programs(request, topo, one_chip):
+    """The paged engine of a serve cell (its configuration's widths, its
+    engine settings, parameters as shapes), with both programs compiled for
+    the chip: {program: compiled}, and the pool's leaves. The kernels are
+    forced on, as on the chip, and asked to compile rather than interpret."""
+    import os
+
+    from benchmarks import loader
+    from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
+
+    cell = loader.Manifest().cell(request.param)
+    settings = cell.settings["engine"]
+    program = cell.family.program
+    cfg = program.model_config(cell.config, max_seq=settings["max_len"], remat=False)
+    params = jax.eval_shape(lambda: program.engine_params(cell.config, 1))
+    mp = pytest.MonkeyPatch()
+    mp.setenv("RLT_PAGED_KERNEL", "1")
+    try:
+        engine = InferenceEngine(params, cfg, EngineConfig(**settings))
+        leaves = [(a.shape, a.dtype) for a in engine.pool.cache.values()]
+        pool_bytes = sum(int(a.nbytes) for a in engine.pool.cache.values())
+        mp.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        compiled = {}
+        for name, fn, args in engine._program_specs():
+            shapes = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+                args)
+            compiled[name] = fn.lower(*shapes).compile()
+    finally:
+        mp.undo()
+    return compiled, leaves, pool_bytes
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_serve_programs_update_the_pool_in_place_at_the_cells_shapes(
+    cell_programs, program
+):
+    """The chat cell's K/V pool (2 x [16, 2401, 8, 16, 128] bf16, 2.5 GB)
+    and the reason cell's latent pool ([1 | 4, 8193, 16, 640], 0.84 GB):
+    each program aliases the whole pool, nothing of a leaf's or a layer's
+    size is copied, sliced or stacked, and the temporaries stay a small part
+    of the pool. Written ``.at[phys, :, off, :]`` on the five-axis pool, the
+    decode step fails all three: the carried pool takes the scatter's layout
+    and is copied whole to the kernel's, every layer. Prefill's temporaries
+    are the prompt's activations, which do not grow with the pool; they are
+    held under the largest leaf (a copy of one would be at least that)."""
+    compiled, leaves, pool_bytes = cell_programs
+    exe = compiled[program]
+    assert _pool_sized_copies(exe.as_text(), leaves) == []
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    if program == "serve_decode":
+        assert mem.temp_size_in_bytes < pool_bytes / 10
+    else:
+        largest = max(int(np.prod(s)) * jnp.dtype(d).itemsize for s, d in leaves)
+        assert mem.temp_size_in_bytes < largest
