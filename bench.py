@@ -686,10 +686,8 @@ def _serve_microbench(
     interarrival = 1.0 / max(rate_rps, 1e-9)
     decode0 = engine.stats["decode_steps"]
     busy0 = engine.stats["busy_slot_steps"]
-    paged = getattr(engine, "kv_layout", "slot") == "paged"
-    if paged:
-        hits0 = engine.pool.allocator.prefix_hits_total
-        misses0 = engine.pool.allocator.prefix_misses_total
+    hits0 = engine.pool.allocator.prefix_hits_total
+    misses0 = engine.pool.allocator.prefix_misses_total
     completions = []
     t0 = time.perf_counter()
     for i in range(num_requests):
@@ -720,15 +718,14 @@ def _serve_microbench(
             busy / max(decode_steps * num_slots, 1), 4
         ),
     }
-    if paged:
-        alloc = engine.pool.allocator
-        hits = alloc.prefix_hits_total - hits0
-        misses = alloc.prefix_misses_total - misses0
-        # peak (not instantaneous: the level has drained by now)
-        out["block_utilization"] = round(
-            alloc.blocks_highwater / max(alloc.capacity, 1), 4
-        )
-        out["prefix_hit_rate"] = round(hits / max(hits + misses, 1), 4)
+    alloc = engine.pool.allocator
+    hits = alloc.prefix_hits_total - hits0
+    misses = alloc.prefix_misses_total - misses0
+    # peak (not instantaneous: the level has drained by now)
+    out["block_utilization"] = round(
+        alloc.blocks_highwater / max(alloc.capacity, 1), 4
+    )
+    out["prefix_hit_rate"] = round(hits / max(hits + misses, 1), 4)
     return out
 
 
@@ -827,15 +824,13 @@ def _serve_sweep(args: argparse.Namespace) -> int:
         if r.strip()
     ]
     num_requests = int(os.environ.get("RLT_BENCH_SERVE_REQUESTS", "12"))
-    kv_layout = os.environ.get("RLT_BENCH_SERVE_KV_LAYOUT", "slot").strip()
     cfg = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
     params = init_params(jax.random.PRNGKey(0), cfg)
     engine = InferenceEngine(
         params,
         cfg,
         EngineConfig(
-            num_slots=4, max_prompt_len=8, max_len=32, kv_layout=kv_layout,
-            block_size=8 if kv_layout == "paged" else None,
+            num_slots=4, max_prompt_len=8, max_len=32, block_size=8,
         ),
     )
     engine.start()
@@ -858,7 +853,6 @@ def _serve_sweep(args: argparse.Namespace) -> int:
     payload = {
         "platform": "cpu",
         "num_slots": 4,
-        "kv_layout": kv_layout,
         "levels": levels,
         "peak_tokens_per_sec": max(
             lvl["tokens_per_sec"] for lvl in levels
@@ -876,9 +870,8 @@ def _attach_serve_sweep(result: dict, here: str, env: dict) -> None:
     """Attach detail.serving (the continuous-batching offered-load ramp)
     to a fresh measurement. CPU-pinned like the DCN/input sweeps — the
     child never acquires the chip. RLT_BENCH_SERVE_SWEEP=0 disables;
-    RLT_BENCH_SERVE_RATES / RLT_BENCH_SERVE_REQUESTS shape the ramp and
-    RLT_BENCH_SERVE_KV_LAYOUT ("slot" | "paged") picks the KV layout
-    recorded in detail.serving.kv_layout. RLT_BENCH_SERVE_CHAOS=1 adds
+    RLT_BENCH_SERVE_RATES / RLT_BENCH_SERVE_REQUESTS shape the ramp.
+    RLT_BENCH_SERVE_CHAOS=1 adds
     detail.serving.retries / .shed / .goodput_under_kill from a
     replica-kill-loop run (see _serve_chaos_bench)."""
     if os.environ.get("RLT_BENCH_SERVE_SWEEP", "1") == "0":

@@ -34,7 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 # one child process per entry, in this order; a child runs its phases in turn
-ONE_CHIP = ("train", "serve_slot", "serve_paged", "actor")
+ONE_CHIP = ("train", "serve", "serve_spec", "actor")
 FOUR_CHIP = ("workers4", "dp4")
 CHILD_TIMEOUT_S = 900
 
@@ -355,7 +355,7 @@ def _prompt(length: int, vocab: int, seed: int) -> List[int]:
     return [int(motif[i % 4]) for i in range(length)]
 
 
-def phase_serve(cfg, *, kv_layout: str, speculate_k: int,
+def phase_serve(cfg, *, speculate_k: int,
                 prompt_lens: Sequence[int], max_new: int, seed: int,
                 **engine_kwargs) -> Dict[str, Any]:
     """``InferenceEngine`` with the defaults of this backend, a few requests
@@ -388,8 +388,7 @@ def phase_serve(cfg, *, kv_layout: str, speculate_k: int,
 
     engine = InferenceEngine(
         params, cfg,
-        EngineConfig(kv_layout=kv_layout, speculate_k=speculate_k,
-                     **engine_kwargs),
+        EngineConfig(speculate_k=speculate_k, **engine_kwargs),
     )
     t0 = time.perf_counter()
     engine.warmup()
@@ -409,7 +408,6 @@ def phase_serve(cfg, *, kv_layout: str, speculate_k: int,
         raise AssertionError(f"short completions: {[len(g) for g in gots]}")
 
     facts: Dict[str, Any] = {
-        "kv_layout": kv_layout,
         "speculate_k": speculate_k,
         "prompt_lens": list(prompt_lens),
         "max_new": max_new,
@@ -632,23 +630,23 @@ def _child(args: argparse.Namespace) -> int:
         _need_kernels(phase, facts["custom_calls"])
         _emit(phase, t0, dict(facts, **cache.facts(), device=device))
 
-    elif phase in ("serve_slot", "serve_paged"):
+    elif phase in ("serve", "serve_spec"):
+        # the one-token decode program, then the verify program at k = 4
         device = _require_tpu()
         cache = _CacheWatch()
-        paged = phase == "serve_paged"
+        spec = phase == "serve_spec"
         facts = phase_serve(
             cfg,
-            kv_layout="paged" if paged else "slot",
-            speculate_k=4 if paged else 0,
+            speculate_k=4 if spec else 0,
             prompt_lens=(7, 40, 200, 40), max_new=12, seed=seed,
             num_slots=8, max_prompt_len=512, max_len=2048,
         )
         _need_kernels(phase + " prefill", facts["custom_calls"]["serve_prefill"])
         _need_kernels(phase + " decode", facts["custom_calls"]["serve_decode"])
         facts.update(cache.facts())
-        if paged and not facts["jax_cache_hits"] + facts["rltx_disk_hits"]:
+        if spec and not facts["jax_cache_hits"] + facts["rltx_disk_hits"]:
             raise AssertionError(
-                f"no compile-cache hit in {facts['cache_dir']}: the serve_slot "
+                f"no compile-cache hit in {facts['cache_dir']}: the serve "
                 "child compiled the same generate() programs before this one"
             )
         _emit(phase, t0, dict(facts, device=device))

@@ -144,11 +144,6 @@ KNOBS = {
         "defaults": ["'replica0:crash@every:8'"],
         "read": True, "set": False,
     },
-    'RLT_BENCH_SERVE_KV_LAYOUT': {
-        "modules": ['bench'],
-        "defaults": ["'slot'"],
-        "read": True, "set": False,
-    },
     'RLT_BENCH_SERVE_RATES': {
         "modules": ['bench'],
         "defaults": ["'4,64,512'"],

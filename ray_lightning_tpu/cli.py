@@ -241,7 +241,6 @@ def _cmd_serve(args) -> int:
         temperature=args.temperature,
         eos_id=args.eos_id,
         seed=args.seed,
-        kv_layout=args.kv_layout,
         block_size=args.block_size,
     )
     fleet = None
@@ -327,17 +326,13 @@ def _cmd_serve(args) -> int:
         "shed": len(shed_rows),
         "wall_s": round(wall, 3),
         "tokens_per_sec": round(total_tokens / wall, 2) if wall > 0 else None,
-        "kv_layout": engine.kv_layout,
         "slot_utilization": round(engine.slot_utilization(), 4),
+        "block_utilization": round(engine.pool.block_utilization(), 4),
         "compile_stats": engine.compile_stats(),
         "pool": engine.pool.stats(),
     }
     if fleet is not None:
         summary["journal"] = fleet.stats()
-    if engine.kv_layout == "paged":
-        summary["block_utilization"] = round(
-            engine.pool.block_utilization(), 4
-        )
     print(json.dumps({"summary": summary}))
     if args.cost:
         # second compile of both serving programs; off the serving loop
@@ -905,13 +900,8 @@ def main(argv: Optional[list] = None) -> int:
     serve.add_argument("--max-len", type=int, default=256)
     serve.add_argument("--max-new-tokens", type=int, default=16)
     serve.add_argument(
-        "--kv-layout", choices=("slot", "paged"), default="slot",
-        help="KV cache layout: full row per request (slot) or block-paged "
-        "with shared-prefix reuse (paged)",
-    )
-    serve.add_argument(
         "--block-size", type=int, default=None,
-        help="paged layout block size in tokens "
+        help="KV block size in tokens "
         "(default: RLT_SERVE_BLOCK_SIZE or 16; must divide --max-len)",
     )
     serve.add_argument("--temperature", type=float, default=0.0)
@@ -936,7 +926,7 @@ def main(argv: Optional[list] = None) -> int:
         "--prefill-replicas", type=int, default=0,
         help="> 0 disaggregates the fleet: the first N replicas form the "
         "prefill pool and ship checksummed KV to the decode pool "
-        "(requires --kv-layout paged and N < --replicas)",
+        "(requires N < --replicas)",
     )
     serve.add_argument(
         "--max-retries", type=int, default=0,

@@ -456,12 +456,11 @@ def decode_step_paged(
 
 class DeepseekServing:
     """The model's side of the serving contract (see
-    ``models/generation.py::LlamaServing`` for the contract): the paged
-    layout only, no speculation, no block shipments, and a pool of one leaf
-    a group of layers whose block is ``[block_size, latent_row]``."""
+    ``models/generation.py::LlamaServing`` for the contract): no
+    speculation, no block shipments, and a pool of one leaf a group of
+    layers whose block is ``[block_size, latent_row]``."""
 
     name = "latent-attention MoE decoder (models/deepseek.py)"
-    layouts = ("paged",)
     speculation = False
     counters = DECODE_COUNTERS
 

@@ -8,11 +8,14 @@ TPU-first design:
   buffer (C = window, slot = pos % C — the Mistral rolling-buffer
   design): decode memory is O(window) regardless of generation length,
   and the band mask is implied by the buffer itself;
-- the prompt is consumed in ONE batched forward pass (``prefill``) that
-  reuses the training layer math (models/llama.py::_decoder_layer with
-  ``return_kv=True``) — MXU-shaped [B, P, D] matmuls instead of P
-  sequential matvecs — and writes every layer's post-rope (k, v) into
-  the cache;
+- the prompt is consumed in ONE batched forward pass (``prefill``) —
+  MXU-shaped [B, P, D] matmuls instead of P sequential matvecs — that
+  writes every layer's post-rope (k, v) into the cache;
+- ONE layer body (``_layer``) serves ``prefill``, ``decode_step`` (the
+  contiguous cache ``generate`` steps, and the tests' reference), the
+  engine's ``decode_step_paged`` and its speculative ``decode_step_verify``:
+  they differ in the ``attend`` they hand it — what is written to the
+  cache, what is read back and how — and in nothing else;
 - the decode loop is a single ``lax.scan`` over step index, so the host
   never round-trips per token;
 - attention at decode is a masked matvec over the cache (memory-bound;
@@ -40,12 +43,13 @@ unbinding capacity_factor).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_lightning_tpu.models.llama import LlamaConfig, _decoder_layer
+from ray_lightning_tpu.models.llama import LlamaConfig
 from ray_lightning_tpu.ops.attention import attention, flash_supported
 from ray_lightning_tpu.ops.rmsnorm import rmsnorm
 from ray_lightning_tpu.ops.rope import rope_angles, rope_scaling_kind
@@ -93,31 +97,89 @@ def _rope_at(table: Tuple[jnp.ndarray, jnp.ndarray], pos: jnp.ndarray):
     return c, s
 
 
-def _apply_rope_one(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, H, hd] at one position; c/s: [1, hd/2]."""
+def _rope(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """Rotate pairs. x: [..., H, hd]; c/s: [..., hd/2], the rope rows of
+    x's positions, broadcast over the heads (and over any leading axis they
+    lack: one table row for a whole batch, a prompt's rows for every row)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
+    c = c[..., None, :]
+    s = s[..., None, :]
     x1, x2 = jnp.split(x, 2, axis=-1)
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(dtype)
 
 
-def _apply_rope_rows(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, H, hd], each row at its OWN position; c/s: [B, hd/2]."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    c = c[:, None, :]
-    s = s[:, None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(dtype)
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache):
+    """One decoder layer at inference, written once for every serving
+    function of the family. x: [..., D] — a prompt ``[B, P, D]``, one
+    position a row ``[B, D]`` or K a row ``[B, K, D]``; cos/sin: the rope
+    rows of those positions (``_rope``). The math is the training layer's
+    (models/llama.py::_decoder_layer), which ``forward`` pins the decode
+    functions to in the tests; head counts come from the weight shapes.
+
+    ``attend(q [..., H, hd], k, v [..., Hkv, hd], cache) -> (att, cache)`` is
+    the one thing the callers differ in: what of this layer's (k, v) is
+    written where, what the queries read and under which mask, by kernel,
+    gather or plain einsum. ``att`` is anything that flattens to
+    ``[..., H * hd]``; ``cache`` is the caller's own (a layer's rows, the
+    carried pool, or nothing in and the prompt's rows out).
+
+    Experts route LOSSLESSLY: capacity dropping is a training-time
+    load-balancing artifact computed over B*S competing tokens and has no
+    analogue at inference, so every routed token keeps its experts (dense
+    all-experts evaluation, no O(T^2*E) dispatch tensors), and prefill and
+    stepwise decode write the same cache."""
+    hd = cfg.head_dim
+    lead = x.shape[:-1]
+    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+    q = h @ lp["wq"]
+    k = h @ lp["wk"]
+    v = h @ lp["wv"]
+    if "bq" in lp:  # Qwen2-family qkv bias
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = _rope(q.reshape(lead + (-1, hd)), cos, sin)
+    k = _rope(k.reshape(lead + (-1, hd)), cos, sin)
+    v = v.reshape(lead + (-1, hd))
+    att, cache = attend(q, k, v, cache)
+    x = x + att.reshape(lead + (-1,)).astype(x.dtype) @ lp["wo"]
+    h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.n_experts and "moe" in lp:
+        from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
+
+        tokens = h2.reshape(x.shape[0], -1, x.shape[-1])  # [batch, seq, d]
+        x = x + moe_ffn_lossless(
+            lp["moe"], tokens, top_k=cfg.expert_top_k
+        ).reshape(x.shape)
+    else:
+        gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
+        x = x + gated @ lp["w_down"]
+    return x, cache
+
+
+def _cached_attention(q, k_cache, v_cache, valid):
+    """Masked attention of GQA-folded queries over cached rows in position
+    order. q: [B, Hkv, G, hd] (or [B, Hkv, G, K, hd], K queries a row);
+    k/v: [B, Hkv, T, hd]; valid: bool, broadcastable to the scores
+    [B, Hkv, G, (K,) T]. Float32 throughout: decode is memory-bound (the
+    MXU flash kernel buys nothing at q-length 1)."""
+    scores = jnp.einsum(
+        "bhg...d,bhtd->bhg...t", q.astype(jnp.float32),
+        k_cache.astype(jnp.float32),
+    ) / jnp.sqrt(jnp.float32(q.shape[-1]))
+    probs = jax.nn.softmax(jnp.where(valid, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhg...t,bhtd->bhg...d", probs, v_cache.astype(jnp.float32))
+
+
+def _logits(x, params, cfg: LlamaConfig) -> jnp.ndarray:
+    h = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    return (h @ params["lm_head"]).astype(jnp.float32)
 
 
 def _flat_pages(cache: jnp.ndarray) -> jnp.ndarray:
-    """A stacked cache ``[L, P, Hkv, T, hd]`` (P pages of T positions a
-    layer: the paged pool's blocks, or the slot layout's rows) as the decode
-    steps carry it through their layer loop: ``[L * P * Hkv, T, hd]``, a
-    reshape of leading axes and no copy."""
+    """The paged pool ``[L, N, Hkv, bs, hd]`` as the decode steps carry it
+    through their layer loop: ``[L * N * Hkv, bs, hd]``, a reshape of
+    leading axes and no copy."""
     return cache.reshape((-1,) + cache.shape[3:])
 
 
@@ -125,12 +187,12 @@ def _write_rows(
     flat: jnp.ndarray, page: jnp.ndarray, off: jnp.ndarray, new: jnp.ndarray
 ) -> jnp.ndarray:
     """Write ``new[..., h, :]`` at position ``off[...]`` of page
-    ``page[...]`` (counted through all layers: ``layer * P + p``) of
+    ``page[...]`` (counted through all layers: ``layer * N + block``) of
     ``flat`` (``_flat_pages``). new: [..., Hkv, hd]; page, off: [...] int32.
 
-    The update window is one ``[hd]`` row of the ``[L * P * Hkv, T, hd]``
+    The update window is one ``[hd]`` row of the ``[L * N * Hkv, bs, hd]``
     view on purpose. Written as ``.at[page, :, off, :]`` on the five-axis
-    pool the window is ``[Hkv, hd]`` across the T axis, XLA then lays the
+    pool the window is ``[Hkv, hd]`` across the bs axis, XLA then lays the
     carried pool out with such a window contiguous, and the attention kernel
     (row-major pages) gets a copy of the WHOLE pool a layer. With a one-row
     window nothing but row-major suits the scatter, the kernel's operand and
@@ -154,25 +216,30 @@ def _gather_pages(flat: jnp.ndarray, tables: jnp.ndarray, nkv: int) -> jnp.ndarr
     return pages.transpose(0, 2, 1, 3, 4).reshape(b, nkv, cols * bs, hd)
 
 
-def _layer_rows(flat: jnp.ndarray, layer, rows: int, nkv: int) -> jnp.ndarray:
-    """One layer of the slot layout out of the stack: ``[B, Hkv, C, hd]``."""
-    return jax.lax.dynamic_slice_in_dim(
-        flat, layer * (rows * nkv), rows * nkv
-    ).reshape((rows, nkv) + flat.shape[1:])
+def _scan_layers_over_cache(x, layers, cfg: LlamaConfig, cos, sin, attend, cache):
+    """``lax.scan`` of a paged decode step's layers with the pool as the
+    loop's CARRY, k/v as ``_flat_pages`` lays them;
+    ``attend(q, k, v, (k_flat, v_flat), first)`` as ``_layer`` asks,
+    ``first = layer * N``: this layer's pages are ``[first, first + N)`` of
+    the stack, so its writes and the tables it reads through are offset by
+    that. Returns (x, the pool in its own shape). A pool scanned over
+    instead (an ``xs`` operand taken back as stacked ``ys``) is sliced a
+    layer, copied for the kernel and stacked into a second buffer every
+    step; carried, and donated by the caller's jit, the buffer that goes in
+    is the one that comes out."""
+    count, n_pages = cache["k"].shape[:2]
 
+    def layer_fn(carry, inputs):
+        x, pool = carry
+        lp, layer = inputs
+        return _layer(
+            x, lp, cfg, cos, sin,
+            functools.partial(attend, first=layer * n_pages), pool,
+        ), None
 
-def _scan_layers_over_cache(layer_fn, x, layers, cache):
-    """``lax.scan`` of a decode step's layers with the cache as the loop's
-    CARRY: ``layer_fn((x, k_flat, v_flat), (lp, layer)) -> (carry, None)``,
-    k/v as ``_flat_pages`` lays them. Returns (x, the cache in its own
-    shape). A cache scanned over instead (an ``xs`` operand taken back as
-    stacked ``ys``) is sliced a layer, copied for the kernel and stacked
-    into a second buffer every step; carried, and donated by the caller's
-    jit, the buffer that goes in is the one that comes out."""
-    count = cache["k"].shape[0]
-    (x, k_flat, v_flat), _ = jax.lax.scan(
+    (x, (k_flat, v_flat)), _ = jax.lax.scan(
         layer_fn,
-        (x, _flat_pages(cache["k"]), _flat_pages(cache["v"])),
+        (x, (_flat_pages(cache["k"]), _flat_pages(cache["v"]))),
         (layers, jnp.arange(count, dtype=jnp.int32)),
     )
     return x, {"k": k_flat.reshape(cache["k"].shape),
@@ -189,12 +256,8 @@ def prefill(
     """Consume the whole prompt [B, P] in one batched forward, writing every
     layer's (k, v) into ``cache`` positions [0, P). Returns (last-position
     logits [B, V] fp32, updated cache).
-
-    Reuses the training layer (``_decoder_layer`` with ``return_kv=True``)
-    so the cache contents cannot drift from the training math.
     """
     B, P = prompt.shape
-    hd = cfg.head_dim
     if rope_table is None:
         # sized to the PROMPT, not the cache: a rolling window buffer is
         # shorter than the prompt positions it receives
@@ -202,7 +265,8 @@ def prefill(
     cos, sin = rope_table[0][:P], rope_table[1][:P]
     x = params["embed"][prompt]  # [B, P, D]
 
-    def attn_fn(q, k, v):
+    def attend(q, k, v, _):
+        q, k, v = (a.swapaxes(1, 2) for a in (q, k, v))  # [B, H, P, hd]
         # prompts have arbitrary lengths; a config-pinned impl="flash"
         # degrades to auto (which falls back to the einsum path) when the
         # prompt shape is not block-tileable, instead of raising
@@ -212,22 +276,16 @@ def prefill(
             cfg.flash_block_k or None,
         ):
             impl = None
-        return attention(q, k, v, causal=True, impl=impl,
-                         block_q=cfg.flash_block_q or None,
-                         block_k=cfg.flash_block_k or None,
-                         window=cfg.sliding_window or None)
+        att = attention(q, k, v, causal=True, impl=impl,
+                        block_q=cfg.flash_block_q or None,
+                        block_k=cfg.flash_block_k or None,
+                        window=cfg.sliding_window or None)
+        return att.swapaxes(1, 2), (k, v)  # the rows to cache, post-rope
 
-    # MoE prompts route losslessly too: generation's semantic is uniformly
-    # no-drop — prefill and stepwise decode must produce identical caches,
-    # and training's capacity truncation is a load-balancing artifact, not
-    # an inference behavior. moe_lossless runs all experts densely (no
-    # O(T^2*E) dispatch tensors).
-    def layer_fn(x, lp):
-        x, _, kv = _decoder_layer(x, lp, cfg, cos, sin, attn_fn,
-                                  return_kv=True, moe_lossless=True)
-        return x, kv
-
-    x, (ks, vs) = jax.lax.scan(layer_fn, x, params["layers"])
+    x, (ks, vs) = jax.lax.scan(
+        lambda x, lp: _layer(x, lp, cfg, cos, sin, attend, None),
+        x, params["layers"],
+    )
     # ks/vs: [L, B, Hkv, P, hd]. C >= P: slots [0, P) (pos % C == pos).
     # C < P (rolling window cache, prompt longer than the window): only
     # the last C positions can ever be attended again — scatter them to
@@ -257,9 +315,7 @@ def prefill(
             "silently loses attendable context (rolling is only valid "
             "for sliding-window configs with cache length >= the window)"
         )
-    h = rmsnorm(x[:, -1], params["final_norm"], cfg.norm_eps)
-    logits = h @ params["lm_head"]
-    return logits.astype(jnp.float32), cache
+    return _logits(x[:, -1], params, cfg), cache
 
 
 def decode_step(
@@ -270,8 +326,9 @@ def decode_step(
     cfg: LlamaConfig,
     rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One decode step. token: [B] int32; pos: scalar int32 (same position
-    for the whole batch). Returns (logits [B, V], updated cache).
+    """One decode step over a contiguous cache. token: [B] int32; pos:
+    scalar int32 (same position for the whole batch). Returns
+    (logits [B, V], updated cache).
 
     The layer stack is a ``lax.scan`` over the stacked params with the
     per-layer cache slices as a second scanned input, mirroring the
@@ -282,7 +339,6 @@ def decode_step(
     serves) — pass it when stepping in a loop so the tables are built
     once, not per step.
     """
-    hd = cfg.head_dim
     C = cache["k"].shape[3]  # may be a ROLLING window buffer (< total)
     if rope_table is None:
         # sized to the model's position limit, NOT the cache: a rolling
@@ -317,170 +373,25 @@ def decode_step(
     # (C <= window) holds exactly the band by construction, while a
     # full-length cache with a window still needs the band mask
     positions = jnp.arange(C)
-    keep = positions <= pos
+    valid = positions <= pos
     if cfg.sliding_window and C > cfg.sliding_window:
-        keep &= positions > pos - cfg.sliding_window
-    valid = keep[None, None, :]  # [1, 1, C]
+        valid &= positions > pos - cfg.sliding_window
 
-    def layer_fn(x, inputs):
-        lp, k_cache, v_cache = inputs  # k/v: [B, Hkv, C, hd]
-        B = x.shape[0]
-        nh = lp["wq"].shape[-1] // hd
-        nkv = lp["wk"].shape[-1] // hd
-        group = nh // nkv
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if "bq" in lp:  # Qwen2-family qkv bias
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(B, nh, hd)
-        k = k.reshape(B, nkv, hd)
-        v = v.reshape(B, nkv, hd)
-        q = _apply_rope_one(q, c, s)
-        k = _apply_rope_one(k, c, s)
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k[:, :, None, :].astype(k_cache.dtype), (0, 0, slot, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v[:, :, None, :].astype(v_cache.dtype), (0, 0, slot, 0)
+    def attend(q, k, v, rows):  # this layer's rows: k/v [B, Hkv, C, hd]
+        k_cache, v_cache = (
+            jax.lax.dynamic_update_slice(
+                old, new[:, :, None, :].astype(old.dtype), (0, 0, slot, 0))
+            for old, new in zip(rows, (k, v))
         )
         # GQA: fold q heads to [B, Hkv, G, hd]; attend over the cache
-        qf = q.reshape(B, nkv, group, hd).astype(jnp.float32)
-        logits = jnp.einsum(
-            "bhgd,bhtd->bhgt", qf, k_cache.astype(jnp.float32)
-        ) / jnp.sqrt(jnp.float32(hd))
-        logits = jnp.where(valid[:, :, None, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        att = jnp.einsum("bhgt,bhtd->bhgd", probs, v_cache.astype(jnp.float32))
-        att = att.reshape(B, nh * hd).astype(x.dtype)
-        x = x + att @ lp["wo"]
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts and "moe" in lp:
-            from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
-
-            # lossless routing at decode: capacity dropping is a TRAINING
-            # load-balancing artifact computed over B*S competing tokens
-            # and has no analogue at one-position decode — every routed
-            # token keeps its experts (dense all-experts evaluation)
-            moe_out = moe_ffn_lossless(
-                lp["moe"], h2[:, None, :], top_k=cfg.expert_top_k
-            )
-            x = x + moe_out[:, 0]
-        else:
-            gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
-            x = x + gated @ lp["w_down"]
-        return x, (k_cache, v_cache)
+        qf = q.reshape(k.shape[:2] + (-1, q.shape[-1]))
+        return _cached_attention(qf, k_cache, v_cache, valid), (k_cache, v_cache)
 
     x, (k_new, v_new) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"])
+        lambda x, a: _layer(x, a[0], cfg, c, s, attend, a[1:]),
+        x, (params["layers"], cache["k"], cache["v"]),
     )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), {"k": k_new, "v": v_new}
-
-
-def decode_step_ragged(
-    params: Dict[str, Any],
-    cache: Dict[str, jnp.ndarray],
-    token: jnp.ndarray,
-    pos: jnp.ndarray,
-    cfg: LlamaConfig,
-    rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One decode step with PER-ROW positions. token: [B] int32; pos: [B]
-    int32 — every batch row advances independently. This is the primitive
-    the continuous-batching serving engine steps: the rows of one cache
-    are SLOTS holding unrelated requests at different depths, so a single
-    scalar position (``decode_step``) cannot describe the batch.
-
-    Same math as ``decode_step`` with the scalar position lifted to a
-    vector: rope rows are gathered per row (``cos[pos]``), the cache
-    update is a per-row scatter at ``slot_b = pos_b % C``, and the
-    validity mask compares each row's cache slots against its own
-    position. Rows therefore never see each other's keys — isolation
-    between slots is structural, not masked in.
-
-    Returns (logits [B, V] fp32, updated cache).
-    """
-    hd = cfg.head_dim
-    C = cache["k"].shape[3]
-    if rope_table is None:
-        rope_table = _default_table_or_raise(cfg, max(C, cfg.max_seq))
-    # identical soundness constraint to decode_step: a cache strictly
-    # between the window and the served position range wraps its slots
-    # while the band mask compares absolute positions
-    total = int(rope_table[0].shape[0])
-    if cfg.sliding_window and cfg.sliding_window < C < total:
-        raise ValueError(
-            f"cache length {C} is between sliding_window "
-            f"{cfg.sliding_window} and the served position range {total}: "
-            "size the cache to the window (rolling) or to the full "
-            "position range (see decode_step)"
-        )
-    cos, sin = rope_table
-    c = cos[pos]  # [B, hd/2]
-    s = sin[pos]
-    B = token.shape[0]
-    x = params["embed"][token]  # [B, D]
-
-    slot = pos % C  # [B]
-    rows = jnp.arange(B)
-    positions = jnp.arange(C)
-    keep = positions[None, :] <= pos[:, None]  # [B, C]
-    if cfg.sliding_window and C > cfg.sliding_window:
-        keep &= positions[None, :] > pos[:, None] - cfg.sliding_window
-    valid = keep[:, None, None, :]  # [B, 1, 1, C]
-
-    def layer_fn(carry, inputs):
-        # the cache rides the loop as its carry (see decode_step_paged):
-        # k/v [L * B * Hkv, C, hd], a layer writes its B rows in place
-        x, k_flat, v_flat = carry
-        lp, layer = inputs
-        nh = lp["wq"].shape[-1] // hd
-        nkv = lp["wk"].shape[-1] // hd
-        group = nh // nkv
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if "bq" in lp:  # Qwen2-family qkv bias
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(B, nh, hd)
-        k = k.reshape(B, nkv, hd)
-        v = v.reshape(B, nkv, hd)
-        q = _apply_rope_rows(q, c, s)
-        k = _apply_rope_rows(k, c, s)
-        k_flat = _write_rows(k_flat, layer * B + rows, slot, k)
-        v_flat = _write_rows(v_flat, layer * B + rows, slot, v)
-        k_cache = _layer_rows(k_flat, layer, B, nkv)
-        v_cache = _layer_rows(v_flat, layer, B, nkv)
-        qf = q.reshape(B, nkv, group, hd).astype(jnp.float32)
-        logits = jnp.einsum(
-            "bhgd,bhtd->bhgt", qf, k_cache.astype(jnp.float32)
-        ) / jnp.sqrt(jnp.float32(hd))
-        logits = jnp.where(valid, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        att = jnp.einsum("bhgt,bhtd->bhgd", probs, v_cache.astype(jnp.float32))
-        att = att.reshape(B, nh * hd).astype(x.dtype)
-        x = x + att @ lp["wo"]
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts and "moe" in lp:
-            from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
-
-            moe_out = moe_ffn_lossless(
-                lp["moe"], h2[:, None, :], top_k=cfg.expert_top_k
-            )
-            x = x + moe_out[:, 0]
-        else:
-            gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
-            x = x + gated @ lp["w_down"]
-        return (x, k_flat, v_flat), None
-
-    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), cache
+    return _logits(x, params, cfg), {"k": k_new, "v": v_new}
 
 
 def decode_step_paged(
@@ -493,20 +404,24 @@ def decode_step_paged(
     rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     kernel: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """One decode step over a BLOCK-PAGED cache. token: [B] int32; pos:
-    [B] int32 (per-row positions, as in ``decode_step_ragged``);
-    block_tables: [B, max_blocks] int32 mapping each row's logical block
-    index to a physical block in the pool. cache k/v are
-    [L, num_blocks, Hkv, block_size, D] — ONE allocation shared by every
-    request, carved into fixed-size blocks by the serving allocator
-    (serving/paged_kv.py).
+    """One decode step over a BLOCK-PAGED cache with PER-ROW positions —
+    the primitive the continuous-batching engine steps: the rows of one
+    batch are slots holding unrelated requests at different depths.
+    token: [B] int32; pos: [B] int32; block_tables: [B, max_blocks] int32
+    mapping each row's logical block index to a physical block in the pool.
+    cache k/v are [L, num_blocks, Hkv, block_size, D] — ONE allocation
+    shared by every request, carved into fixed-size blocks by the serving
+    allocator (serving/paged_kv.py).
 
     Logical position ``p`` of row ``b`` lives at physical cache slot
     ``block_tables[b, p // block_size] * block_size + p % block_size``.
+    Rope rows are gathered per row, each row's (k, v) is written at its own
+    position and attention is masked per row against that position, so rows
+    never see each other's keys — isolation between slots is structural.
 
-    The pool is the layer loop's CARRY, never a scanned operand: a layer
-    sees the whole stack as ``[L * N * Hkv, bs, hd]`` (``_flat_pages``, a
-    reshape of leading axes), writes each row's new (k, v) into page
+    The pool is the layer loop's CARRY, never a scanned operand
+    (``_scan_layers_over_cache``): a layer sees the whole stack as
+    ``[L * N * Hkv, bs, hd]``, writes each row's new (k, v) into page
     ``layer * N + physical block`` one ``[hd]`` row at a time
     (``_write_rows``, which says why one row), and reads through
     ``block_tables + layer * N``. So nothing of the pool is sliced, copied
@@ -514,15 +429,10 @@ def decode_step_paged(
     same buffer back with B rows a layer changed, and one that does not
     pays one copy of it on entry.
 
-    The read gathers each row's referenced blocks and reshapes them back
-    into logical position order [B, Hkv, max_blocks * block_size, D], after
-    which the attention math — validity mask included — is IDENTICAL to
-    ``decode_step_ragged`` over a cache of length
-    ``max_blocks * block_size``. Rows sharing prefix blocks (refcounted
-    by the allocator) read the same physical (k, v) without copies;
-    writes only ever target private blocks (the allocator's
-    copy-on-write admission guarantees it), so sharing is invisible
-    here.
+    Rows sharing prefix blocks (refcounted by the allocator) read the same
+    physical (k, v) without copies; writes only ever target private blocks
+    (the allocator's copy-on-write admission guarantees it), so sharing is
+    invisible here.
 
     Shapes are fixed by ``block_tables.shape`` — growing a request's
     table on the host mutates VALUES, not shapes, so steady-state decode
@@ -534,8 +444,7 @@ def decode_step_paged(
     ``kernel``: use the fused Pallas block-table-walking attention
     kernel (ops/paged_attention.py) instead of the gather + einsum read
     path. ``None`` (default) defers to ``paged_kernel_enabled()``
-    (env ``RLT_PAGED_KERNEL``; off on CPU unless forced, so the default
-    CPU path stays byte-identical to the pre-kernel implementation).
+    (env ``RLT_PAGED_KERNEL``; off on CPU unless forced).
     The gather path materializes [B, Hkv, max_blocks * block_size, hd]
     a layer whatever the rows hold; the kernel reads each row's live
     pages only, whole ``[Hkv, bs, hd]`` pages a copy and a few hundred
@@ -550,7 +459,6 @@ def decode_step_paged(
     )
 
     use_kernel = paged_kernel_enabled() if kernel is None else bool(kernel)
-    hd = cfg.head_dim
     if cfg.sliding_window:
         raise ValueError(
             "decode_step_paged requires dense-causal configs: a rolling "
@@ -562,100 +470,46 @@ def decode_step_paged(
     if rope_table is None:
         rope_table = _default_table_or_raise(cfg, max(C, cfg.max_seq))
     cos, sin = rope_table
-    c = cos[pos]  # [B, hd/2]
-    s = sin[pos]
-    B = token.shape[0]
     x = params["embed"][token]  # [B, D]
 
     phys = jnp.take_along_axis(
         block_tables, (pos // bs)[:, None], axis=1
     )[:, 0]  # [B] physical block holding each row's write position
     off = pos % bs  # [B]
-    positions = jnp.arange(C)
-    valid = (positions[None, :] <= pos[:, None])[:, None, None, :]
+    valid = (jnp.arange(C)[None, :] <= pos[:, None])[:, None, None, :]
 
-    n_pages = cache["k"].shape[1]
-
-    def layer_fn(carry, inputs):
-        x, k_flat, v_flat = carry  # k/v: [L * N * Hkv, bs, hd]
-        lp, layer = inputs
-        nh = lp["wq"].shape[-1] // hd
-        nkv = lp["wk"].shape[-1] // hd
-        group = nh // nkv
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if "bq" in lp:  # Qwen2-family qkv bias
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(B, nh, hd)
-        k = k.reshape(B, nkv, hd)
-        v = v.reshape(B, nkv, hd)
-        q = _apply_rope_rows(q, c, s)
-        k = _apply_rope_rows(k, c, s)
-        # this layer's pages are [first, first + N) of the stack: a row's
-        # write and the tables it attends through are offset by that.
-        # Per-row scatter into (physical block, offset); free slots all
+    def attend(q, k, v, pool, first):
+        # per-row scatter into (physical block, offset); free slots all
         # target the trash block — duplicate indices there are harmless
         # because trash contents are never attendable
-        first = layer * n_pages
-        k_flat = _write_rows(k_flat, first + phys, off, k)
-        v_flat = _write_rows(v_flat, first + phys, off, v)
+        k_flat, v_flat = (
+            _write_rows(flat, first + phys, off, new)
+            for flat, new in zip(pool, (k, v))
+        )
         # attention reads the WHOLE stack through the offset tables (a
         # reshape of leading axes): a layer sliced out of it and handed to
         # the kernel would be a copy of that layer
         tables = block_tables + first
-        qf = q.reshape(B, nkv, group, hd).astype(jnp.float32)
+        nkv, hd = k.shape[-2:]
+        qf = q.reshape(q.shape[0], nkv, -1, hd)  # GQA: [B, Hkv, G, hd]
         if use_kernel:
             # fused path: the kernel walks the block table itself (the
             # table rides in as a scalar-prefetch operand), so the
             # [B, Hkv, C, hd] logical gather is never materialized
             att = paged_decode_attention(
-                qf, k_flat.reshape(-1, nkv, bs, hd),
+                qf.astype(jnp.float32), k_flat.reshape(-1, nkv, bs, hd),
                 v_flat.reshape(-1, nkv, bs, hd), tables, pos,
             )
         else:
-            kk = _gather_pages(k_flat, tables, nkv)
-            vv = _gather_pages(v_flat, tables, nkv)
-            logits = jnp.einsum(
-                "bhgd,bhtd->bhgt", qf, kk.astype(jnp.float32)
-            ) / jnp.sqrt(jnp.float32(hd))
-            logits = jnp.where(valid, logits, -jnp.inf)
-            probs = jax.nn.softmax(logits, axis=-1)
-            att = jnp.einsum(
-                "bhgt,bhtd->bhgd", probs, vv.astype(jnp.float32)
+            att = _cached_attention(
+                qf, _gather_pages(k_flat, tables, nkv),
+                _gather_pages(v_flat, tables, nkv), valid,
             )
-        att = att.reshape(B, nh * hd).astype(x.dtype)
-        x = x + att @ lp["wo"]
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts and "moe" in lp:
-            from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
+        return att, (k_flat, v_flat)
 
-            moe_out = moe_ffn_lossless(
-                lp["moe"], h2[:, None, :], top_k=cfg.expert_top_k
-            )
-            x = x + moe_out[:, 0]
-        else:
-            gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
-            x = x + gated @ lp["w_down"]
-        return (x, k_flat, v_flat), None
-
-    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), cache
-
-
-def _apply_rope_block(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
-    """x: [B, H, K, hd], row b / query i at its own position; c/s:
-    [B, K, hd/2] gathered per (row, query)."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    c = c[:, None, :, :]
-    s = s[:, None, :, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(dtype)
+    x, cache = _scan_layers_over_cache(
+        x, params["layers"], cfg, cos[pos], sin[pos], attend, cache)
+    return _logits(x, params, cfg), cache
 
 
 def decode_step_verify(
@@ -663,24 +517,23 @@ def decode_step_verify(
     cache: Dict[str, jnp.ndarray],
     tokens: jnp.ndarray,
     pos: jnp.ndarray,
+    block_tables: jnp.ndarray,
     cfg: LlamaConfig,
     rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-    block_tables: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Score K candidate positions per row in ONE pass — the verify step
     of self-speculative decoding. tokens: [B, K] int32, row b's candidate
     tokens for positions ``pos[b] .. pos[b] + K - 1`` (t_0 is the row's
     pending token, t_1.. are proposals, the tail is padding for rows with
-    fewer proposals); pos: [B] int32 base positions. Returns
+    fewer proposals); pos: [B] int32 base positions; cache and block_tables
+    as ``decode_step_paged`` takes them. Returns
     (logits [B, K, V] fp32 — logits[b, i] conditions on t_0..t_i — and
     the updated cache).
 
     K is STATIC: rows with fewer than K-1 real proposals ride along with
     padding tokens whose writes are clamped and whose outputs the host
     discards, so the zero-recompile contract holds at any acceptance
-    pattern. With ``block_tables=None`` the cache is the slot layout
-    ([L, B, Hkv, C, hd], as ``decode_step_ragged``); with block tables it
-    is the paged layout ([L, N, Hkv, bs, hd], as ``decode_step_paged``).
+    pattern.
 
     Why garbage never leaks, in three invariants:
 
@@ -694,23 +547,22 @@ def decode_step_verify(
       exposes before attending (the same idempotent-rewrite trick that
       serves prefill's last token), so stale garbage is structurally
       unreachable;
-    - write positions are CLAMPED to the last cache slot (slot layout)
-      or redirected through the block table (paged: unallocated tail ->
-      trash), and real queries never expose that slot because the
-      serving budget caps real candidate positions at
-      ``prompt_len + max_new_tokens - 2 <= C - 2``.
+    - write positions are CLAMPED to the last logical position and go
+      through the block table (unallocated tail -> trash), and real queries
+      never expose that position because the serving budget caps real
+      candidate positions at ``prompt_len + max_new_tokens - 2 <= C - 2``.
 
     Greedy acceptance over these logits is token-identical to stepping
-    ``decode_step_ragged``/``decode_step_paged`` one token at a time —
-    the ``promises_decode_parity`` contract (utils/precision.py) carries
+    ``decode_step_paged`` one token at a time — the
+    ``promises_decode_parity`` contract (utils/precision.py) carries
     over unchanged because the per-position math is the same einsum
-    against the same cache contents.
+    against the same cache contents (the gather read path on every
+    platform).
 
-    Sliding-window configs are refused (the serving pools already refuse
+    Sliding-window configs are refused (the serving pool already refuses
     them; a rolling buffer's wrap interacts unsoundly with multi-position
     writes).
     """
-    hd = cfg.head_dim
     if cfg.sliding_window:
         raise ValueError(
             "decode_step_verify requires dense-causal configs: a rolling "
@@ -718,104 +570,45 @@ def decode_step_verify(
             "K-position write burst could wrap onto its own still-"
             "attendable band"
         )
-    paged = block_tables is not None
-    if paged:
-        bs = cache["k"].shape[3]
-        C = block_tables.shape[1] * bs
-    else:
-        C = cache["k"].shape[3]
+    bs = cache["k"].shape[3]
+    C = block_tables.shape[1] * bs
     if rope_table is None:
         rope_table = _default_table_or_raise(cfg, max(C, cfg.max_seq))
     cos, sin = rope_table
-    total = int(cos.shape[0])
     B, K = tokens.shape
     x = params["embed"][tokens]  # [B, K, D]
 
     qpos = pos[:, None] + jnp.arange(K)[None, :]  # [B, K] logical positions
     # rope rows per (row, query); clamp padding queries into the table
-    ridx = jnp.minimum(qpos, total - 1)
-    c = cos[ridx]  # [B, K, hd/2]
-    s = sin[ridx]
+    ridx = jnp.minimum(qpos, int(cos.shape[0]) - 1)
     # write positions: clamped so padding queries past the budget land in
-    # the last slot (slot layout: never attendable, see docstring) or in
-    # the trash-padded block-table tail (paged)
+    # the trash-padded block-table tail
     wpos = jnp.minimum(qpos, C - 1)  # [B, K]
-    if paged:
-        blk = wpos // bs  # [B, K]
-        phys = jnp.take_along_axis(block_tables, blk, axis=1)  # [B, K]
-        off = wpos % bs
-    else:
-        rows = jnp.arange(B)
-    positions = jnp.arange(C)
-    # [B, K, C]: query i of row b sees cache positions <= pos[b] + i
-    keep = positions[None, None, :] <= qpos[:, :, None]
-    valid = keep[:, None, None, :, :]  # [B, 1, 1, K, C]
+    phys = jnp.take_along_axis(block_tables, wpos // bs, axis=1)  # [B, K]
+    off = wpos % bs
+    # [B, 1, 1, K, C]: query i of row b sees cache positions <= pos[b] + i
+    valid = (jnp.arange(C)[None, None, :] <= qpos[:, :, None])[:, None, None]
 
-    # pages a layer, as _flat_pages counts them: blocks, or the slots' rows
-    n_pages = cache["k"].shape[1]
-
-    def layer_fn(carry, inputs):
-        x, k_flat, v_flat = carry  # the cache is the loop's carry
-        lp, layer = inputs
-        nh = lp["wq"].shape[-1] // hd
-        nkv = lp["wk"].shape[-1] // hd
-        group = nh // nkv
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = h @ lp["wq"]
-        k = h @ lp["wk"]
-        v = h @ lp["wv"]
-        if "bq" in lp:  # Qwen2-family qkv bias
-            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        q = q.reshape(B, K, nh, hd).transpose(0, 2, 1, 3)  # [B, nh, K, hd]
-        k = k.reshape(B, K, nkv, hd).transpose(0, 2, 1, 3)
-        v = v.reshape(B, K, nkv, hd).transpose(0, 2, 1, 3)
-        q = _apply_rope_block(q, c, s)
-        k = _apply_rope_block(k, c, s)
+    def attend(q, k, v, pool, first):
         # scatter all K (k, v) per row BEFORE attending — query i then
         # sees candidate positions <= i through the same cache read path
-        # as the one-token steps. [B, nkv, K, hd] -> [B, K, nkv, hd] to
-        # line up with the advanced-indexing result layout.
-        kw = k.transpose(0, 2, 1, 3)
-        vw = v.transpose(0, 2, 1, 3)
-        first = layer * n_pages
-        if paged:
-            k_flat = _write_rows(k_flat, first + phys, off, kw)
-            v_flat = _write_rows(v_flat, first + phys, off, vw)
-            tables = block_tables + first
-            kk = _gather_pages(k_flat, tables, nkv)
-            vv = _gather_pages(v_flat, tables, nkv)
-        else:
-            k_flat = _write_rows(k_flat, first + rows[:, None], wpos, kw)
-            v_flat = _write_rows(v_flat, first + rows[:, None], wpos, vw)
-            kk = _layer_rows(k_flat, layer, B, nkv)
-            vv = _layer_rows(v_flat, layer, B, nkv)
-        qf = q.reshape(B, nkv, group, K, hd).astype(jnp.float32)
-        logits = jnp.einsum(
-            "bhgqd,bhtd->bhgqt", qf, kk.astype(jnp.float32)
-        ) / jnp.sqrt(jnp.float32(hd))
-        logits = jnp.where(valid, logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        att = jnp.einsum("bhgqt,bhtd->bhgqd", probs, vv.astype(jnp.float32))
-        att = att.reshape(B, nh, K, hd).transpose(0, 2, 1, 3).reshape(
-            B, K, nh * hd
-        ).astype(x.dtype)
-        x = x + att @ lp["wo"]
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts and "moe" in lp:
-            from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
+        # as the one-token step
+        k_flat, v_flat = (
+            _write_rows(flat, first + phys, off, new)
+            for flat, new in zip(pool, (k, v))
+        )
+        tables = block_tables + first
+        nkv, hd = k.shape[-2:]
+        qf = q.swapaxes(1, 2).reshape(B, nkv, -1, K, hd)
+        att = _cached_attention(
+            qf, _gather_pages(k_flat, tables, nkv),
+            _gather_pages(v_flat, tables, nkv), valid,
+        )  # [B, Hkv, G, K, hd]
+        return att.reshape(B, -1, K, hd).swapaxes(1, 2), (k_flat, v_flat)
 
-            # lossless routing, as everywhere at inference: h2 is already
-            # [B, K, D] = [batch, seq, d], the shape moe_ffn_lossless takes
-            x = x + moe_ffn_lossless(lp["moe"], h2, top_k=cfg.expert_top_k)
-        else:
-            gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
-            x = x + gated @ lp["w_down"]
-        return (x, k_flat, v_flat), None
-
-    x, cache = _scan_layers_over_cache(layer_fn, x, params["layers"], cache)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = x @ params["lm_head"]
-    return logits.astype(jnp.float32), cache
+    x, cache = _scan_layers_over_cache(
+        x, params["layers"], cfg, cos[ridx], sin[ridx], attend, cache)
+    return _logits(x, params, cfg), cache
 
 
 class LlamaServing:
@@ -823,9 +616,9 @@ class LlamaServing:
     Llama family. A config object answers ``cfg.serving()`` with one of
     these, and that is the one place the engine learns its model from:
 
-    - ``layouts``: the ``kv_layout``s it serves; ``speculation``: whether it
-      has a verify step; ``counters``: names of the int32 counters its paged
-      decode step returns beside the logits (none here);
+    - ``speculation``: whether it has a verify step; ``counters``: names of
+      the int32 counters its paged decode step returns beside the logits
+      (none here);
     - ``rope_table(max_len)``: one table for prefill and decode;
     - ``paged_block_leaves(block_size)``: the pool's device leaves, each
       (layers, shape of one block in one layer, dtype), and
@@ -836,12 +629,11 @@ class LlamaServing:
     - ``decode_paged(params, cache, token, pos, tables, table)`` ->
       (logits, cache, counters or None).
 
-    A model that serves the slot layout or speculates also has
-    ``prefill_row``, ``decode_ragged`` and ``decode_verify``; one that does
-    not is refused those settings by name when the engine is built."""
+    A model that speculates also has ``decode_verify(params, cache, tokens,
+    pos, tables, table)`` -> (logits, cache); one that does not is refused
+    ``speculate_k`` by name when the engine is built."""
 
     name = "Llama family (models/llama.py)"
-    layouts = ("slot", "paged")
     speculation = True
     counters = ()
 
@@ -883,19 +675,9 @@ class LlamaServing:
             params, cache, token, pos, tables, self.cfg, table)
         return logits, cache, None
 
-    # the slot layout and speculation
-    def prefill_row(self, params, prompt_row, max_len: int, table):
-        """The prompt's cache as one row of the slot layout's length."""
-        _, row = prefill(params, prompt_row, self.cfg,
-                         init_kv_cache(self.cfg, 1, max_len), table)
-        return row
-
-    def decode_ragged(self, params, cache, token, pos, table):
-        return decode_step_ragged(params, cache, token, pos, self.cfg, table)
-
-    def decode_verify(self, params, cache, tokens, pos, table, block_tables=None):
+    def decode_verify(self, params, cache, tokens, pos, tables, table):
         return decode_step_verify(
-            params, cache, tokens, pos, self.cfg, table, block_tables=block_tables)
+            params, cache, tokens, pos, tables, self.cfg, table)
 
 
 def _sample_logits(logits, key, temperature, top_k, top_p):

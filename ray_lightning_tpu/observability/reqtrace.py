@@ -3,7 +3,7 @@
 A :class:`RequestTrace` is minted at ``InferenceEngine.submit`` (and
 stamped with routing info at ``ReplicaGroup`` submit) and threaded — as
 one attribute on the scheduler :class:`~..serving.scheduler.Request` and
-on the KV :class:`~..serving.kv_pool.Slot` — through admission,
+on the KV :class:`~..serving.paged_kv.Slot` — through admission,
 block-pool deferral, prefill, and every decode tick. It accumulates a
 per-request timeline: queue wait, deferred-block wait, prefill duration,
 TTFT, and per-token ITL stamps.

@@ -1,18 +1,16 @@
 """Continuous-batching inference serving.
 
-Five layers, bottom-up:
+Bottom-up:
 
-- :mod:`.kv_pool` — slot-based KV-cache pool: one device allocation
-  whose batch rows are request slots, recycled on EOS/max-tokens. The
-  parity baseline for the paged layout.
-- :mod:`.paged_kv` — block-paged KV allocation: fixed-size blocks from
-  one shared pool, per-request block tables grown on demand, refcounted
-  shared-prefix reuse with LRU eviction, admission by block
-  availability.
+- :mod:`.paged_kv` — the KV pool, block-paged: fixed-size blocks from
+  one device allocation, per-request block tables grown on demand,
+  refcounted shared-prefix reuse with LRU eviction, admission by block
+  availability; the rows of the decode batch are request slots
+  (:class:`~.paged_kv.Slot`), recycled on EOS/max-tokens.
 - :mod:`.scheduler` — bounded admission queue + prefill/decode
   interleave policy (pure host logic, peek-then-acquire back-pressure).
 - :mod:`.engine` — single-replica loop: one jitted prefill + one jitted
-  decode step per KV layout, streaming callbacks, drain/shutdown. Zero
+  decode step, streaming callbacks, drain/shutdown. Zero
   steady-state recompiles by construction (fixed shapes everywhere).
 - :mod:`.replica` — elastic multi-replica front door over the actor
   runtime: least-loaded routing, heartbeat-driven relaunch, and an
@@ -43,7 +41,6 @@ from ray_lightning_tpu.serving.engine import (  # noqa: F401
     EngineConfig,
     InferenceEngine,
 )
-from ray_lightning_tpu.serving.kv_pool import KVSlotPool, Slot  # noqa: F401
 from ray_lightning_tpu.serving.migration import (  # noqa: F401
     KVShipment,
     MigrationPolicy,
@@ -61,6 +58,7 @@ from ray_lightning_tpu.serving.paged_kv import (  # noqa: F401
     BlockAllocator,
     OutOfBlocks,
     PagedKVPool,
+    Slot,
 )
 from ray_lightning_tpu.serving.replica import (  # noqa: F401
     Autoscaler,
@@ -108,7 +106,6 @@ __all__ = [
     "InferenceEngine",
     "JournalEntry",
     "KVShipment",
-    "KVSlotPool",
     "LocalReplicaFleet",
     "MigrationPolicy",
     "MigrationRejected",

@@ -5,35 +5,29 @@ Two compiled programs, full stop:
 - ``_prefill_fn`` — one jitted prefill at the FIXED shape
   [1, max_prompt_len]. Prompts are right-padded to that length; the pad
   positions write garbage (k, v) at positions >= the real length, but
-  the per-row validity mask in ``decode_step_ragged`` only ever exposes
-  positions <= the row's current position, and decode overwrites each
-  garbage position before advancing past it — so padding is free
+  the per-row validity mask of the model's paged decode step only ever
+  exposes positions <= the row's current position, and decode overwrites
+  each garbage position before advancing past it — so padding is free
   correctness-wise and buys shape stability. Causality means the REAL
   positions' cache entries are identical to an unpadded prefill.
-- ``_decode_fn`` — one jitted ``decode_step_ragged`` + sampler over the
-  whole pool ([num_slots] tokens at [num_slots] positions). Free slots
-  ride along with dummy inputs (their outputs are ignored and their
-  rows are garbage until the next prefill overwrites them).
+- ``_decode_fn`` — one jitted paged decode step + sampler over every
+  slot ([num_slots] tokens at [num_slots] positions; [num_slots, K] under
+  speculation). Free slots ride along with dummy inputs (their outputs
+  are ignored and their writes land in the trash block).
 
-Two KV layouts behind the same two-program contract
-(``EngineConfig.kv_layout`` / ``InferenceEngine(kv_layout=...)``):
-
-- ``"slot"`` — every request owns a full-``max_len`` cache row
-  (``kv_pool.KVSlotPool``); the parity baseline.
-- ``"paged"`` — requests hold fixed-size BLOCKS from one shared pool
-  (``paged_kv.PagedKVPool``, a tree of device leaves whose names and block
-  shapes the model states: K and V per head, or one latent row a position
-  for latent attention): prefill writes through a per-request
-  write-redirect table (shared-prefix blocks land in trash, written
-  exactly once by the first request), decode reads each row's pages
-  through the fixed-shape [num_slots, max_blocks] block table (the
-  model's paged decode step), and block tables GROW on demand as rows
-  cross block boundaries — a host-side value mutation, never a shape
-  change, so both layouts hold the zero-steady-state-recompile
-  contract. Admission is by block availability (scheduler back-
-  pressure), and common prompt prefixes are refcount-shared across
-  requests, which is what lifts resident concurrency past
-  ``num_slots × max_len`` HBM.
+One KV layout: requests hold fixed-size BLOCKS from one shared pool
+(``paged_kv.PagedKVPool``, a tree of device leaves whose names and block
+shapes the model states: K and V per head, or one latent row a position
+for latent attention). Prefill writes through a per-request
+write-redirect table (shared-prefix blocks land in trash, written
+exactly once by the first request), decode reads each row's pages
+through the fixed-shape [num_slots, max_blocks] block table (the
+model's paged decode step), and block tables GROW on demand as rows
+cross block boundaries — a host-side value mutation, never a shape
+change, which is the zero-steady-state-recompile contract. Admission is
+by block availability (scheduler back-pressure), and common prompt
+prefixes are refcount-shared across requests, which is what lifts
+resident concurrency past ``num_slots × max_len`` HBM.
 
 The pool is updated IN PLACE: it is donated to both programs, prefill
 scatters whole blocks into it and the decode steps carry it through their
@@ -69,7 +63,7 @@ import threading
 from ray_lightning_tpu.analysis.sanitizer import rlt_condition, rlt_lock
 import time
 from collections import deque
-from dataclasses import dataclass, replace as _dc_replace
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -80,7 +74,6 @@ from ray_lightning_tpu.observability import reqtrace as _reqtrace
 from ray_lightning_tpu.runtime import compile_cache as _compile_cache
 from ray_lightning_tpu.runtime import faults as _faults
 from ray_lightning_tpu.serving import migration as _migration
-from ray_lightning_tpu.serving.kv_pool import KVSlotPool
 from ray_lightning_tpu.serving.paged_kv import TRASH_BLOCK, PagedKVPool
 from ray_lightning_tpu.serving.resilience import RequestShed, ShedPolicy
 from ray_lightning_tpu.serving.scheduler import (
@@ -125,13 +118,12 @@ class EngineConfig:
     request. Sampling knobs are ENGINE-level (static in the compiled
     sampler); per-request temperatures would be a recompile per value.
 
-    ``kv_layout``: ``"slot"`` (full row per request, the parity
-    baseline) or ``"paged"`` (block allocation + shared-prefix reuse;
-    see ``serving/paged_kv.py``). ``block_size`` (paged only) defaults
-    to env ``RLT_SERVE_BLOCK_SIZE`` or 16 and must divide ``max_len``;
-    ``num_kv_blocks`` sizes the block pool (default: the slot-
-    equivalent ``num_slots * max_len / block_size`` + trash);
-    ``prefix_cache`` toggles shared-prefix matching.
+    The KV pool is block-paged (``serving/paged_kv.py``): ``block_size``
+    defaults to env ``RLT_SERVE_BLOCK_SIZE`` or 16 and must divide
+    ``max_len``; ``num_kv_blocks`` sizes the block pool (default: every
+    slot at ``max_len``, ``num_slots * max_len / block_size`` + trash);
+    ``prefix_cache`` toggles shared-prefix matching. ``kv_layout`` has
+    one value, ``"paged"``: the benchmark's data files still pass it.
 
     Resilience knobs: ``shed_watermark`` is the queue-fill fraction at
     which priority >= 1 requests are shed (priority 0 never sheds;
@@ -153,8 +145,7 @@ class EngineConfig:
     pre-disaggregation behavior), ``"prefill"`` (prefill requests and
     park the result for KV shipment to a decode replica; retains full
     decode capability as the migration fallback), or ``"decode"``
-    (additionally accepts shipped KV via ``import_shipment``). The
-    prefill role requires the paged layout: shipments are block chains.
+    (additionally accepts shipped KV via ``import_shipment``).
     """
 
     num_slots: int = 4
@@ -167,7 +158,7 @@ class EngineConfig:
     top_p: Optional[float] = None
     eos_id: Optional[int] = None  # default per-request eos
     seed: int = 0
-    kv_layout: str = "slot"
+    kv_layout: str = "paged"
     block_size: Optional[int] = None  # None -> RLT_SERVE_BLOCK_SIZE or 16
     num_kv_blocks: Optional[int] = None
     prefix_cache: bool = True
@@ -206,20 +197,19 @@ class EngineConfig:
             raise ValueError(
                 f"shed_watermark must be > 0, got {self.shed_watermark}"
             )
-        if self.kv_layout not in ("slot", "paged"):
+        if self.kv_layout != "paged":
             raise ValueError(
-                f"kv_layout must be 'slot' or 'paged', got "
-                f"{self.kv_layout!r}"
+                f"kv_layout={self.kv_layout!r}: the KV pool is paged (the "
+                "slot layout was removed in PR 28)"
             )
-        if self.kv_layout == "paged":
-            bs = self.resolved_block_size()
-            if bs < 1:
-                raise ValueError(f"block_size must be >= 1, got {bs}")
-            if self.max_len % bs != 0:
-                raise ValueError(
-                    f"max_len ({self.max_len}) must be a multiple of "
-                    f"block_size ({bs}) for the paged layout"
-                )
+        bs = self.resolved_block_size()
+        if bs < 1:
+            raise ValueError(f"block_size must be >= 1, got {bs}")
+        if self.max_len % bs != 0:
+            raise ValueError(
+                f"max_len ({self.max_len}) must be a multiple of "
+                f"block_size ({bs})"
+            )
         k = self.resolved_speculate_k()
         if k < 0 or k == 1:
             raise ValueError(
@@ -238,12 +228,6 @@ class EngineConfig:
             raise ValueError(
                 f"role must be 'both', 'prefill' or 'decode', got "
                 f"{self.role!r}"
-            )
-        if self.role == "prefill" and self.kv_layout != "paged":
-            raise ValueError(
-                "role='prefill' requires kv_layout='paged': KV shipments "
-                "are paged block chains (the slot layout has no block "
-                "granularity to ship)"
             )
 
 
@@ -340,34 +324,27 @@ class InferenceEngine:
         params,
         cfg,
         engine_config: Optional[EngineConfig] = None,
-        kv_layout: Optional[str] = None,
         replica_index: Optional[int] = None,
     ):
         import jax
 
         ecfg = engine_config or EngineConfig()
-        if kv_layout is not None:
-            ecfg = _dc_replace(ecfg, kv_layout=kv_layout)
         ecfg.validate()
         self.cfg = cfg
         self.engine_config = ecfg
         self.params = params
-        self.kv_layout = ecfg.kv_layout
         # the one place the engine learns its model from: prefill, the decode
         # steps and the pool's leaves are the config object's to state
         self._model = cfg.serving()
         self._refuse_unserved(ecfg)
-        if self.kv_layout == "paged":
-            self.pool = PagedKVPool(
-                cfg,
-                ecfg.num_slots,
-                ecfg.max_len,
-                block_size=ecfg.resolved_block_size(),
-                num_blocks=ecfg.num_kv_blocks,
-                prefix_cache=ecfg.prefix_cache,
-            )
-        else:
-            self.pool = KVSlotPool(cfg, ecfg.num_slots, ecfg.max_len)
+        self.pool = PagedKVPool(
+            cfg,
+            ecfg.num_slots,
+            ecfg.max_len,
+            block_size=ecfg.resolved_block_size(),
+            num_blocks=ecfg.num_kv_blocks,
+            prefix_cache=ecfg.prefix_cache,
+        )
         self.scheduler = ContinuousBatchScheduler(
             self.pool,
             max_queue=ecfg.max_queue,
@@ -484,11 +461,6 @@ class InferenceEngine:
         """Settings this model has no code for are refused here, by name,
         not somewhere inside a tick."""
         model = self._model
-        if ecfg.kv_layout not in model.layouts:
-            raise ValueError(
-                f"kv_layout={ecfg.kv_layout!r}: the {model.name} serves "
-                f"{' / '.join(model.layouts)} only"
-            )
         if ecfg.resolved_speculate_k() > 0 and not model.speculation:
             raise ValueError(
                 f"speculate_k={ecfg.resolved_speculate_k()}: the "
@@ -569,83 +541,57 @@ class InferenceEngine:
                 return out
             return jnp.concatenate([out, counters.astype(jnp.int32)])
 
-        def prefill_into(params, cache, prompt_row, slot_index):
-            # [1, max_prompt_len] through the batched prefill into a
-            # single-row scratch cache, then one dynamic_update_slice
-            # drops the row into the pool at slot_index. The scratch row
-            # is length max_len so shapes line up with the pool rows.
-            row = model.prefill_row(params, prompt_row, ecfg.max_len, table)
+        bs = self.pool.block_size
+        # prompt blocks the fixed-shape prefill spans; the prompt's
+        # rows are padded up to a block multiple so whole blocks can
+        # be scattered through the write table
+        n_prompt_blocks = (ecfg.max_prompt_len - 1) // bs + 1
+        self._n_prompt_blocks = n_prompt_blocks
+
+        def prefill_into_paged(params, cache, prompt_row, write_table):
+            # the model's batched prefill, its cache rows cut into
+            # blocks, scattered to the PHYSICAL blocks named by
+            # write_table — shared-prefix entries point at the trash
+            # block, so a cached prefix is written exactly once (by
+            # the request that registered it), never re-written per hit
+            blocks = model.prefill_blocks(
+                params, prompt_row, n_prompt_blocks, bs, table
+            )
             return {
-                name: jax.lax.dynamic_update_slice(
-                    cache[name], row[name], (0, slot_index, 0, 0, 0)
+                name: leaf.at[:, write_table].set(
+                    blocks[name].astype(leaf.dtype)
                 )
-                for name in cache
+                for name, leaf in cache.items()
             }
 
-        def decode(params, cache, token, pos, key):
-            logits, cache = model.decode_ragged(params, cache, token, pos, table)
-            return sampled_of(logits, key), cache
+        def decode_paged(params, cache, token, pos, tables, key):
+            logits, cache, counters = model.decode_paged(
+                params, cache, token, pos, tables, table
+            )
+            return sampled_of(logits, key, counters), cache
 
-        def decode_verify(params, cache, tokens, pos, key):
+        def decode_verify_paged(params, cache, tokens, pos, tables, key):
             # speculative verify: tokens is [num_slots, K] (pending token
             # + K-1 proposals), logits come back [S, K, V] and every
             # position is greedily sampled — the host accept loop keeps
             # the longest matching prefix, so any row that proposed
             # nothing degenerates to the k=0 program's math exactly
-            logits, cache = model.decode_verify(params, cache, tokens, pos, table)
+            logits, cache = model.decode_verify(
+                params, cache, tokens, pos, tables, table
+            )
             return sampled_of(logits, key), cache
 
-        if self.kv_layout == "paged":
-            bs = self.pool.block_size
-            # prompt blocks the fixed-shape prefill spans; the prompt's
-            # rows are padded up to a block multiple so whole blocks can
-            # be scattered through the write table
-            n_prompt_blocks = (ecfg.max_prompt_len - 1) // bs + 1
-            self._n_prompt_blocks = n_prompt_blocks
+        def install_blocks(cache, ids, blocks):
+            # an imported shipment's blocks, written where the prefill
+            # would have written them
+            return {
+                name: leaf.at[:, ids].set(blocks[name].astype(leaf.dtype))
+                for name, leaf in cache.items()
+            }
 
-            def prefill_into_paged(params, cache, prompt_row, write_table):
-                # the model's batched prefill, its cache rows cut into
-                # blocks, scattered to the PHYSICAL blocks named by
-                # write_table — shared-prefix entries point at the trash
-                # block, so a cached prefix is written exactly once (by
-                # the request that registered it), never re-written per hit
-                blocks = model.prefill_blocks(
-                    params, prompt_row, n_prompt_blocks, bs, table
-                )
-                return {
-                    name: leaf.at[:, write_table].set(
-                        blocks[name].astype(leaf.dtype)
-                    )
-                    for name, leaf in cache.items()
-                }
-
-            def decode_paged(params, cache, token, pos, tables, key):
-                logits, cache, counters = model.decode_paged(
-                    params, cache, token, pos, tables, table
-                )
-                return sampled_of(logits, key, counters), cache
-
-            def decode_verify_paged(params, cache, tokens, pos, tables, key):
-                logits, cache = model.decode_verify(
-                    params, cache, tokens, pos, table, block_tables=tables
-                )
-                return sampled_of(logits, key), cache
-
-            def install_blocks(cache, ids, blocks):
-                # an imported shipment's blocks, written where the prefill
-                # would have written them
-                return {
-                    name: leaf.at[:, ids].set(blocks[name].astype(leaf.dtype))
-                    for name, leaf in cache.items()
-                }
-
-            # not one of the two tracked programs (a shape a block count)
-            self._install_fn = jax.jit(install_blocks, donate_argnums=(0,))
-            prefill_fn = prefill_into_paged
-            decode_fn = decode_verify_paged if spec_k > 0 else decode_paged
-        else:
-            prefill_fn = prefill_into
-            decode_fn = decode_verify if spec_k > 0 else decode
+        # not one of the two tracked programs (a shape a block count)
+        self._install_fn = jax.jit(install_blocks, donate_argnums=(0,))
+        decode_fn = decode_verify_paged if spec_k > 0 else decode_paged
         # The pool (argument 1, behind the parameters) is donated to both:
         # the buffer that goes in is the one that comes out, and a program
         # writes only the rows that change (the decode steps carry the pool
@@ -653,7 +599,8 @@ class InferenceEngine:
         # the donation alone). Undonated, each would copy the whole pool to
         # change a few rows of it, and a tick would hold it two or three times.
         self._prefill_fn = _compile_cache.jit_program(
-            _with_precision(prefill_fn), "serve_prefill", donate_argnums=(1,)
+            _with_precision(prefill_into_paged), "serve_prefill",
+            donate_argnums=(1,)
         )
         self._decode_fn = _compile_cache.jit_program(
             _with_precision(decode_fn), "serve_decode", donate_argnums=(1,)
@@ -682,20 +629,13 @@ class InferenceEngine:
             token = jnp.zeros((self.pool.num_slots,), jnp.int32)
         pos = jnp.zeros((self.pool.num_slots,), jnp.int32)
         key = jax.random.key(0)
-        if self.kv_layout == "paged":
-            wt = jnp.zeros((self._n_prompt_blocks,), jnp.int32)
-            return (
-                ("serve_prefill", self._prefill_fn,
-                 (self.params, cache, prompt, wt)),
-                ("serve_decode", self._decode_fn,
-                 (self.params, cache, token, pos,
-                  jnp.asarray(self.pool.block_tables), key)),
-            )
+        wt = jnp.zeros((self._n_prompt_blocks,), jnp.int32)
         return (
             ("serve_prefill", self._prefill_fn,
-             (self.params, cache, prompt, jnp.int32(0))),
+             (self.params, cache, prompt, wt)),
             ("serve_decode", self._decode_fn,
-             (self.params, cache, token, pos, key)),
+             (self.params, cache, token, pos,
+              jnp.asarray(self.pool.block_tables), key)),
         )
 
     def warmup(self) -> Dict[str, int]:
@@ -936,7 +876,6 @@ class InferenceEngine:
         # (trace, dispatch start, dispatch end) of this tick's prefills:
         # their duration is known at the tick's sync, not at the enqueue
         prefill_traces: List[tuple] = []
-        paged = self.kv_layout == "paged"
         for req, slot in plan.prefills:
             with _obs.phase_span("rlt.serve.prefill", prompt_len=req.prompt_len):
                 self._admit_seq += 1
@@ -951,12 +890,9 @@ class InferenceEngine:
                 padded[0, : req.prompt_len] = req.tokens
                 tr = req.trace
                 t0 = time.perf_counter() if tr is not None else 0.0
-                if paged:
-                    where = jnp.asarray(self.pool.prompt_write_table(
-                        slot.index, self._n_prompt_blocks
-                    ))
-                else:
-                    where = jnp.int32(slot.index)
+                where = jnp.asarray(self.pool.prompt_write_table(
+                    slot.index, self._n_prompt_blocks
+                ))
                 prompt_row = jnp.asarray(padded)
                 self._update_pool(lambda cache: (
                     self._prefill_fn(self.params, cache, prompt_row, where),
@@ -991,7 +927,7 @@ class InferenceEngine:
         # it is a no-op for "both"/"decode" roles — homogeneous fleets
         # run the exact pre-disaggregation path.
         decode_slots = plan.decode_slots
-        block_tables = self.pool.block_tables if paged else None
+        block_tables = self.pool.block_tables
         if self._role == "prefill":
             decode_slots = [s for s in decode_slots if not s.export_pending]
             parked = [
@@ -999,7 +935,7 @@ class InferenceEngine:
                 for s in self.pool.slots
                 if s.occupied and s.export_pending
             ]
-            if paged and parked:
+            if parked:
                 # A parked slot is occupied but excluded from the decode
                 # batch, so its row rides the fixed-shape program as a
                 # padding row (token 0, pos 0) — with its LIVE block
@@ -1045,20 +981,20 @@ class InferenceEngine:
                     else:
                         props = ()
                         token[slot.index] = slot.pending_token
-                    if paged:
-                        # on-demand growth: the block holding the deepest
-                        # write position (slot.pos, or the last speculative
-                        # one) must be physical before the compiled scatter
-                        # writes it (a host-side table-value change, never a
-                        # shape change)
-                        self.pool.ensure_writable(
-                            slot, upto_pos=slot.pos + len(props)
-                        )
+                    # on-demand growth: the block holding the deepest
+                    # write position (slot.pos, or the last speculative
+                    # one) must be physical before the compiled scatter
+                    # writes it (a host-side table-value change, never a
+                    # shape change)
+                    self.pool.ensure_writable(
+                        slot, upto_pos=slot.pos + len(props)
+                    )
                     pos[slot.index] = slot.pos
                 self._rng, sub = jax.random.split(self._rng)
-                inputs = [jnp.asarray(token), jnp.asarray(pos)]
-                if paged:
-                    inputs.append(jnp.asarray(block_tables))
+                inputs = (
+                    jnp.asarray(token), jnp.asarray(pos),
+                    jnp.asarray(block_tables),
+                )
             with _obs.phase_span("rlt.serve.decode_dispatch"):
                 sampled = self._update_pool(lambda cache: self._decode_fn(
                     self.params, cache, *inputs, sub
@@ -1287,15 +1223,10 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def kv_fingerprint(self) -> str:
         """Engine/layout identity a KV shipment must match to be
-        admitted. Paged layout only — shipments are block chains."""
-        if self.kv_layout != "paged":
-            raise ValueError(
-                "kv_fingerprint requires kv_layout='paged'"
-            )
+        admitted."""
         cache = self.pool.cache
         first = next(iter(cache.values()))
         return _migration.kv_fingerprint(
-            self.kv_layout,
             self.pool.block_size,
             # one block through every layer: [L, *block]
             first.shape[:1] + first.shape[2:],
@@ -1449,10 +1380,6 @@ class InferenceEngine:
         (no slot/blocks under the worst-case reservation),
         :class:`EngineClosed`, and whatever a scripted crash-mid-admit
         fault kills the engine with."""
-        if self.kv_layout != "paged":
-            raise ValueError(
-                "import_shipment requires kv_layout='paged'"
-            )
         rid = request_id or f"req-{next(self._req_counter)}"
         if eos_id == "__default__":
             eos_id = self.engine_config.eos_id
@@ -1820,12 +1747,8 @@ class InferenceEngine:
         out = dict(self.stats)
         out.update(self.pool.stats())
         out.update(self.compile_stats())
-        out["kv_layout"] = self.kv_layout
         out["slot_utilization"] = round(self.slot_utilization(), 4)
-        if self.kv_layout == "paged":
-            out["block_utilization"] = round(
-                self.pool.block_utilization(), 4
-            )
+        out["block_utilization"] = round(self.pool.block_utilization(), 4)
         out["queue_depth"] = self.scheduler.queue_depth
         return out
 

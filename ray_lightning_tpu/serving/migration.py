@@ -69,7 +69,6 @@ class MigrationRejected(RuntimeError):
 
 
 def kv_fingerprint(
-    kv_layout: str,
     block_size: int,
     block_shape: Tuple[int, ...],
     dtype: str,
@@ -97,7 +96,8 @@ def kv_fingerprint(
         repr(
             (
                 SHIPMENT_VERSION,
-                str(kv_layout),
+                "paged",  # the layout's name, from when there were two:
+                # kept, so a shipment's fingerprint is what it was
                 int(block_size),
                 tuple(int(d) for d in block_shape),
                 str(dtype),

@@ -1,11 +1,10 @@
 """Paged KV cache: block allocation + shared-prefix reuse for serving.
 
-The slot pool (``kv_pool.py``) gives every request a full-``max_len``
-cache row, so resident concurrency is capped at ``num_slots × max_len``
-HBM regardless of actual lengths. This module carves ONE device
-allocation into fixed-size blocks (``block_size`` tokens each, knob
-``RLT_SERVE_BLOCK_SIZE``) and hands requests exactly the blocks their
-positions need:
+A full-``max_len`` cache row a request would cap resident concurrency at
+``num_slots × max_len`` HBM regardless of actual lengths. This module
+carves ONE device allocation into fixed-size blocks (``block_size`` tokens
+each, knob ``RLT_SERVE_BLOCK_SIZE``) and hands requests exactly the blocks
+their positions need:
 
 - :class:`BlockAllocator` — pure host logic (no jax, no model): a free
   list of physical blocks, per-request allocations with a worst-case
@@ -20,9 +19,9 @@ positions need:
   ``[block_size, W]`` a group of layers for latent attention),
   the host block-table mirror ([num_slots, max_blocks] int32 — a FIXED
   shape, which is what keeps the paged decode at zero steady-state
-  recompiles), and the slot bookkeeping, delegating block policy to the
-  allocator. Interface-compatible with :class:`~.kv_pool.KVSlotPool`
-  so the scheduler and engine switch layouts without forking.
+  recompiles), and the slot bookkeeping (:class:`Slot`: the host-side
+  state of one row of the decode batch), delegating block policy to the
+  allocator.
 
 Prefix sharing (the system-prompt amortization):
 
@@ -64,19 +63,81 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ray_lightning_tpu import observability as _obs
-from ray_lightning_tpu.serving.kv_pool import Slot
 
 __all__ = [
     "BlockAllocation",
     "BlockAllocator",
     "OutOfBlocks",
     "PagedKVPool",
+    "Slot",
     "TRASH_BLOCK",
 ]
 
 # physical block 0: write-redirect target for shared-prefix prefill slots
 # and free-slot dummy decode writes; never allocated, never attendable
 TRASH_BLOCK = 0
+
+
+@dataclass
+class Slot:
+    """Host-side state of one row of the decode batch (an engine slot).
+
+    ``pos`` is the position of ``pending_token`` — the token the NEXT
+    batched decode step feeds for this row. After a prefill of P prompt
+    tokens the cache holds positions [0, P) and ``pos = P - 1`` with
+    ``pending_token = prompt[-1]``: the first decode step rewrites that
+    last position's (k, v) with identical values and yields the logits
+    for position P, i.e. the request's FIRST sampled token. That is what
+    lets one jitted decode step serve both "first token after prefill"
+    and every later token — there is no separate first-token program.
+    """
+
+    index: int
+    request_id: Optional[str] = None
+    pos: int = -1
+    pending_token: int = 0
+    prompt_len: int = 0
+    generated: int = 0
+    max_new_tokens: int = 0
+    eos_id: Optional[int] = None
+    admitted_at: float = 0.0
+    first_token_at: Optional[float] = None
+    last_token_at: Optional[float] = None
+    # absolute deadline (time.perf_counter domain) and priority class of
+    # the tenant — the engine evicts expired slots at tick start so a
+    # dead-on-arrival stream stops burning decode flops and its KV
+    # capacity recycles immediately
+    deadline: Optional[float] = None
+    priority: int = 0
+    # the tenant's RequestTrace (None when telemetry is off or the
+    # request was not head-sampled) — the decode loop's only per-token
+    # tracing cost is reading this attribute
+    trace: Optional[object] = None
+    # disaggregated serving: a prefill-role engine parks a freshly
+    # prefilled slot here while its KV shipment is in flight — the
+    # decode loop skips the slot, and a failed migration clears the flag
+    # so the request falls back to decoding in place
+    export_pending: bool = False
+
+    @property
+    def occupied(self) -> bool:
+        return self.request_id is not None
+
+    def reset(self) -> None:
+        self.request_id = None
+        self.pos = -1
+        self.pending_token = 0
+        self.prompt_len = 0
+        self.generated = 0
+        self.max_new_tokens = 0
+        self.eos_id = None
+        self.admitted_at = 0.0
+        self.first_token_at = None
+        self.last_token_at = None
+        self.deadline = None
+        self.priority = 0
+        self.trace = None
+        self.export_pending = False
 
 
 class OutOfBlocks(RuntimeError):
@@ -133,8 +194,8 @@ class BlockAllocator:
     worst-case growth fit in ``free + evictable-cached`` blocks, so
     :meth:`grow` can never fail mid-decode — a request that was admitted
     always finishes. Requests that finish early (EOS) return their
-    unused reservation immediately, which is the capacity win over the
-    slot layout.
+    unused reservation immediately, which is the capacity win over a
+    full-length row a request.
     """
 
     def __init__(
@@ -457,9 +518,8 @@ class BlockAllocator:
 
 
 class PagedKVPool:
-    """Block-paged device KV pool: the paged sibling of
-    :class:`~.kv_pool.KVSlotPool` (same acquire/release/occupancy
-    surface, so the scheduler and engine are layout-agnostic).
+    """Block-paged device KV pool: ``num_slots`` rows of the decode batch
+    (free list, occupancy, tenancy history) over one block allocation.
 
     One device allocation of ``num_blocks`` blocks, a leaf
     ``[layers, num_blocks, *block]`` for each that the model's
@@ -469,16 +529,16 @@ class PagedKVPool:
     [num_slots, max_blocks] (int32, trash-padded) that the model's paged
     decode step reads its pages through. The allocator, the tables and
     the slots know blocks only, never what is in them. The leaves
-    (``self.cache``) are donated to the engine's programs and rebound to
-    their outputs every tick, as :class:`~.kv_pool.KVSlotPool`'s are: one
-    allocation, updated in place.
+    (``self.cache``) are DONATED to every engine program that writes them
+    and rebound to the program's output (``InferenceEngine._update_pool``):
+    the same buffers, updated in place, under new array objects. An array
+    read off ``self.cache`` earlier is deleted by the next tick, so keep a
+    host copy (``np.array``), not the array.
     Admission is by block availability (the allocator's reservation
     contract), not by free slot alone — the pool can refuse a request
     while slots are free, which is the back-pressure signal the
     scheduler turns into FIFO head-of-line waiting.
     """
-
-    layout = "paged"
 
     def __init__(
         self,
@@ -505,8 +565,7 @@ class PagedKVPool:
             raise ValueError(
                 f"max_len ({max_len}) must be a multiple of block_size "
                 f"({block_size}): the paged decode's logical length is "
-                "max_blocks * block_size and must equal max_len so the "
-                "paged and slot layouts share identical attention shapes"
+                "max_blocks * block_size and must equal max_len"
             )
         self.cfg = cfg
         self.num_slots = int(num_slots)
@@ -514,9 +573,9 @@ class PagedKVPool:
         self.block_size = int(block_size)
         self.max_blocks = self.max_len // self.block_size
         if num_blocks is None:
-            # slot-equivalent worst case + the trash block; the paged win
-            # at equal HBM comes from sharing + early release, and a
-            # SMALLER num_blocks trades worst-case capacity for HBM
+            # every slot at max_len + the trash block; a SMALLER num_blocks
+            # trades worst-case capacity for HBM (sharing and early release
+            # are what make that safe)
             num_blocks = self.num_slots * self.max_blocks + 1
         self.allocator = BlockAllocator(
             num_blocks, self.block_size, prefix_cache=prefix_cache
@@ -546,7 +605,7 @@ class PagedKVPool:
         self._published_hits = 0.0
 
     # ------------------------------------------------------------------ #
-    # admission / recycling (KVSlotPool-compatible surface)
+    # admission / recycling
     # ------------------------------------------------------------------ #
     def acquire(
         self,
@@ -617,7 +676,7 @@ class PagedKVPool:
         return slot
 
     # ------------------------------------------------------------------ #
-    # paged-specific hooks the engine drives
+    # block hooks the engine drives
     # ------------------------------------------------------------------ #
     def prompt_write_table(
         self, slot_index: int, n_prompt_blocks: int
@@ -661,7 +720,7 @@ class PagedKVPool:
         return self.allocator.used_blocks / max(self.allocator.capacity, 1)
 
     # ------------------------------------------------------------------ #
-    # views (KVSlotPool-compatible)
+    # views
     # ------------------------------------------------------------------ #
     @property
     def occupancy(self) -> int:
@@ -679,7 +738,6 @@ class PagedKVPool:
 
     def stats(self) -> Dict[str, object]:
         out = {
-            "layout": self.layout,
             "num_slots": self.num_slots,
             "max_len": self.max_len,
             "occupancy": self.occupancy,

@@ -596,11 +596,6 @@ class LocalReplicaFleet:
                 f"prefill_replicas ({pf}) must leave at least one decode "
                 f"replica (initial_replicas={initial_replicas})"
             )
-        if pf and self._engine_kwargs.get("kv_layout") != "paged":
-            raise ValueError(
-                "disaggregated serving ships paged KV block chains: set "
-                "engine_kwargs kv_layout='paged'"
-            )
         self.disaggregated = pf > 0
         self.migration_policy = migration_policy or _migration.MigrationPolicy()
         self.migration_stats = _migration.MigrationStats()

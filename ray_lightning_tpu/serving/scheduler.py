@@ -58,7 +58,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 from ray_lightning_tpu import observability as _obs
 from ray_lightning_tpu.observability import metrics as _metrics
-from ray_lightning_tpu.serving.kv_pool import KVSlotPool, Slot
+from ray_lightning_tpu.serving.paged_kv import PagedKVPool, Slot
 
 
 class RequestQueueFull(RuntimeError):
@@ -117,7 +117,7 @@ class ContinuousBatchScheduler:
 
     def __init__(
         self,
-        pool: KVSlotPool,
+        pool: PagedKVPool,
         max_queue: int = 256,
         max_prefills_per_tick: int = 1,
         head_skip_limit: int = 0,
@@ -256,7 +256,7 @@ class ContinuousBatchScheduler:
         and return the iteration plan.
 
         Admission is peek-then-acquire: the pool may refuse the queue
-        head (no free slot, or — paged layout — not enough KV blocks for
+        head (no free slot, or not enough KV blocks for
         the prompt plus its worst-case growth reservation), in which
         case the head stays queued and, by default, this tick admits
         nothing more. Strict FIFO head-of-line blocking is deliberate:

@@ -35,12 +35,10 @@ def test_train_phase_tiny(tmp_root):
     assert facts["custom_calls"] == 0  # the CPU has no Mosaic kernels
 
 
-@pytest.mark.parametrize(
-    "layout,k", [("slot", 0), ("paged", 4)], ids=["slot", "paged-spec4"]
-)
-def test_serve_phase_tiny(layout, k):
+@pytest.mark.parametrize("k", [0, 4], ids=["paged", "paged-spec4"])
+def test_serve_phase_tiny(k):
     facts = chip_smoke.phase_serve(
-        TINY32, kv_layout=layout, speculate_k=k, prompt_lens=(3, 9, 14, 9),
+        TINY32, speculate_k=k, prompt_lens=(3, 9, 14, 9),
         max_new=6, seed=0, num_slots=2, max_prompt_len=16, max_len=32,
     )
     assert facts["tokens_equal_generate"] is True
@@ -125,7 +123,7 @@ def test_serve_phase_bf16_takes_the_tie_branch(monkeypatch):
 
     monkeypatch.setattr(generation, "generate", parted)
     facts = chip_smoke.phase_serve(
-        TINY, kv_layout="slot", speculate_k=0, prompt_lens=(3, 9),
+        TINY, speculate_k=0, prompt_lens=(3, 9),
         max_new=6, seed=0, num_slots=2, max_prompt_len=16, max_len=32,
     )
     assert facts["tokens_equal_generate"] is False
@@ -133,7 +131,7 @@ def test_serve_phase_bf16_takes_the_tie_branch(monkeypatch):
     assert facts["worst_logit_gap_ulps"] <= chip_smoke.BF16_TIE_ULPS
     with pytest.raises(AssertionError, match="tokens differ from generate"):
         chip_smoke.phase_serve(
-            TINY32, kv_layout="slot", speculate_k=0, prompt_lens=(3,),
+            TINY32, speculate_k=0, prompt_lens=(3,),
             max_new=6, seed=0, num_slots=2, max_prompt_len=16, max_len=32,
         )
 

@@ -214,7 +214,7 @@ def test_absorbed_decode_equals_the_decompressed_form(params):
 # ---------------------------------------------------------------------- #
 # the engine over the latent pool
 # ---------------------------------------------------------------------- #
-ENGINE = dict(num_slots=3, max_prompt_len=16, max_len=32, kv_layout="paged", block_size=4)
+ENGINE = dict(num_slots=3, max_prompt_len=16, max_len=32, block_size=4)
 
 
 @pytest.mark.parametrize("kernel", ["0", "1"], ids=["gather", "kernel-interpreted"])
@@ -246,10 +246,10 @@ def test_engine_serves_it_token_for_token_with_the_forward(params, monkeypatch, 
 
 
 @pytest.mark.parametrize("setting,names", [
-    (dict(kv_layout="slot"), ["kv_layout='slot'", "paged"]),
-    (dict(kv_layout="paged", speculate_k=4), ["speculate_k=4", "verify"]),
-    (dict(kv_layout="paged", role="prefill"), ["role='prefill'", "K and V"]),
-    (dict(kv_layout="paged", role="decode"), ["role='decode'", "K and V"]),
+    (dict(kv_layout="slot"), ["kv_layout='slot'", "removed in PR 28"]),  # EngineConfig's own
+    (dict(speculate_k=4), ["speculate_k=4", "verify"]),
+    (dict(role="prefill"), ["role='prefill'", "K and V"]),
+    (dict(role="decode"), ["role='decode'", "K and V"]),
 ])
 def test_engine_refuses_by_name_what_this_model_cannot_do(params, setting, names):
     with pytest.raises(ValueError) as err:
@@ -264,14 +264,14 @@ def test_engine_refuses_by_name_what_this_model_cannot_do(params, setting, names
 def test_a_latent_pool_has_no_migration_fingerprint(params):
     pool = PagedKVPool(CFG, 2, 16, block_size=4)
     with pytest.raises(migration.ShipmentMismatch, match="latent pool"):
-        migration.kv_fingerprint("paged", 4, (3, 4, 128), "float32", 16,
+        migration.kv_fingerprint(4, (3, 4, 128), "float32", 16,
                                  leaves=tuple(pool.cache))
     engine = InferenceEngine(params, CFG, EngineConfig(**ENGINE))
     with pytest.raises(migration.ShipmentMismatch, match=r"\('dense', 'moe'\)"):
         engine.kv_fingerprint()
     # the K/V pool's fingerprint is what it was
-    assert migration.kv_fingerprint("paged", 4, (2, 2, 4, 16), "float32", 16) == \
-        migration.kv_fingerprint("paged", 4, (2, 2, 4, 16), "float32", 16, leaves=("k", "v"))
+    assert migration.kv_fingerprint(4, (2, 2, 4, 16), "float32", 16) == \
+        migration.kv_fingerprint(4, (2, 2, 4, 16), "float32", 16, leaves=("k", "v"))
 
 
 def test_module_holds_the_selection_bias_fixed(params):

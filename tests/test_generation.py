@@ -169,14 +169,21 @@ def test_top_k_top_p_composition():
     assert got == {0, 1}, got
 
 
+def _paged_pool(cfg, rows, n_blocks, block_size):
+    """A pool for ``rows`` rows of ``n_blocks`` blocks each, and the
+    identity block table over it: row b's logical block j is physical
+    block ``1 + b * n_blocks + j`` (block 0 is the trash block)."""
+    shape = (cfg.n_layers, 1 + rows * n_blocks, cfg.n_kv_heads, block_size,
+             cfg.head_dim)
+    tables = 1 + jnp.arange(rows * n_blocks, dtype=jnp.int32).reshape(rows, n_blocks)
+    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}, tables
+
+
 def test_ragged_decode_parity_with_prefill():
-    """decode_step_ragged at PER-ROW positions is the serving contract:
+    """decode_step_paged at PER-ROW positions is the serving contract:
     rows parked at different depths must each produce the same next-token
     logits as a full prefill forward over their own prefix."""
-    from ray_lightning_tpu.models.generation import (
-        decode_step_ragged,
-        prefill,
-    )
+    from ray_lightning_tpu.models.generation import decode_step_paged, prefill
 
     cfg = _cfg()
     params = init_params(jax.random.key(3), cfg)
@@ -195,23 +202,83 @@ def test_ragged_decode_parity_with_prefill():
         logits, _ = prefill(params, row, cfg, init_kv_cache(cfg, 1, C))
         refs.append(np.asarray(logits[0], np.float32))
 
-    # ragged path: replay both prefixes through decode_step_ragged, each
+    # ragged path: replay both prefixes through decode_step_paged, each
     # row advancing only while it still has prompt left (shorter row
     # re-feeds its last token at a frozen position — idempotent rewrite)
-    cache = init_kv_cache(cfg, 2, C)
+    cache, tables = _paged_pool(cfg, 2, C // 4, 4)
     got = {}
     for t in range(max(lens)):
         tok = jnp.asarray(
             [int(rows[b][0, min(t, lens[b] - 1)]) for b in range(2)], jnp.int32
         )
         pos = jnp.asarray([min(t, lens[b] - 1) for b in range(2)], jnp.int32)
-        logits, cache = decode_step_ragged(params, cache, tok, pos, cfg)
+        logits, cache = decode_step_paged(params, cache, tok, pos, tables, cfg)
         for b in range(2):
             if t == lens[b] - 1:
                 got[b] = np.asarray(logits[b], np.float32)
     for b in range(2):
         err = float(np.max(np.abs(got[b] - refs[b])))
         assert err < 1e-3, (b, err)
+
+
+def _layer_body_model(kind):
+    """(cfg, params) of the three things the one layer body branches on:
+    a dense feed-forward, the Qwen2-family qkv bias, the experts."""
+    if kind == "experts":
+        cfg = dataclasses.replace(
+            LlamaConfig.tiny_moe(), dtype=jnp.float32, capacity_factor=8.0)
+        return cfg, init_params(jax.random.key(5), cfg)
+    cfg = _cfg()
+    params = init_params(jax.random.key(5), cfg)
+    if kind == "qkv-bias":
+        layers = dict(params["layers"])
+        for i, (bias, weight) in enumerate((("bq", "wq"), ("bk", "wk"), ("bv", "wv"))):
+            layers[bias] = 0.5 * jax.random.normal(
+                jax.random.key(10 + i),
+                (cfg.n_layers, layers[weight].shape[-1]), jnp.float32)
+        params = dict(params, layers=layers)
+    return cfg, params
+
+
+@pytest.mark.parametrize("step", ["contiguous", "paged-gather", "verify"])
+@pytest.mark.parametrize("kind", ["dense", "qkv-bias", "experts"])
+def test_one_layer_body_matches_forward_logits(kind, step):
+    """The single decoder layer of models/generation.py under each of its
+    cache accesses — ``decode_step`` on a contiguous cache, the gather read
+    of ``decode_step_paged``, K positions a call through
+    ``decode_step_verify`` — stepping a short sequence, held to the
+    teacher-forced ``forward`` at every position. (The experts' capacity
+    is set not to bind: ``forward`` drops, inference never does.)"""
+    from ray_lightning_tpu.models.generation import (
+        decode_step_paged,
+        decode_step_verify,
+    )
+
+    cfg, params = _layer_body_model(kind)
+    B, S, K = 2, 12, 4
+    tokens = jnp.asarray(
+        np.random.default_rng(0).integers(0, cfg.vocab_size, (B, S)), jnp.int32
+    )
+    full_logits, _ = forward(params, tokens, cfg)  # [B, S, V]
+    full_logits = np.asarray(full_logits, np.float32)
+
+    if step == "contiguous":
+        cache = init_kv_cache(cfg, B, S)
+        run = jax.jit(lambda c, tok, t: decode_step(params, c, tok[:, 0], t, cfg))
+    else:
+        cache, tables = _paged_pool(cfg, B, S // 4, 4)
+        at = lambda t: jnp.full((B,), t, jnp.int32)
+        if step == "paged-gather":
+            run = jax.jit(lambda c, tok, t: decode_step_paged(
+                params, c, tok[:, 0], at(t), tables, cfg, kernel=False))
+        else:
+            run = jax.jit(lambda c, tok, t: decode_step_verify(
+                params, c, tok, at(t), tables, cfg))
+    width = K if step == "verify" else 1
+    for t in range(0, S, width):
+        logits, cache = run(cache, tokens[:, t: t + width], jnp.int32(t))
+        want = full_logits[:, t: t + width].reshape(logits.shape)
+        assert np.abs(np.asarray(logits) - want).max() < 1e-3, t
 
 
 def test_generate_eos_freezes_finished_rows():

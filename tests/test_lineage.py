@@ -522,7 +522,7 @@ def _fault_env(spec):
 
 ENGINE_KW = dict(
     num_slots=4, max_prompt_len=16, max_len=32, max_queue=64,
-    kv_layout="paged", block_size=4,
+    block_size=4,
 )
 
 

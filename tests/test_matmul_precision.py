@@ -75,7 +75,7 @@ def test_matmul_precision_scope_is_trace_scoped():
 def _decode_tokens(params, cfg, policy, monkeypatch):
     monkeypatch.setenv("RLT_MATMUL_PRECISION", policy)
     engine = InferenceEngine(
-        params, cfg, EngineConfig(num_slots=1, max_prompt_len=8, max_len=24)
+        params, cfg, EngineConfig(num_slots=1, max_prompt_len=8, max_len=24, block_size=8)
     )
     comp = engine.submit([3, 5, 7, 11], max_new_tokens=8)
     engine.run_until_idle()

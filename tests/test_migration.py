@@ -11,7 +11,7 @@ stalled shipment, receiver crash mid-admit) is retried under the
 migration policy's bounded budget and degrades — never drops — to
 colocated decode on the prefill replica; and the homogeneous single-pool
 configuration stays byte-identical to the colocated path (same tokens,
-flat jit caches) on both KV layouts.
+flat jit caches).
 
 Unit tests (no model) run first; the model-backed e2es reuse the
 module-scoped tiny-Llama fixture from test_serving.py's idiom.
@@ -90,10 +90,9 @@ def _fault_env(spec):
         faults._migration_cache = (None, [])
 
 
-# paged layout everywhere: shipments are block chains
 ENGINE_KW = dict(
     num_slots=4, max_prompt_len=16, max_len=32, max_queue=64,
-    kv_layout="paged", block_size=4,
+    block_size=4,
 )
 
 
@@ -148,14 +147,13 @@ def test_digest_seals_header_not_just_payloads():
 
 def test_kv_fingerprint_covers_every_layout_property():
     base = dict(
-        kv_layout="paged", block_size=4, block_shape=(2, 2, 4, 3),
-        dtype="float32", max_len=32,
+        block_size=4, block_shape=(2, 2, 4, 3), dtype="float32", max_len=32,
     )
     fp = kv_fingerprint(**base)
     assert fp == kv_fingerprint(**base)  # deterministic
     for key, bad in [
         ("block_size", 8), ("block_shape", (2, 2, 8, 3)),
-        ("dtype", "bfloat16"), ("max_len", 64), ("kv_layout", "dense"),
+        ("dtype", "bfloat16"), ("max_len", 64),
     ]:
         assert fp != kv_fingerprint(**{**base, key: bad}), key
 
@@ -699,15 +697,12 @@ def test_fleet_sustained_migration_kill_loop_zero_drop(model):
 
 # --------------------------------------------------------------------- #
 # the regression floor: a single homogeneous pool is byte-identical to
-# the colocated path — same tokens, flat jit caches, on BOTH layouts
+# the colocated path — same tokens, flat jit caches
 # --------------------------------------------------------------------- #
-@pytest.mark.parametrize("layout", ["slot", "paged"])
-def test_homogeneous_single_pool_identical_to_colocated(model, layout):
+def test_homogeneous_single_pool_identical_to_colocated(model):
     params, cfg = model
     ekw = dict(num_slots=4, max_prompt_len=16, max_len=32, max_queue=64,
-               kv_layout=layout)
-    if layout == "paged":
-        ekw["block_size"] = 4
+               block_size=4)
     fleet = LocalReplicaFleet(
         lambda: (params, cfg), engine_kwargs=ekw, initial_replicas=1,
     )

@@ -34,14 +34,12 @@ DEEPSEEK = ds.DeepseekConfig(
     q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
     v_head_dim=16, ffn_dim=96, moe_ffn_dim=32, n_experts=8, n_shared_experts=1,
     expert_top_k=2, max_seq=64, dtype=jnp.float32, remat=False)
-PAGED = dict(num_slots=3, max_prompt_len=16, max_len=32, kv_layout="paged", block_size=4)
-SLOT = dict(num_slots=3, max_prompt_len=16, max_len=32, kv_layout="slot")
+PAGED = dict(num_slots=3, max_prompt_len=16, max_len=32, block_size=4)
 PROMPTS = [[5, 9, 2, 7, 1], [3] * 11, [8, 4]]  # rows at different positions
 
-# (config, engine settings): the two families, and the slot layout of the first
+# (config, engine settings): the two families
 VARIANTS = {
     "llama-paged": (LLAMA, PAGED),
-    "llama-slot": (LLAMA, SLOT),
     "deepseek-paged": (DEEPSEEK, PAGED),
 }
 
@@ -83,7 +81,7 @@ def test_a_tick_consumes_the_pool_it_was_given(weights, variant):
 
 
 @pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
-@pytest.mark.parametrize("variant", list(VARIANTS) + ["llama-paged-verify", "llama-slot-verify"])
+@pytest.mark.parametrize("variant", list(VARIANTS) + ["llama-paged-verify"])
 def test_each_program_aliases_the_whole_pool(weights, variant, program):
     """``cost_summary`` reports, a program, the bytes its executable updates
     in place beside the pool's: the whole pool. (Temporaries are held to a
@@ -138,8 +136,8 @@ def _scanned_llama_paged(params, cache, token, pos, block_tables, cfg, rope_tabl
         nkv = lp["wk"].shape[-1] // hd
         group = nh // nkv
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = gen._apply_rope_rows((h @ lp["wq"]).reshape(B, nh, hd), c, s)
-        k = gen._apply_rope_rows((h @ lp["wk"]).reshape(B, nkv, hd), c, s)
+        q = gen._rope((h @ lp["wq"]).reshape(B, nh, hd), c, s)
+        k = gen._rope((h @ lp["wk"]).reshape(B, nkv, hd), c, s)
         v = (h @ lp["wv"]).reshape(B, nkv, hd)
         k_cache = k_cache.at[phys, :, off, :].set(k.astype(k_cache.dtype))
         v_cache = v_cache.at[phys, :, off, :].set(v.astype(v_cache.dtype))
@@ -319,7 +317,7 @@ def test_engine_tokens_equal_the_run_on_the_scanned_decode_step(
 # the readers of pool.cache
 # ---------------------------------------------------------------------- #
 MIGRATION = dict(num_slots=4, max_prompt_len=16, max_len=32, max_queue=256,
-                 kv_layout="paged", block_size=4)
+                 block_size=4)
 
 
 def _prefill_engine(weights, **settings):

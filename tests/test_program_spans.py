@@ -47,8 +47,7 @@ def model():
 
 def _engine(model, **kw):
     params, cfg = model
-    kw = dict(dict(num_slots=4, max_prompt_len=16, max_len=32,
-                   kv_layout="paged"), **kw)
+    kw = dict(dict(num_slots=4, max_prompt_len=16, max_len=32), **kw)
     return InferenceEngine(params, cfg, EngineConfig(**kw))
 
 
@@ -73,9 +72,8 @@ def _lowered_texts(engine):
             for name, fn, args in engine._program_specs()}
 
 
-@pytest.mark.parametrize("layout", ["paged", "slot"])
-def test_engine_programs_lower_to_modules_named_by_their_labels(model, layout):
-    texts = _lowered_texts(_engine(model, kv_layout=layout))
+def test_engine_programs_lower_to_modules_named_by_their_labels(model):
+    texts = _lowered_texts(_engine(model))
     assert texts["serve_prefill"].startswith("module @jit_serve_prefill ")
     assert texts["serve_decode"].startswith("module @jit_serve_decode ")
 

@@ -595,7 +595,7 @@ def test_decode_replica_kill_mid_migration_token_identical(model, tmp_path):
     pool and finish the request token-identical to generate(), with
     exactly one charged retry and zero dropped requests."""
     params, cfg = model
-    ekw = dict(ENGINE_KW, kv_layout="paged", block_size=4)
+    ekw = dict(ENGINE_KW, block_size=4)
     with _fault_env("replica1:crash@tick4", fuse=tmp_path):
         fleet = LocalReplicaFleet(
             lambda: (params, cfg),

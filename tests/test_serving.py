@@ -1,4 +1,4 @@
-"""Continuous-batching serving (ray_lightning_tpu/serving/): slot pool,
+"""Continuous-batching serving (ray_lightning_tpu/serving/): the pool's slots,
 scheduler policy, the two-program engine, and the replica front door.
 
 The acceptance bar: >= 8 concurrent requests with staggered arrival and
@@ -23,7 +23,6 @@ from ray_lightning_tpu.serving import (
     EngineClosed,
     EngineConfig,
     InferenceEngine,
-    KVSlotPool,
     LocalReplicaFleet,
     PagedKVPool,
     Request,
@@ -57,11 +56,11 @@ def _reference(params, cfg, prompt, n_new):
 
 
 # --------------------------------------------------------------------- #
-# KV slot pool
+# the pool's slots (rows of the decode batch)
 # --------------------------------------------------------------------- #
 def test_pool_acquire_release_cycle(model):
     _, cfg = model
-    pool = KVSlotPool(cfg, num_slots=2, max_len=16)
+    pool = PagedKVPool(cfg, num_slots=2, max_len=16)
     a = pool.acquire("a", prompt_len=3, max_new_tokens=4)
     b = pool.acquire("b", prompt_len=5, max_new_tokens=2)
     assert a.index != b.index and pool.occupancy == 2
@@ -83,7 +82,7 @@ def test_pool_acquire_release_cycle(model):
 
 def test_pool_validates_lengths(model):
     _, cfg = model
-    pool = KVSlotPool(cfg, num_slots=1, max_len=8)
+    pool = PagedKVPool(cfg, num_slots=1, max_len=8, block_size=4)
     with pytest.raises(ValueError, match="max_len=8"):
         pool.acquire("a", prompt_len=6, max_new_tokens=3)
     with pytest.raises(ValueError, match="prompt_len"):
@@ -93,7 +92,7 @@ def test_pool_validates_lengths(model):
 def test_pool_rejects_sliding_window():
     cfg = dataclasses.replace(_cfg(), sliding_window=8)
     with pytest.raises(ValueError, match="sliding"):
-        KVSlotPool(cfg, num_slots=2, max_len=16)
+        PagedKVPool(cfg, num_slots=2, max_len=16)
 
 
 # --------------------------------------------------------------------- #
@@ -101,7 +100,7 @@ def test_pool_rejects_sliding_window():
 # --------------------------------------------------------------------- #
 def test_scheduler_fifo_admission_and_interleave(model):
     _, cfg = model
-    pool = KVSlotPool(cfg, num_slots=2, max_len=16)
+    pool = PagedKVPool(cfg, num_slots=2, max_len=16)
     sched = ContinuousBatchScheduler(pool, max_queue=8, max_prefills_per_tick=1)
     for name in ("a", "b", "c"):
         sched.submit(Request(name, (1, 2, 3), max_new_tokens=2))
@@ -127,7 +126,7 @@ def test_scheduler_fifo_admission_and_interleave(model):
 
 def test_scheduler_bounded_queue_backpressure(model):
     _, cfg = model
-    pool = KVSlotPool(cfg, num_slots=1, max_len=16)
+    pool = PagedKVPool(cfg, num_slots=1, max_len=16)
     sched = ContinuousBatchScheduler(pool, max_queue=2)
     sched.submit(Request("a", (1,), 1))
     sched.submit(Request("b", (1,), 1))
@@ -231,7 +230,7 @@ def test_engine_threaded_loop_stream_and_drain(model):
 def test_engine_rejects_bad_submissions(model):
     params, cfg = model
     engine = InferenceEngine(
-        params, cfg, EngineConfig(num_slots=1, max_prompt_len=4, max_len=8)
+        params, cfg, EngineConfig(num_slots=1, max_prompt_len=4, max_len=8, block_size=4)
     )
     with pytest.raises(ValueError, match="non-empty"):
         engine.submit([], max_new_tokens=1)
@@ -244,6 +243,14 @@ def test_engine_rejects_bad_submissions(model):
         engine.submit([1], max_new_tokens=1, request_id="dup")
     with pytest.raises(ValueError, match="max_prompt_len"):
         EngineConfig(num_slots=1, max_prompt_len=8, max_len=8).validate()
+
+
+def test_engine_config_refuses_the_removed_slot_layout_by_name():
+    """``kv_layout`` has one value left (the benchmark's data files still
+    pass it); any other is refused where the settings are checked."""
+    EngineConfig(kv_layout="paged").validate()
+    with pytest.raises(ValueError, match="kv_layout='slot'.*removed in PR 28"):
+        EngineConfig(kv_layout="slot").validate()
 
 
 def test_engine_publishes_serving_metrics(model):
@@ -353,21 +360,21 @@ def test_replica_group_serves_and_balances(model):
 
 
 # --------------------------------------------------------------------- #
-# paged KV layout: parity, prefix sharing, block back-pressure
+# the paged pool: parity, prefix sharing, block back-pressure
 # --------------------------------------------------------------------- #
-def test_paged_engine_matches_slot_and_generate(model):
-    """The staggered acceptance e2e on the PAGED layout with a tiny block
-    size: the same 8 requests as the slot-layout e2e above, so every
-    completion being token-identical to sequential generate() also proves
-    paged == slot bitwise. Block growth happens mid-decode (grown_total),
-    and the jit caches stay FLAT across admit/recycle/growth."""
+def test_paged_engine_matches_generate(model):
+    """The staggered acceptance e2e with a tiny block size: the same 8
+    requests as the e2e above at the default block size, every completion
+    token-identical to sequential generate(). Block growth happens
+    mid-decode (grown_total), and the jit caches stay FLAT across
+    admit/recycle/growth."""
     params, cfg = model
     engine = InferenceEngine(
         params,
         cfg,
         EngineConfig(
             num_slots=2, max_prompt_len=8, max_len=32,
-            kv_layout="paged", block_size=4,
+            block_size=4,
         ),
     )
     rng = np.random.default_rng(0)
@@ -398,7 +405,8 @@ def test_paged_engine_matches_slot_and_generate(model):
     assert engine.pool.occupancy == 0
     # zero steady-state recompiles under admission, recycling AND growth
     assert engine.compile_stats() == warm
-    assert engine.describe()["kv_layout"] == "paged"
+    assert "kv_layout" not in engine.describe()
+    assert engine.describe()["block_utilization"] == 0.0
 
 
 def test_paged_shared_prefix_bitwise_identical(model):
@@ -417,7 +425,7 @@ def test_paged_shared_prefix_bitwise_identical(model):
             cfg,
             EngineConfig(
                 num_slots=2, max_prompt_len=12, max_len=32,
-                kv_layout="paged", block_size=4, prefix_cache=prefix_cache,
+                block_size=4, prefix_cache=prefix_cache,
             ),
         )
         comps = [engine.submit(p, max_new_tokens=n_new) for p in prompts]
@@ -740,7 +748,7 @@ def test_scheduler_deferral_stamps_trace(model):
     from ray_lightning_tpu.observability import reqtrace
 
     _, cfg = model
-    pool = KVSlotPool(cfg, num_slots=1, max_len=16)
+    pool = PagedKVPool(cfg, num_slots=1, max_len=16)
     sched = ContinuousBatchScheduler(pool, max_queue=4)
     a = Request("a", (1, 2), 2)
     b = Request("b", (1, 2), 2, trace=reqtrace.RequestTrace("b", 2, 2))

@@ -18,7 +18,6 @@ import pytest
 
 from ray_lightning_tpu.models.generation import (
     decode_step_paged,
-    decode_step_ragged,
     decode_step_verify,
     generate,
     init_kv_cache,
@@ -121,8 +120,26 @@ def _prefill_rows(params, cfg, prompts, max_len):
     return cache
 
 
+def _paged_rows(params, cfg, prompts, max_len, block_size=4):
+    """``_prefill_rows`` cut into the paged pool's blocks, with the
+    identity block table: row b's logical block j is physical block
+    ``1 + b * blocks + j`` (block 0 is the trash block)."""
+    rows = _prefill_rows(params, cfg, prompts, max_len)
+    n, blocks = len(prompts), max_len // block_size
+
+    def pool(leaf):  # [L, B, Hkv, C, hd] -> [L, 1 + B * blocks, Hkv, bs, hd]
+        layers, _, heads, _, hd = leaf.shape
+        cut = leaf.reshape(layers, n, heads, blocks, block_size, hd)
+        cut = cut.transpose(0, 1, 3, 2, 4, 5).reshape(
+            layers, n * blocks, heads, block_size, hd)
+        return jnp.concatenate([jnp.zeros_like(cut[:, :1]), cut], axis=1)
+
+    tables = 1 + jnp.arange(n * blocks, dtype=jnp.int32).reshape(n, blocks)
+    return {"k": pool(rows["k"]), "v": pool(rows["v"])}, tables
+
+
 def test_verify_matches_sequential_decode_bitwise(model):
-    """K sequential decode_step_ragged calls and ONE decode_step_verify
+    """K sequential decode_step_paged calls and ONE decode_step_verify
     call over the same proposals produce bitwise-identical logits and
     cache — the verify program IS the decode program, k times."""
     params, cfg = model
@@ -130,7 +147,7 @@ def test_verify_matches_sequential_decode_bitwise(model):
     rng = np.random.default_rng(0)
     prompts = rng.integers(1, cfg.vocab_size, (B, P)).tolist()
 
-    cache_seq = _prefill_rows(params, cfg, prompts, max_len)
+    cache_seq, tables = _paged_rows(params, cfg, prompts, max_len)
     cache_ver = jax.tree.map(jnp.copy, cache_seq)
 
     # proposals = the actual greedy continuation, so every sequential
@@ -140,15 +157,15 @@ def test_verify_matches_sequential_decode_bitwise(model):
     seq_logits = []
     chain = [toks]
     for i in range(K):
-        lg, cache_seq = decode_step_ragged(
-            params, cache_seq, chain[-1], pos + i, cfg
+        lg, cache_seq = decode_step_paged(
+            params, cache_seq, chain[-1], pos + i, tables, cfg, kernel=False
         )
         seq_logits.append(lg)
         chain.append(jnp.argmax(lg, axis=-1).astype(jnp.int32))
 
     tokens = jnp.stack(chain[:K], axis=1)  # [B, K]
     ver_logits, cache_ver = decode_step_verify(
-        params, cache_ver, tokens, pos, cfg
+        params, cache_ver, tokens, pos, tables, cfg
     )
     np.testing.assert_array_equal(
         np.asarray(ver_logits),
@@ -171,16 +188,17 @@ def test_verify_zero_accept_position_zero_is_exact(model):
     rng = np.random.default_rng(1)
     prompts = rng.integers(1, cfg.vocab_size, (B, P)).tolist()
 
-    cache = _prefill_rows(params, cfg, prompts, max_len)
+    cache, tables = _paged_rows(params, cfg, prompts, max_len)
     toks = jnp.asarray([p[-1] for p in prompts], jnp.int32)
     pos = jnp.asarray([P - 1] * B, jnp.int32)
-    ref_logits, _ = decode_step_ragged(params, cache, toks, pos, cfg)
+    ref_logits, _ = decode_step_paged(
+        params, cache, toks, pos, tables, cfg, kernel=False)
 
     garbage = jnp.concatenate(
         [toks[:, None], jnp.zeros((B, K - 1), jnp.int32)], axis=1
     )
     ver_logits, _ = decode_step_verify(
-        params, jax.tree.map(jnp.copy, cache), garbage, pos, cfg
+        params, jax.tree.map(jnp.copy, cache), garbage, pos, tables, cfg
     )
     np.testing.assert_array_equal(
         np.asarray(ver_logits[:, 0]), np.asarray(ref_logits)
@@ -194,7 +212,7 @@ def test_verify_rejects_sliding_window(model):
     with pytest.raises(ValueError, match="sliding"):
         decode_step_verify(
             params, cache, jnp.zeros((1, 2), jnp.int32),
-            jnp.zeros((1,), jnp.int32), cfg,
+            jnp.zeros((1,), jnp.int32), jnp.zeros((1, 4), jnp.int32), cfg,
         )
 
 
@@ -235,19 +253,17 @@ def _staggered_run(params, cfg, ecfg, prompts, n_new):
     return eng, comps
 
 
-@pytest.mark.parametrize("layout", ["slot", "paged"])
-def test_engine_speculative_token_identity(model, layout):
+def test_engine_speculative_token_identity(model):
     """Staggered multi-request serving at k=4 == sequential generate(),
-    both KV layouts, with flat jit caches (zero steady-state recompiles
-    even though per-row acceptance varies every tick)."""
+    with flat jit caches (zero steady-state recompiles even though per-row
+    acceptance varies every tick)."""
     params, cfg = model
     prompts = [[5, 9, 5, 9, 5, 9, 5], [3, 3, 3, 3],
                [7, 1, 2, 7, 1, 2], [11, 12, 13]]
     n_new = [10, 8, 12, 6]
     ecfg = EngineConfig(
         num_slots=2, max_len=32, max_prompt_len=8, temperature=0.0,
-        kv_layout=layout, speculate_k=4,
-        num_kv_blocks=64 if layout == "paged" else None,
+        speculate_k=4, num_kv_blocks=64,
     )
     eng, comps = _staggered_run(params, cfg, ecfg, prompts, n_new)
     for c, p, n in zip(comps, prompts, n_new):
@@ -626,7 +642,7 @@ def test_engine_kernel_knob_token_identity(model):
                 params, cfg,
                 engine_config=EngineConfig(
                     num_slots=2, max_len=32, max_prompt_len=8,
-                    temperature=0.0, kv_layout="paged", num_kv_blocks=64,
+                    temperature=0.0, num_kv_blocks=64,
                 ),
             )
             comps = [eng.submit(p, max_new_tokens=8) for p in prompts]

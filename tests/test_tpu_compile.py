@@ -399,8 +399,7 @@ def test_engine_programs_carry_their_labels_and_kernel_names(
     )
     engine = InferenceEngine(
         init_params(jax.random.key(0), cfg), cfg,
-        EngineConfig(num_slots=4, max_prompt_len=64, max_len=128,
-                     kv_layout="paged"),
+        EngineConfig(num_slots=4, max_prompt_len=64, max_len=128),
     )
     monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
     texts = {}
