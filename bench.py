@@ -1070,7 +1070,7 @@ def _compile_sweep(args: argparse.Namespace) -> int:
             # model a FRESH build (new engine / rebuilt step): drop the
             # wrapper handles so warm pays the real lower+hash+lookup...
             for _, fn, _a in programs:
-                fn._compiled = None
+                fn._compiled.clear()
         if phase == "disk_ms":
             # ...and a FRESH PROCESS: drop the memory layer so the resolve
             # deserializes the persisted executable (the relaunch path)

@@ -362,8 +362,8 @@ def phase_serve(cfg, *, speculate_k: int,
     of mixed prompt lengths through ``submit()``; tokens equal to
     ``generate(temperature=0.0)`` on the same weights (in bf16, where they
     part, greedy under the teacher-forced reference up to a tie:
-    :func:`greedy_under_reference`), one prefill and one decode
-    compilation."""
+    :func:`greedy_under_reference`), one prefill compilation a rung of the
+    engine's prefill lengths and one decode compilation."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -391,7 +391,7 @@ def phase_serve(cfg, *, speculate_k: int,
         EngineConfig(speculate_k=speculate_k, **engine_kwargs),
     )
     t0 = time.perf_counter()
-    engine.warmup()
+    warm = engine.warmup()  # prefill resolved at each of its lengths, and decode
     compile_s = time.perf_counter() - t0
     engine.start()
     try:
@@ -402,8 +402,8 @@ def phase_serve(cfg, *, speculate_k: int,
     finally:
         engine.shutdown()
     compiles = engine.compile_stats()
-    if compiles != {"prefill_compiles": 1, "decode_compiles": 1}:
-        raise AssertionError(f"serving programs recompiled: {compiles}")
+    if compiles != warm:
+        raise AssertionError(f"serving programs recompiled: {warm} -> {compiles}")
     if any(len(g) != max_new for g in gots):
         raise AssertionError(f"short completions: {[len(g) for g in gots]}")
 
@@ -638,7 +638,8 @@ def _child(args: argparse.Namespace) -> int:
         facts = phase_serve(
             cfg,
             speculate_k=4 if spec else 0,
-            prompt_lens=(7, 40, 200, 40), max_new=12, seed=seed,
+            # two rungs of prefill (256, 512), a prompt on each
+            prompt_lens=(7, 40, 300, 40), max_new=12, seed=seed,
             num_slots=8, max_prompt_len=512, max_len=2048,
         )
         _need_kernels(phase + " prefill", facts["custom_calls"]["serve_prefill"])

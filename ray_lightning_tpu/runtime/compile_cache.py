@@ -583,50 +583,68 @@ def _is_signature_mismatch(exc: BaseException) -> bool:
     return any(marker in text for marker in _PREDISPATCH_MISMATCH_MARKERS)
 
 
+def _argument_shapes(args) -> tuple:
+    """What an executable is specialised to, as jit is: the shape and dtype
+    of every leaf of the arguments (an array, a ``ShapeDtypeStruct`` or a
+    Python scalar)."""
+    import jax
+
+    return tuple(
+        (getattr(a, "shape", ()), getattr(a, "dtype", type(a)))
+        for a in jax.tree_util.tree_leaves(args)
+    )
+
+
 class CachedProgram:
     """Callable facade swapping a jitted function's first-dispatch compile
     for a cache resolution.
 
-    The jitted ``fn`` is kept for lowering (``.lower`` delegates, so the
-    profiler's AOT path works unchanged) and as the escape hatch: if a call
-    arrives with a different signature than the resolved executable
-    (jit-style shape polymorphism), the wrapper permanently falls back to
-    the jit path for correctness. ``_cache_size()`` mirrors jit's private
-    counter so ``compile_stats()``-style zero-recompile asserts keep
-    working.
+    Like the jitted ``fn`` it wraps, it specialises by the arguments'
+    shapes: one resolved executable a shape signature, so a program called
+    at a few shapes (the engine's ladder of prefill lengths) dispatches each
+    to its own executable and resolves none of them twice. ``fn`` is kept
+    for lowering (``.lower`` delegates, so the profiler's AOT path works
+    unchanged) and as the escape hatch: if a call does not fit the
+    executable resolved for its own shapes even after a second resolution,
+    the wrapper permanently falls back to the jit path for correctness.
+    ``_cache_size()`` mirrors jit's private counter (executables resolved)
+    so ``compile_stats()``-style zero-recompile asserts keep working.
     """
 
     def __init__(self, fn, program: str, cache: Optional[CompileCache] = None):
         self._fn = fn
         self._program = program
         self._cache = cache or get_cache()
-        self._compiled = None
+        self._compiled: Dict[tuple, Any] = {}  # argument shapes -> executable
         self._resolved = 0
         self._polymorphic = False
 
-    def warmup(self, *args) -> "CachedProgram":
-        """Resolve (compile or load) without executing; idempotent."""
-        if self._compiled is None:
-            self._compiled = self._cache.get_or_compile(
+    def _resolve(self, shapes: tuple, args):
+        compiled = self._compiled.get(shapes)
+        if compiled is None:
+            compiled = self._compiled[shapes] = self._cache.get_or_compile(
                 self._fn, *args, program=self._program
             )
             self._resolved += 1
+        return compiled
+
+    def warmup(self, *args) -> "CachedProgram":
+        """Resolve (compile or load) without executing; idempotent."""
+        self._resolve(_argument_shapes(args), args)
         return self
 
     def cached_compiled(self, *args):
         """The underlying ``Compiled`` (resolving on first use) — the AOT
         handle ``cost_summary()``/``analyze_jitted`` reuse instead of paying
         a second compile."""
-        self.warmup(*args)
-        return self._compiled
+        return self._resolve(_argument_shapes(args), args)
 
     def __call__(self, *args):
         if self._polymorphic:
             return self._fn(*args)
-        if self._compiled is None:
-            self.warmup(*args)
+        shapes = _argument_shapes(args)
         try:
-            return self._compiled(*args)
+            return self._resolve(shapes, args)(*args)
         except (TypeError, ValueError) as exc:
             # Only jax's pre-dispatch signature checks are retryable: they
             # fire before execution, so donated buffers are intact.
@@ -639,12 +657,11 @@ class CachedProgram:
             if not _is_signature_mismatch(exc):
                 raise
             try:
-                self._compiled = None
-                self.warmup(*args)
-                return self._compiled(*args)
+                self._compiled.pop(shapes, None)
+                return self._resolve(shapes, args)(*args)
             except (TypeError, ValueError) as exc2:
-                # the re-resolution does not fit either: genuine jit-style
-                # shape polymorphism — hand dispatch to jit permanently
+                # the re-resolution does not fit either: what differs is
+                # nothing the shapes show — hand dispatch to jit permanently
                 if not _is_signature_mismatch(exc2):
                     raise
                 self._polymorphic = True

@@ -9,9 +9,10 @@ Bottom-up:
   (:class:`~.paged_kv.Slot`), recycled on EOS/max-tokens.
 - :mod:`.scheduler` — bounded admission queue + prefill/decode
   interleave policy (pure host logic, peek-then-acquire back-pressure).
-- :mod:`.engine` — single-replica loop: one jitted prefill + one jitted
-  decode step, streaming callbacks, drain/shutdown. Zero
-  steady-state recompiles by construction (fixed shapes everywhere).
+- :mod:`.engine` — single-replica loop: one jitted prefill (at a few
+  lengths, chosen by the prompt's) + one jitted decode step, streaming
+  callbacks, drain/shutdown. Zero steady-state recompiles by
+  construction (fixed shapes everywhere, all resolved at warmup).
 - :mod:`.replica` — elastic multi-replica front door over the actor
   runtime: least-loaded routing, heartbeat-driven relaunch, and an
   :class:`~.replica.Autoscaler` scaling the fleet on queue depth and

@@ -1,15 +1,22 @@
 """Single-replica continuous-batching inference engine.
 
-Two compiled programs, full stop:
+Two compiled programs, the first at a few lengths:
 
-- ``_prefill_fn`` — one jitted prefill at the FIXED shape
-  [1, max_prompt_len]. Prompts are right-padded to that length; the pad
-  positions write garbage (k, v) at positions >= the real length, but
-  the per-row validity mask of the model's paged decode step only ever
-  exposes positions <= the row's current position, and decode overwrites
-  each garbage position before advancing past it — so padding is free
-  correctness-wise and buys shape stability. Causality means the REAL
-  positions' cache entries are identical to an unpadded prefill.
+- ``_prefill_fn`` — one jitted prefill of one prompt, [1, rung], where
+  the rung is the shortest of a short LADDER of lengths that holds the
+  prompt (:func:`prefill_rungs`: doubling from 256 up to
+  ``max_prompt_len``, the longest prompt admitted and always the last
+  rung; an engine whose ``max_prompt_len`` is 256 or less has the one).
+  The ladder follows from ``max_prompt_len`` and the block size alone and
+  the rung from the prompt's length: there is nothing to set. Prompts are
+  right-padded to the rung; the pad positions write garbage (k, v) at
+  positions >= the real length, and whole blocks past the prompt go to
+  the trash block, but the per-row validity mask of the model's paged
+  decode step only ever exposes positions <= the row's current position,
+  and decode overwrites each garbage position before advancing past it —
+  so padding is free correctness-wise and a handful of shapes is all the
+  program ever sees. Causality means the REAL positions' cache entries
+  are what an unpadded prefill, or one padded to any other rung, writes.
 - ``_decode_fn`` — one jitted paged decode step + sampler over every
   slot ([num_slots] tokens at [num_slots] positions; [num_slots, K] under
   speculation). Free slots ride along with dummy inputs (their outputs
@@ -37,9 +44,10 @@ program moves the rows it changes, not the pool. The arrays under
 follows it; ``_pool_lock`` covers that span, for the one reader on another
 thread (``export_shipment``).
 
-After warmup (one prefill + one decode compile) the jit caches are
-flat: admission, recycling, mixed prompt lengths, EOS — none of it
-changes a device shape. ``compile_stats()`` exposes the cache sizes so
+After warmup (one prefill compile a rung + one decode compile) the jit
+caches are flat: admission, recycling, mixed prompt lengths, EOS — none
+of it brings a shape warmup has not resolved. ``compile_stats()`` exposes
+the cache sizes so
 tests (and the bench sweep) can assert zero steady-state recompiles.
 
 The first sampled token of a request comes from the first DECODE step
@@ -56,6 +64,7 @@ immediately.
 """
 from __future__ import annotations
 
+import bisect
 import itertools
 import os
 import threading
@@ -64,7 +73,7 @@ from ray_lightning_tpu.analysis.sanitizer import rlt_condition, rlt_lock
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -90,6 +99,7 @@ __all__ = [
     "InferenceEngine",
     "RequestQueueFull",
     "RequestShed",
+    "prefill_rungs",
 ]
 
 # TTFT/ITL land in seconds; the default step/IO bounds start at 100 µs
@@ -104,6 +114,36 @@ LATENCY_BOUNDS = (
 ACCEPTED_BOUNDS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0)
 
 
+# The shortest prefill length worth a program of its own: the chip's ridge
+# (a v5e's 197 TFLOP/s over 819 GB/s = 240 operations a byte) rounded up to a
+# power of two. With bf16 weights a prefill of one prompt is bound by the
+# weights' bytes below about that many tokens, so a shorter shape saves
+# nothing.
+FIRST_PREFILL_RUNG = 256
+
+
+def prefill_rungs(max_prompt_len: int, block_size: int) -> Tuple[int, ...]:
+    """The padded lengths prefill runs at, ascending: doubling from
+    ``FIRST_PREFILL_RUNG``, each rounded up to whole blocks, as far as they
+    stay under ``max_prompt_len``, which is always the last rung as it is
+    given. 2048 -> (256, 512, 1024, 2048); 1536 -> (256, 512, 1024, 1536);
+    256 or less -> the one length. A prompt runs at the first rung that
+    holds it (:func:`rung_for`)."""
+    rungs = []
+    n = FIRST_PREFILL_RUNG
+    while n < max_prompt_len:
+        whole = -(-n // block_size) * block_size
+        if whole < max_prompt_len and whole not in rungs:
+            rungs.append(whole)
+        n *= 2
+    return (*rungs, max_prompt_len)
+
+
+def rung_for(rungs: Sequence[int], prompt_len: int) -> int:
+    """The shortest rung that holds ``prompt_len`` positions."""
+    return rungs[bisect.bisect_left(rungs, prompt_len)]
+
+
 class EngineClosed(RuntimeError):
     """submit() after drain/shutdown."""
 
@@ -112,8 +152,9 @@ class EngineClosed(RuntimeError):
 class EngineConfig:
     """Serving knobs (see docs/serving.md for the tuning guide).
 
-    ``max_prompt_len`` is the single compiled prefill shape — prompts
-    longer than it are rejected at submit. ``max_len`` is each slot's
+    ``max_prompt_len`` is the longest prompt admitted — longer ones are
+    rejected at submit — and the last rung of the prefill lengths
+    (:func:`prefill_rungs`). ``max_len`` is each slot's
     cache length: ``prompt_len + max_new_tokens <= max_len`` per
     request. Sampling knobs are ENGINE-level (static in the compiled
     sampler); per-request temperatures would be a recompile per value.
@@ -434,6 +475,10 @@ class InferenceEngine:
         self.stats: Dict[str, float] = {
             "decode_steps": 0,
             "prefills": 0,
+            # the sum of the rungs those prefills ran at: what prefill
+            # computed, padding and all (1 - prompt tokens / this is the
+            # padded share)
+            "prefill_positions": 0,
             "tokens_out": 0,
             "busy_slot_steps": 0,
             "completed": 0,
@@ -542,20 +587,18 @@ class InferenceEngine:
             return jnp.concatenate([out, counters.astype(jnp.int32)])
 
         bs = self.pool.block_size
-        # prompt blocks the fixed-shape prefill spans; the prompt's
-        # rows are padded up to a block multiple so whole blocks can
-        # be scattered through the write table
-        n_prompt_blocks = (ecfg.max_prompt_len - 1) // bs + 1
-        self._n_prompt_blocks = n_prompt_blocks
+        self._rungs = prefill_rungs(ecfg.max_prompt_len, bs)
 
         def prefill_into_paged(params, cache, prompt_row, write_table):
-            # the model's batched prefill, its cache rows cut into
-            # blocks, scattered to the PHYSICAL blocks named by
+            # the model's batched prefill at the rung the prompt was padded
+            # to, its cache rows padded up to whole blocks and cut into
+            # them, scattered to the PHYSICAL blocks named by
             # write_table — shared-prefix entries point at the trash
             # block, so a cached prefix is written exactly once (by
             # the request that registered it), never re-written per hit
             blocks = model.prefill_blocks(
-                params, prompt_row, n_prompt_blocks, bs, table
+                params, prompt_row, self._blocks_of(prompt_row.shape[1]),
+                bs, table
             )
             return {
                 name: leaf.at[:, write_table].set(
@@ -592,7 +635,9 @@ class InferenceEngine:
         # not one of the two tracked programs (a shape a block count)
         self._install_fn = jax.jit(install_blocks, donate_argnums=(0,))
         decode_fn = decode_verify_paged if spec_k > 0 else decode_paged
-        # The pool (argument 1, behind the parameters) is donated to both:
+        # One prefill program, specialised by the prompt row's shape: an
+        # executable a rung. The pool (argument 1, behind the parameters)
+        # is donated to both:
         # the buffer that goes in is the one that comes out, and a program
         # writes only the rows that change (the decode steps carry the pool
         # through their layer loop; prefill's scatter of whole blocks needs
@@ -606,21 +651,25 @@ class InferenceEngine:
             _with_precision(decode_fn), "serve_decode", donate_argnums=(1,)
         )
 
+    def _blocks_of(self, rung: int) -> int:
+        """Whole blocks that hold ``rung`` positions."""
+        return (rung - 1) // self.pool.block_size + 1
+
     def _program_specs(self):
-        """(name, fn, dummy_args) for both serving programs, with dummy
-        arguments matching the :meth:`step` call-site shapes/dtypes exactly
-        — shared by :meth:`warmup` and :meth:`cost_summary` so the program
-        they build is the program the serving loop dispatches."""
+        """(name, fn, dummy_args) for the serving programs, ``serve_prefill``
+        once a rung (ascending, so the last is ``max_prompt_len``'s) and then
+        ``serve_decode``, with dummy arguments matching the :meth:`step`
+        call-site shapes/dtypes exactly — shared by :meth:`warmup` and
+        :meth:`cost_summary` so the program they build is the program the
+        serving loop dispatches."""
         import jax
         import jax.numpy as jnp
 
-        ecfg = self.engine_config
         # the pool by its shapes alone: its arrays are donated every tick
         cache = {
             name: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
             for name, leaf in self.pool.cache.items()
         }
-        prompt = jnp.zeros((1, ecfg.max_prompt_len), jnp.int32)
         if self._speculate_k > 0:
             token = jnp.zeros(
                 (self.pool.num_slots, self._speculate_k), jnp.int32
@@ -629,10 +678,13 @@ class InferenceEngine:
             token = jnp.zeros((self.pool.num_slots,), jnp.int32)
         pos = jnp.zeros((self.pool.num_slots,), jnp.int32)
         key = jax.random.key(0)
-        wt = jnp.zeros((self._n_prompt_blocks,), jnp.int32)
-        return (
+        prefills = tuple(
             ("serve_prefill", self._prefill_fn,
-             (self.params, cache, prompt, wt)),
+             (self.params, cache, jnp.zeros((1, rung), jnp.int32),
+              jnp.zeros((self._blocks_of(rung),), jnp.int32)))
+            for rung in self._rungs
+        )
+        return prefills + (
             ("serve_decode", self._decode_fn,
              (self.params, cache, token, pos,
               jnp.asarray(self.pool.block_tables), key)),
@@ -640,13 +692,25 @@ class InferenceEngine:
 
     def warmup(self) -> Dict[str, int]:
         """Resolve (load from the compile cache, or compile and persist)
-        both serving programs without executing them, so the first real
-        request pays dispatch cost only. Replica bring-up calls this before
-        reporting alive; a relaunch on a warm cache is load-bound, not
-        compile-bound. No-op when the cache is disabled."""
+        the serving programs, prefill at every rung, so the first real
+        request of any length pays dispatch cost only. Replica bring-up
+        calls this before reporting alive; a relaunch on a warm cache is
+        load-bound, not compile-bound.
+
+        Each rung of prefill is then run once, on zeros, with a write table
+        that is all trash block: on the chip a resolved executable's first
+        run costs 2-3 ms more than a later one (PERF.md, PR 29), and no
+        request should pay that inside a window either. Decode's first run
+        is any engine's first tick. With the cache disabled nothing is
+        resolved ahead and these runs are what compiles the rungs."""
         for _name, fn, args in self._program_specs():
             if hasattr(fn, "warmup"):
                 fn.warmup(*args)
+        for rung in self._rungs:
+            self._dispatch_prefill(
+                np.zeros((1, rung), np.int32),
+                np.full((self._blocks_of(rung),), TRASH_BLOCK, np.int32),
+            )
         return self.compile_stats()
 
     def compile_stats(self) -> Dict[str, int]:
@@ -722,8 +786,9 @@ class InferenceEngine:
         if len(tokens) > self.engine_config.max_prompt_len:
             raise ValueError(
                 f"prompt length {len(tokens)} exceeds max_prompt_len="
-                f"{self.engine_config.max_prompt_len} (the single compiled "
-                "prefill shape; raise it at engine construction)"
+                f"{self.engine_config.max_prompt_len} (the longest prompt "
+                "admitted and the longest prefill shape compiled; raise it "
+                "at engine construction)"
             )
         if eos_id == "__default__":
             eos_id = self.engine_config.eos_id
@@ -848,6 +913,16 @@ class InferenceEngine:
             self.pool.cache = cache
         return out
 
+    def _dispatch_prefill(self, padded: np.ndarray, where: np.ndarray) -> None:
+        """Enqueue prefill of one padded prompt row [1, rung] into the
+        physical blocks ``where`` names (one entry a block of the rung)."""
+        import jax.numpy as jnp
+
+        prompt_row, where = jnp.asarray(padded), jnp.asarray(where)
+        self._update_pool(lambda cache: (
+            self._prefill_fn(self.params, cache, prompt_row, where), None,
+        ))
+
     def _run_tick(self) -> Dict[str, Any]:
         import jax
         import jax.numpy as jnp
@@ -870,14 +945,16 @@ class InferenceEngine:
             self._process_imports()
             self._evict_expired_slots()
             plan = self.scheduler.tick()
-        ecfg = self.engine_config
 
         new_exports: List[str] = []
         # (trace, dispatch start, dispatch end) of this tick's prefills:
         # their duration is known at the tick's sync, not at the enqueue
         prefill_traces: List[tuple] = []
         for req, slot in plan.prefills:
-            with _obs.phase_span("rlt.serve.prefill", prompt_len=req.prompt_len):
+            rung = rung_for(self._rungs, req.prompt_len)
+            with _obs.phase_span(
+                "rlt.serve.prefill", prompt_len=req.prompt_len, rung=rung
+            ):
                 self._admit_seq += 1
                 fspec = _faults.serve_request_fault(
                     self.replica_index, self._admit_seq
@@ -886,17 +963,12 @@ class InferenceEngine:
                     self._drop_stream[req.request_id] = max(
                         1, int(fspec.arg or 1)
                     )
-                padded = np.zeros((1, ecfg.max_prompt_len), np.int32)
+                padded = np.zeros((1, rung), np.int32)
                 padded[0, : req.prompt_len] = req.tokens
                 tr = req.trace
                 t0 = time.perf_counter() if tr is not None else 0.0
-                where = jnp.asarray(self.pool.prompt_write_table(
-                    slot.index, self._n_prompt_blocks
-                ))
-                prompt_row = jnp.asarray(padded)
-                self._update_pool(lambda cache: (
-                    self._prefill_fn(self.params, cache, prompt_row, where),
-                    None,
+                self._dispatch_prefill(padded, self.pool.prompt_write_table(
+                    slot.index, self._blocks_of(rung)
                 ))
                 if tr is not None:
                     prefill_traces.append((tr, t0, time.perf_counter()))
@@ -917,6 +989,7 @@ class InferenceEngine:
                     }
                     new_exports.append(req.request_id)
                 self.stats["prefills"] += 1
+                self.stats["prefill_positions"] += rung
 
         # export-pending slots are parked: their KV is in flight to a
         # decode replica, so this engine must not decode them — not even
@@ -1755,7 +1828,8 @@ class InferenceEngine:
     def cost_summary(self) -> Dict[str, Any]:
         """Analytic HLO cost of the two compiled serving programs.
 
-        AOT-lowers prefill and decode with dummy arguments matching the
+        AOT-lowers prefill (at its last rung, ``max_prompt_len``) and decode
+        with dummy arguments matching the
         :meth:`step` call-site shapes/dtypes, publishes the
         ``rlt_step_flops``/``rlt_step_bytes``/collective gauges labeled
         ``program=serve_prefill|serve_decode``, and returns the per-program
@@ -1769,11 +1843,14 @@ class InferenceEngine:
         from ray_lightning_tpu import observability as _obs2
         from ray_lightning_tpu.observability import profiler as _profiler
 
-        programs = self._program_specs()
+        # a name once: of prefill's rungs the last
+        programs = {
+            name: (fn, args) for name, fn, args in self._program_specs()
+        }
         pool_bytes = sum(int(a.nbytes) for a in self.pool.cache.values())
         out: Dict[str, Any] = {}
         reg = _obs2.registry()
-        for name, fn, args in programs:
+        for name, (fn, args) in programs.items():
             rep = _profiler.analyze_jitted(fn, *args, program=name)
             if rep is None:
                 out[name] = None

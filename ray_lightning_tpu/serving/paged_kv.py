@@ -48,7 +48,7 @@ Prefix sharing (the system-prompt amortization):
 
 Physical block 0 is the TRASH block: prefill writes of shared (already
 cached) block slots and the dummy decode writes of free engine slots are
-redirected there, so the single fixed-shape prefill/decode programs
+redirected there, so the fixed-shape prefill/decode programs
 never need a "skip this write" branch. Trash contents are garbage and
 are never attendable (block tables only reference it at masked
 positions).
@@ -681,7 +681,8 @@ class PagedKVPool:
     def prompt_write_table(
         self, slot_index: int, n_prompt_blocks: int
     ) -> np.ndarray:
-        """Write-redirect table for the fixed-shape prefill: entry j is
+        """Write-redirect table for a prefill of ``n_prompt_blocks`` blocks
+        (the rung the engine padded this prompt to): entry j is
         the physical block for prompt block j, or TRASH for shared-prefix
         blocks (already written once, immutable while referenced) and for
         padding blocks past this prompt's real length."""

@@ -509,8 +509,8 @@ class LocalReplicaFleet:
       replica with the remaining budget, and the greedy continuation is
       bitwise-identical to the unfaulted stream. Size ``max_prompt_len``
       for the RESUME prefill: a request is recoverable at any point of
-      its stream only when ``prompt_len + max_new_tokens - 1`` fits the
-      compiled prefill shape (otherwise a mid-stream death past the
+      its stream only when ``prompt_len + max_new_tokens - 1`` fits
+      ``max_prompt_len`` (otherwise a mid-stream death past the
       prefill limit fails the request rather than resuming it);
     - each replica index owns a :class:`CircuitBreaker`: consecutive
       failures eject it from routing, and it only re-earns traffic by
@@ -1008,7 +1008,7 @@ class LocalReplicaFleet:
             return True
         except ValueError as e:
             # malformed for EVERY replica (e.g. resumed prompt exceeds
-            # the compiled prefill shape): retrying elsewhere cannot help
+            # max_prompt_len): retrying elsewhere cannot help
             self.journal.abort_attempt(entry)
             self.journal.finish(
                 entry, "failed", finish_reason="error", error=e

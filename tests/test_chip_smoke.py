@@ -45,6 +45,18 @@ def test_serve_phase_tiny(k):
     assert facts["compile_stats"] == {"prefill_compiles": 1, "decode_compiles": 1}
 
 
+def test_serve_phase_at_the_smokes_prompt_lengths_resolves_a_rung_each():
+    """The chip's serve phases take prompts up to 512: two prefill lengths
+    (256, 512), a prompt on each, one compilation a rung and no more."""
+    facts = chip_smoke.phase_serve(
+        TINY32, speculate_k=0, prompt_lens=(7, 40, 300, 40),
+        max_new=6, seed=0, num_slots=4, max_prompt_len=512, max_len=576,
+    )
+    assert facts["tokens_equal_generate"] is True
+    assert facts["compile_stats"] == {"prefill_compiles": 2, "decode_compiles": 1}
+    assert set(facts["custom_calls"]) == {"serve_prefill", "serve_decode"}
+
+
 @pytest.fixture(scope="module")
 def twinned():
     """Tiny bf16 weights whose lm_head repeats its first half: token

@@ -194,7 +194,7 @@ def test_runtime_error_propagates_without_redispatch():
         def __call__(self, *args):
             raise boom
 
-    prog._compiled = _DeadPeer()
+    prog._compiled = dict.fromkeys(prog._compiled, _DeadPeer())
     prog._fn = lambda *args: fn_calls.append(args)  # jit fallback must not run
     with pytest.raises(ValueError) as excinfo:
         prog(a)
@@ -217,7 +217,7 @@ def test_signature_mismatch_reresolves_against_current_args():
                 "match the sharding(s) the computation was compiled with."
             )
 
-    prog._compiled = _Mismatch()
+    prog._compiled = dict.fromkeys(prog._compiled, _Mismatch())
     out = prog(a)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(_fn(a)))
     assert not prog._polymorphic

@@ -152,7 +152,8 @@ def test_tick_spans_nest_in_order_and_cover_the_tick(model, tmp_path):
         with_prefill += sync.args["prefills"]
     assert with_prefill == 2
     assert len(pt.decode_only_syncs(spans)) == len(ticks) - 2
-    assert pt.named(spans, "rlt.serve.prefill")[0].args["prompt_len"] == 4
+    first = pt.named(spans, "rlt.serve.prefill")[0]
+    assert (first.args["prompt_len"], first.args["rung"]) == (4, 16)
     assert pt.cover_share(spans) >= 0.95
 
 
@@ -258,7 +259,7 @@ def test_ring_gets_the_same_names_when_telemetry_is_on(model):
     assert set(names) == set(SERVE_PHASES) | {"rlt.serve.tick"}
     assert "serve_prefill" not in names and "serve_decode" not in names
     prefill = next(e for e in events if e[1] == "rlt.serve.prefill")
-    assert prefill[0] == "X" and prefill[5] == {"prompt_len": 3}
+    assert prefill[0] == "X" and prefill[5] == {"prompt_len": 3, "rung": 16}
     tick = next(e for e in events if e[1] == "rlt.serve.tick")
     assert tick[5]["tick"] >= 1 and tick[3] > 0.0
 

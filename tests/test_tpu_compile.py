@@ -31,6 +31,7 @@ from ray_lightning_tpu.models.llama import (
 from ray_lightning_tpu.ops import paged_attention as pa
 from ray_lightning_tpu.ops.attention import attention
 from ray_lightning_tpu.ops.rmsnorm import _rmsnorm_pallas
+from ray_lightning_tpu.serving.engine import prefill_rungs
 
 SMALL = LlamaConfig.small()
 
@@ -531,14 +532,19 @@ def test_an_undonated_scatter_copies_its_leaf_and_the_check_sees_it(one_chip):
     assert given.memory_analysis().alias_size_in_bytes == 4 * 65 * 8 * 16 * 128 * 2
 
 
-@pytest.fixture(scope="module", params=["serve-dense-chat", "serve-mla-moe-reason"])
+SERVE_CELLS = ["serve-dense-chat", "serve-moe-batch", "serve-mla-moe-reason"]
+# the cells' engines all take prompts up to 2048 in blocks of 16
+PREFILLS = [f"serve_prefill@{rung}" for rung in prefill_rungs(2048, 16)]
+
+
+@pytest.fixture(scope="module", params=SERVE_CELLS)
 def cell_programs(request, topo, one_chip):
     """The paged engine of a serve cell (its configuration's widths, its
-    engine settings, parameters as shapes), with both programs compiled for
-    the chip: {program: compiled}, and the pool's leaves. The kernels are
-    forced on, as on the chip, and asked to compile rather than interpret."""
-    import os
-
+    engine settings, parameters as shapes), with its programs compiled for
+    the chip, prefill once a rung: {program: compiled} under the names
+    ``serve_decode`` and ``serve_prefill@<rung>``, and the pool's leaves. The
+    kernels are forced on, as on the chip, and asked to compile rather than
+    interpret."""
     from benchmarks import loader
     from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
 
@@ -559,32 +565,69 @@ def cell_programs(request, topo, one_chip):
             shapes = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
                 args)
+            if name == "serve_prefill":
+                name = f"{name}@{args[2].shape[1]}"
             compiled[name] = fn.lower(*shapes).compile()
     finally:
         mp.undo()
-    return compiled, leaves, pool_bytes
+    assert sorted(compiled) == sorted(["serve_decode", *PREFILLS])
+    return request.param, compiled, leaves, pool_bytes
 
 
-@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+@pytest.mark.parametrize("program", ["serve_decode", *PREFILLS])
 def test_serve_programs_update_the_pool_in_place_at_the_cells_shapes(
     cell_programs, program
 ):
-    """The chat cell's K/V pool (2 x [16, 2401, 8, 16, 128] bf16, 2.5 GB)
-    and the reason cell's latent pool ([1 | 4, 8193, 16, 640], 0.84 GB):
-    each program aliases the whole pool, nothing of a leaf's or a layer's
+    """The chat cell's K/V pool (2 x [16, 2401, 8, 16, 128] bf16, 2.5 GB),
+    the batch cell's (2 x [4, 1281, ...], 0.34 GB) and the reason cell's
+    latent pool ([1 | 4, 8193, 16, 640], 0.84 GB): each program, prefill at
+    every rung, aliases the whole pool, nothing of a leaf's or a layer's
     size is copied, sliced or stacked, and the temporaries stay a small part
     of the pool. Written ``.at[phys, :, off, :]`` on the five-axis pool, the
     decode step fails all three: the carried pool takes the scatter's layout
     and is copied whole to the kernel's, every layer. Prefill's temporaries
     are the prompt's activations, which do not grow with the pool; they are
-    held under the largest leaf (a copy of one would be at least that)."""
-    compiled, leaves, pool_bytes = cell_programs
+    held under the largest leaf (a copy of one would be at least that),
+    where a leaf is the larger: the batch cell's is 0.17 GB beside 2.8 GB of
+    expert temporaries at 2048 positions."""
+    cell, compiled, leaves, pool_bytes = cell_programs
     exe = compiled[program]
     assert _pool_sized_copies(exe.as_text(), leaves) == []
     mem = exe.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
+    if cell == "serve-moe-batch":
+        return  # a layer's expert matrices are among both programs' temporaries
     if program == "serve_decode":
         assert mem.temp_size_in_bytes < pool_bytes / 10
     else:
         largest = max(int(np.prod(s)) * jnp.dtype(d).itemsize for s, d in leaves)
         assert mem.temp_size_in_bytes < largest
+
+
+@pytest.mark.parametrize("program", PREFILLS)
+def test_every_rung_of_prefill_is_the_labelled_module_with_its_kernels_inside(
+    cell_programs, program
+):
+    """Whatever the rung, the program is the module ``jit_serve_prefill`` (one
+    name in a device trace's ``XLA Modules``) and holds the kernels prefill
+    holds today: flash attention, and in the reason cell the grouped matmul
+    of the routed experts."""
+    cell, compiled, _, _ = cell_programs
+    text = compiled[program].as_text()
+    assert text.startswith("HloModule jit_serve_prefill")
+    kernels = set(_kernel_instructions(text))
+    assert "flash_fwd" in kernels
+    assert ("gmm" in kernels) == (cell == "serve-mla-moe-reason")
+
+
+def test_prefill_temporaries_shrink_with_the_rung(cell_programs):
+    """What a shorter rung saves besides time: the prompt's activations. Each
+    rung's temporaries are under those of the next, and the first rung's
+    under a third of the last's; but for the batch cell, where 2.8 GB of
+    every rung's 2.8-3.0 are one layer's expert matrices sliced out of their
+    stack (``moe_ffn_lossless``), whatever the prompt's length."""
+    cell, compiled, _, _ = cell_programs
+    temps = [compiled[p].memory_analysis().temp_size_in_bytes for p in PREFILLS]
+    assert temps == sorted(temps) and len(set(temps)) == len(temps)
+    if cell != "serve-moe-batch":
+        assert temps[0] < temps[-1] / 3
