@@ -686,8 +686,8 @@ def _serve_microbench(
     interarrival = 1.0 / max(rate_rps, 1e-9)
     decode0 = engine.stats["decode_steps"]
     busy0 = engine.stats["busy_slot_steps"]
-    hits0 = engine.pool.allocator.prefix_hits_total
-    misses0 = engine.pool.allocator.prefix_misses_total
+    hits0 = engine.pool.kinds["full"].allocator.prefix_hits_total
+    misses0 = engine.pool.kinds["full"].allocator.prefix_misses_total
     completions = []
     t0 = time.perf_counter()
     for i in range(num_requests):
@@ -718,7 +718,7 @@ def _serve_microbench(
             busy / max(decode_steps * num_slots, 1), 4
         ),
     }
-    alloc = engine.pool.allocator
+    alloc = engine.pool.kinds["full"].allocator
     hits = alloc.prefix_hits_total - hits0
     misses = alloc.prefix_misses_total - misses0
     # peak (not instantaneous: the level has drained by now)
@@ -2098,7 +2098,7 @@ def _paged_kernel_sweep(args: argparse.Namespace) -> int:
     table = rope_angles(max_len, cfg.head_dim, cfg.rope_theta)
     tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, num_slots), jnp.int32)
     pos = jnp.asarray(pos_host)
-    tables = jnp.asarray(pool.block_tables)
+    tables = jnp.asarray(pool.kinds["full"].block_tables)
     reps = max(1, int(os.environ.get("RLT_BENCH_PAGED_KERNEL_STEPS", "20")))
 
     out = {}

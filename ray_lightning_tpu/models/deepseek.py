@@ -50,7 +50,7 @@ from ray_lightning_tpu.core.module import LightningModule
 from ray_lightning_tpu.ops.attention import attention
 from ray_lightning_tpu.ops.losses import masked_softmax_cross_entropy
 from ray_lightning_tpu.ops.rmsnorm import rmsnorm
-from ray_lightning_tpu.ops.rope import rope_angles
+from ray_lightning_tpu.ops.rope import rope_adjacent as _rope, rope_angles
 from ray_lightning_tpu.parallel.moe import moe_ffn_routed, route_sigmoid_bias
 
 # counters the paged decode step returns, summed over its expert layers:
@@ -190,16 +190,6 @@ def init_params(rng: jax.Array, cfg: DeepseekConfig) -> Dict[str, Any]:
 # --------------------------------------------------------------------- #
 def rope_table(cfg: DeepseekConfig, length: int):
     return rope_angles(length, cfg.qk_rope_head_dim, cfg.rope_theta)
-
-
-def _rope(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
-    """Turn the adjacent pairs ``(x[2i], x[2i+1])`` of the last axis by the
-    angles ``c, s`` (broadcastable to ``[..., hd/2]``); the halves come back
-    apart, all ``2i`` then all ``2i + 1``."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(dtype)
 
 
 def _queries(h, lp, cfg: DeepseekConfig):
@@ -495,7 +485,8 @@ class DeepseekServing:
             for name, rows in latent.items()}
 
     def decode_paged(self, params, cache, token, pos, tables, table):
-        return decode_step_paged(params, cache, token, pos, tables, self.cfg, table)
+        return decode_step_paged(
+            params, cache, token, pos, tables["full"], self.cfg, table)
 
 
 # --------------------------------------------------------------------- #

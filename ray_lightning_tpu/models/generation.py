@@ -216,6 +216,12 @@ def _gather_pages(flat: jnp.ndarray, tables: jnp.ndarray, nkv: int) -> jnp.ndarr
     return pages.transpose(0, 2, 1, 3, 4).reshape(b, nkv, cols * bs, hd)
 
 
+# the paged pool's helpers under public names: another model's decode step
+# carries and writes the pool the same way (models/cohere.py)
+cached_attention, flat_pages = _cached_attention, _flat_pages
+gather_pages, write_rows = _gather_pages, _write_rows
+
+
 def _scan_layers_over_cache(x, layers, cfg: LlamaConfig, cos, sin, attend, cache):
     """``lax.scan`` of a paged decode step's layers with the pool as the
     loop's CARRY, k/v as ``_flat_pages`` lays them;
@@ -621,13 +627,16 @@ class LlamaServing:
       (none here);
     - ``rope_table(max_len)``: one table for prefill and decode;
     - ``paged_block_leaves(block_size)``: the pool's device leaves, each
-      (layers, shape of one block in one layer, dtype), and
+      (layers, shape of one block in one layer, dtype) and, where the model
+      has layers that attend a window only, a fourth entry, the leaf's kind:
+      0 for ``"full"``, the window's width for ``"window"``; and
       ``cache_bytes_per_position()`` through all of them;
     - ``prefill_blocks(params, prompt_row, n_blocks, block_size, table)``:
       the leaves' contents for a prompt, cut into blocks
       ``[layers, n_blocks, ...]`` for the engine's write table;
     - ``decode_paged(params, cache, token, pos, tables, table)`` ->
-      (logits, cache, counters or None).
+      (logits, cache, counters or None); ``tables`` is ``{kind: block
+      table}``, ``{"full": ...}`` for a model whose leaves state no kind.
 
     A model that speculates also has ``decode_verify(params, cache, tokens,
     pos, tables, table)`` -> (logits, cache); one that does not is refused
@@ -672,12 +681,12 @@ class LlamaServing:
 
     def decode_paged(self, params, cache, token, pos, tables, table):
         logits, cache = decode_step_paged(
-            params, cache, token, pos, tables, self.cfg, table)
+            params, cache, token, pos, tables["full"], self.cfg, table)
         return logits, cache, None
 
     def decode_verify(self, params, cache, tokens, pos, tables, table):
         return decode_step_verify(
-            params, cache, tokens, pos, tables, self.cfg, table)
+            params, cache, tokens, pos, tables["full"], self.cfg, table)
 
 
 def _sample_logits(logits, key, temperature, top_k, top_p):

@@ -122,16 +122,20 @@ def _pages_per_step(n_cols, hkv, bs, hd, dtype) -> int:
 
 
 def _paged_decode_kernel(
-    bt_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-    k_buf, v_buf, sems, slot_ref, acc_scr, m_scr, l_scr,
-    *, scale, block_size, n_cols,
+    bt_ref, pos_ref, *refs, scale, block_size, n_cols, windowed=False,
 ):
     """One row a grid step: walk the row's live page groups, the next
     group's (or the next row's first group's) copies in flight while this
-    one is computed for all KV heads at once."""
+    one is computed for all KV heads at once. ``windowed``: a third
+    scalar-prefetch operand holds each row's first live position; the walk
+    starts at the group that holds it and positions before it are masked
+    as those past ``pos`` are."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    first_ref = refs[0] if windowed else None
+    (q_ref, k_hbm, v_hbm, o_ref,
+     k_buf, v_buf, sems, slot_ref, acc_scr, m_scr, l_scr) = refs[int(windowed):]
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
     hkv, pages = k_buf.shape[1:3]
@@ -139,6 +143,16 @@ def _paged_decode_kernel(
     pos_b = pos_ref[b]
     # groups holding at least one valid position; group 0 always does
     n_live = pos_b // step_tokens + 1
+
+    def first_group(row):
+        """The first group of ``row``'s walk: 0, or the one that holds its
+        first live position."""
+        return first_ref[row] // step_tokens if windowed else 0
+
+    def live(positions):
+        if windowed:
+            return (positions <= pos_b) & (positions >= first_ref[b])
+        return positions <= pos_b
 
     def copies(row, group, half):
         """The 2 * P page copies of one (row, group) into buffer ``half``:
@@ -160,7 +174,7 @@ def _paged_decode_kernel(
     @pl.when(b == 0)
     def _prime():
         slot_ref[0] = 0
-        for c in copies(0, 0, 0):
+        for c in copies(0, first_group(0), 0):
             c.start()
 
     acc_scr[:] = jnp.zeros_like(acc_scr)
@@ -176,9 +190,10 @@ def _paged_decode_kernel(
 
         @pl.when(jnp.logical_or(~row_ends, b + 1 < n_rows))
         def _prefetch():
+            next_row = jnp.minimum(b + 1, n_rows - 1)
             for c in copies(
-                jnp.where(row_ends, jnp.minimum(b + 1, n_rows - 1), b),
-                jnp.where(row_ends, 0, g + 1),
+                jnp.where(row_ends, next_row, b),
+                jnp.where(row_ends, first_group(next_row), g + 1),
                 other,
             ):
                 c.start()
@@ -200,9 +215,10 @@ def _paged_decode_kernel(
         )  # [Hkv, Gp, T]
         base = g * step_tokens
         cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        # the group's first position is valid, so every row of the score
-        # block has a finite column and -inf masking stays nan-safe
-        s = jnp.where(cols <= pos_b, s, -jnp.inf)
+        # a group of the walk holds a valid position (its first one, or
+        # the row's first live one), so every row of the score block has a
+        # finite column and -inf masking stays nan-safe
+        s = jnp.where(live(cols), s, -jnp.inf)
         m_prev = m_scr[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -215,7 +231,7 @@ def _paged_decode_kernel(
         # 0 * NaN is NaN: its V rows are selected to zero, not only its
         # scores to -inf
         rows = base + jax.lax.broadcasted_iota(jnp.int32, vs.shape, 1)
-        vs = jnp.where(rows <= pos_b, vs.astype(jnp.float32), 0.0)
+        vs = jnp.where(live(rows), vs.astype(jnp.float32), 0.0)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
             p, vs, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -223,7 +239,8 @@ def _paged_decode_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         return other
 
-    slot_ref[0] = jax.lax.fori_loop(0, n_live, group_step, slot_ref[0])
+    slot_ref[0] = jax.lax.fori_loop(
+        first_group(b), n_live, group_step, slot_ref[0])
     o_ref[:] = (acc_scr[:] / l_scr[:, :, :1]).astype(o_ref.dtype)
 
 
@@ -236,6 +253,7 @@ def paged_decode_attention(
     *,
     sm_scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    first: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Fused block-table-walking decode attention.
 
@@ -260,6 +278,14 @@ def paged_decode_attention(
     live group is fetched, and nothing past ``pos[b]`` reaches the
     result: the kernel's time follows the live context, not
     ``B * max_blocks``.
+
+    ``first``: [B] int32, each row's first live position (a window layer:
+    ``max(0, pos - W + 1)``). The row's logical positions are then
+    ``[first[b], pos[b]]``: the walk starts at the group that holds
+    ``first[b]``, nothing before that group is fetched (its table columns
+    may name the trash block: the blocks were given back), and positions
+    before ``first[b]`` inside it are masked. Absent, the kernel is the one
+    without the operand, traced as it was.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -280,11 +306,15 @@ def paged_decode_attention(
         q = jnp.pad(q, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     pages = _pages_per_step(n_cols, hkv, bs, hd, k_cache.dtype)
 
+    windowed = first is not None
+    scalars = (block_tables.astype(jnp.int32), pos.astype(jnp.int32))
+    if windowed:
+        scalars += (first.astype(jnp.int32),)
     row_block = pl.BlockSpec(
-        (None, hkv, gp, hd), lambda b, bt_ref, pos_ref: (b, 0, 0, 0)
+        (None, hkv, gp, hd), lambda b, *scalar_refs: (b, 0, 0, 0)
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(B,),
         in_specs=[
             row_block,
@@ -305,7 +335,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel,
-            scale=scale, block_size=bs, n_cols=n_cols,
+            scale=scale, block_size=bs, n_cols=n_cols, windowed=windowed,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hkv, gp, hd), jnp.float32),
@@ -315,8 +345,7 @@ def paged_decode_attention(
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
-      q, k_cache, v_cache)
+    )(*scalars, q, k_cache, v_cache)
     return out[:, :, :group] if gp != group else out
 
 

@@ -227,3 +227,15 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarra
     s = sin[:, None, :]
     out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
     return out.astype(dtype)
+
+
+def rope_adjacent(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
+    """Turn the adjacent pairs ``(x[2i], x[2i+1])`` of the last axis by the
+    angles ``c, s`` (broadcastable to ``[..., hd/2]``); the halves come back
+    apart, all ``2i`` then all ``2i + 1``. Queries and keys turned by this
+    alike keep every score (``rope_interleave`` of the latent-attention
+    decoder, ``rope_gptj`` of the parallel-block one)."""
+    dtype = x.dtype
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(dtype)

@@ -97,19 +97,27 @@ def moe_ffn_lossless(
 def route_sigmoid_bias(
     xt: jnp.ndarray,
     router: jnp.ndarray,
-    bias: jnp.ndarray,
+    bias: Optional[jnp.ndarray],
     top_k: int,
     scale: float = 1.0,
     renormalize: bool = True,
+    precision=None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sigmoid routing with a selection bias (no groups). xt: [T, D];
-    router: [D, E] float32; bias: [E]. Scores ``s = sigmoid(x W_r)`` in
+    router: [D, E] float32; bias: [E], or None for a router published
+    without one. Scores ``s = sigmoid(x W_r)`` in
     float32; the chosen experts are the top-k of ``s + bias``, ties to the
     lower index (``lax.top_k``); the weights are ``s`` at the chosen ones,
     WITHOUT the bias, over their sum (+ 1e-20) if ``renormalize``, times
-    ``scale``. Returns (idx [T, K] int32, weights [T, K] float32)."""
-    s = jax.nn.sigmoid(xt.astype(jnp.float32) @ router.astype(jnp.float32))
-    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    ``scale``. ``precision``: the product's (on the chip a float32 product
+    at the default takes one bfloat16 pass: the scores then carry a
+    bfloat16 rounding of both factors, enough to change which experts are
+    chosen among near-ties). Returns (idx [T, K] int32, weights [T, K]
+    float32)."""
+    s = jax.nn.sigmoid(jnp.dot(
+        xt.astype(jnp.float32), router.astype(jnp.float32), precision=precision))
+    _, idx = jax.lax.top_k(
+        s if bias is None else s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if renormalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -121,6 +129,20 @@ def route_sigmoid_bias(
 # every step reads a whole [D, F] expert slab whatever rows it has: the step
 # is bound by that read, so the row tile only has to divide the pairs
 _GMM_ROWS = 128
+# the most one weight tile of a step may take of the chip's fast memory (it
+# is double-buffered): a [K, N] slab over this is cut along N
+_GMM_SLAB_BYTES = 4 * 1024 * 1024
+
+
+def _gmm_tiles(k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """(rows, K, N) of one grid step of the grouped matmul: the whole
+    ``[K, N]`` slab of an expert where it fits ``_GMM_SLAB_BYTES`` (3 MiB at
+    the latent-attention cell's 2048 x 768), else N halved until it does
+    while the halves stay whole lanes (4096 x 4096 bfloat16 -> 4096 x 512)."""
+    tn = n
+    while k * tn * itemsize > _GMM_SLAB_BYTES and tn % 256 == 0:
+        tn //= 2
+    return _GMM_ROWS, k, tn
 
 
 def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
@@ -149,7 +171,16 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     return gmm(xs, w, sizes.astype(jnp.int32), jnp.float32,
-               (_GMM_ROWS, k, n), interpret=interpret)
+               _gmm_tiles(k, n, w.dtype.itemsize), interpret=interpret)
+
+
+def held_groups(idx: jnp.ndarray, first: int, count: int, layer=0) -> jnp.ndarray:
+    """Where a holder of the experts ``[first, first + count)`` of a layer
+    finds each routed pair in its weight stacks: ``layer * count + idx -
+    first`` (``layer``: which of the layers stacked one after another, may
+    be traced), and -1 for a pair whose expert it does not hold."""
+    local = idx - first
+    return jnp.where((local >= 0) & (local < count), layer * count + local, -1)
 
 
 def moe_ffn_routed(
@@ -158,6 +189,7 @@ def moe_ffn_routed(
     idx: jnp.ndarray,
     weights: jnp.ndarray,
     kernel: Optional[bool] = None,
+    held: Optional[Tuple[int, ...]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """No-drop MoE evaluation that computes only the routed pairs.
 
@@ -170,13 +202,28 @@ def moe_ffn_routed(
     no capacity: an expert takes every row routed to it, all T * K if the
     routing sends them there, and one that gets none is skipped.
 
+    A SHARE of the experts: ``held = (first, count)`` or ``(first, count,
+    layer)`` says the stacks hold the experts ``[first, first + count)`` of
+    the numbering ``idx`` uses (:func:`held_groups`), as one of the chips
+    that divide a layer by experts holds them; the router stays the
+    caller's, over every expert. A pair whose expert is not held (an
+    ``idx`` outside ``[0, E)`` once localised, or given so by the caller)
+    belongs to no group: it sorts behind every held expert's rows, no
+    grouped matmul reaches it, it adds nothing to the token's sum and
+    ``sizes`` does not count it. What it would have added is another
+    holder's to compute; nothing here stands in for that exchange.
+
     Returns (out [T, D] in xt's dtype, sizes [E] int32: the rows each
-    expert got, which is what the serving counters are made of)."""
+    held expert got, which is what the serving counters are made of)."""
     t, k = idx.shape
     e = params["w_gate"].shape[0]
+    if held is not None:
+        idx = held_groups(idx, *held)
     flat = idx.reshape(t * k)
+    here = (flat >= 0) & (flat < e)
+    flat = jnp.where(here, flat, e)  # group E: the pairs that leave
     order = jnp.argsort(flat, stable=True)  # pair numbers, expert by expert
-    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
     xs = xt[order // k]  # [T*K, D]: each pair's token
     dt = xt.dtype
     h = (
@@ -184,7 +231,9 @@ def moe_ffn_routed(
         * grouped_matmul(xs, params["w_up"], sizes, kernel)
     ).astype(dt)
     y = grouped_matmul(h, params["w_down"], sizes, kernel)  # [T*K, D] f32
-    y = y * weights.reshape(t * k)[order][:, None]
+    # rows behind the last group were never written: selected, not scaled
+    y = jnp.where(here[order][:, None],
+                  y * weights.reshape(t * k)[order][:, None], 0.0)
     # back to pair order by a gather (the inverse permutation), then a
     # token's K rows are adjacent
     inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(

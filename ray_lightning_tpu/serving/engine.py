@@ -83,7 +83,11 @@ from ray_lightning_tpu.observability import reqtrace as _reqtrace
 from ray_lightning_tpu.runtime import compile_cache as _compile_cache
 from ray_lightning_tpu.runtime import faults as _faults
 from ray_lightning_tpu.serving import migration as _migration
-from ray_lightning_tpu.serving.paged_kv import TRASH_BLOCK, PagedKVPool
+from ray_lightning_tpu.serving.paged_kv import (
+    TRASH_BLOCK,
+    PagedKVPool,
+    stated_leaves,
+)
 from ray_lightning_tpu.serving.resilience import RequestShed, ShedPolicy
 from ray_lightning_tpu.serving.scheduler import (
     ContinuousBatchScheduler,
@@ -476,9 +480,11 @@ class InferenceEngine:
             "decode_steps": 0,
             "prefills": 0,
             # the sum of the rungs those prefills ran at: what prefill
-            # computed, padding and all (1 - prompt tokens / this is the
-            # padded share)
+            # computed, padding and all, and the prompts' own tokens among
+            # them (1 - prefill_tokens / prefill_positions is the padded
+            # share)
             "prefill_positions": 0,
+            "prefill_tokens": 0,
             "tokens_out": 0,
             "busy_slot_steps": 0,
             "completed": 0,
@@ -500,6 +506,12 @@ class InferenceEngine:
         # experts), summed over decode ticks: they come back in the array
         # of sampled tokens, so reading them costs no sync of its own
         self.stats.update({name: 0 for name in self._model.counters})
+        # a pool with a window kind: the positions a decode tick's rows
+        # attend in a full layer (pos + 1 each) and in a window layer (no
+        # more than the window), summed over decode ticks
+        self._kv_window = max(k.window for k in self.pool.kinds.values())
+        if self._kv_window:
+            self.stats.update(kv_positions_full=0, kv_positions_window=0)
         self._build_compiled()
 
     def _refuse_unserved(self, ecfg: EngineConfig) -> None:
@@ -510,6 +522,16 @@ class InferenceEngine:
             raise ValueError(
                 f"speculate_k={ecfg.resolved_speculate_k()}: the "
                 f"{model.name} has no verify step (speculate_k=0 only)"
+            )
+        windows = sorted(
+            {w for *_, w in stated_leaves(model, 1).values() if w}
+        )
+        if windows and ecfg.prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True: the {model.name} keeps leaves of a "
+                f"window kind ({windows[0]} positions), and a block shared "
+                "by prefix would be given back by the first request it "
+                "falls out of the window of (prefix_cache=False only)"
             )
         if ecfg.role != "both" and tuple(
             model.paged_block_leaves(1)
@@ -600,8 +622,10 @@ class InferenceEngine:
                 params, prompt_row, self._blocks_of(prompt_row.shape[1]),
                 bs, table
             )
+            # a write table a kind of leaf (a window kind's names the trash
+            # block for everything before the window's tail)
             return {
-                name: leaf.at[:, write_table].set(
+                name: leaf.at[:, write_table[self.pool.leaf_kind[name]]].set(
                     blocks[name].astype(leaf.dtype)
                 )
                 for name, leaf in cache.items()
@@ -681,14 +705,27 @@ class InferenceEngine:
         prefills = tuple(
             ("serve_prefill", self._prefill_fn,
              (self.params, cache, jnp.zeros((1, rung), jnp.int32),
-              jnp.zeros((self._blocks_of(rung),), jnp.int32)))
+              self._on_device(self._trash_table(rung))))
             for rung in self._rungs
         )
         return prefills + (
             ("serve_decode", self._decode_fn,
              (self.params, cache, token, pos,
-              jnp.asarray(self.pool.block_tables), key)),
+              self._on_device(self.pool.program_tables()), key)),
         )
+
+    @staticmethod
+    def _on_device(tables):
+        """Block tables by kind of leaf, as the programs take them."""
+        import jax.numpy as jnp
+
+        return {kind: jnp.asarray(t) for kind, t in tables.items()}
+
+    def _trash_table(self, rung: int):
+        """Prompt write tables of ``rung`` positions that write nothing
+        (every entry the trash block)."""
+        table = np.full((self._blocks_of(rung),), TRASH_BLOCK, np.int32)
+        return {kind: table for kind in self.pool.kinds}
 
     def warmup(self) -> Dict[str, int]:
         """Resolve (load from the compile cache, or compile and persist)
@@ -708,8 +745,7 @@ class InferenceEngine:
                 fn.warmup(*args)
         for rung in self._rungs:
             self._dispatch_prefill(
-                np.zeros((1, rung), np.int32),
-                np.full((self._blocks_of(rung),), TRASH_BLOCK, np.int32),
+                np.zeros((1, rung), np.int32), self._trash_table(rung)
             )
         return self.compile_stats()
 
@@ -913,12 +949,13 @@ class InferenceEngine:
             self.pool.cache = cache
         return out
 
-    def _dispatch_prefill(self, padded: np.ndarray, where: np.ndarray) -> None:
+    def _dispatch_prefill(self, padded: np.ndarray, where) -> None:
         """Enqueue prefill of one padded prompt row [1, rung] into the
-        physical blocks ``where`` names (one entry a block of the rung)."""
+        physical blocks ``where`` names (one entry a block of the rung; one
+        such table a kind of leaf where the pool has several)."""
         import jax.numpy as jnp
 
-        prompt_row, where = jnp.asarray(padded), jnp.asarray(where)
+        prompt_row, where = jnp.asarray(padded), self._on_device(where)
         self._update_pool(lambda cache: (
             self._prefill_fn(self.params, cache, prompt_row, where), None,
         ))
@@ -967,7 +1004,7 @@ class InferenceEngine:
                 padded[0, : req.prompt_len] = req.tokens
                 tr = req.trace
                 t0 = time.perf_counter() if tr is not None else 0.0
-                self._dispatch_prefill(padded, self.pool.prompt_write_table(
+                self._dispatch_prefill(padded, self.pool.prompt_write_tables(
                     slot.index, self._blocks_of(rung)
                 ))
                 if tr is not None:
@@ -982,7 +1019,8 @@ class InferenceEngine:
                     # op) so a sibling release can't drop them to refcount 0
                     # and have them evicted while the shipment is in flight
                     slot.export_pending = True
-                    pinned = self.pool.allocator.pin_request(req.request_id)
+                    pinned = self.pool.kinds["full"].allocator.pin_request(
+                        req.request_id)
                     self._exports[req.request_id] = {
                         "slot": slot.index, "pinned": pinned,
                         "prompt": tuple(req.tokens),
@@ -990,6 +1028,7 @@ class InferenceEngine:
                     new_exports.append(req.request_id)
                 self.stats["prefills"] += 1
                 self.stats["prefill_positions"] += rung
+                self.stats["prefill_tokens"] += req.prompt_len
 
         # export-pending slots are parked: their KV is in flight to a
         # decode replica, so this engine must not decode them — not even
@@ -1000,7 +1039,7 @@ class InferenceEngine:
         # it is a no-op for "both"/"decode" roles — homogeneous fleets
         # run the exact pre-disaggregation path.
         decode_slots = plan.decode_slots
-        block_tables = self.pool.block_tables
+        block_tables = self.pool.program_tables()
         if self._role == "prefill":
             decode_slots = [s for s in decode_slots if not s.export_pending]
             parked = [
@@ -1017,8 +1056,9 @@ class InferenceEngine:
                 # the shipment (and any in-place fallback decode)
                 # depends on. Point parked rows at the trash block, the
                 # same sink free slots use.
-                block_tables = block_tables.copy()
-                block_tables[parked, :] = TRASH_BLOCK
+                block_tables = {k: t.copy() for k, t in block_tables.items()}
+                for t in block_tables.values():
+                    t[parked, :] = TRASH_BLOCK
 
         completed: List[str] = []
         K = self._speculate_k
@@ -1064,9 +1104,14 @@ class InferenceEngine:
                     )
                     pos[slot.index] = slot.pos
                 self._rng, sub = jax.random.split(self._rng)
+                if self._kv_window:
+                    live = pos[[s.index for s in decode_slots]] + 1
+                    self.stats["kv_positions_full"] += int(live.sum())
+                    self.stats["kv_positions_window"] += int(
+                        np.minimum(live, self._kv_window).sum())
                 inputs = (
                     jnp.asarray(token), jnp.asarray(pos),
-                    jnp.asarray(block_tables),
+                    self._on_device(block_tables),
                 )
             with _obs.phase_span("rlt.serve.decode_dispatch"):
                 sampled = self._update_pool(lambda cache: self._decode_fn(
@@ -1340,7 +1385,7 @@ class InferenceEngine:
             raise KeyError(
                 f"request {request_id!r} no longer owns slot {rec['slot']}"
             )
-        alloc = self.pool._alloc_of[rec["slot"]]
+        alloc = self.pool.kinds["full"].allocs[rec["slot"]]
         bs = self.pool.block_size
         n_prompt_blocks = (slot.prompt_len - 1) // bs + 1
         ids = np.asarray(alloc.blocks[:n_prompt_blocks], np.int32)
@@ -1416,7 +1461,7 @@ class InferenceEngine:
             slot = self.pool.slots[rec["slot"]]
             if slot.request_id != rid:
                 continue  # slot already recycled (expiry / engine death)
-            self.pool.allocator.unpin(rec["pinned"])
+            self.pool.kinds["full"].allocator.unpin(rec["pinned"])
             if action == "finish":
                 slot.export_pending = False
                 self._finish(rid, "migrated")
@@ -1540,7 +1585,7 @@ class InferenceEngine:
         # else gets the shipped blocks. A jitted scatter the pool is donated
         # to, like the two tracked programs (so in place), but not one of
         # them: compile_stats stays flat.
-        alloc = self.pool._alloc_of[slot.index]
+        alloc = self.pool.kinds["full"].allocs[slot.index]
         bs = self.pool.block_size
         n_prompt_blocks = (len(prompt) - 1) // bs + 1
         write = [
@@ -1614,7 +1659,7 @@ class InferenceEngine:
                     with self._work:
                         rec = self._exports.pop(slot.request_id, None)
                     if rec is not None:
-                        self.pool.allocator.unpin(rec["pinned"])
+                        self.pool.kinds["full"].allocator.unpin(rec["pinned"])
                 self._expire(slot.request_id, slot.trace)
                 self.pool.release(slot.index)
 

@@ -23,6 +23,32 @@ their positions need:
   state of one row of the decode batch), delegating block policy to the
   allocator.
 
+Kinds of leaf. A model states of each leaf its KIND (the fourth entry of
+``paged_block_leaves``: 0, or a window's width): a FULL kind's layers
+attend every position, so a request holds a block for every position it
+has; a WINDOW kind's layers attend the last ``window`` positions only, so a
+request holds the blocks of those and no others. Each kind has its own
+blocks, its own :class:`BlockAllocator` and its own block table a slot
+(``PagedKVPool.kinds``); admission is by every kind, each reserving its own
+worst case: a window kind's is the most it holds at once, the window and one
+block (:func:`window_blocks`), whatever the request's length. A prompt
+longer than the window writes only the window's tail into a window kind
+(its write table names the trash block before that); as the request grows,
+``ensure_writable`` gives the blocks that fell wholly out of the window back
+(``BlockAllocator.give_back``: their table entries become the trash block,
+and the decode kernel starts its walk behind them) before it grows, so
+growth still cannot fail. How many blocks a kind gets follows from the one
+setting there is and from what its slots can hold at most; no setting is
+new. Refused for a model with a window kind, by name, when the engine or the
+pool is built: prefix sharing (a block shared by prefix would be given back
+by the first request it falls out of the window of), and in the engine
+speculation, KV migration and a mesh. A config with a uniform
+``sliding_window`` whose leaves state no kind (the Llama family) is refused
+as before. A pool of one kind has the one form too: ``kinds`` is ``{"full":
+...}``, and the tables the programs take are ``{"full": table}``;
+``block_tables`` and ``prompt_write_table()`` hand out that one table not by
+kind, for a pool of one kind only.
+
 Prefix sharing (the system-prompt amortization):
 
 - a prompt's FULL blocks are identified by a rolling hash chain
@@ -176,6 +202,12 @@ class BlockAllocation:
     cached: int  # leading blocks owned by the chain cache (>= shared)
     chain_keys: List[bytes] = field(default_factory=list)
     reserved: int = 0
+    # a window kind holds a moving run of the request's logical blocks:
+    # ``blocks[i]`` is logical block ``first + i``; ``total`` logical blocks
+    # over the request's life, never more than ``peak`` of them at once
+    first: int = 0
+    total: int = 0
+    peak: int = 0
 
 
 def blocks_for(prompt_len: int, max_new_tokens: int, block_size: int) -> int:
@@ -184,6 +216,13 @@ def blocks_for(prompt_len: int, max_new_tokens: int, block_size: int) -> int:
     output, never written)."""
     last_pos = prompt_len + max_new_tokens - 2
     return last_pos // block_size + 1
+
+
+def window_blocks(window: int, block_size: int) -> int:
+    """The most blocks the ``window`` positions ``[pos - window + 1, pos]``
+    can touch: ``window / block_size + 1`` where the block divides the
+    window (4096 positions in blocks of 16: 257)."""
+    return -(-(window - 1) // block_size) + 1
 
 
 class BlockAllocator:
@@ -196,11 +235,29 @@ class BlockAllocator:
     always finishes. Requests that finish early (EOS) return their
     unused reservation immediately, which is the capacity win over a
     full-length row a request.
+
+    ``window`` > 0 makes it the allocator of a WINDOW kind of leaf, whose
+    layers attend the last ``window`` positions only: a request holds the
+    blocks of those positions and no others. Admission allocates the
+    prompt's tail (the blocks of positions ``[prompt_len - window,
+    prompt_len)``) and reserves up to the most it can hold at once
+    (:func:`window_blocks`, or its whole life's blocks if that is fewer);
+    :meth:`give_back` frees the blocks that fell out of the window and
+    turns them into reservation again while the request still has blocks to
+    grow into, so growth cannot fail here either. No prefix sharing: a
+    block that one request gives back another may still need.
     """
 
     def __init__(
-        self, num_blocks: int, block_size: int, prefix_cache: bool = True
+        self, num_blocks: int, block_size: int, prefix_cache: bool = True,
+        window: int = 0,
     ):
+        if window and prefix_cache:
+            raise ValueError(
+                "prefix sharing over a window kind of leaf: a shared block "
+                "would be given back by the first request it falls out of "
+                "the window of (prefix_cache=False only)"
+            )
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (1 data block + the trash "
@@ -211,6 +268,7 @@ class BlockAllocator:
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.prefix_cache_enabled = bool(prefix_cache)
+        self.window = int(window)
         # block 0 is TRASH: excluded from the free list forever
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._allocs: Dict[str, BlockAllocation] = {}
@@ -229,6 +287,7 @@ class BlockAllocator:
         self.evictions_total = 0
         self.deferred_total = 0  # admissions refused for lack of blocks
         self.blocks_highwater = 0  # peak used_blocks over the lifetime
+        self.given_back_total = 0  # blocks freed as they left the window
 
     # ------------------------------------------------------------------ #
     # capacity views
@@ -293,6 +352,13 @@ class BlockAllocator:
         bs = self.block_size
         total_needed = blocks_for(prompt_len, max_new_tokens, bs)
         prompt_blocks = (prompt_len - 1) // bs + 1
+        # a window kind: the first decode step (at prompt_len - 1) attends
+        # positions from prompt_len - window on, so the prompt's earlier
+        # blocks are never allocated
+        first = max(0, prompt_len - self.window) // bs if self.window else 0
+        peak = total_needed - first
+        if self.window:
+            peak = min(peak, window_blocks(self.window, bs))
         # decode writes positions >= prompt_len - 1, so the block holding
         # that position (and everything after) must be private: sharing is
         # copy-on-write at admission, not at decode time
@@ -317,8 +383,8 @@ class BlockAllocator:
 
         shared = len(matched)
         revived = sum(1 for n in matched if n.refcount == 0)
-        private_now = prompt_blocks - shared
-        reserved_new = total_needed - prompt_blocks
+        private_now = prompt_blocks - first - shared
+        reserved_new = peak - (prompt_blocks - first)
         if private_now + reserved_new + revived > self.available():
             self.deferred_total += 1
             return None
@@ -336,7 +402,7 @@ class BlockAllocator:
         blocks = [n.block for n in matched]
         chain_keys = list(keys[:shared])
         cached = shared
-        for i in range(shared, prompt_blocks):
+        for i in range(first + shared, prompt_blocks):
             block = self._alloc_block()
             blocks.append(block)
             if i < len(keys):  # full block before the write frontier
@@ -358,6 +424,9 @@ class BlockAllocator:
             cached=cached,
             chain_keys=chain_keys,
             reserved=reserved_new,
+            first=first,
+            total=total_needed,
+            peak=peak,
         )
         self._allocs[request_id] = alloc
         self.admitted_total += 1
@@ -384,6 +453,34 @@ class BlockAllocator:
         self.grown_total += 1
         self.blocks_highwater = max(self.blocks_highwater, self.used_blocks)
         return block
+
+    def give_back(self, request_id: str, first_live_block: int) -> int:
+        """Free the blocks of a window kind's request that lie before
+        logical block ``first_live_block`` (every position in them is out
+        of the window of every query still to come). What the request may
+        yet grow into, up to its peak, stays reserved, so a block given
+        back is its own next block if it needs one. Returns how many were
+        freed."""
+        if not self.window:
+            raise ValueError(
+                "give_back on a full kind of leaf: its layers attend every "
+                "position, so no block ever falls out"
+            )
+        alloc = self._allocs.get(request_id)
+        if alloc is None:
+            raise KeyError(f"request {request_id!r} is not admitted")
+        n = min(first_live_block - alloc.first, len(alloc.blocks))
+        if n <= 0:
+            return 0
+        self._free.extend(alloc.blocks[:n])
+        del alloc.blocks[:n]
+        alloc.first += n
+        ungrown = alloc.total - (alloc.first + len(alloc.blocks))
+        reserved = min(alloc.peak - len(alloc.blocks), ungrown)
+        self._reserved_total += reserved - alloc.reserved
+        alloc.reserved = reserved
+        self.given_back_total += n
+        return n
 
     def release(self, request_id: str) -> None:
         """Return a finished request's blocks: refcount-down the cached
@@ -514,7 +611,36 @@ class BlockAllocator:
             "cow_private_total": self.cow_private_total,
             "evictions_total": self.evictions_total,
             "deferred_total": self.deferred_total,
+            "given_back_total": self.given_back_total,
         }
+
+
+def stated_leaves(model, block_size: int) -> Dict[str, tuple]:
+    """A model's ``paged_block_leaves`` with every leaf's kind written out:
+    leaf -> (layers, block shape, dtype, window; 0 = the full kind). A model
+    that states three entries a leaf states the full kind."""
+    return {
+        name: (layers, tuple(block), dtype, int(rest[0]) if rest else 0)
+        for name, (layers, block, dtype, *rest) in
+        model.paged_block_leaves(block_size).items()
+    }
+
+
+@dataclass
+class _Kind:
+    """One kind of leaf of the pool: the leaves of the layers that attend
+    every position (``window`` 0, ``"full"``) or of those that attend the
+    last ``window`` only (``"window"``). A kind has its own blocks, its own
+    allocator and its own block table a slot; a request is admitted when
+    every kind admits it."""
+
+    name: str
+    window: int
+    leaves: List[str]
+    layers: int  # layers of this kind, for the byte arithmetic of readers
+    allocator: "BlockAllocator"
+    block_tables: np.ndarray  # [num_slots, max_blocks] int32, trash-padded
+    allocs: Dict[int, BlockAllocation] = field(default_factory=dict)
 
 
 class PagedKVPool:
@@ -555,12 +681,6 @@ class PagedKVPool:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         if max_len < 2:
             raise ValueError(f"max_len must be >= 2, got {max_len}")
-        if cfg.sliding_window:
-            raise ValueError(
-                "the paged KV pool requires dense-causal configs: block "
-                "tables map logical positions 1:1 to cache slots, which "
-                "is unsound for rolling sliding-window buffers"
-            )
         if max_len % block_size != 0:
             raise ValueError(
                 f"max_len ({max_len}) must be a multiple of block_size "
@@ -572,30 +692,62 @@ class PagedKVPool:
         self.max_len = int(max_len)
         self.block_size = int(block_size)
         self.max_blocks = self.max_len // self.block_size
-        if num_blocks is None:
-            # every slot at max_len + the trash block; a SMALLER num_blocks
-            # trades worst-case capacity for HBM (sharing and early release
-            # are what make that safe)
-            num_blocks = self.num_slots * self.max_blocks + 1
-        self.allocator = BlockAllocator(
-            num_blocks, self.block_size, prefix_cache=prefix_cache
-        )
         model = cfg.serving()
+        stated = stated_leaves(model, self.block_size)
+        widths = sorted({w for *_, w in stated.values() if w})
+        if len(widths) > 1:
+            raise ValueError(
+                f"window kinds of {widths} positions in one model: the pool "
+                "keeps one window kind beside the full one"
+            )
+        if getattr(cfg, "sliding_window", 0) and not widths:
+            raise ValueError(
+                "the paged KV pool requires dense-causal configs, or a "
+                "model that states which of its leaves are of a window "
+                "kind (paged_block_leaves): block tables map logical "
+                "positions 1:1 to cache slots, which is unsound for "
+                "rolling sliding-window buffers"
+            )
+        # the kinds of leaf, the full one first: each with blocks of its
+        # own. ``num_blocks`` is what a kind gets, but never more than every
+        # slot at the most it can hold (+ the trash block): max_len for the
+        # full kind, the window and one block for a window kind. The split
+        # follows from the model's kinds and max_len, not from a setting.
+        self.kinds: Dict[str, _Kind] = {}
+        for window in sorted({w for *_, w in stated.values()}):
+            kind = "window" if window else "full"
+            most = self.max_blocks
+            if window:
+                most = min(most, window_blocks(window, self.block_size))
+            worst = self.num_slots * most + 1
+            n = worst if num_blocks is None else int(num_blocks)
+            if window:
+                n = min(n, worst)
+            names = [k for k, v in stated.items() if v[3] == window]
+            self.kinds[kind] = _Kind(
+                name=kind, window=window, leaves=names,
+                layers=stated[names[0]][0],
+                allocator=BlockAllocator(
+                    n, self.block_size, prefix_cache=prefix_cache,
+                    window=window),
+                # host mirror of the device block tables; trash-padded so
+                # free slots and unallocated entries write/gather harmlessly
+                block_tables=np.full(
+                    (self.num_slots, self.max_blocks), TRASH_BLOCK, np.int32),
+            )
+        self.leaf_kind = {
+            name: kind.name for kind in self.kinds.values()
+            for name in kind.leaves
+        }
         self.cache = {
-            name: jnp.zeros((layers, num_blocks) + tuple(block), dtype)
-            for name, (layers, block, dtype) in model.paged_block_leaves(
-                self.block_size
-            ).items()
+            name: jnp.zeros(
+                (layers, self.kinds[self.leaf_kind[name]].allocator.num_blocks)
+                + block, dtype)
+            for name, (layers, block, dtype, _) in stated.items()
         }
         self.bytes_per_position = int(model.cache_bytes_per_position())
-        # host mirror of the device block tables; trash-padded so free
-        # slots and unallocated tail entries write/gather harmlessly
-        self.block_tables = np.full(
-            (self.num_slots, self.max_blocks), TRASH_BLOCK, np.int32
-        )
         self.slots: List[Slot] = [Slot(i) for i in range(self.num_slots)]
         self._free: List[int] = list(range(self.num_slots - 1, -1, -1))
-        self._alloc_of: Dict[int, BlockAllocation] = {}
         self.admitted_total = 0
         self.recycled_total = 0
         self.highwater = 0
@@ -634,13 +786,18 @@ class PagedKVPool:
             )
         if not self._free:
             return None
-        alloc = self.allocator.admit(
-            request_id, prompt_len, max_new_tokens,
-            prompt_tokens=prompt_tokens,
-        )
-        if alloc is None:
-            self._publish_gauges()
-            return None
+        admitted: Dict[str, BlockAllocation] = {}
+        for kind in self.kinds.values():
+            alloc = kind.allocator.admit(
+                request_id, prompt_len, max_new_tokens,
+                prompt_tokens=prompt_tokens,
+            )
+            if alloc is None:  # one kind is short: no kind keeps a block
+                for name in admitted:
+                    self.kinds[name].allocator.release(request_id)
+                self._publish_gauges()
+                return None
+            admitted[kind.name] = alloc
         slot = self.slots[self._free.pop()]
         slot.request_id = request_id
         slot.prompt_len = int(prompt_len)
@@ -652,10 +809,12 @@ class PagedKVPool:
         slot.admitted_at = time.perf_counter()
         slot.first_token_at = None
         slot.last_token_at = None
-        row = self.block_tables[slot.index]
-        row[:] = TRASH_BLOCK
-        row[: len(alloc.blocks)] = alloc.blocks
-        self._alloc_of[slot.index] = alloc
+        for kind in self.kinds.values():
+            alloc = admitted[kind.name]
+            row = kind.block_tables[slot.index]
+            row[:] = TRASH_BLOCK
+            row[alloc.first: alloc.first + len(alloc.blocks)] = alloc.blocks
+            kind.allocs[slot.index] = alloc
         self.admitted_total += 1
         self.tenancies[slot.index].append(request_id)
         self.highwater = max(self.highwater, self.occupancy)
@@ -666,9 +825,10 @@ class PagedKVPool:
         slot = self.slots[index]
         if not slot.occupied:
             raise ValueError(f"slot {index} is already free")
-        self.allocator.release(slot.request_id)
-        self.block_tables[index, :] = TRASH_BLOCK
-        self._alloc_of.pop(index, None)
+        for kind in self.kinds.values():
+            kind.allocator.release(slot.request_id)
+            kind.block_tables[index, :] = TRASH_BLOCK
+            kind.allocs.pop(index, None)
         slot.reset()
         self._free.append(index)
         self.recycled_total += 1
@@ -678,21 +838,49 @@ class PagedKVPool:
     # ------------------------------------------------------------------ #
     # block hooks the engine drives
     # ------------------------------------------------------------------ #
-    def prompt_write_table(
-        self, slot_index: int, n_prompt_blocks: int
-    ) -> np.ndarray:
+    def prompt_write_tables(self, slot_index: int, n_prompt_blocks: int):
         """Write-redirect table for a prefill of ``n_prompt_blocks`` blocks
         (the rung the engine padded this prompt to): entry j is
         the physical block for prompt block j, or TRASH for shared-prefix
-        blocks (already written once, immutable while referenced) and for
-        padding blocks past this prompt's real length."""
-        alloc = self._alloc_of[slot_index]
+        blocks (already written once, immutable while referenced), for
+        padding blocks past this prompt's real length and, in a window
+        kind, for the blocks before the window's tail, which were never
+        allocated. ``{kind: table}``."""
         slot = self.slots[slot_index]
         own = (slot.prompt_len - 1) // self.block_size + 1
-        table = np.full((n_prompt_blocks,), TRASH_BLOCK, np.int32)
-        for j in range(alloc.shared, min(own, n_prompt_blocks)):
-            table[j] = alloc.blocks[j]
-        return table
+        tables = {}
+        for kind in self.kinds.values():
+            alloc = kind.allocs[slot_index]
+            table = np.full((n_prompt_blocks,), TRASH_BLOCK, np.int32)
+            lo, hi = alloc.first + alloc.shared, min(own, n_prompt_blocks)
+            if hi > lo:
+                table[lo:hi] = alloc.blocks[lo - alloc.first: hi - alloc.first]
+            tables[kind.name] = table
+        return tables
+
+    def program_tables(self):
+        """The block tables as the decode program takes them: ``{kind:
+        [num_slots, max_blocks]}``, the host mirrors themselves."""
+        return {kind.name: kind.block_tables for kind in self.kinds.values()}
+
+    # A pool of the full kind alone, as the accepted benchmark's tests read
+    # it (tests/bench_harness/test_deepseek_family.py, not a program PR's
+    # to edit): the one table and the one write table, not by kind.
+    def _only_kind(self) -> _Kind:
+        if len(self.kinds) != 1:
+            raise ValueError(
+                f"a pool of kinds {sorted(self.kinds)} has a block table a "
+                "kind: program_tables() / prompt_write_tables()"
+            )
+        return next(iter(self.kinds.values()))
+
+    @property
+    def block_tables(self) -> np.ndarray:
+        return self._only_kind().block_tables
+
+    def prompt_write_table(self, slot_index: int, n_prompt_blocks: int):
+        name = self._only_kind().name
+        return self.prompt_write_tables(slot_index, n_prompt_blocks)[name]
 
     def ensure_writable(
         self, slot: Slot, upto_pos: Optional[int] = None
@@ -707,18 +895,32 @@ class PagedKVPool:
         physical blocks. The engine clamps proposals to the remaining
         token budget, which keeps ``upto_pos`` within the admission-time
         reservation (``blocks_for``) — growth still cannot fail."""
-        alloc = self._alloc_of[slot.index]
         pos = slot.pos if upto_pos is None else int(upto_pos)
         needed = pos // self.block_size + 1
-        while len(alloc.blocks) < needed:
-            block = self.allocator.grow(slot.request_id)
-            self.block_tables[slot.index, len(alloc.blocks) - 1] = block
+        for kind in self.kinds.values():
+            alloc = kind.allocs[slot.index]
+            row = kind.block_tables[slot.index]
+            if kind.window:
+                # the step at slot.pos attends [slot.pos - window + 1,
+                # slot.pos]: blocks wholly before that go back to the pool
+                # first, so the request never holds more than its peak
+                live = max(0, slot.pos - kind.window + 1) // self.block_size
+                gone = kind.allocator.give_back(slot.request_id, live)
+                if gone:
+                    row[alloc.first - gone: alloc.first] = TRASH_BLOCK
+            while alloc.first + len(alloc.blocks) < needed:
+                block = kind.allocator.grow(slot.request_id)
+                row[alloc.first + len(alloc.blocks) - 1] = block
 
     def shared_blocks(self, slot_index: int) -> int:
-        return self._alloc_of[slot_index].shared
+        """Prefix blocks the slot's request shares (a window kind shares
+        none: the engine refuses a prefix cache over it)."""
+        return max(kind.allocs[slot_index].shared for kind in self.kinds.values())
 
     def block_utilization(self) -> float:
-        return self.allocator.used_blocks / max(self.allocator.capacity, 1)
+        """Of the fullest kind: the one that refuses the next admission."""
+        return max(kind.allocator.used_blocks / max(kind.allocator.capacity, 1)
+                   for kind in self.kinds.values())
 
     # ------------------------------------------------------------------ #
     # views
@@ -751,7 +953,14 @@ class PagedKVPool:
                 i: len(v) for i, v in self.tenancies.items()
             },
         }
-        out.update(self.allocator.stats())
+        # a kind's layers, window and allocator: the first kind's under the
+        # names a pool has always reported, a further kind's behind its name
+        for i, kind in enumerate(self.kinds.values()):
+            prefix = f"{kind.name}." if i else ""
+            out.update({f"{prefix}layers": kind.layers,
+                        f"{prefix}window": kind.window})
+            out.update({f"{prefix}{k}": v
+                        for k, v in kind.allocator.stats().items()})
         return out
 
     def _publish_gauges(self) -> None:
@@ -760,7 +969,7 @@ class PagedKVPool:
             return
         reg.gauge("rlt_serve_slot_occupancy").set(self.occupancy)
         reg.gauge("rlt_serve_slot_highwater").set(self.highwater)
-        alloc = self.allocator
+        alloc = next(iter(self.kinds.values())).allocator
         reg.gauge("rlt_serve_kv_blocks_used").set(alloc.used_blocks)
         reg.gauge("rlt_serve_kv_blocks_free").set(alloc.free_blocks)
         reg.gauge("rlt_serve_kv_blocks_cached").set(alloc.cached_blocks)

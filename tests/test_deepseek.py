@@ -242,7 +242,7 @@ def test_engine_serves_it_token_for_token_with_the_forward(params, monkeypatch, 
     pool = engine.pool.stats()
     assert pool["bytes_per_position"] == CFG.n_layers * 128 * 4  # 24 values in 128 lanes, float32
     assert set(engine.pool.cache) == {"dense", "moe"}
-    assert engine.pool.cache["moe"].shape == (2, engine.pool.allocator.num_blocks, 4, 128)
+    assert engine.pool.cache["moe"].shape == (2, engine.pool.kinds["full"].allocator.num_blocks, 4, 128)
 
 
 @pytest.mark.parametrize("setting,names", [

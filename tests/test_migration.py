@@ -364,7 +364,7 @@ def test_engine_migration_token_identical_and_caches_flat(model):
         comp_src = src.submit(prompt, max_new_tokens=n_new)
         src.step()  # prefill runs; the slot parks export-pending
         [rid] = src.drain_ready_exports()
-        assert src.pool.allocator.stats()["chains_pinned"] > 0
+        assert src.pool.kinds["full"].allocator.stats()["chains_pinned"] > 0
 
         ship = src.export_shipment(rid)
         assert verify_shipment(ship, dst.kv_fingerprint()) == ship.nbytes()
@@ -384,7 +384,7 @@ def test_engine_migration_token_identical_and_caches_flat(model):
         warm_dst = dst.compile_stats()
         assert warm_dst == {"prefill_compiles": 0, "decode_compiles": 1}
         # pins released with the export record on both outcomes
-        assert src.pool.allocator.stats()["chains_pinned"] == 0
+        assert src.pool.kinds["full"].allocator.stats()["chains_pinned"] == 0
         assert src.pool.occupancy == 0
 
         # steady state: a second handoff (different length) recompiles
@@ -461,7 +461,7 @@ def test_engine_cancel_export_decodes_in_place(model):
             params, cfg, prompt, n_new
         )
         assert comp.finish_reason == "length"
-        assert src.pool.allocator.stats()["chains_pinned"] == 0
+        assert src.pool.kinds["full"].allocator.stats()["chains_pinned"] == 0
     finally:
         src.shutdown()
 

@@ -299,10 +299,10 @@ def test_engine_tokens_equal_the_run_on_the_scanned_decode_step(
     ref = _engine(weights, variant)
     if family == "llama":
         ref._model.decode_paged = lambda params, cache, token, pos, tables, table: (
-            *_scanned_llama_paged(params, cache, token, pos, tables, cfg, table), None)
+            *_scanned_llama_paged(params, cache, token, pos, tables["full"], cfg, table), None)
     else:
         ref._model.decode_paged = lambda params, cache, token, pos, tables, table: (
-            _scanned_deepseek_paged(params, cache, token, pos, tables, cfg, table))
+            _scanned_deepseek_paged(params, cache, token, pos, tables["full"], cfg, table))
     engine.warmup()
     warm = engine.compile_stats()
     assert _run(engine) == _run(ref)
@@ -405,7 +405,7 @@ def test_import_then_tick_installs_in_place(weights):
         time.sleep(0.01)
     waiter.join(10.0)
     assert all(a.is_deleted() for a in before.values())
-    blocks = dst.pool._alloc_of[dst.pool.slots[0].index].blocks[:ship.num_blocks]
+    blocks = dst.pool.kinds["full"].allocs[dst.pool.slots[0].index].blocks[:ship.num_blocks]
     for j, bid in enumerate(blocks):
         # to rounding: the tick that admitted it has already decoded the
         # prompt's last token again, which rewrites that position's row

@@ -202,22 +202,23 @@ def test_prefill_positions_is_the_sum_of_the_rungs_used(model):
     _serve(engine, _prompts(cfg, lengths))
     assert engine.stats["prefills"] == len(lengths)
     assert engine.stats["prefill_positions"] == 256 * 3 + 512 + 1024
-    padded_share = 1 - sum(lengths) / engine.stats["prefill_positions"]
+    assert engine.stats["prefill_tokens"] == sum(lengths)
+    padded_share = 1 - engine.stats["prefill_tokens"] / engine.stats["prefill_positions"]
     assert padded_share == pytest.approx(1 - 1766 / 2304)
 
 
 def test_a_wrapper_round_prefill_fn_sees_every_prefill_at_its_rung(model):
     """The serve driver's traced run replaces ``engine._prefill_fn`` with a
     wrapper: it is still ONE attribute with the call ``(params, cache,
-    prompt_row, where)``, so the wrapper sees every prefill, whatever its
-    rung."""
+    prompt_row, where)`` (``where``: a write table a kind of leaf), so
+    the wrapper sees every prefill, whatever its rung."""
     _, params, cfg = model
     engine = InferenceEngine(params, cfg, EngineConfig(**ENGINE))
     engine.warmup()
     inner, seen = engine._prefill_fn, []
 
     def spanned(params, cache, prompt_row, where):
-        seen.append((prompt_row.shape, where.shape))
+        seen.append((prompt_row.shape, where["full"].shape))
         return inner(params, cache, prompt_row, where)
 
     engine._prefill_fn = spanned

@@ -398,7 +398,7 @@ def test_paged_engine_matches_generate(model):
         assert comp.finish_reason == "length"
         assert comp.result(timeout=1) == _reference(params, cfg, prompt, n_new)
 
-    alloc = engine.pool.allocator
+    alloc = engine.pool.kinds["full"].allocator
     assert alloc.grown_total > 0  # decode crossed block boundaries
     assert alloc.used_blocks == 0  # every request released its blocks
     assert engine.pool.recycled_total == 8
@@ -434,9 +434,9 @@ def test_paged_shared_prefix_bitwise_identical(model):
 
     shared_engine, shared = run(prefix_cache=True)
     # both leading system-prompt blocks were served from the chain cache
-    assert shared_engine.pool.allocator.prefix_hits_total == 2
+    assert shared_engine.pool.kinds["full"].allocator.prefix_hits_total == 2
     unshared_engine, unshared = run(prefix_cache=False)
-    assert unshared_engine.pool.allocator.prefix_hits_total == 0
+    assert unshared_engine.pool.kinds["full"].allocator.prefix_hits_total == 0
     for prompt, a, b in zip(prompts, shared, unshared):
         ref = _reference(params, cfg, prompt, n_new)
         assert a == ref  # shared run matches sequential generate()
@@ -463,17 +463,17 @@ def test_paged_pool_write_redirect_and_growth(model):
     assert list(wt2[:2]) == [TRASH_BLOCK, TRASH_BLOCK]
     assert wt2[2] not in (TRASH_BLOCK, wt1[2])
     # both block tables gather the same physical prefix blocks
-    assert list(pool.block_tables[s1.index][:2]) == \
-        list(pool.block_tables[s2.index][:2])
+    assert list(pool.kinds["full"].block_tables[s1.index][:2]) == \
+        list(pool.kinds["full"].block_tables[s2.index][:2])
     # decode reaching position 12 pulls block 3 from the reservation
-    assert pool.block_tables[s1.index][3] == TRASH_BLOCK
+    assert pool.kinds["full"].block_tables[s1.index][3] == TRASH_BLOCK
     s1.pos = 12
     pool.ensure_writable(s1)
-    assert pool.block_tables[s1.index][3] != TRASH_BLOCK
-    assert pool.allocator.grown_total == 1
+    assert pool.kinds["full"].block_tables[s1.index][3] != TRASH_BLOCK
+    assert pool.kinds["full"].allocator.grown_total == 1
     pool.release(s1.index)
     pool.release(s2.index)
-    assert pool.allocator.used_blocks == 0
+    assert pool.kinds["full"].allocator.used_blocks == 0
 
 
 def test_scheduler_defers_on_block_exhaustion_fifo(model):
@@ -494,7 +494,7 @@ def test_scheduler_defers_on_block_exhaustion_fifo(model):
     assert [r.request_id for r, _ in plan.prefills] == ["big"]
     assert sched.queue_depth == 2
     assert sched.deferred_total == 1
-    assert pool.allocator.available() == 0
+    assert pool.kinds["full"].allocator.available() == 0
     sched.tick()
     assert sched.deferred_total == 2  # still waiting, still queued
 

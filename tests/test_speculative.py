@@ -523,13 +523,13 @@ def test_decode_step_paged_kernel_vs_lax_token_parity(model):
         # only block 0 is physical at pos=5 (the rest of the table is
         # trash until ensure_writable grows it) — and only positions
         # <= pos are ever exposed by the mask anyway
-        dst = pool.block_tables[b, 0]
+        dst = pool.kinds["full"].block_tables[b, 0]
         k[:, dst] = np.asarray(cache["k"][:, b, :, 0:8])
         v[:, dst] = np.asarray(cache["v"][:, b, :, 0:8])
     paged_cache = {"k": jnp.asarray(k), "v": jnp.asarray(v)}
     toks = jnp.asarray([p[-1] for p in prompts], jnp.int32)
     pos = jnp.asarray([5, 5], jnp.int32)
-    tables = jnp.asarray(pool.block_tables)
+    tables = jnp.asarray(pool.kinds["full"].block_tables)
 
     lg_lax, _ = decode_step_paged(
         params, paged_cache, toks, pos, tables, cfg, kernel=False

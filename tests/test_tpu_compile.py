@@ -449,6 +449,54 @@ def test_a_576_wide_latent_page_is_what_the_chip_refuses(one_chip):
             ((64, 256), jnp.int32), ((64,), jnp.int32), sharding=one_chip)
 
 
+# what the document cell hands the kernel: 32 slots, 8 KV heads of 16 queries
+# and 128 wide as float32, max_len 17408 = 1088 table columns; a full layer's
+# pool of 16385 pages and the three window layers' of 3 x 8225, with each
+# row's first live position
+_DOC_PAGED = {
+    "doc-cell-full": _paged_shapes(32, 8, 16, 128, 16, 16385, 1088, jnp.float32),
+    "doc-cell-window": _paged_shapes(32, 8, 16, 128, 16, 3 * 8225, 1088, jnp.float32)
+    + (((32,), jnp.int32),),
+}
+
+
+@pytest.mark.parametrize("shapes", list(_DOC_PAGED), ids=list(_DOC_PAGED))
+def test_windowed_paged_decode_attention_compiles_at_the_cells_shapes(one_chip, shapes):
+    """The kernel with a first live position a row (a window layer) and
+    without (a full layer), 16 queries a KV head: one Mosaic call each, and
+    the windowed one takes a third scalar-prefetch operand."""
+    def call(q, k, v, bt, pos, first=None):
+        return pa.paged_decode_attention(q, k, v, bt, pos, first=first, interpret=False)
+
+    assert _custom_calls(call, *_DOC_PAGED[shapes], sharding=one_chip) == 1
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in _DOC_PAGED[shapes]]
+    (kernel,) = _pallas_calls(jax.make_jaxpr(call)(*args).jaxpr)
+    assert kernel.params["name"] == "paged_decode_attention"
+    assert kernel.params["grid_mapping"].num_index_operands == len(args) - 3
+
+
+@pytest.mark.parametrize("rows", [256, 16384], ids=["decode-tick", "prefill-chunk"])
+def test_routed_expert_matmuls_compile_at_4096_square(one_chip, rows):
+    """The document cell's held experts, 64 of 4096 x 4096 in one stack: a
+    whole slab is 32 MiB, so a step takes 512 of its columns."""
+    from ray_lightning_tpu.parallel.moe import _gmm_tiles, grouped_matmul
+
+    assert _gmm_tiles(4096, 4096, 2) == (128, 4096, 512)
+    assert _gmm_tiles(2048, 768, 2) == (128, 2048, 768)  # the latent cell's, whole
+    assert _gmm_tiles(768, 2048, 2) == (128, 768, 2048)
+
+    def call(xs, w_gate, w_up, w_down, sizes):
+        mm = lambda a, w: grouped_matmul(a, w, sizes, kernel=True, interpret=False)
+        h = (jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
+        return mm(h, w_down)
+
+    stack = ((64, 4096, 4096), jnp.bfloat16)
+    n = _custom_calls(
+        call, ((rows, 4096), jnp.bfloat16), stack, stack, stack,
+        ((64,), jnp.int32), sharding=one_chip)
+    assert n == 3
+
+
 @pytest.mark.parametrize("rows", [512, 16384], ids=["decode-tick", "padded-prefill"])
 def test_routed_expert_matmuls_compile_at_the_cells_shapes(one_chip, rows):
     """A decode tick's 64 x 8 pairs and a padded prefill's 2048 x 8, over
