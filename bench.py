@@ -2107,11 +2107,11 @@ def _paged_kernel_sweep(args: argparse.Namespace) -> int:
         fn = jax.jit(functools.partial(
             decode_step_paged, cfg=cfg, rope_table=table, kernel=use_kernel
         ))
-        logits, _ = fn(params, pool.cache, tokens, pos, tables)
+        logits, _, _ = fn(params, pool.cache, tokens, pos, tables)
         jax.block_until_ready(logits)  # compile off the clock
         t0 = time.perf_counter()
         for _ in range(reps):
-            logits, _ = fn(params, pool.cache, tokens, pos, tables)
+            logits, _, _ = fn(params, pool.cache, tokens, pos, tables)
         jax.block_until_ready(logits)
         step_s = (time.perf_counter() - t0) / reps
         toks[name] = np.asarray(jnp.argmax(logits, axis=-1)).tolist()

@@ -25,13 +25,14 @@ TPU-first design:
   ``generate`` and
   passed into every step (loop-invariant by construction, not by hoping
   XLA hoists them);
-- MoE configs route LOSSLESSLY throughout generation
-  (``moe_ffn_lossless``: all experts evaluated densely, combined with the
-  top-k gate weights, so no token ever drops and no O(T^2*E) dispatch
-  tensors are built): capacity truncation is a training-time
-  load-balancing artifact computed over B*S competing tokens and has no
-  analogue at inference. Prefill and stepwise decode therefore produce
-  identical caches for MoE configs too.
+- MoE configs route WITHOUT CAPACITY throughout generation
+  (``parallel/moe.py::moe_ffn_routed``: the routed (token, expert) pairs
+  alone are computed, sorted by expert, by grouped matmul over the expert
+  stacks of ALL layers held as one stack that no layer scan slices, so no
+  token ever drops and no O(T^2*E) dispatch tensors are built): capacity
+  truncation is a training-time load-balancing artifact computed over B*S
+  competing tokens and has no analogue at inference. Prefill and stepwise
+  decode therefore produce identical caches for MoE configs too.
 
 The reference wraps user torch models and has no generation surface
 (SURVEY §2a — examples train/validate only); this is native capability on
@@ -53,6 +54,13 @@ from ray_lightning_tpu.models.llama import LlamaConfig
 from ray_lightning_tpu.ops.attention import attention, flash_supported
 from ray_lightning_tpu.ops.rmsnorm import rmsnorm
 from ray_lightning_tpu.ops.rope import rope_angles, rope_scaling_kind
+from ray_lightning_tpu.parallel.moe import moe_ffn_routed, route_softmax_top_k
+
+# counters the paged decode step of a configuration with experts returns,
+# summed over its layers: distinct experts chosen, (row, expert) pairs, the
+# fullest expert's rows (the names the engine carries for every family)
+DECODE_COUNTERS = ("moe_expert_hits", "moe_routed_pairs", "moe_max_expert_rows")
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 
 def _default_table_or_raise(cfg: LlamaConfig, seq_len: int):
@@ -110,7 +118,28 @@ def _rope(x: jnp.ndarray, c: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
     return out.astype(dtype)
 
 
-def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache):
+def _scanned_layers(params):
+    """(the leaves a layer scan slices a layer at a time, the expert stacks
+    of ALL layers as ONE stack ``[L * E, ...]``: a reshape of the leading
+    axes, no copy). A scan slices its operands, and a slice handed to a
+    kernel or a loop is a copy: three matrices of 4096 x 14336 an expert, a
+    layer, a program at Mixtral's widths, two thirds of both serving
+    programs' time on the chip when the experts were scanned over. So
+    they are not: every layer sees the whole stack and finds its own from
+    ``lp["moe"]["layer"]``, its number, which rides the scan in their
+    place. A dense configuration's layers come back as they are, with no
+    stack."""
+    layers = params["layers"]
+    if "moe" not in layers:
+        return layers, None
+    moe = layers["moe"]
+    experts = {k: moe[k].reshape((-1,) + moe[k].shape[2:]) for k in EXPERT_STACKS}
+    rest = {k: v for k, v in moe.items() if k not in EXPERT_STACKS}
+    rest["layer"] = jnp.arange(moe["router"].shape[0], dtype=jnp.int32)
+    return dict(layers, moe=rest), experts
+
+
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache, experts=None):
     """One decoder layer at inference, written once for every serving
     function of the family. x: [..., D] — a prompt ``[B, P, D]``, one
     position a row ``[B, D]`` or K a row ``[B, K, D]``; cos/sin: the rope
@@ -125,11 +154,15 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache):
     ``[..., H * hd]``; ``cache`` is the caller's own (a layer's rows, the
     carried pool, or nothing in and the prompt's rows out).
 
-    Experts route LOSSLESSLY: capacity dropping is a training-time
+    Experts route WITHOUT CAPACITY: capacity dropping is a training-time
     load-balancing artifact computed over B*S competing tokens and has no
-    analogue at inference, so every routed token keeps its experts (dense
-    all-experts evaluation, no O(T^2*E) dispatch tensors), and prefill and
-    stepwise decode write the same cache."""
+    analogue at inference, so every routed token keeps its experts (the
+    routed pairs alone are computed, none dropped, no O(T^2*E) dispatch
+    tensors), and prefill and stepwise decode write the same cache.
+    ``experts``: the expert stacks of all layers (``_scanned_layers``).
+
+    Returns (x, cache, sizes): the rows each expert of the stack got from
+    this layer (``moe_ffn_routed``), None for a dense layer."""
     hd = cfg.head_dim
     lead = x.shape[:-1]
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -144,17 +177,19 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache):
     att, cache = attend(q, k, v, cache)
     x = x + att.reshape(lead + (-1,)).astype(x.dtype) @ lp["wo"]
     h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+    sizes = None
     if cfg.n_experts and "moe" in lp:
-        from ray_lightning_tpu.parallel.moe import moe_ffn_lossless
-
-        tokens = h2.reshape(x.shape[0], -1, x.shape[-1])  # [batch, seq, d]
-        x = x + moe_ffn_lossless(
-            lp["moe"], tokens, top_k=cfg.expert_top_k
-        ).reshape(x.shape)
+        moe = lp["moe"]
+        tokens = h2.reshape(-1, h2.shape[-1])  # [T, D]
+        idx, weights = route_softmax_top_k(tokens, moe["router"], cfg.expert_top_k)
+        routed, sizes = moe_ffn_routed(
+            experts, tokens, idx, weights,
+            held=(0, moe["router"].shape[-1], moe["layer"]))
+        x = x + routed.reshape(x.shape)
     else:
         gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
         x = x + gated @ lp["w_down"]
-    return x, cache
+    return x, cache, sizes
 
 
 def _cached_attention(q, k_cache, v_cache, valid):
@@ -222,34 +257,41 @@ cached_attention, flat_pages = _cached_attention, _flat_pages
 gather_pages, write_rows = _gather_pages, _write_rows
 
 
-def _scan_layers_over_cache(x, layers, cfg: LlamaConfig, cos, sin, attend, cache):
+def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache):
     """``lax.scan`` of a paged decode step's layers with the pool as the
     loop's CARRY, k/v as ``_flat_pages`` lays them;
     ``attend(q, k, v, (k_flat, v_flat), first)`` as ``_layer`` asks,
     ``first = layer * N``: this layer's pages are ``[first, first + N)`` of
     the stack, so its writes and the tables it reads through are offset by
-    that. Returns (x, the pool in its own shape). A pool scanned over
+    that. Returns (x, the pool in its own shape, the layers' routing summed
+    into ``DECODE_COUNTERS``' [3] int32 or None for a dense configuration:
+    over all rows of the step, free slots' dummy rows among them, which is
+    what the step computed). A pool scanned over
     instead (an ``xs`` operand taken back as stacked ``ys``) is sliced a
     layer, copied for the kernel and stacked into a second buffer every
     step; carried, and donated by the caller's jit, the buffer that goes in
     is the one that comes out."""
     count, n_pages = cache["k"].shape[:2]
+    layers, experts = _scanned_layers(params)
 
     def layer_fn(carry, inputs):
         x, pool = carry
         lp, layer = inputs
-        return _layer(
+        x, pool, sizes = _layer(
             x, lp, cfg, cos, sin,
-            functools.partial(attend, first=layer * n_pages), pool,
-        ), None
+            functools.partial(attend, first=layer * n_pages), pool, experts,
+        )
+        return (x, pool), None if sizes is None else jnp.stack(
+            [jnp.sum(sizes > 0), jnp.sum(sizes), jnp.max(sizes)]).astype(jnp.int32)
 
-    (x, (k_flat, v_flat)), _ = jax.lax.scan(
+    (x, (k_flat, v_flat)), counters = jax.lax.scan(
         layer_fn,
         (x, (_flat_pages(cache["k"]), _flat_pages(cache["v"]))),
         (layers, jnp.arange(count, dtype=jnp.int32)),
     )
-    return x, {"k": k_flat.reshape(cache["k"].shape),
-               "v": v_flat.reshape(cache["v"].shape)}
+    cache = {"k": k_flat.reshape(cache["k"].shape),
+             "v": v_flat.reshape(cache["v"].shape)}
+    return x, cache, None if counters is None else jnp.sum(counters, axis=0)
 
 
 def prefill(
@@ -288,9 +330,10 @@ def prefill(
                         window=cfg.sliding_window or None)
         return att.swapaxes(1, 2), (k, v)  # the rows to cache, post-rope
 
+    layers, experts = _scanned_layers(params)
     x, (ks, vs) = jax.lax.scan(
-        lambda x, lp: _layer(x, lp, cfg, cos, sin, attend, None),
-        x, params["layers"],
+        lambda x, lp: _layer(x, lp, cfg, cos, sin, attend, None, experts)[:2],
+        x, layers,
     )
     # ks/vs: [L, B, Hkv, P, hd]. C >= P: slots [0, P) (pos % C == pos).
     # C < P (rolling window cache, prompt longer than the window): only
@@ -393,9 +436,10 @@ def decode_step(
         qf = q.reshape(k.shape[:2] + (-1, q.shape[-1]))
         return _cached_attention(qf, k_cache, v_cache, valid), (k_cache, v_cache)
 
+    layers, experts = _scanned_layers(params)
     x, (k_new, v_new) = jax.lax.scan(
-        lambda x, a: _layer(x, a[0], cfg, c, s, attend, a[1:]),
-        x, (params["layers"], cache["k"], cache["v"]),
+        lambda x, a: _layer(x, a[0], cfg, c, s, attend, a[1:], experts)[:2],
+        x, (layers, cache["k"], cache["v"]),
     )
     return _logits(x, params, cfg), {"k": k_new, "v": v_new}
 
@@ -409,7 +453,7 @@ def decode_step_paged(
     cfg: LlamaConfig,
     rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     kernel: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], Optional[jnp.ndarray]]:
     """One decode step over a BLOCK-PAGED cache with PER-ROW positions —
     the primitive the continuous-batching engine steps: the rows of one
     batch are slots holding unrelated requests at different depths.
@@ -458,6 +502,11 @@ def decode_step_paged(
     ``num_slots * max_len``. Its flash-style accumulation reorders float
     adds (per group of pages), so logits agree to float tolerance and
     greedy tokens agree exactly — the parity the serving tests pin.
+
+    Returns (logits [B, V] fp32, the updated cache, counters): for a
+    configuration with experts [3] int32 in the order of
+    ``DECODE_COUNTERS``, over all B rows of the step and all layers; None
+    for a dense one.
     """
     from ray_lightning_tpu.ops.paged_attention import (
         paged_decode_attention,
@@ -513,9 +562,9 @@ def decode_step_paged(
             )
         return att, (k_flat, v_flat)
 
-    x, cache = _scan_layers_over_cache(
-        x, params["layers"], cfg, cos[pos], sin[pos], attend, cache)
-    return _logits(x, params, cfg), cache
+    x, cache, counters = _scan_layers_over_cache(
+        x, params, cfg, cos[pos], sin[pos], attend, cache)
+    return _logits(x, params, cfg), cache, counters
 
 
 def decode_step_verify(
@@ -612,8 +661,8 @@ def decode_step_verify(
         )  # [B, Hkv, G, K, hd]
         return att.reshape(B, -1, K, hd).swapaxes(1, 2), (k_flat, v_flat)
 
-    x, cache = _scan_layers_over_cache(
-        x, params["layers"], cfg, cos[ridx], sin[ridx], attend, cache)
+    x, cache, _ = _scan_layers_over_cache(
+        x, params, cfg, cos[ridx], sin[ridx], attend, cache)
     return _logits(x, params, cfg), cache
 
 
@@ -624,7 +673,8 @@ class LlamaServing:
 
     - ``speculation``: whether it has a verify step; ``counters``: names of
       the int32 counters its paged decode step returns beside the logits
-      (none here);
+      (``DECODE_COUNTERS`` for a configuration with experts, none for a
+      dense one);
     - ``rope_table(max_len)``: one table for prefill and decode;
     - ``paged_block_leaves(block_size)``: the pool's device leaves, each
       (layers, shape of one block in one layer, dtype) and, where the model
@@ -644,10 +694,10 @@ class LlamaServing:
 
     name = "Llama family (models/llama.py)"
     speculation = True
-    counters = ()
 
     def __init__(self, cfg: LlamaConfig):
         self.cfg = cfg
+        self.counters = DECODE_COUNTERS if cfg.n_experts else ()
 
     def rope_table(self, max_len: int):
         cfg = self.cfg
@@ -680,9 +730,8 @@ class LlamaServing:
         return {"k": blocks(row["k"]), "v": blocks(row["v"])}
 
     def decode_paged(self, params, cache, token, pos, tables, table):
-        logits, cache = decode_step_paged(
+        return decode_step_paged(
             params, cache, token, pos, tables["full"], self.cfg, table)
-        return logits, cache, None
 
     def decode_verify(self, params, cache, tokens, pos, tables, table):
         return decode_step_verify(

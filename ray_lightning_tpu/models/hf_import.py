@@ -258,7 +258,8 @@ def import_hf_mixtral(
     Semantics notes:
     - Mixtral routes without expert capacity (token choice). The imported
       config sets ``capacity_factor`` to cover the worst case so training
-      matches; generation already routes losslessly.
+      matches; generation routes without capacity (only the routed pairs
+      are computed, none dropped: ``parallel/moe.py::moe_ffn_routed``).
     - ``sliding_window`` checkpoints map onto the native band kernels
       (cfg.sliding_window; ops/attention.py ``window=``), so sequences
       longer than the window import and run with HF-matching masks.

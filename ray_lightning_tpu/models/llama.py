@@ -375,8 +375,7 @@ def _on_each_shard(fn, mesh: Optional[Mesh], in_specs, out_spec):
 
 
 def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, attn_fn, reduce_fn=None,
-                   input_fn=None, return_kv: bool = False,
-                   moe_lossless: bool = False, moe_fn=None, norm_fn=None):
+                   input_fn=None, moe_fn=None, norm_fn=None):
     """One transformer block (pre-norm attention + gated MLP / MoE) shared
     by the scanned dense path and the pipeline stage path — the math must
     stay identical between them.
@@ -388,11 +387,6 @@ def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, attn_fn, reduce_fn=None,
     pattern, expressed once. ``input_fn`` (megatron's f operator) marks the
     normed activations entering the column-parallel matmuls; the manual-VJP
     1F1B schedule needs it to re-sum input cotangents over 'tp'.
-
-    ``return_kv=True`` additionally returns this layer's post-rope
-    (k, v) in cache layout [B, Hkv, S, hd] — the KV-cache prefill path
-    (models/generation.py) reuses the training math verbatim instead of
-    maintaining a drift-prone copy.
 
     ``norm_fn(x, weight)`` replaces ``rmsnorm`` with its per-shard form
     where the caller runs under a multi-device mesh
@@ -420,16 +414,13 @@ def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, attn_fn, reduce_fn=None,
     att = att.swapaxes(1, 2).reshape(B, S, nh * hd)
     x = x + red(att @ lp["wo"])
     if cfg.n_experts and "moe" in lp:
-        from ray_lightning_tpu.parallel.moe import moe_ffn, moe_ffn_lossless
+        from ray_lightning_tpu.parallel.moe import moe_ffn
 
         # NOT fin-wrapped: the moe impl wraps its own input over (ep, tp)
         # when it needs the f operator (vjp_safe) — a second wrap here
         # would double the input cotangent's tp psum under 1F1B
         h2 = norm(x, lp["mlp_norm"])
-        if moe_lossless:  # inference: no-drop routing, no dispatch tensors
-            moe_out = moe_ffn_lossless(lp["moe"], h2, top_k=cfg.expert_top_k)
-            aux = jnp.float32(0.0)
-        elif moe_fn is not None:
+        if moe_fn is not None:
             # pipeline stages inside shard_map pass an explicit impl
             # (moe_ffn_local_experts over the 'ep' axis — GSPMD cannot
             # partition the dispatch einsums for us there)
@@ -445,8 +436,6 @@ def _decoder_layer(x, lp, cfg: LlamaConfig, cos, sin, attn_fn, reduce_fn=None,
         gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
         x = x + red(gated @ lp["w_down"])
         aux = jnp.float32(0.0)
-    if return_kv:
-        return x, aux, (k, v)
     return x, aux
 
 

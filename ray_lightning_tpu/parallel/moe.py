@@ -5,15 +5,15 @@ combine einsums over an expert-sharded weight stack — XLA partitions the
 [tokens, experts, capacity] dispatch tensors into all-to-alls over the 'ep'
 axis (Switch-Transformer style). No scatter/gather, fully static shapes.
 
-Inference has two no-drop paths. :func:`moe_ffn_lossless` runs every
-expert on every token (E / k times the routed work: right for a handful of
-experts). :func:`moe_ffn_routed` computes only the routed (token, expert)
-pairs: the pairs sorted by expert, one grouped matmul a weight stack, no
-capacity, so no pair is ever dropped however uneven the routing. It takes
-the choice and the weights from its caller, so any router feeds it:
+Inference has one no-drop path. :func:`moe_ffn_routed` computes only the
+routed (token, expert) pairs: the pairs sorted by expert, one grouped matmul
+a weight stack, no capacity, so no pair is ever dropped however uneven the
+routing. It takes the choice and the weights from its caller, so any router
+feeds it: :func:`route_softmax_top_k` (softmax over all experts, the top k
+renormalised: the handful of large experts of the Llama family) and
 :func:`route_sigmoid_bias` (sigmoid scores, a selection bias that does not
-enter the weights, renormalised and scaled) is the one hundreds of small
-experts are published with.
+enter the weights, renormalised and scaled: the one hundreds of small
+experts are published with).
 
 The reference has no MoE (SURVEY §2c: EP absent); this is part of the
 framework's first-class parallelism surface.
@@ -56,42 +56,20 @@ def moe_param_specs(n_layers: Optional[int] = None) -> Dict[str, P]:
     }
 
 
-def moe_ffn_lossless(
-    params: Dict[str, Any],
-    x: jnp.ndarray,
-    top_k: int = 2,
-) -> jnp.ndarray:
-    """No-drop MoE evaluation for INFERENCE: every expert runs on every
-    token (a ``lax.scan`` over experts — E dense FFNs), combined with the
-    normalized top-k gate weights. Semantically identical to ``moe_ffn``
-    whenever its capacity does not bind, but with no [T, E, C] dispatch
-    tensors: memory O(T*F) and compute E/k x the routed path — the right
-    trade at generation shapes, where the dispatch one-hots are O(T^2*E)
-    once capacity must cover a worst-case expert load (lossless).
-    x: [B, S, D] -> out [B, S, D] (no aux loss: inference only).
-    """
-    b, s, d = x.shape
-    e = params["router"].shape[-1]
-    xt = x.reshape(b * s, d)
-
-    logits = (xt.astype(jnp.float32) @ params["router"]).astype(jnp.float32)
+def route_softmax_top_k(
+    xt: jnp.ndarray, router: jnp.ndarray, top_k: int
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Softmax routing as the Llama family's expert models are published
+    with (and as :func:`_route` trains them). xt: [T, D]; router: [D, E]
+    float32. The gates are the softmax over ALL experts of the float32
+    product; the chosen experts are their top k, ties to the lower index
+    (``lax.top_k``); the weights are the chosen gates over their sum.
+    Returns (idx [T, K] int32, weights [T, K] float32)."""
+    logits = (xt.astype(jnp.float32) @ router).astype(jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)  # [T, E]
     top_vals, top_idx = jax.lax.top_k(gates, top_k)
     top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
-    sel = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)  # [T, K, E]
-    w = (sel * top_vals[..., None]).sum(axis=1)  # [T, E]
-
-    def body(acc, expert):
-        wg, wu, wd, gate_col = expert  # [D,F], [D,F], [F,D], [T]
-        h = jax.nn.silu(xt @ wg) * (xt @ wu)
-        return acc + gate_col[:, None] * (h @ wd).astype(jnp.float32), None
-
-    acc0 = jnp.zeros((b * s, d), jnp.float32)
-    out, _ = jax.lax.scan(
-        body, acc0,
-        (params["w_gate"], params["w_up"], params["w_down"], w.T),
-    )
-    return out.reshape(b, s, d).astype(x.dtype)
+    return top_idx.astype(jnp.int32), top_vals
 
 
 def route_sigmoid_bias(
@@ -127,22 +105,46 @@ def route_sigmoid_bias(
 # rows of the sorted pairs a grid step of the grouped matmul takes. A decode
 # tick of 64 rows makes 512 pairs over 256 experts, two rows an expert, and
 # every step reads a whole [D, F] expert slab whatever rows it has: the step
-# is bound by that read, so the row tile only has to divide the pairs
+# is bound by that read, so the row tile only has to cover the pairs (fewer
+# than a tile of them, or a ragged last tile, are padded up to it)
 _GMM_ROWS = 128
 # the most one weight tile of a step may take of the chip's fast memory (it
-# is double-buffered): a [K, N] slab over this is cut along N
+# is double-buffered): a [K, N] slab over this is cut along N, and along K
+# too where N alone would leave its rows short
 _GMM_SLAB_BYTES = 4 * 1024 * 1024
+# the fewest columns a tile that keeps K whole may be left with: each of its
+# K rows is one run of the memory's, and runs under a KiB are read poorly
+_GMM_MIN_COLS = 512
+# the columns of a tile whose K is cut (the kernel accumulates over K tiles)
+_GMM_LONG_COLS = 2048
+
+
+def _lane_divisors(n: int) -> Tuple[int, ...]:
+    """The multiples of 128 (whole lanes) that divide ``n``, ascending."""
+    return tuple(c for c in range(128, n + 1, 128) if n % c == 0)
 
 
 def _gmm_tiles(k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
-    """(rows, K, N) of one grid step of the grouped matmul: the whole
-    ``[K, N]`` slab of an expert where it fits ``_GMM_SLAB_BYTES`` (3 MiB at
-    the latent-attention cell's 2048 x 768), else N halved until it does
-    while the halves stay whole lanes (4096 x 4096 bfloat16 -> 4096 x 512)."""
-    tn = n
-    while k * tn * itemsize > _GMM_SLAB_BYTES and tn % 256 == 0:
-        tn //= 2
-    return _GMM_ROWS, k, tn
+    """(rows, K, N) of one grid step of the grouped matmul, by the slab's
+    shape alone. The whole ``[K, N]`` slab of an expert where it fits
+    ``_GMM_SLAB_BYTES`` (3 MiB at the latent-attention cell's 2048 x 768 and
+    768 x 2048). Else K whole and the most columns, a whole-lane divisor of
+    N, that fit (4096 x 4096 bfloat16 -> 4096 x 512; 4096 x 14336 -> 4096 x
+    512). Where that leaves under ``_GMM_MIN_COLS`` (14336 x 4096: 128
+    columns, 256-byte runs) K is cut as well: up to ``_GMM_LONG_COLS``
+    columns, and the most of K, a whole-lane divisor again, that fits beside
+    them (-> 1024 x 2048). A width no multiple of 128 divides is left whole."""
+    def fits(tk, tn):
+        return tk * tn * itemsize <= _GMM_SLAB_BYTES
+
+    if fits(k, n):
+        return _GMM_ROWS, k, n
+    cols = _lane_divisors(n)
+    whole_k = max((c for c in cols if fits(k, c)), default=0)
+    if whole_k >= _GMM_MIN_COLS or not cols:
+        return _GMM_ROWS, k, whole_k or n
+    tn = max(c for c in cols if c <= _GMM_LONG_COLS)
+    return _GMM_ROWS, max((r for r in _lane_divisors(k) if fits(r, tn)), default=k), tn
 
 
 def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
@@ -151,11 +153,14 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
     """``xs[start_g : start_g + sizes[g]] @ w[g]`` for every group g, the
     rows of ``xs`` [M, K] lying group after group; w: [G, K, N]; returns
     float32 [M, N]. An empty group costs nothing: its weights are not read.
+    Rows behind the last group belong to none and come back unwritten.
 
     On the TPU (``kernel`` None: where Pallas is native) this is JAX's
     Pallas grouped matmul (``pallas.ops.tpu.megablox``), which walks the
-    (group, row tile) pairs that hold rows and takes a whole ``[K, N]``
-    slab a step; elsewhere ``lax.ragged_dot``, which differentiates.
+    (group, row tile) pairs that hold rows and takes a ``[K, N]`` tile
+    (:func:`_gmm_tiles`) a step, over whole row tiles: M is padded up to
+    one with rows of no group (64 pairs of a 32-row decode tick with two
+    experts a token). Elsewhere ``lax.ragged_dot``, which differentiates.
     ``kernel=True`` off the TPU interprets the kernel (the tests)."""
     on_tpu = jax.devices()[0].platform == "tpu"
     if kernel is None:
@@ -164,14 +169,18 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
         interpret = not on_tpu
     m, k = xs.shape
     n = w.shape[2]
-    if not kernel or m % _GMM_ROWS:
+    if not kernel:
         return jax.lax.ragged_dot(
             xs, w, sizes.astype(jnp.int32),
             preferred_element_type=jnp.float32)
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    return gmm(xs, w, sizes.astype(jnp.int32), jnp.float32,
-               _gmm_tiles(k, n, w.dtype.itemsize), interpret=interpret)
+    ragged = -m % _GMM_ROWS
+    if ragged:
+        xs = jnp.pad(xs, ((0, ragged), (0, 0)))
+    out = gmm(xs, w, sizes.astype(jnp.int32), jnp.float32,
+              _gmm_tiles(k, n, w.dtype.itemsize), interpret=interpret)
+    return out[:m] if ragged else out
 
 
 def held_groups(idx: jnp.ndarray, first: int, count: int, layer=0) -> jnp.ndarray:
@@ -306,7 +315,7 @@ def moe_ffn(
     capacity_factor formula (exact integer bound — the float
     capacity_factor math can round below an intended bound). Note:
     generation does NOT use this; it routes through
-    :func:`moe_ffn_lossless`, which needs no dispatch tensors at all.
+    :func:`moe_ffn_routed`, which has no capacity and no dispatch tensors.
     """
     b, s, d = x.shape
     e = params["router"].shape[-1]

@@ -212,7 +212,7 @@ def test_ragged_decode_parity_with_prefill():
             [int(rows[b][0, min(t, lens[b] - 1)]) for b in range(2)], jnp.int32
         )
         pos = jnp.asarray([min(t, lens[b] - 1) for b in range(2)], jnp.int32)
-        logits, cache = decode_step_paged(params, cache, tok, pos, tables, cfg)
+        logits, cache, _ = decode_step_paged(params, cache, tok, pos, tables, cfg)
         for b in range(2):
             if t == lens[b] - 1:
                 got[b] = np.asarray(logits[b], np.float32)
@@ -270,7 +270,7 @@ def test_one_layer_body_matches_forward_logits(kind, step):
         at = lambda t: jnp.full((B,), t, jnp.int32)
         if step == "paged-gather":
             run = jax.jit(lambda c, tok, t: decode_step_paged(
-                params, c, tok[:, 0], at(t), tables, cfg, kernel=False))
+                params, c, tok[:, 0], at(t), tables, cfg, kernel=False)[:2])
         else:
             run = jax.jit(lambda c, tok, t: decode_step_verify(
                 params, c, tok, at(t), tables, cfg))
@@ -328,8 +328,9 @@ def test_module_generate_requires_params():
 
 
 def test_moe_generate_runs_and_respects_prompt():
-    """The flagship MoE variant decodes through lossless routing
-    (moe_ffn_lossless) (VERDICT r2 missing #4 — this used to raise)."""
+    """The flagship MoE variant decodes through routing without capacity
+    (``moe_ffn_routed``: the routed pairs alone, none dropped) (VERDICT r2
+    missing #4 — this used to raise)."""
     cfg = dataclasses.replace(LlamaConfig.tiny_moe(), dtype=jnp.float32)
     params = init_params(jax.random.key(0), cfg)
     B, P, NEW = 2, 4, 5
@@ -343,10 +344,10 @@ def test_moe_generate_runs_and_respects_prompt():
 
 
 def test_moe_decode_matches_forward_when_capacity_unbinding():
-    """Exactness for MoE: decode uses lossless routing (capacity = B), so
-    when training's capacity does not bind either (capacity_factor high
-    enough that no token drops), stepwise decode logits must equal the
-    training forward's at every position."""
+    """Exactness for MoE: decode routes without capacity (every routed
+    pair is computed), so when training's capacity does not bind either
+    (capacity_factor high enough that no token drops), stepwise decode
+    logits must equal the training forward's at every position."""
     cfg = dataclasses.replace(
         LlamaConfig.tiny_moe(), dtype=jnp.float32,
         capacity_factor=4.0,  # capacity = int(4*2*T/4) = 2T: never binds
@@ -368,9 +369,9 @@ def test_moe_decode_matches_forward_when_capacity_unbinding():
 def test_prefill_matches_stepwise_cache(preset):
     """Batched prefill must write the exact (k, v) the stepwise decode path
     writes — the cache contents are the contract between the two. MoE
-    configs must match too: generation routes losslessly on BOTH paths
-    (training's default capacity_factor would drop tokens in prefill that
-    stepwise decode keeps)."""
+    configs must match too: generation routes without capacity on BOTH
+    paths (training's default capacity_factor would drop tokens in prefill
+    that stepwise decode keeps)."""
     from ray_lightning_tpu.models.generation import prefill
 
     if preset == "moe":

@@ -157,7 +157,7 @@ def test_verify_matches_sequential_decode_bitwise(model):
     seq_logits = []
     chain = [toks]
     for i in range(K):
-        lg, cache_seq = decode_step_paged(
+        lg, cache_seq, _ = decode_step_paged(
             params, cache_seq, chain[-1], pos + i, tables, cfg, kernel=False
         )
         seq_logits.append(lg)
@@ -191,7 +191,7 @@ def test_verify_zero_accept_position_zero_is_exact(model):
     cache, tables = _paged_rows(params, cfg, prompts, max_len)
     toks = jnp.asarray([p[-1] for p in prompts], jnp.int32)
     pos = jnp.asarray([P - 1] * B, jnp.int32)
-    ref_logits, _ = decode_step_paged(
+    ref_logits, _, _ = decode_step_paged(
         params, cache, toks, pos, tables, cfg, kernel=False)
 
     garbage = jnp.concatenate(
@@ -531,10 +531,10 @@ def test_decode_step_paged_kernel_vs_lax_token_parity(model):
     pos = jnp.asarray([5, 5], jnp.int32)
     tables = jnp.asarray(pool.kinds["full"].block_tables)
 
-    lg_lax, _ = decode_step_paged(
+    lg_lax, _, _ = decode_step_paged(
         params, paged_cache, toks, pos, tables, cfg, kernel=False
     )
-    lg_ker, _ = decode_step_paged(
+    lg_ker, _, _ = decode_step_paged(
         params, paged_cache, toks, pos, tables, cfg, kernel=True
     )
     np.testing.assert_array_equal(

@@ -515,6 +515,30 @@ def test_routed_expert_matmuls_compile_at_the_cells_shapes(one_chip, rows):
     assert n == 3
 
 
+@pytest.mark.parametrize("rows", [64, 512, 4096], ids=["decode-tick", "first-rung", "last-rung"])
+def test_routed_expert_matmuls_compile_at_the_batch_cells_shapes(one_chip, rows):
+    """Mixtral's widths, 4 layers x 8 experts in one stack: a decode tick's
+    32 x 2 pairs (under the row tile: padded up to it), the first prefill
+    rung's 256 x 2 and the last's 2048 x 2. A whole slab is 112 MiB, so
+    ``w_gate`` / ``w_up`` go by 4096 x 512 and ``w_down``, whose whole K
+    would leave 128 columns, by 1024 x 2048: three grouped matmuls."""
+    from ray_lightning_tpu.parallel.moe import _gmm_tiles, grouped_matmul
+
+    assert _gmm_tiles(4096, 14336, 2) == (128, 4096, 512)
+    assert _gmm_tiles(14336, 4096, 2) == (128, 1024, 2048)
+
+    def call(xs, w_gate, w_up, w_down, sizes):
+        mm = lambda a, w: grouped_matmul(a, w, sizes, kernel=True, interpret=False)
+        h = (jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
+        return mm(h, w_down)
+
+    n = _custom_calls(
+        call, ((rows, 4096), jnp.bfloat16), ((32, 4096, 14336), jnp.bfloat16),
+        ((32, 4096, 14336), jnp.bfloat16), ((32, 14336, 4096), jnp.bfloat16),
+        ((32,), jnp.int32), sharding=one_chip)
+    assert n == 3
+
+
 def test_flash_attention_with_192_wide_keys_and_128_wide_values_compiles(one_chip):
     fn = lambda q, k, v: attention(
         q, k, v, causal=True, sm_scale=192 ** -0.5, impl="flash", interpret=False)
@@ -531,15 +555,28 @@ def test_flash_attention_with_192_wide_keys_and_128_wide_values_compiles(one_chi
 _COPIES = ("copy", "copy-start", "copy-done", "dynamic-slice", "dynamic-update-slice")
 
 
+def _copy_instructions(text):
+    """(name, result, op) of a compiled program's instructions that copy,
+    slice or stack. A fusion counts by its name, which XLA makes of what it
+    fused (``copy_dynamic-update-slice_fusion``)."""
+    import re
+
+    for m in re.finditer(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(", text, re.M
+    ):
+        name, result, op = m.groups()
+        fused = op == "fusion" and any(
+            w in name.replace("_", "-") for w in ("copy", "dynamic-slice", "dynamic-update-slice"))
+        if op in _COPIES or fused:
+            yield name, result, op
+
+
 def _pool_sized_copies(text, leaves):
     """Instructions of a compiled program that copy, slice or stack
     something of a pool leaf's size: ``leaves`` are ``(shape, dtype)`` of the
     leaves ``[L, N, *block]``; looked for are results shaped like a leaf, a
     leaf flattened over layers (and over heads, as the decode steps carry
-    it), or one layer of either. A fusion counts by its name, which XLA
-    makes of what it fused (``copy_dynamic-update-slice_fusion``)."""
-    import re
-
+    it), or one layer of either."""
     prefix = {"bfloat16": "bf16", "float32": "f32"}
     sized = set()
     for shape, dtype in leaves:
@@ -550,18 +587,35 @@ def _pool_sized_copies(text, leaves):
                        (pages * block[0],) + block[1:]]
         sized |= {f"{prefix[jnp.dtype(dtype).name]}[{','.join(map(str, s))}]"
                   for s in shapes}
-    found = []
-    for m in re.finditer(
-        r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][a-z\-]*)\(", text, re.M
-    ):
-        name, result, op = m.groups()
-        if not any(s in result for s in sized):
-            continue
-        fused = op == "fusion" and any(
-            w in name.replace("_", "-") for w in ("copy", "dynamic-slice", "dynamic-update-slice"))
-        if op in _COPIES or fused:
-            found.append(f"{op} {name} {result}")
-    return found
+    return [f"{op} {name} {result}" for name, result, op in _copy_instructions(text)
+            if any(s in result for s in sized)]
+
+
+def _copies_of_at_least(text, nbytes):
+    """Instructions of a compiled program that copy, slice or stack a result
+    of ``nbytes`` or more."""
+    import re
+
+    width = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4}
+    return [
+        f"{op} {name} {result}" for name, result, op in _copy_instructions(text)
+        if any(width.get(dtype, 1) * int(np.prod([int(d) for d in dims.split(",")])) >= nbytes
+               for dtype, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]+)\]", result))]
+
+
+def test_the_slab_check_sees_an_experts_matrix_sliced_out_of_its_stack():
+    """``_copies_of_at_least`` on the instruction the batch cell's programs
+    held while the experts were scanned over (the trace's
+    ``dynamic-slice_bitcast_fusion.8``): seen; a matmul fusion with a result
+    of the same size and a slice of a smaller one are not."""
+    text = (
+        "  %dynamic-slice_bitcast_fusion.8 = bf16[4096,14336]{1,0:T(8,128)(2,1)} "
+        "fusion(%p.1, %p.2), kind=kLoop\n"
+        "  %fusion.127 = bf16[4096,14336]{1,0:T(8,128)(2,1)} fusion(%p.3), kind=kOutput\n"
+        "  ROOT %dynamic-slice.4 = bf16[4096,4096]{1,0} dynamic-slice(%p.4, %c), "
+        "dynamic_slice_sizes={1,4096,4096}\n")
+    found = _copies_of_at_least(text, 4096 * 14336 * 2)
+    assert len(found) == 1 and "dynamic-slice_bitcast_fusion.8" in found[0]
 
 
 def test_an_undonated_scatter_copies_its_leaf_and_the_check_sees_it(one_chip):
@@ -636,18 +690,21 @@ def test_serve_programs_update_the_pool_in_place_at_the_cells_shapes(
     and is copied whole to the kernel's, every layer. Prefill's temporaries
     are the prompt's activations, which do not grow with the pool; they are
     held under the largest leaf (a copy of one would be at least that),
-    where a leaf is the larger: the batch cell's is 0.17 GB beside 2.8 GB of
-    expert temporaries at 2048 positions."""
+    where a leaf is the larger: not in the batch cell, whose leaf is
+    0.17 GB beside the 14336-wide activations of 2 x 2048 routed pairs.
+    There what is held out of the temporaries is an expert's matrix (4096 x
+    14336 bf16, 117 MB; scanned over, 2.8 GB of them were in every
+    program): nothing of that size or more is copied, sliced or stacked."""
     cell, compiled, leaves, pool_bytes = cell_programs
     exe = compiled[program]
     assert _pool_sized_copies(exe.as_text(), leaves) == []
     mem = exe.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     if cell == "serve-moe-batch":
-        return  # a layer's expert matrices are among both programs' temporaries
+        assert _copies_of_at_least(exe.as_text(), 4096 * 14336 * 2) == []
     if program == "serve_decode":
         assert mem.temp_size_in_bytes < pool_bytes / 10
-    else:
+    elif cell != "serve-moe-batch":
         largest = max(int(np.prod(s)) * jnp.dtype(d).itemsize for s, d in leaves)
         assert mem.temp_size_in_bytes < largest
 
@@ -658,24 +715,36 @@ def test_every_rung_of_prefill_is_the_labelled_module_with_its_kernels_inside(
 ):
     """Whatever the rung, the program is the module ``jit_serve_prefill`` (one
     name in a device trace's ``XLA Modules``) and holds the kernels prefill
-    holds today: flash attention, and in the reason cell the grouped matmul
-    of the routed experts."""
+    holds today: flash attention, and in the cells with experts the grouped
+    matmul of the routed pairs."""
     cell, compiled, _, _ = cell_programs
     text = compiled[program].as_text()
     assert text.startswith("HloModule jit_serve_prefill")
     kernels = set(_kernel_instructions(text))
     assert "flash_fwd" in kernels
-    assert ("gmm" in kernels) == (cell == "serve-mla-moe-reason")
+    assert ("gmm" in kernels) == (cell != "serve-dense-chat")
+
+
+def test_the_decode_program_holds_the_grouped_matmul_where_there_are_experts(
+    cell_programs
+):
+    """``jit_serve_decode`` of the cells with experts computes the routed
+    pairs by the kernel (the batch cell's 64 pairs are under its row tile
+    and are padded up to it: ``lax.ragged_dot`` is for where Pallas is not
+    native); the chat cell's has none."""
+    cell, compiled, _, _ = cell_programs
+    text = compiled["serve_decode"].as_text()
+    assert text.startswith("HloModule jit_serve_decode")
+    assert ("gmm" in _kernel_instructions(text)) == (cell != "serve-dense-chat")
+    assert ("ragged-dot" in text) is False
 
 
 def test_prefill_temporaries_shrink_with_the_rung(cell_programs):
     """What a shorter rung saves besides time: the prompt's activations. Each
     rung's temporaries are under those of the next, and the first rung's
-    under a third of the last's; but for the batch cell, where 2.8 GB of
-    every rung's 2.8-3.0 are one layer's expert matrices sliced out of their
-    stack (``moe_ffn_lossless``), whatever the prompt's length."""
+    under a third of the last's, in the batch cell too since no expert's
+    matrices are among them."""
     cell, compiled, _, _ = cell_programs
     temps = [compiled[p].memory_analysis().temp_size_in_bytes for p in PREFILLS]
     assert temps == sorted(temps) and len(set(temps)) == len(temps)
-    if cell != "serve-moe-batch":
-        assert temps[0] < temps[-1] / 3
+    assert temps[0] < temps[-1] / 3
