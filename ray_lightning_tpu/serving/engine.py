@@ -86,7 +86,9 @@ from ray_lightning_tpu.serving import migration as _migration
 from ray_lightning_tpu.serving.paged_kv import (
     TRASH_BLOCK,
     PagedKVPool,
-    stated_leaves,
+    STATE,
+    stated_windows,
+    states_kind,
 )
 from ray_lightning_tpu.serving.resilience import RequestShed, ShedPolicy
 from ray_lightning_tpu.serving.scheduler import (
@@ -512,20 +514,46 @@ class InferenceEngine:
         self._kv_window = max(k.window for k in self.pool.kinds.values())
         if self._kv_window:
             self.stats.update(kv_positions_full=0, kv_positions_window=0)
+        # a pool with a state kind: the positions a decode tick's rows hold
+        # (pos + 1 each: what a layer that reads every position would read,
+        # beside what the model's own counters say its layers chose), and
+        # the state's bytes a decode tick reads and writes, every slot's
+        self._state_bytes_per_tick = (
+            2 * self.pool.num_slots * self.pool.state_bytes_per_slot)
+        if self._state_bytes_per_tick:
+            self.stats.update(kv_positions_live=0, state_bytes_touched=0)
         self._build_compiled()
 
     def _refuse_unserved(self, ecfg: EngineConfig) -> None:
         """Settings this model has no code for are refused here, by name,
         not somewhere inside a tick."""
         model = self._model
+        if states_kind(model, ecfg.resolved_block_size()):
+            if ecfg.resolved_speculate_k() > 0:
+                raise ValueError(
+                    f"speculate_k={ecfg.resolved_speculate_k()}: the "
+                    f"{model.name} keeps leaves of a state kind, and a "
+                    "proposal that is not accepted cannot be taken back out "
+                    "of a state (speculate_k=0 only)"
+                )
+            if ecfg.prefix_cache:
+                raise ValueError(
+                    f"prefix_cache=True: the {model.name} keeps leaves of a "
+                    "state kind, and a block shared by prefix says nothing "
+                    "of the state behind it (prefix_cache=False only)"
+                )
+            if ecfg.role != "both":
+                raise ValueError(
+                    f"role={ecfg.role!r}: KV migration ships K and V blocks, "
+                    f"and the {model.name} keeps leaves of a state kind, "
+                    "which no shipment carries"
+                )
         if ecfg.resolved_speculate_k() > 0 and not model.speculation:
             raise ValueError(
                 f"speculate_k={ecfg.resolved_speculate_k()}: the "
                 f"{model.name} has no verify step (speculate_k=0 only)"
             )
-        windows = sorted(
-            {w for *_, w in stated_leaves(model, 1).values() if w}
-        )
+        windows = stated_windows(model, ecfg.resolved_block_size())
         if windows and ecfg.prefix_cache:
             raise ValueError(
                 f"prefix_cache=True: the {model.name} keeps leaves of a "
@@ -618,18 +646,26 @@ class InferenceEngine:
             # write_table — shared-prefix entries point at the trash
             # block, so a cached prefix is written exactly once (by
             # the request that registered it), never re-written per hit
+            # over a state kind the table names [the slot, the prompt's
+            # length]: a padded position must not reach the state
+            state_at = write_table.get(STATE)
+            known = {} if state_at is None else {"length": state_at[1]}
             blocks = model.prefill_blocks(
                 params, prompt_row, self._blocks_of(prompt_row.shape[1]),
-                bs, table
+                bs, table, **known
             )
+
             # a write table a kind of leaf (a window kind's names the trash
-            # block for everything before the window's tail)
-            return {
-                name: leaf.at[:, write_table[self.pool.leaf_kind[name]]].set(
-                    blocks[name].astype(leaf.dtype)
-                )
-                for name, leaf in cache.items()
-            }
+            # block for everything before the window's tail); a state leaf
+            # is written whole at its slot, and nowhere if that is no slot
+            def written(name, leaf):
+                new = blocks[name].astype(leaf.dtype)
+                kind = self.pool.leaf_kind[name]
+                if kind == STATE:
+                    return leaf.at[:, state_at[0]].set(new, mode="drop")
+                return leaf.at[:, write_table[kind]].set(new)
+
+            return {name: written(name, leaf) for name, leaf in cache.items()}
 
         def decode_paged(params, cache, token, pos, tables, key):
             logits, cache, counters = model.decode_paged(
@@ -725,7 +761,10 @@ class InferenceEngine:
         """Prompt write tables of ``rung`` positions that write nothing
         (every entry the trash block)."""
         table = np.full((self._blocks_of(rung),), TRASH_BLOCK, np.int32)
-        return {kind: table for kind in self.pool.kinds}
+        tables = {kind: table for kind in self.pool.kinds}
+        if self.pool.state_leaves:  # no slot: the state is written nowhere
+            tables[STATE] = np.array([self.pool.num_slots, rung], np.int32)
+        return tables
 
     def warmup(self) -> Dict[str, int]:
         """Resolve (load from the compile cache, or compile and persist)
@@ -1109,6 +1148,11 @@ class InferenceEngine:
                     self.stats["kv_positions_full"] += int(live.sum())
                     self.stats["kv_positions_window"] += int(
                         np.minimum(live, self._kv_window).sum())
+                if self._state_bytes_per_tick:
+                    self.stats["kv_positions_live"] += int(
+                        pos[[s.index for s in decode_slots]].sum()) + rows
+                    self.stats["state_bytes_touched"] += (
+                        self._state_bytes_per_tick)
                 inputs = (
                     jnp.asarray(token), jnp.asarray(pos),
                     self._on_device(block_tables),

@@ -49,6 +49,21 @@ as before. A pool of one kind has the one form too: ``kinds`` is ``{"full":
 ``block_tables`` and ``prompt_write_table()`` hand out that one table not by
 kind, for a pool of one kind only.
 
+A third kind holds STATE: a leaf whose fourth entry is ``"state"`` is one
+fixed-size array a slot a layer (``[layers, num_slots, *shape]``: a linear-
+attention layer's ``[H, hd, hd]`` float32 state) that does not grow with the
+request. It takes no blocks and has no allocator and no table: row ``i`` is
+slot ``i``'s. ``acquire`` zeroes the slot's rows, prefill writes them once
+(its write tables carry, under ``"state"``, the slot and the prompt's
+length), every decode tick updates them in place, and they are given up with
+the slot: whoever held the slot before, by whatever end (finished, expired,
+failed), the next request starts from zero. ``stats()`` reports
+``state.layers``, ``state.bytes_per_slot`` and ``state.slots_used``. Refused
+over a state kind, by name, when the engine is built: prefix sharing (a
+shared block says nothing of the state behind it), speculation, KV migration.
+A leaf of the full kind may state any block shape: pooled keys, one a 16
+positions, are a leaf of 4 rows a block of 64 under the same tables.
+
 Prefix sharing (the system-prompt amortization):
 
 - a prompt's FULL blocks are identified by a rolling hash chain
@@ -102,6 +117,8 @@ __all__ = [
 # physical block 0: write-redirect target for shared-prefix prefill slots
 # and free-slot dummy decode writes; never allocated, never attendable
 TRASH_BLOCK = 0
+# a leaf's fourth entry where it holds state a slot and no blocks
+STATE = "state"
 
 
 @dataclass
@@ -617,13 +634,30 @@ class BlockAllocator:
 
 def stated_leaves(model, block_size: int) -> Dict[str, tuple]:
     """A model's ``paged_block_leaves`` with every leaf's kind written out:
-    leaf -> (layers, block shape, dtype, window; 0 = the full kind). A model
+    leaf -> (layers, block shape, dtype, window; 0 = the full kind,
+    ``STATE`` = the state kind, whose "block" is one slot's state). A model
     that states three entries a leaf states the full kind."""
+    def kind(rest):
+        if not rest:
+            return 0
+        return STATE if rest[0] == STATE else int(rest[0])
+
     return {
-        name: (layers, tuple(block), dtype, int(rest[0]) if rest else 0)
+        name: (layers, tuple(block), dtype, kind(rest))
         for name, (layers, block, dtype, *rest) in
         model.paged_block_leaves(block_size).items()
     }
+
+
+def stated_windows(model, block_size: int) -> List[int]:
+    """The widths of a model's window kinds of leaf."""
+    return sorted({w for *_, w in stated_leaves(model, block_size).values()
+                   if w != STATE and w})
+
+
+def states_kind(model, block_size: int) -> bool:
+    """Whether a model keeps a leaf of the state kind."""
+    return any(w == STATE for *_, w in stated_leaves(model, block_size).values())
 
 
 @dataclass
@@ -694,7 +728,17 @@ class PagedKVPool:
         self.max_blocks = self.max_len // self.block_size
         model = cfg.serving()
         stated = stated_leaves(model, self.block_size)
-        widths = sorted({w for *_, w in stated.values() if w})
+        # the state kind's leaves: one array a slot a layer, no blocks
+        self.state_leaves: List[str] = [
+            k for k, v in stated.items() if v[3] == STATE]
+        if self.state_leaves and prefix_cache:
+            raise ValueError(
+                "prefix sharing over a state kind of leaf: a block shared by "
+                "prefix says nothing of the state behind it "
+                "(prefix_cache=False only)"
+            )
+        paged = {k: v for k, v in stated.items() if v[3] != STATE}
+        widths = sorted({w for *_, w in paged.values() if w})
         if len(widths) > 1:
             raise ValueError(
                 f"window kinds of {widths} positions in one model: the pool "
@@ -714,7 +758,7 @@ class PagedKVPool:
         # full kind, the window and one block for a window kind. The split
         # follows from the model's kinds and max_len, not from a setting.
         self.kinds: Dict[str, _Kind] = {}
-        for window in sorted({w for *_, w in stated.values()}):
+        for window in sorted({w for *_, w in paged.values()}):
             kind = "window" if window else "full"
             most = self.max_blocks
             if window:
@@ -723,10 +767,10 @@ class PagedKVPool:
             n = worst if num_blocks is None else int(num_blocks)
             if window:
                 n = min(n, worst)
-            names = [k for k, v in stated.items() if v[3] == window]
+            names = [k for k, v in paged.items() if v[3] == window]
             self.kinds[kind] = _Kind(
                 name=kind, window=window, leaves=names,
-                layers=stated[names[0]][0],
+                layers=paged[names[0]][0],
                 allocator=BlockAllocator(
                     n, self.block_size, prefix_cache=prefix_cache,
                     window=window),
@@ -739,12 +783,19 @@ class PagedKVPool:
             name: kind.name for kind in self.kinds.values()
             for name in kind.leaves
         }
+        self.leaf_kind.update(dict.fromkeys(self.state_leaves, STATE))
         self.cache = {
             name: jnp.zeros(
-                (layers, self.kinds[self.leaf_kind[name]].allocator.num_blocks)
+                (layers, self.num_slots if kind == STATE else
+                 self.kinds[self.leaf_kind[name]].allocator.num_blocks)
                 + block, dtype)
-            for name, (layers, block, dtype, _) in stated.items()
+            for name, (layers, block, dtype, kind) in stated.items()
         }
+        self.state_layers = sum(stated[k][0] for k in self.state_leaves)
+        self.state_bytes_per_slot = sum(
+            int(self.cache[k].nbytes) // self.num_slots
+            for k in self.state_leaves)
+        self._zero_fn = None
         self.bytes_per_position = int(model.cache_bytes_per_position())
         self.slots: List[Slot] = [Slot(i) for i in range(self.num_slots)]
         self._free: List[int] = list(range(self.num_slots - 1, -1, -1))
@@ -815,11 +866,27 @@ class PagedKVPool:
             row[:] = TRASH_BLOCK
             row[alloc.first: alloc.first + len(alloc.blocks)] = alloc.blocks
             kind.allocs[slot.index] = alloc
+        self._zero_state(slot.index)
         self.admitted_total += 1
         self.tenancies[slot.index].append(request_id)
         self.highwater = max(self.highwater, self.occupancy)
         self._publish_gauges()
         return slot
+
+    def _zero_state(self, index: int) -> None:
+        """Zero slot ``index``'s rows of every state leaf, in place (the
+        leaf is donated to the write and rebound, as ``cache`` is by every
+        engine program). Called by the thread that dispatches those programs
+        (the scheduler's tick), so it is ordered among them."""
+        if not self.state_leaves:
+            return
+        if self._zero_fn is None:
+            import jax
+
+            self._zero_fn = jax.jit(
+                lambda leaf, i: leaf.at[:, i].set(0), donate_argnums=(0,))
+        for name in self.state_leaves:
+            self.cache[name] = self._zero_fn(self.cache[name], np.int32(index))
 
     def release(self, index: int) -> Slot:
         slot = self.slots[index]
@@ -845,7 +912,9 @@ class PagedKVPool:
         blocks (already written once, immutable while referenced), for
         padding blocks past this prompt's real length and, in a window
         kind, for the blocks before the window's tail, which were never
-        allocated. ``{kind: table}``."""
+        allocated. ``{kind: table}``; where the pool holds state, ``"state"``
+        names where prefill writes it and what of the rung is real: ``[the
+        slot, the prompt's length]``."""
         slot = self.slots[slot_index]
         own = (slot.prompt_len - 1) // self.block_size + 1
         tables = {}
@@ -856,6 +925,8 @@ class PagedKVPool:
             if hi > lo:
                 table[lo:hi] = alloc.blocks[lo - alloc.first: hi - alloc.first]
             tables[kind.name] = table
+        if self.state_leaves:
+            tables[STATE] = np.array([slot_index, slot.prompt_len], np.int32)
         return tables
 
     def program_tables(self):
@@ -961,6 +1032,10 @@ class PagedKVPool:
                         f"{prefix}window": kind.window})
             out.update({f"{prefix}{k}": v
                         for k, v in kind.allocator.stats().items()})
+        if self.state_leaves:
+            out.update({"state.layers": self.state_layers,
+                        "state.bytes_per_slot": self.state_bytes_per_slot,
+                        "state.slots_used": self.occupancy})
         return out
 
     def _publish_gauges(self) -> None:

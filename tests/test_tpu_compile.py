@@ -639,39 +639,57 @@ SERVE_CELLS = ["serve-dense-chat", "serve-moe-batch", "serve-mla-moe-reason"]
 PREFILLS = [f"serve_prefill@{rung}" for rung in prefill_rungs(2048, 16)]
 
 
+def _cell_engine(name, mp):
+    """The paged engine of a serve cell: its configuration's widths, its
+    engine settings, parameters as shapes, the kernels forced on."""
+    from benchmarks import loader
+    from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
+
+    cell = loader.Manifest().cell(name)
+    settings = cell.settings["engine"]
+    program = cell.family.program
+    cfg = program.model_config(cell.config, max_seq=settings["max_len"], remat=False)
+    params = jax.eval_shape(lambda: program.engine_params(cell.config, 1))
+    mp.setenv("RLT_PAGED_KERNEL", "1")
+    return InferenceEngine(params, cfg, EngineConfig(**settings))
+
+
+def _cell_specs(engine, one_chip):
+    """(program, fn, argument shapes on the described chip) of an engine's
+    programs, prefill under ``serve_prefill@<rung>``."""
+    for name, fn, args in engine._program_specs():
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args)
+        if name == "serve_prefill":
+            name = f"{name}@{args[2].shape[1]}"
+        yield name, fn, shapes
+
+
+def _compile_cell(name, topo, one_chip):
+    """A serve cell's programs compiled for the chip, prefill once a rung:
+    ({program: compiled}, the pool's leaves, the pool's bytes). The kernels
+    are forced on, as on the chip, and asked to compile rather than
+    interpret."""
+    mp = pytest.MonkeyPatch()
+    try:
+        engine = _cell_engine(name, mp)
+        leaves = [(a.shape, a.dtype) for a in engine.pool.cache.values()]
+        pool_bytes = sum(int(a.nbytes) for a in engine.pool.cache.values())
+        mp.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        compiled = {program: fn.lower(*shapes).compile()
+                    for program, fn, shapes in _cell_specs(engine, one_chip)}
+    finally:
+        mp.undo()
+    return compiled, leaves, pool_bytes
+
+
 @pytest.fixture(scope="module", params=SERVE_CELLS)
 def cell_programs(request, topo, one_chip):
     """The paged engine of a serve cell (its configuration's widths, its
     engine settings, parameters as shapes), with its programs compiled for
     the chip, prefill once a rung: {program: compiled} under the names
-    ``serve_decode`` and ``serve_prefill@<rung>``, and the pool's leaves. The
-    kernels are forced on, as on the chip, and asked to compile rather than
-    interpret."""
-    from benchmarks import loader
-    from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
-
-    cell = loader.Manifest().cell(request.param)
-    settings = cell.settings["engine"]
-    program = cell.family.program
-    cfg = program.model_config(cell.config, max_seq=settings["max_len"], remat=False)
-    params = jax.eval_shape(lambda: program.engine_params(cell.config, 1))
-    mp = pytest.MonkeyPatch()
-    mp.setenv("RLT_PAGED_KERNEL", "1")
-    try:
-        engine = InferenceEngine(params, cfg, EngineConfig(**settings))
-        leaves = [(a.shape, a.dtype) for a in engine.pool.cache.values()]
-        pool_bytes = sum(int(a.nbytes) for a in engine.pool.cache.values())
-        mp.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
-        compiled = {}
-        for name, fn, args in engine._program_specs():
-            shapes = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-                args)
-            if name == "serve_prefill":
-                name = f"{name}@{args[2].shape[1]}"
-            compiled[name] = fn.lower(*shapes).compile()
-    finally:
-        mp.undo()
+    ``serve_decode`` and ``serve_prefill@<rung>``, and the pool's leaves."""
+    compiled, leaves, pool_bytes = _compile_cell(request.param, topo, one_chip)
     assert sorted(compiled) == sorted(["serve_decode", *PREFILLS])
     return request.param, compiled, leaves, pool_bytes
 
@@ -748,3 +766,120 @@ def test_prefill_temporaries_shrink_with_the_rung(cell_programs):
     temps = [compiled[p].memory_analysis().temp_size_in_bytes for p in PREFILLS]
     assert temps == sorted(temps) and len(set(temps)) == len(temps)
     assert temps[0] < temps[-1] / 3
+
+
+# ---------------------------------------------------------------------- #
+# the sparse / linear attention cell (benchmarks/configs/minicpm-sala-d4.json,
+# workloads/serve-sparse-linear-long.json): a pool with a state kind
+# ---------------------------------------------------------------------- #
+LONG_CELL = "serve-sparse-linear-long"
+LONG_PREFILLS = [f"serve_prefill@{rung}" for rung in prefill_rungs(16384, 64)]
+HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def long_cell_programs(topo, one_chip):
+    compiled, leaves, pool_bytes = _compile_cell(LONG_CELL, topo, one_chip)
+    assert sorted(compiled) == sorted(["serve_decode", *LONG_PREFILLS])
+    return compiled, leaves, pool_bytes
+
+
+@pytest.mark.parametrize("program", ["serve_decode", *LONG_PREFILLS])
+def test_the_sparse_linear_cells_programs_compile_and_fit_the_chip(long_cell_programs, program):
+    """32 slots, ``max_len`` 20,480, pages of 64: K and V 2 x [1, 10241, 2,
+    64, 128] bf16, the pooled keys [1, 10241, 2, 4, 128], the state [3, 32,
+    32, 128, 128] float32, 0.89 GB together. Every program (the rungs 256 to
+    16,384 and the decode step) aliases the whole pool, the state among it,
+    copies nothing of a leaf's size, and fits the chip's 16 GB with the
+    weights and its own temporaries: under 2 GB of them at the longest rung,
+    under a tenth of the pool in the decode step."""
+    compiled, leaves, pool_bytes = long_cell_programs
+    assert 0.88e9 < pool_bytes < 0.90e9
+    exe = compiled[program]
+    found = _pool_sized_copies(exe.as_text(), leaves)
+    if program == "serve_decode":
+        # the compiler stages the pooled keys' leaf (21 MB, gathered whole by
+        # every row's scores) through its fast memory: a copy there and back
+        # a tick, of no leaf that a kernel reads
+        pooled = ("bf16[1,10241,2,4,128]", "bf16[20482,4,128]")  # the leaf, and flattened
+        found = [f for f in found if not (
+            f.startswith(("copy-start ", "copy-done ")) and any(p in f for p in pooled))]
+    else:
+        # prefill writes the slot's state whole where it is: an update in
+        # place of the donated leaf, which the alias below holds it to
+        found = [f for f in found if not (
+            f.startswith("dynamic-update-slice ") and "f32[3,32,32,128,128]" in f)]
+    assert found == []
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < HBM_BYTES / 2
+    if program == "serve_decode":
+        assert mem.temp_size_in_bytes < pool_bytes / 10
+    else:
+        assert mem.temp_size_in_bytes < 2.0e9
+
+
+def test_the_sparse_linear_cells_programs_hold_their_kernels_by_name(long_cell_programs):
+    """The decode step reads the chosen blocks through ``paged_decode_attention``
+    and moves the states on by ``lightning_decode``; prefill scans by
+    ``lightning_prefill`` at every rung and attends by ``flash_fwd`` under
+    ``dense_len`` (8,192) and by ``flash_fwd_selected`` from there on."""
+    compiled, _, _ = long_cell_programs
+    decode = compiled["serve_decode"].as_text()
+    assert decode.startswith("HloModule jit_serve_decode")
+    assert set(_kernel_instructions(decode)) == {
+        "paged_decode_attention", "lightning_decode", "rmsnorm", "fused_argmax"}
+    assert _kernel_instructions(decode).count("lightning_decode") == 3
+    for program in LONG_PREFILLS:
+        text = compiled[program].as_text()
+        assert text.startswith("HloModule jit_serve_prefill")
+        rung = int(program.split("@")[1])
+        attends = "flash_fwd_selected" if rung >= 8192 else "flash_fwd"
+        assert set(_kernel_instructions(text)) == {attends, "lightning_prefill", "rmsnorm"}
+    temps = [compiled[p].memory_analysis().temp_size_in_bytes for p in LONG_PREFILLS]
+    assert temps == sorted(temps)
+
+
+# ---------------------------------------------------------------------- #
+# the accepted serve cells' programs are the parent's
+# ---------------------------------------------------------------------- #
+def _fingerprint(fn, shapes):
+    """sha256 of a serving program as it is handed to the compiler: the
+    lowered text, in which a Mosaic kernel is an opaque body that carries the
+    source's paths and line numbers (cut out), and the program's jaxpr, which
+    holds every kernel's body as equations and no line numbers (object
+    addresses cut out). A change to an accepted cell's program moves one of
+    the two; a moved line of the engine moves neither."""
+    import hashlib
+    import re
+
+    text = re.sub(r'(\\22body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", fn.lower(*shapes).as_text())
+    jaxpr = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(getattr(fn, "_fn", fn))(*shapes)))
+    return hashlib.sha256((text + jaxpr).encode()).hexdigest()
+
+
+@pytest.mark.parametrize(
+    "cell", [*SERVE_CELLS, "serve-swa-moe-doc"])
+def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
+    cell, topo, one_chip, monkeypatch
+):
+    """``tests/serve_programs_lowered.json`` holds the fingerprint of every
+    serving program of the four accepted serve cells as the parent of PR 34
+    lowered them for a described v5e (PR 33's method: a program that lowers
+    to the same text is the same program, so the cell cannot have moved). A
+    PR that changes one of these programs on purpose records the file anew
+    (``_fingerprint`` over ``_cell_specs``) and says which and why."""
+    import json
+    import os
+
+    recorded = json.load(open(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "serve_programs_lowered.json")))
+    engine = _cell_engine(cell, monkeypatch)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    got = {f"{cell}/{program}": _fingerprint(fn, shapes)
+           for program, fn, shapes in _cell_specs(engine, one_chip)}
+    want = {k: v for k, v in recorded.items() if k.startswith(cell + "/")}
+    assert sorted(got) == sorted(want) and len(want) >= 5
+    assert [k for k in want if got[k] != want[k]] == []
