@@ -56,6 +56,33 @@ idempotent cache write, same logits as prefill's last position), which
 is what lets prefill skip its logits head and keeps "first token" and
 "every other token" the same compiled program.
 
+The order of a tick. The next decode step's tokens are the array the last
+one returned, its positions are ``pos + 1`` whatever those tokens were, and
+the block a position is written to follows from the position alone. So a
+decode program is DISPATCHED BEFORE THE ONE BEFORE IT IS READ: one call of
+``step()`` schedules, enqueues the admitted prefills, prepares and
+dispatches decode step n+1 (its rows take their tokens from step n's output
+on the device; a row prefilled or imported since takes the host's), and only
+then RETIRES step n: waits for its tokens, delivers them, finishes requests,
+releases slots. The host's part of a tick runs under the device's, and the
+device goes from one decode program to the next without waiting. What needs
+a position advances at dispatch (``Slot.pos``, the block made writable, the
+position counters); what needs the token's value happens at retire, in
+order. A stop by length is known at dispatch: a row whose step in flight is
+its last is not dispatched again, and no row is ever stepped past
+``max_new_tokens``. A stop learnt at retire (``eos_id``, an expiry, a
+callback that shuts the engine down) finds the row one step further on: that
+step's token is DROPPED (never delivered, never counted in ``tokens_out``;
+``stats["dropped_row_steps"]``), and its K/V write landed in a block the
+request still owned at dispatch, which device order puts before any later
+owner's writes (the prefix cache registers only whole prompt blocks before
+the first position decode writes, so never a block such a step can reach).
+A slot comes free at retire, so the scheduler sees it one tick later. A
+speculating engine (``speculate_k > 0``) proposes from the tokens' values,
+so its dispatch needs them on the host: the same routine retires each step
+before the next is dispatched, as ever. What ``step()`` returns describes
+the tick it RETIRED, the one whose program it waited for.
+
 Threading: ``submit`` is callable from any thread; ``start()`` spawns
 the loop thread, or call ``step()`` yourself for deterministic
 single-threaded driving (tests, bench). ``drain()`` stops admission and
@@ -86,6 +113,7 @@ from ray_lightning_tpu.serving import migration as _migration
 from ray_lightning_tpu.serving.paged_kv import (
     TRASH_BLOCK,
     PagedKVPool,
+    Slot,
     STATE,
     stated_windows,
     states_kind,
@@ -362,6 +390,22 @@ class _ImportTicket:
         self.event = threading.Event()
 
 
+@dataclass
+class _Tick:
+    """What one call of ``step()`` enqueued, until it is retired: the decode
+    program's output still on the device (the sampled tokens, the model's
+    counters behind them; ``None`` where the tick dispatched no decode
+    program), the rows it stepped, each with the admission it was stepped
+    for, a speculating tick's proposals by slot, and the prefills enqueued
+    ahead of it (how many, and their traces)."""
+
+    sampled: Any
+    rows: List[Tuple[Slot, int]]
+    proposals: Dict[int, List[int]]
+    prefills: int
+    prefill_traces: List[tuple]
+
+
 class InferenceEngine:
     """Continuous batching over one model replica (one process, one set
     of params). See the module docstring for the two-program design."""
@@ -415,6 +459,8 @@ class InferenceEngine:
         # journal pump reads it (via `alive`) to trigger relaunch
         self.failed: Optional[BaseException] = None
         self._ticks = 0
+        # the tick whose decode program is dispatched and not yet read
+        self._inflight: Optional[_Tick] = None
         self._admit_seq = 0
         # request_id -> remaining-token budget armed by a drop-stream fault
         self._drop_stream: Dict[str, int] = {}
@@ -480,6 +526,9 @@ class InferenceEngine:
         # throughput/utilization accounting (host side, always on)
         self.stats: Dict[str, float] = {
             "decode_steps": 0,
+            # of those, the decode programs dispatched while an earlier one
+            # was unread: the host's part of that tick ran under the device's
+            "overlapped_steps": 0,
             "prefills": 0,
             # the sum of the rungs those prefills ran at: what prefill
             # computed, padding and all, and the prompts' own tokens among
@@ -489,6 +538,9 @@ class InferenceEngine:
             "prefill_tokens": 0,
             "tokens_out": 0,
             "busy_slot_steps": 0,
+            # of those, the row steps whose token was dropped: the request
+            # had stopped (eos, expiry, shutdown) by the time it was read
+            "dropped_row_steps": 0,
             "completed": 0,
             # speculative accounting: accepted_tokens / spec_row_ticks is
             # the mean accepted-tokens-per-slot-tick the bench reports
@@ -667,7 +719,18 @@ class InferenceEngine:
 
             return {name: written(name, leaf) for name, leaf in cache.items()}
 
-        def decode_paged(params, cache, token, pos, tables, key):
+        num_slots = self.pool.num_slots
+        # what stands in for the output of "the decode program before" where
+        # there is none unread: its shape, and no row asks for a token of it
+        self._no_previous = jnp.zeros(
+            (num_slots + len(model.counters),), jnp.int32
+        )
+
+        def decode_paged(params, cache, token, pos, tables, key, prev):
+            # a row's token is the host's, or under -1 the one the decode
+            # program before this one sampled for it: prev is that program's
+            # first output as it stands on the device, unread by the host
+            token = jnp.where(token < 0, prev[:num_slots], token)
             logits, cache, counters = model.decode_paged(
                 params, cache, token, pos, tables, table
             )
@@ -747,8 +810,18 @@ class InferenceEngine:
         return prefills + (
             ("serve_decode", self._decode_fn,
              (self.params, cache, token, pos,
-              self._on_device(self.pool.program_tables()), key)),
+              self._on_device(self.pool.program_tables()), key,
+              *self._previous_output(None))),
         )
+
+    def _previous_output(self, tick: Optional[_Tick]) -> tuple:
+        """The decode program's last argument: the output of the decode
+        program before it, where ``tick`` is that one and still unread, else
+        zeros of its shape (no row then asks for a token of it). A
+        speculating engine's program takes none: its tokens are the host's."""
+        if self._speculate_k > 0:
+            return ()
+        return (self._no_previous if tick is None else tick.sampled,)
 
     @staticmethod
     def _on_device(tables):
@@ -953,10 +1026,20 @@ class InferenceEngine:
     # one iteration
     # ------------------------------------------------------------------ #
     def step(self) -> Dict[str, Any]:
-        """Run one scheduler tick: up to N prefills + one batched decode.
+        """Run one scheduler tick: up to N prefills + one batched decode
+        dispatched, and one tick retired: the one dispatched by the call
+        before this one, whose program the device ran meanwhile (a
+        speculating engine retires the tick it just dispatched; see the
+        module docstring on the order of a tick).
 
-        Returns ``{"prefills": int, "decoded": int, "completed": [ids]}``.
-        Call from a single thread only (the loop thread, or the test).
+        Returns ``{"prefills": int, "decoded": int, "completed": [ids]}``
+        of the tick this call RETIRED, the one whose program it waited for:
+        the prefills enqueued ahead of that decode program, the rows it
+        stepped, the requests that finished when its tokens were read. An
+        engine's first call of a run retires nothing and returns zeros; the
+        call after the last dispatch retires the last tick and dispatches
+        nothing. Call from a single thread only (the loop thread, or the
+        test).
 
         The tick and its phases are ``rlt.serve.*`` spans on the profiler's
         clock (``observability.phase_span``), and its wall time and its one
@@ -1069,38 +1152,23 @@ class InferenceEngine:
                 self.stats["prefill_positions"] += rung
                 self.stats["prefill_tokens"] += req.prompt_len
 
-        # export-pending slots are parked: their KV is in flight to a
-        # decode replica, so this engine must not decode them — not even
-        # the same-tick first decode of a fresh prefill, or the source
-        # would emit a token the receiver then duplicates (a failed
-        # migration clears the flag and they resume in place). The filter
-        # runs AFTER the prefill loop so it sees slots parked this tick;
-        # it is a no-op for "both"/"decode" roles — homogeneous fleets
-        # run the exact pre-disaggregation path.
-        decode_slots = plan.decode_slots
-        block_tables = self.pool.program_tables()
-        if self._role == "prefill":
-            decode_slots = [s for s in decode_slots if not s.export_pending]
-            parked = [
-                s.index
-                for s in self.pool.slots
-                if s.occupied and s.export_pending
-            ]
-            if parked:
-                # A parked slot is occupied but excluded from the decode
-                # batch, so its row rides the fixed-shape program as a
-                # padding row (token 0, pos 0) — with its LIVE block
-                # table still in place, that padding write would land in
-                # the request's first prompt block and corrupt the KV
-                # the shipment (and any in-place fallback decode)
-                # depends on. Point parked rows at the trash block, the
-                # same sink free slots use.
-                block_tables = {k: t.copy() for k, t in block_tables.items()}
-                for t in block_tables.values():
-                    t[parked, :] = TRASH_BLOCK
-
-        completed: List[str] = []
+        # Who is stepped: every occupied slot but the parked and the spent.
+        # An export-pending slot is parked: its KV is in flight to a decode
+        # replica, so this engine must not decode it (not even the same-tick
+        # first decode of a fresh prefill, or the source would emit a token
+        # the receiver then duplicates; a failed migration clears the flag
+        # and it resumes in place). The filter runs AFTER the prefill loop so
+        # it sees slots parked this tick. A slot is spent when every step of
+        # its ``max_new_tokens`` is dispatched: it waits for the retire of
+        # the last, and no row is ever stepped past its length (the table
+        # has no block there).
+        decode_slots = [
+            s for s in plan.decode_slots
+            if not s.export_pending
+            and s.pos - s.prompt_len + 1 < s.max_new_tokens
+        ]
         K = self._speculate_k
+        tick = _Tick(None, [], {}, len(plan.prefills), prefill_traces)
         if decode_slots:
             rows = len(decode_slots)
             with _obs.phase_span("rlt.serve.decode_prep", rows=rows):
@@ -1114,7 +1182,6 @@ class InferenceEngine:
                     np.int32,
                 )
                 pos = np.zeros((self.pool.num_slots,), np.int32)
-                proposals: Dict[int, List[int]] = {}
                 for slot in decode_slots:
                     if K > 0:
                         # budget: a row may deliver at most `remaining`
@@ -1126,13 +1193,18 @@ class InferenceEngine:
                             self._history.get(slot.request_id, ()),
                             min(K - 1, remaining - 1),
                         )
-                        proposals[slot.index] = props
+                        tick.proposals[slot.index] = props
                         token[slot.index, 0] = slot.pending_token
                         for j, p in enumerate(props):
                             token[slot.index, 1 + j] = p
                     else:
                         props = ()
-                        token[slot.index] = slot.pending_token
+                        # -1: the token is the one the decode program in
+                        # flight sampled for this row, still on the device
+                        token[slot.index] = (
+                            -1 if slot.pending_token is None
+                            else slot.pending_token
+                        )
                     # on-demand growth: the block holding the deepest
                     # write position (slot.pos, or the last speculative
                     # one) must be physical before the compiled scatter
@@ -1153,77 +1225,66 @@ class InferenceEngine:
                         pos[[s.index for s in decode_slots]].sum()) + rows
                     self.stats["state_bytes_touched"] += (
                         self._state_bytes_per_tick)
+                # The tables go up as a snapshot, taken now, behind the
+                # growth above: the host's mirrors change again (a release
+                # at the retire below, growth at the next dispatch) while
+                # the program that reads this upload may still be running,
+                # and an upload may alias the host's memory or read it late.
+                block_tables = {
+                    k: t.copy() for k, t in self.pool.program_tables().items()
+                }
+                if rows < len(plan.decode_slots):
+                    # A slot that is occupied and not stepped rides the
+                    # fixed-shape program as a padding row (token 0, pos 0):
+                    # with its LIVE block table in place, that padding
+                    # write would land in the request's first prompt block
+                    # and corrupt the KV the shipment (and any in-place
+                    # fallback decode, and any request that shares the
+                    # block by prefix) depends on. Point such rows at the
+                    # trash block, the same sink free slots use.
+                    stepped = {s.index for s in decode_slots}
+                    idle = [
+                        s.index for s in plan.decode_slots
+                        if s.index not in stepped
+                    ]
+                    for t in block_tables.values():
+                        t[idle, :] = TRASH_BLOCK
                 inputs = (
                     jnp.asarray(token), jnp.asarray(pos),
-                    self._on_device(block_tables),
+                    self._on_device(block_tables), sub,
+                    *self._previous_output(self._inflight),
                 )
             with _obs.phase_span("rlt.serve.decode_dispatch"):
-                sampled = self._update_pool(lambda cache: self._decode_fn(
-                    self.params, cache, *inputs, sub
+                tick.sampled = self._update_pool(lambda cache: self._decode_fn(
+                    self.params, cache, *inputs
                 )[::-1])
-            t_sync = time.perf_counter()
-            with _obs.phase_span(
-                "rlt.serve.sample_sync", prefills=len(plan.prefills)
-            ):
-                sampled_host = np.asarray(sampled)  # the per-step sync point
-            now = time.perf_counter()
-            self.stats["sync_wait_s"] += now - t_sync
-            counters = self._model.counters
-            if counters:  # behind the tokens, in the same array
-                for name, value in zip(
-                    counters, sampled_host[self.pool.num_slots:]
-                ):
-                    self.stats[name] += int(value)
-                sampled_host = sampled_host[: self.pool.num_slots]
-            # the first instant the host knows this tick's prefills are done
-            for tr, t0, _ in prefill_traces:
-                tr.prefilled(now - t0, done_at=now)
-            with _obs.phase_span("rlt.serve.deliver", rows=rows):
-                reg = _obs.registry()
-                for slot in decode_slots:
-                    rid = slot.request_id
-                    if rid is None:
-                        # released mid-step (re-entrant shutdown from an
-                        # on_token callback): nothing to deliver
-                        continue
-                    if K == 0:
-                        self._deliver_token(
-                            slot, rid, int(sampled_host[slot.index]), now,
-                            reg, completed,
-                        )
-                        continue
-                    out = sampled_host[slot.index]
-                    # greedy accept: out[j] is the model's token AFTER
-                    # consuming proposals[:j]; the first mismatch both ends
-                    # the accepted prefix AND contributes its correction —
-                    # so at least one token always lands, same as k=0
-                    accepted = 1
-                    for j, p in enumerate(proposals.get(slot.index, [])):
-                        if int(out[j]) == int(p):
-                            accepted += 1
-                        else:
-                            break
-                    before = self.stats["tokens_out"]
-                    for j in range(accepted):
-                        if not self._deliver_token(
-                            slot, rid, int(out[j]), now, reg, completed
-                        ):
-                            break
-                    delivered = int(self.stats["tokens_out"] - before)
-                    self.stats["spec_row_ticks"] += 1
-                    self.stats["accepted_tokens"] += delivered
-                    if delivered > 0 and reg is not None:
-                        reg.histogram(
-                            "rlt_serve_accepted_tokens",
-                            bounds=ACCEPTED_BOUNDS,
-                        ).observe(float(delivered), exemplar=rid)
+            # the step is on its way: what follows from the positions alone
+            # moves now, what needs the tokens when they are read
+            for slot in decode_slots:
+                tick.rows.append((slot, slot.admission))
+                slot.pos += 1
+                if K == 0:
+                    slot.pending_token = None
             self.stats["decode_steps"] += 1
             self.stats["busy_slot_steps"] += rows
+            if self._inflight is not None:
+                self.stats["overlapped_steps"] += 1
+
+        # One tick is retired a call. Where the next dispatch reads the
+        # tokens on the host (a speculating engine proposes from them) it is
+        # the tick just dispatched; where it does not, that tick stays in
+        # flight and the one before it is retired, which the device finished
+        # while the host scheduled, prepared and dispatched this one. A tick
+        # without a decode program has nothing to wait for and goes with it.
+        retiring, self._inflight = [self._inflight], None
+        if tick.sampled is not None and K == 0:
+            self._inflight = tick
         else:
-            # no decode, so no sync this tick (a prefill-role replica whose
-            # slots are all parked): all the host knows is the enqueue
-            for tr, t0, t1 in prefill_traces:
-                tr.prefilled(t1 - t0, done_at=t1, synced=False)
+            retiring.append(tick)
+        report = {"prefills": 0, "decoded": 0, "completed": []}
+        for retired in retiring:
+            if retired is not None:
+                self._retire(retired, report)
 
         if new_exports:
             # published once the tick is over: the fleet's migration pump
@@ -1232,11 +1293,85 @@ class InferenceEngine:
             with self._work:
                 self._ready_exports.extend(new_exports)
                 self._work.notify_all()
-        return {
-            "prefills": len(plan.prefills),
-            "decoded": len(decode_slots),
-            "completed": completed,
-        }
+        return report
+
+    def _retire(self, tick: _Tick, report: Dict[str, Any]) -> None:
+        """Read one dispatched tick's tokens and do everything that needs
+        their values, in order: deliver them, finish and release what
+        stopped. The tick's one wait for the device is here. A row whose
+        slot has changed hands since the dispatch (released at an earlier
+        retire, by an expiry or a shutdown, and perhaps admitted anew) had
+        stopped before this step: its token is dropped. ``report`` gathers
+        what ``step()`` returns."""
+        K = self._speculate_k
+        report["prefills"] += tick.prefills
+        if tick.sampled is None:
+            # no decode, so no sync (a prefill-role replica whose slots are
+            # all parked): all the host knows is the enqueue
+            for tr, t0, t1 in tick.prefill_traces:
+                tr.prefilled(t1 - t0, done_at=t1, synced=False)
+            return
+        rows = len(tick.rows)
+        report["decoded"] += rows
+        completed = report["completed"]
+        t_sync = time.perf_counter()
+        with _obs.phase_span("rlt.serve.sample_sync", prefills=tick.prefills):
+            sampled_host = np.asarray(tick.sampled)  # the per-step sync point
+        now = time.perf_counter()
+        self.stats["sync_wait_s"] += now - t_sync
+        counters = self._model.counters
+        if counters:  # behind the tokens, in the same array
+            for name, value in zip(
+                counters, sampled_host[self.pool.num_slots:]
+            ):
+                self.stats[name] += int(value)
+            sampled_host = sampled_host[: self.pool.num_slots]
+        # the first instant the host knows this tick's prefills are done
+        for tr, t0, _ in tick.prefill_traces:
+            tr.prefilled(now - t0, done_at=now)
+        with _obs.phase_span("rlt.serve.deliver", rows=rows):
+            reg = _obs.registry()
+            for slot, admission in tick.rows:
+                rid = slot.request_id
+                if rid is None or slot.admission != admission:
+                    # the request stopped after this step was dispatched
+                    # (eos or expiry learnt since, a re-entrant shutdown
+                    # from an on_token callback): nothing to deliver
+                    self.stats["dropped_row_steps"] += 1
+                    continue
+                if K == 0:
+                    self._deliver_token(
+                        slot, rid, int(sampled_host[slot.index]), now,
+                        reg, completed,
+                    )
+                    continue
+                out = sampled_host[slot.index]
+                # greedy accept: out[j] is the model's token AFTER
+                # consuming proposals[:j]; the first mismatch both ends
+                # the accepted prefix AND contributes its correction —
+                # so at least one token always lands, same as k=0
+                accepted = 1
+                for j, p in enumerate(tick.proposals.get(slot.index, [])):
+                    if int(out[j]) == int(p):
+                        accepted += 1
+                    else:
+                        break
+                before = self.stats["tokens_out"]
+                for j in range(accepted):
+                    if j:  # the dispatch advanced the one sure position
+                        slot.pos += 1
+                    if not self._deliver_token(
+                        slot, rid, int(out[j]), now, reg, completed
+                    ):
+                        break
+                delivered = int(self.stats["tokens_out"] - before)
+                self.stats["spec_row_ticks"] += 1
+                self.stats["accepted_tokens"] += delivered
+                if delivered > 0 and reg is not None:
+                    reg.histogram(
+                        "rlt_serve_accepted_tokens",
+                        bounds=ACCEPTED_BOUNDS,
+                    ).observe(float(delivered), exemplar=rid)
 
     def _deliver_token(
         self,
@@ -1332,8 +1467,10 @@ class InferenceEngine:
         if tr is not None:
             tr.token()
         slot.generated += 1
-        slot.pos += 1
-        slot.pending_token = tok
+        if self._inflight is None:
+            # no later step of this row is dispatched: the next takes this
+            # token from the host (else it has it from the device already)
+            slot.pending_token = tok
         hist = self._history.get(rid)
         if hist is not None:
             hist.append(tok)
@@ -1410,8 +1547,9 @@ class InferenceEngine:
         :class:`~.migration.KVShipment`.
 
         Read-only and callable from the fleet's pump thread: the slot is
-        export-parked (the decode filter skips it, so its blocks are
-        never written) and its prefix chains were pinned at arm time. The
+        export-parked (the decode filter skips it from its prefill on, so
+        no step of it is ever in flight and its blocks are never written)
+        and its prefix chains were pinned at arm time. The
         pool's arrays are NOT stable values, though: every tick donates them
         to its programs, and what ``self.pool.cache`` named a moment ago may
         be deleted. So the blocks are gathered under ``_pool_lock``, which a
@@ -1753,10 +1891,12 @@ class InferenceEngine:
                 return
 
     def _tick_due(self) -> bool:
-        """Under ``self._work``: the scheduler has work, or migration work
-        needs a tick even when it is idle."""
+        """Under ``self._work``: the scheduler has work, a dispatched tick
+        is unread (its rows may all have stopped since, so no slot says so),
+        or migration work needs a tick even when it is idle."""
         return bool(
             self.scheduler.has_work()
+            or self._inflight is not None
             or self._pending_imports
             or self._export_actions
         )
@@ -1769,6 +1909,11 @@ class InferenceEngine:
         again, and the replica is relaunched, not restarted."""
         if self.failed is None:
             self.failed = error
+        # the tick in flight is never read: its rows' requests fail with
+        # every other below
+        tick, self._inflight = self._inflight, None
+        if tick is not None:
+            self.stats["dropped_row_steps"] += len(tick.rows)
         if self._goodput is not None:
             # the time from here until a successor engine adopts the
             # ledger is unplanned recovery, not idle
@@ -1825,9 +1970,10 @@ class InferenceEngine:
         return out
 
     def run_until_idle(self, max_steps: int = 100_000) -> None:
-        """Single-threaded drive: step until queue and pool are empty."""
+        """Single-threaded drive: step until queue and pool are empty and
+        the last dispatched tick is retired."""
         for _ in range(max_steps):
-            if not self.scheduler.has_work():
+            if not (self.scheduler.has_work() or self._inflight is not None):
                 return
             self.step()
         raise RuntimeError(f"still busy after {max_steps} steps")

@@ -133,12 +133,21 @@ class Slot:
     for position P, i.e. the request's FIRST sampled token. That is what
     lets one jitted decode step serve both "first token after prefill"
     and every later token — there is no separate first-token program.
+
+    ``pos`` advances when a step is DISPATCHED (the next position follows
+    from this one whatever the token turns out to be), ``generated`` when
+    its token is read. ``pending_token`` is ``None`` while that token is
+    still on the device: the output of a decode program the host has not
+    read, which the next program takes from there. ``admission`` says which
+    of the pool's admissions the occupant is, so a step dispatched for an
+    earlier occupant of the slot is told from the present one's.
     """
 
     index: int
     request_id: Optional[str] = None
+    admission: int = 0
     pos: int = -1
-    pending_token: int = 0
+    pending_token: Optional[int] = 0
     prompt_len: int = 0
     generated: int = 0
     max_new_tokens: int = 0
@@ -168,6 +177,7 @@ class Slot:
 
     def reset(self) -> None:
         self.request_id = None
+        self.admission = 0
         self.pos = -1
         self.pending_token = 0
         self.prompt_len = 0
@@ -868,6 +878,7 @@ class PagedKVPool:
             kind.allocs[slot.index] = alloc
         self._zero_state(slot.index)
         self.admitted_total += 1
+        slot.admission = self.admitted_total
         self.tenancies[slot.index].append(request_id)
         self.highwater = max(self.highwater, self.occupancy)
         self._publish_gauges()

@@ -78,6 +78,10 @@ def test_a_tick_consumes_the_pool_it_was_given(weights, variant):
         assert not any(a.is_deleted() for a in engine.pool.cache.values())
         assert {k: a.shape for k, a in engine.pool.cache.items()} == {
             k: a.shape for k, a in before.items()}
+        # the tick this call left in flight holds no pool: all the engine
+        # keeps of it on the device is its few sampled tokens
+        assert engine._inflight.sampled.nbytes <= 4 * (
+            engine.pool.num_slots + len(engine._model.counters))
 
 
 @pytest.mark.parametrize("program", ["serve_prefill", "serve_decode"])
@@ -472,3 +476,4 @@ def test_a_program_that_raises_after_it_took_the_pool_ends_the_engine(weights):
         engine.export_shipment(rid)
     engine._fail_all(engine.failed)
     assert done.done and done.finish_reason == "error"
+    assert engine._inflight is None  # the unread tick went with the rest

@@ -37,9 +37,11 @@ def _telemetry_off():
 @pytest.fixture(scope="module")
 def model():
     # wide enough that a tick on the CPU takes milliseconds, so that the
-    # microseconds between two spans are a small share of it
+    # microseconds between two spans are a small share of it (twice as wide
+    # since a call no longer waits for the program it dispatched: the call
+    # got shorter and the code between its spans did not)
     cfg = dataclasses.replace(
-        LlamaConfig.tiny(), dim=256, ffn_dim=1024, n_layers=4,
+        LlamaConfig.tiny(), dim=512, ffn_dim=2048, n_layers=4,
         dtype=jnp.float32,
     )
     return init_params(jax.random.key(0), cfg), cfg
@@ -119,7 +121,11 @@ def test_trainer_step_lowers_to_jit_train_step(tmp_root):
 
 
 # --------------------------------------------------------------------- #
-# (c) spans of a tick nest, in order, and cover it
+# (c) spans of a tick nest, in order, and cover it. A call of step()
+# dispatches its own decode program and then retires the one before it, so
+# the phases keep their order inside a call, and the sync and the deliver
+# of a call are the LAST call's tick's: the first call of a run has none,
+# the call after the last dispatch has nothing else.
 # --------------------------------------------------------------------- #
 def test_tick_spans_nest_in_order_and_cover_the_tick(model, tmp_path):
     engine = _engine(model)
@@ -140,18 +146,31 @@ def test_tick_spans_nest_in_order_and_cover_the_tick(model, tmp_path):
     assert numbers == list(range(numbers[0], numbers[0] + len(ticks)))
     order = {name: i for i, name in enumerate(SERVE_PHASES)}
     with_prefill = 0
-    for tick in ticks:
+    enqueued = 0  # prefills the call before this one enqueued
+    for i, tick in enumerate(ticks):
         kids = pt.children(tick, spans)
         names = [k.name for k in kids]
         assert set(names) <= set(SERVE_PHASES)
         assert [order[n] for n in names] == sorted(order[n] for n in names)
         assert all(a.end_ns <= b.start_ns for a, b in zip(kids, kids[1:]))
-        assert names[0] == "rlt.serve.schedule" and names[-1] == "rlt.serve.deliver"
-        sync = next(k for k in kids if k.name == pt.SAMPLE_SYNC)
-        assert sync.args["prefills"] == names.count("rlt.serve.prefill")
-        with_prefill += sync.args["prefills"]
+        assert names[0] == "rlt.serve.schedule"
+        if i == 0:  # nothing was in flight: this call retires nothing
+            assert names[-1] == "rlt.serve.decode_dispatch"
+        else:
+            assert names[-1] == "rlt.serve.deliver"
+            sync = next(k for k in kids if k.name == pt.SAMPLE_SYNC)
+            # the sync is the retired tick's: it names that tick's prefills
+            assert sync.args["prefills"] == enqueued
+            with_prefill += sync.args["prefills"]
+        enqueued = names.count("rlt.serve.prefill")
+    # the last call retires the last tick and dispatches nothing
+    assert [k.name for k in pt.children(ticks[-1], spans)] == [
+        "rlt.serve.schedule", "rlt.serve.sample_sync", "rlt.serve.deliver"]
     assert with_prefill == 2
-    assert len(pt.decode_only_syncs(spans)) == len(ticks) - 2
+    # one decode program a sync, one sync a call but the first
+    assert len(pt.named(spans, pt.SAMPLE_SYNC)) == len(ticks) - 1 == len(
+        pt.named(spans, "rlt.serve.decode_dispatch"))
+    assert len(pt.decode_only_syncs(spans)) == len(ticks) - 3
     first = pt.named(spans, "rlt.serve.prefill")[0]
     assert (first.args["prompt_len"], first.args["rung"]) == (4, 16)
     assert pt.cover_share(spans) >= 0.95
@@ -221,7 +240,10 @@ def test_counters_after_n_ticks(model):
         n += 1
     wall = time.perf_counter() - t0
     s = engine.stats
-    assert s["ticks"] == n == 6
+    # six calls dispatch the six steps, each but the first retiring the step
+    # before it; the seventh retires the last
+    assert s["ticks"] == n == 7
+    assert (s["decode_steps"], s["overlapped_steps"]) == (6, 5)
     assert wall >= s["tick_s"] >= s["sync_wait_s"] >= 0.0
     assert s["tick_s"] > 0.9 * wall  # the loop above does nothing but tick
     assert s["loop_wait_s"] == 0.0  # no loop thread ran
@@ -240,7 +262,7 @@ def test_loop_thread_time_is_ticks_plus_waits(model):
     engine.drain()
     wall = time.perf_counter() - t0
     grew = {k: engine.stats[k] - base[k] for k in ("ticks", "tick_s", "loop_wait_s")}
-    assert grew["ticks"] == 12
+    assert grew["ticks"] == 13  # twelve dispatches and the last retire
     assert grew["loop_wait_s"] >= 0.25
     assert grew["tick_s"] + grew["loop_wait_s"] == pytest.approx(wall, rel=0.05)
 
@@ -255,7 +277,7 @@ def test_ring_gets_the_same_names_when_telemetry_is_on(model):
     engine.run_until_idle()
     events = [e for e in rec.drain() if e[1].startswith("rlt.")]
     names = [e[1] for e in events]
-    assert names.count("rlt.serve.tick") == 3
+    assert names.count("rlt.serve.tick") == 4  # three steps, the last retire
     assert set(names) == set(SERVE_PHASES) | {"rlt.serve.tick"}
     assert "serve_prefill" not in names and "serve_decode" not in names
     prefill = next(e for e in events if e[1] == "rlt.serve.prefill")
@@ -303,9 +325,11 @@ def test_prefill_duration_ends_at_the_ticks_sync(model):
     engine = _engine(model)
     done = engine.submit([1, 2, 3, 4], max_new_tokens=2)
     t0 = time.perf_counter()
-    engine.step()
-    tick_s = time.perf_counter() - t0
+    engine.step()  # enqueues the prefill and the tick's decode program
     trace = next(s.trace for s in engine.pool.slots if s.occupied)
+    assert trace.prefill_s is None and engine.stats["sync_wait_s"] == 0.0
+    engine.step()  # retires that tick: its sync is where the host knows
+    tick_s = time.perf_counter() - t0
     assert trace.prefill_synced is True
     # enqueue to the end of the sync: all of the tick's wait is inside it
     assert tick_s >= trace.prefill_s >= engine.stats["sync_wait_s"] > 0.0

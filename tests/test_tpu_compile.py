@@ -870,7 +870,11 @@ def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
     lowered them for a described v5e (PR 33's method: a program that lowers
     to the same text is the same program, so the cell cannot have moved). A
     PR that changes one of these programs on purpose records the file anew
-    (``_fingerprint`` over ``_cell_specs``) and says which and why."""
+    (``_fingerprint`` over ``_cell_specs``) and says which and why. PR 35
+    recorded the four ``serve_decode`` entries anew: the decode program takes
+    the output of the decode program before it and a row's token from there
+    where the host's is -1 (one ``select`` ahead of the model's step); every
+    ``serve_prefill@<rung>`` entry stands as it was."""
     import json
     import os
 
