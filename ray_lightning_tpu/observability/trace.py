@@ -114,6 +114,11 @@ class _Span:
         self._t0 = time.perf_counter()
         return self
 
+    def set_metadata(self, **args) -> None:
+        """More arguments, known only once the span is open (the name and
+        the meaning of ``jax.profiler.TraceAnnotation.set_metadata``)."""
+        self._args = dict(self._args or (), **args)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._rec.add_span(
             self._name,
@@ -130,6 +135,9 @@ class _NoopSpan:
 
     def __enter__(self) -> "_NoopSpan":
         return self
+
+    def set_metadata(self, **args) -> None:
+        pass
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         return False
@@ -194,6 +202,10 @@ class _Both:
         self._span.__enter__()
         return self
 
+    def set_metadata(self, **args) -> None:
+        self._ann.set_metadata(**args)
+        self._span.set_metadata(**args)
+
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._span.__exit__(exc_type, exc, tb)
         self._ann.__exit__(exc_type, exc, tb)
@@ -210,7 +222,9 @@ def phase_span(name: str, **args):
     inert (one object built) while no trace is being taken. JAX is never
     imported from here, so a launcher's parent stays off it. While the ring
     recorder is on, the same name and arguments are recorded there too.
-    Names start with ``rlt.``; arguments are ints or short strings."""
+    Names start with ``rlt.``; arguments are ints or short strings. What is
+    known only inside the span is added by ``set_metadata(**args)`` of the
+    object the ``with`` binds, whichever of the three it is."""
     jax = sys.modules.get("jax")
     if jax is None:
         return span(name, **args)

@@ -83,6 +83,31 @@ so its dispatch needs them on the host: the same routine retires each step
 before the next is dispatched, as ever. What ``step()`` returns describes
 the tick it RETIRED, the one whose program it waited for.
 
+What ``stats`` says of the ticks, over every call and with no sync of its
+own (plain sums: a reader subtracts the value before its window):
+- ``ticks`` / ``tick_s`` / ``sync_wait_s`` / ``loop_wait_s``: the calls of
+  ``step()``, their wall time, the part of it waiting for the sampled tokens,
+  and the loop thread's time between calls.
+- ``decode_steps`` / ``overlapped_steps``: decode programs dispatched, and
+  those dispatched while the one before was unread.
+- ``starved_steps`` / ``starved_s``: the device STARVES when the tick in
+  flight is complete before the next program is enqueued: nothing is queued
+  and it idles until the dispatch lands. A call asks the array in flight
+  whether it is complete (no transfer, no wait) at its entry, behind the
+  schedule, and just before and at the return of its first dispatch, a
+  prefill's or the decode program's; a call that found it so counts one
+  step, and the time from the first such instant to the return of that
+  dispatch: a lower bound on the dry time. A call with nothing in flight (a
+  run's first, a speculating engine's, one after a time without requests)
+  counts nothing.
+- ``decode_cycles`` / ``decode_cycle_s`` / ``prefill_cycles`` /
+  ``prefill_cycle_s``: a CYCLE runs from the end of one retire's sync to the
+  end of the next, and goes to the ``prefill_*`` pair where the retired tick
+  enqueued prefills ahead of its decode program, else to ``decode_*``; one
+  that holds a wait for work is dropped. While the device does not starve a
+  cycle is the device's time for that tick's programs, so the sums say what
+  share of a window prefill takes, over every tick and with no profiler.
+
 Threading: ``submit`` is callable from any thread; ``start()`` spawns
 the loop thread, or call ``step()`` yourself for deterministic
 single-threaded driving (tests, bench). ``drain()`` stops admission and
@@ -461,6 +486,9 @@ class InferenceEngine:
         self._ticks = 0
         # the tick whose decode program is dispatched and not yet read
         self._inflight: Optional[_Tick] = None
+        # where the next tick cycle starts: the end of the last retire's
+        # sync; None where that cycle would hold a time without work
+        self._cycle_from: Optional[float] = None
         self._admit_seq = 0
         # request_id -> remaining-token budget armed by a drop-stream fault
         self._drop_stream: Dict[str, int] = {}
@@ -555,6 +583,17 @@ class InferenceEngine:
             "tick_s": 0.0,
             "sync_wait_s": 0.0,
             "loop_wait_s": 0.0,
+            # the starvation probe: calls whose first dispatch found the
+            # tick in flight complete (the device had nothing queued), and a
+            # lower bound on how long it had (the module docstring)
+            "starved_steps": 0,
+            "starved_s": 0.0,
+            # tick cycles, sync end to sync end, by whether the retired tick
+            # enqueued prefills ahead of its decode program
+            "decode_cycles": 0,
+            "decode_cycle_s": 0.0,
+            "prefill_cycles": 0,
+            "prefill_cycle_s": 0.0,
         }
         # what the model's decode step counts (routing, for one with
         # experts), summed over decode ticks: they come back in the array
@@ -1044,11 +1083,22 @@ class InferenceEngine:
         The tick and its phases are ``rlt.serve.*`` spans on the profiler's
         clock (``observability.phase_span``), and its wall time and its one
         wait for the device are summed into ``stats`` (``ticks``, ``tick_s``,
-        ``sync_wait_s``) whether or not anything is being traced."""
+        ``sync_wait_s``) whether or not anything is being traced. So are,
+        with no sync of their own, ``starved_steps`` / ``starved_s`` (the
+        calls that found the device with nothing queued at their first
+        dispatch: the host kept it waiting) and the tick cycles
+        ``decode_cycles`` / ``decode_cycle_s`` / ``prefill_cycles`` /
+        ``prefill_cycle_s`` (sync to sync, by whether the retired tick held
+        prefills): the module docstring says what starves and what a cycle
+        is. The spans say whose tick they belong to: ``prefills=`` is what
+        this call enqueued (``rlt.serve.tick``, ``decode_prep``,
+        ``decode_dispatch``) or what the retired tick had (``sample_sync``,
+        and ``retired_prefills=`` on ``rlt.serve.tick``); ``starved=0|1`` is
+        on the call's first dispatch where a tick was in flight."""
         t0 = time.perf_counter()
         try:
-            with _obs.phase_span("rlt.serve.tick", tick=self._ticks + 1):
-                return self._run_tick()
+            with _obs.phase_span("rlt.serve.tick", tick=self._ticks + 1) as span:
+                return self._run_tick(span)
         finally:
             self.stats["ticks"] += 1
             self.stats["tick_s"] += time.perf_counter() - t0
@@ -1082,7 +1132,31 @@ class InferenceEngine:
             self._prefill_fn(self.params, cache, prompt_row, where), None,
         ))
 
-    def _run_tick(self) -> Dict[str, Any]:
+    def _dry_since(self, since: Optional[float]) -> Optional[float]:
+        """The starvation probe. An engine that overlaps its ticks keeps one
+        dispatched tick unread; if that tick's output is complete before the
+        next program is enqueued, the device has nothing queued and idles
+        until the dispatch lands. Asks the array in flight whether it is
+        complete (no transfer, no wait) and returns the first instant it was
+        seen so: ``since`` if an earlier probe of this call saw it, else now,
+        else None (and None with nothing in flight: an idle engine is not a
+        starved device)."""
+        if since is not None or self._inflight is None:
+            return since
+        return time.perf_counter() if self._inflight.sampled.is_ready() else None
+
+    def _first_dispatched(self, span, dry: Optional[float]) -> None:
+        """At the return of a call's first dispatch, with a tick in flight:
+        the last probe (a dispatch takes a millisecond or more, and a tick
+        that completed under it left the device with nothing queued too),
+        the span's ``starved=`` and the two counters."""
+        dry = self._dry_since(dry)
+        span.set_metadata(starved=int(dry is not None))
+        if dry is not None:
+            self.stats["starved_steps"] += 1
+            self.stats["starved_s"] += time.perf_counter() - dry
+
+    def _run_tick(self, tick_span) -> Dict[str, Any]:
         import jax
         import jax.numpy as jnp
 
@@ -1099,21 +1173,28 @@ class InferenceEngine:
         # and dies, which is exactly the replica death the journal and
         # breakers must recover from
         _faults.fire_serve_tick_faults(self.replica_index, self._ticks)
+        # the starvation probe, at the instants the call passes anyway: here,
+        # behind the schedule, and just before and behind its first dispatch
+        dry = self._dry_since(None)
         with _obs.phase_span("rlt.serve.schedule"):
             self._process_export_actions()
             self._process_imports()
             self._evict_expired_slots()
             plan = self.scheduler.tick()
+        dry = self._dry_since(dry)
 
         new_exports: List[str] = []
         # (trace, dispatch start, dispatch end) of this tick's prefills:
         # their duration is known at the tick's sync, not at the enqueue
         prefill_traces: List[tuple] = []
-        for req, slot in plan.prefills:
+        for i, (req, slot) in enumerate(plan.prefills):
             rung = rung_for(self._rungs, req.prompt_len)
+            probing = i == 0 and self._inflight is not None
+            if probing:
+                dry = self._dry_since(dry)
             with _obs.phase_span(
                 "rlt.serve.prefill", prompt_len=req.prompt_len, rung=rung
-            ):
+            ) as span:
                 self._admit_seq += 1
                 fspec = _faults.serve_request_fault(
                     self.replica_index, self._admit_seq
@@ -1129,6 +1210,8 @@ class InferenceEngine:
                 self._dispatch_prefill(padded, self.pool.prompt_write_tables(
                     slot.index, self._blocks_of(rung)
                 ))
+                if probing:
+                    self._first_dispatched(span, dry)
                 if tr is not None:
                     prefill_traces.append((tr, t0, time.perf_counter()))
                 slot.pos = req.prompt_len - 1
@@ -1171,7 +1254,9 @@ class InferenceEngine:
         tick = _Tick(None, [], {}, len(plan.prefills), prefill_traces)
         if decode_slots:
             rows = len(decode_slots)
-            with _obs.phase_span("rlt.serve.decode_prep", rows=rows):
+            with _obs.phase_span(
+                "rlt.serve.decode_prep", rows=rows, prefills=tick.prefills
+            ):
                 # speculative tick (K > 0): every row carries its pending
                 # token plus up to K-1 prompt-lookup proposals; rows with no
                 # proposal (or at the end of their budget) ride the same
@@ -1254,10 +1339,18 @@ class InferenceEngine:
                     self._on_device(block_tables), sub,
                     *self._previous_output(self._inflight),
                 )
-            with _obs.phase_span("rlt.serve.decode_dispatch"):
+            # else a prefill was the call's first dispatch
+            probing = not tick.prefills and self._inflight is not None
+            if probing:
+                dry = self._dry_since(dry)
+            with _obs.phase_span(
+                "rlt.serve.decode_dispatch", prefills=tick.prefills
+            ) as span:
                 tick.sampled = self._update_pool(lambda cache: self._decode_fn(
                     self.params, cache, *inputs
                 )[::-1])
+                if probing:
+                    self._first_dispatched(span, dry)
             # the step is on its way: what follows from the positions alone
             # moves now, what needs the tokens when they are read
             for slot in decode_slots:
@@ -1293,6 +1386,9 @@ class InferenceEngine:
             with self._work:
                 self._ready_exports.extend(new_exports)
                 self._work.notify_all()
+        tick_span.set_metadata(
+            prefills=tick.prefills, retired_prefills=report["prefills"]
+        )
         return report
 
     def _retire(self, tick: _Tick, report: Dict[str, Any]) -> None:
@@ -1310,6 +1406,8 @@ class InferenceEngine:
             # all parked): all the host knows is the enqueue
             for tr, t0, t1 in tick.prefill_traces:
                 tr.prefilled(t1 - t0, done_at=t1, synced=False)
+            if tick.prefills:  # they run inside a later tick's cycle
+                self._cycle_from = None
             return
         rows = len(tick.rows)
         report["decoded"] += rows
@@ -1319,6 +1417,11 @@ class InferenceEngine:
             sampled_host = np.asarray(tick.sampled)  # the per-step sync point
         now = time.perf_counter()
         self.stats["sync_wait_s"] += now - t_sync
+        if self._cycle_from is not None:
+            kind = "prefill" if tick.prefills else "decode"
+            self.stats[kind + "_cycles"] += 1
+            self.stats[kind + "_cycle_s"] += now - self._cycle_from
+        self._cycle_from = now
         counters = self._model.counters
         if counters:  # behind the tokens, in the same array
             for name, value in zip(
@@ -1875,6 +1978,7 @@ class InferenceEngine:
             try:
                 with self._work:
                     if not self._tick_due():
+                        self._cycle_from = None  # no tick cycle holds a wait
                         with _obs.phase_span("rlt.serve.wait_work"):
                             while not self._tick_due():
                                 if self._stop_when_idle:

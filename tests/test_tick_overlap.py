@@ -91,6 +91,9 @@ class _Noting:
         self._note()
         return np.asarray(self.array)
 
+    def is_ready(self):  # the engine's starvation probe asks; no read
+        return self.array.is_ready()
+
 
 class _Recorder:
     """A stand-in for ``engine._decode_fn``: the real program, with every
