@@ -37,8 +37,6 @@ from ray_lightning_tpu.strategies.ray_strategies import (
     HorovodRayStrategy,
     RayShardedStrategy,
 )
-from ray_lightning_tpu import interop
-
 __version__ = "0.1.0"
 
 __all__ = [
@@ -72,3 +70,14 @@ __all__ = [
     "RayShardedStrategy",
     "interop",
 ]
+
+
+def __getattr__(name: str):
+    # PEP 562: the torch bridge imports torch, so it loads when somebody
+    # asks for it (``rlt.interop``, ``from ray_lightning_tpu import
+    # interop``), not with the package
+    if name == "interop":
+        import importlib
+
+        return importlib.import_module("ray_lightning_tpu.interop")
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -3,10 +3,7 @@ from ray_lightning_tpu.callbacks.checkpoint import ModelCheckpoint
 from ray_lightning_tpu.callbacks.early_stopping import EarlyStopping
 from ray_lightning_tpu.callbacks.throughput import ThroughputMonitor
 from ray_lightning_tpu.callbacks.profiler import ProfilerCallback
-from ray_lightning_tpu.callbacks.orbax_checkpoint import (
-    ORBAX_AVAILABLE,
-    OrbaxModelCheckpoint,
-)
+from ray_lightning_tpu.callbacks.orbax_checkpoint import OrbaxModelCheckpoint
 
 __all__ = [
     "Callback",
@@ -17,3 +14,12 @@ __all__ = [
     "OrbaxModelCheckpoint",
     "ORBAX_AVAILABLE",
 ]
+
+
+def __getattr__(name: str):
+    # worked out when asked for: the answer costs the import of orbax
+    if name == "ORBAX_AVAILABLE":
+        from ray_lightning_tpu.callbacks import orbax_checkpoint
+
+        return orbax_checkpoint.ORBAX_AVAILABLE
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -19,14 +19,22 @@ import numpy as np
 
 from ray_lightning_tpu import observability as obs
 from ray_lightning_tpu.callbacks.base import Callback
+from ray_lightning_tpu.utils.common import optional_import
 
-try:
-    import orbax.checkpoint as ocp
 
-    ORBAX_AVAILABLE = True
-except Exception:  # pragma: no cover
-    ocp = None
-    ORBAX_AVAILABLE = False
+def _ocp():
+    """``orbax.checkpoint``, imported at the first call and not with this
+    module: 12 s on a TPU host, ``google.cloud.logging`` most of it."""
+    ocp = optional_import("orbax.checkpoint")
+    if ocp is None:
+        raise RuntimeError("orbax-checkpoint is not installed")
+    return ocp
+
+
+def __getattr__(name: str):
+    if name == "ORBAX_AVAILABLE":
+        return optional_import("orbax.checkpoint") is not None
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 class OrbaxModelCheckpoint(Callback):
@@ -43,8 +51,7 @@ class OrbaxModelCheckpoint(Callback):
         max_to_keep: int = 3,
         async_save: bool = True,
     ):
-        if not ORBAX_AVAILABLE:
-            raise RuntimeError("orbax-checkpoint is not installed")
+        _ocp()  # a missing library is refused here, at construction
         self.dirpath = dirpath
         self.every_n_epochs = max(1, every_n_epochs)
         if every_n_steps is None:
@@ -79,6 +86,7 @@ class OrbaxModelCheckpoint(Callback):
         # every worker shares one filesystem in the paths that reach this.
         os.makedirs(os.path.abspath(self.dirpath), exist_ok=True)
         self._realign_barrier_counters()
+        ocp = _ocp()
         options = ocp.CheckpointManagerOptions(
             max_to_keep=self.max_to_keep,
             enable_async_checkpointing=self.async_save,
@@ -116,6 +124,7 @@ class OrbaxModelCheckpoint(Callback):
         self._save(trainer, step, epoch_complete=False)
 
     def _save(self, trainer, step: int, epoch_complete: bool) -> None:
+        ocp = _ocp()
         items = {"params": ocp.args.StandardSave(trainer._params)}
         if trainer._opt_state is not None:
             items["opt_state"] = ocp.args.StandardSave(trainer._opt_state)
@@ -202,7 +211,7 @@ class OrbaxModelCheckpoint(Callback):
         lack ``opt_state``.
         """
         dirpath = os.path.abspath(dirpath)
-        manager = ocp.CheckpointManager(dirpath)
+        manager = _ocp().CheckpointManager(dirpath)
         with obs.span("checkpoint/orbax_restore", dir=dirpath):
             return OrbaxModelCheckpoint._restore_with(
                 manager, dirpath, params_template, opt_state_template, step
@@ -210,6 +219,7 @@ class OrbaxModelCheckpoint(Callback):
 
     @staticmethod
     def _restore_with(manager, dirpath, params_template, opt_state_template, step):
+        ocp = _ocp()
         try:
             step = step if step is not None else manager.latest_step()
             if step is None:

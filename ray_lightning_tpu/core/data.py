@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import math
 import os
+import sys
 from typing import Any, Callable, Iterator, Optional, Sequence
 
 import numpy as np
@@ -194,22 +195,16 @@ def default_collate(items: Sequence[Any]):
         return {k: default_collate([it[k] for it in items]) for k in first}
     if isinstance(first, (tuple, list)):
         return type(first)(default_collate(list(col)) for col in zip(*items))
-    try:
-        import torch
-
-        if isinstance(first, torch.Tensor):
-            return np.stack([it.detach().cpu().numpy() for it in items])
-    except ImportError:
-        pass
+    # a sample can be a torch tensor only in a process that has loaded torch
+    torch = sys.modules.get("torch")
+    if torch is not None and isinstance(first, torch.Tensor):
+        return np.stack([it.detach().cpu().numpy() for it in items])
     return np.stack([np.asarray(it) for it in items])
 
 
 def _to_numpy_tree(batch):
     """Convert any torch tensors in a (possibly nested) batch to numpy."""
-    try:
-        import torch
-    except ImportError:
-        torch = None
+    torch = sys.modules.get("torch")
     if torch is not None and isinstance(batch, torch.Tensor):
         return batch.detach().cpu().numpy()
     if isinstance(batch, dict):

@@ -1,6 +1,8 @@
 """Misc shared helpers: rank-zero logging and optional-dependency sentinel."""
 from __future__ import annotations
 
+import functools
+import importlib
 import logging
 import os
 
@@ -19,6 +21,21 @@ def rank_zero_info(msg: str, *args) -> None:
 def rank_zero_warn(msg: str, *args) -> None:
     if _global_rank() == 0:
         logger.warning(msg, *args)
+
+
+@functools.lru_cache(maxsize=None)
+def optional_import(name: str):
+    """The module ``name``, or None where it cannot be imported.
+
+    For a library that only an optional integration needs: it is imported
+    at the first call that asks for it, when the integration is used, and
+    not with the package (TensorBoard's writer and orbax took 30 s of every
+    process's start on a TPU host). The answer is kept, so a library that
+    fails to import is tried once."""
+    try:
+        return importlib.import_module(name)
+    except Exception:
+        return None
 
 
 class Unavailable:

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import os
 import random
+import sys
 from typing import Optional
 
 import numpy as np
@@ -24,12 +25,14 @@ def seed_everything(seed: Optional[int] = None) -> int:
     os.environ[GLOBAL_SEED_ENV] = str(seed)
     random.seed(seed)
     np.random.seed(seed % (2**32))
-    try:
-        import torch
-
-        torch.manual_seed(seed)
-    except Exception:
-        pass
+    # torch is seeded where the process has loaded it; a run that never
+    # imported it has no torch generator to seed and does not load it here
+    torch = sys.modules.get("torch")
+    if torch is not None:
+        try:
+            torch.manual_seed(seed)
+        except Exception:
+            pass
     return seed
 
 
