@@ -10,9 +10,20 @@ Design (TPU-first):
 - forward: online softmax with fp32 scratch accumulators (acc/m/l)
   persisted across the innermost (KV) grid dimension — TPU grids iterate
   sequentially on a core, so scratch carries state between steps;
-- causal skipping: fully-masked blocks are skipped with pl.when on STATIC
-  grid indices (replaces round 1's dynamic fori_loop bound, a flagged
-  perf suspect);
+- the forward grid is (b, h, visited): `flash_schedule`, a pure function
+  of the shapes evaluated at trace time, lists the (q tile, kv tile) pairs
+  the mask admits, a q tile's pairs adjacent and in ascending kv order,
+  each marked first / last of its q tile. The lists ride as
+  scalar-prefetch operands and the index maps read them: no grid step is
+  empty. Every pair of a causal pass pays `_mask_scores` (skipping it on
+  the tiles the mask leaves whole gained nothing on the chip: PERF.md
+  section 6, PR 40). Where the schedule skips nothing (not causal, or a
+  sequence of one tile) or would not fit in scalar memory, the walk is the
+  rectangular grid (b, h, n_q, n_kv) itself and the same kernel reads its
+  place from the grid's indices, as the backward kernels do;
+- the backward kernels keep their rectangular grids: a tile the mask
+  empties is skipped with pl.when, its block index clamped so the skipped
+  step fetches nothing;
 - backward: recompute-based (no S x S materialization): a dQ kernel
   accumulating over KV blocks and a dK/dV kernel accumulating over Q
   blocks, seeded with the saved per-row logsumexp and
@@ -32,7 +43,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,13 +91,17 @@ def reference_attention(
 
 
 # --------------------------------------------------------------------- #
-# pallas forward: grid (b, h, n_q, n_kv), KV innermost; acc/m/l live in
-# fp32 VMEM scratch carried across the KV steps of one q block
+# pallas forward: grid (b, h, visited) over the schedule's pairs (or the
+# rectangular grid where that skips nothing); acc/m/l live in fp32 VMEM
+# scratch carried across the pairs of one q tile
 # --------------------------------------------------------------------- #
 def _mask_scores(s, qi, kj, block_q, block_k, causal, window):
     """Apply the causal (and optional sliding-window band) mask to one
     [BQ, BK] score block at grid position (qi, kj). Shared by all three
-    kernels so the mask cannot drift between forward and backward.
+    kernels so the mask cannot drift between forward and backward. Every
+    computed tile of a causal pass takes it, the ones it leaves whole too:
+    skipping it there gained nothing on the chip, forward or backward
+    (PERF.md section 6, PR 40).
 
     Windowed masking uses a large FINITE negative instead of -inf: an
     active block can contain rows whose band lies entirely outside it
@@ -110,25 +125,35 @@ def _mask_scores(s, qi, kj, block_q, block_k, causal, window):
 
 
 def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, l_ref, acc_scr, m_scr, l_scr,
-    *, scale, causal, window, block_q, block_k, n_kv,
+    *refs, scale, causal, window, block_q, block_k, n_kv,
 ):
+    """One (q tile, kv tile) step of the forward pass. `n_kv` None: the grid
+    is (b, h, visited) and the step's place and marks are pair `t` of the
+    schedule, three scalar-prefetch refs ahead of the others. Else the grid
+    is (b, h, n_q, n_kv) and they are read off its indices, each where the
+    kernel had it before there was a schedule (the program of a one-tile
+    sequence lowers to the same text as then)."""
     from jax.experimental import pallas as pl
 
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    on_grid = n_kv is not None
+    if on_grid:
+        qi, kj = pl.program_id(2), pl.program_id(3)
+    else:
+        q_tile_ref, kv_tile_ref, flags_ref, *refs = refs
+        t = pl.program_id(2)
+        qi, kj, flags = q_tile_ref[t], kv_tile_ref[t], flags_ref[t]
+    q_ref, k_ref, v_ref, o_ref, l_ref, acc_scr, m_scr, l_scr = refs
 
-    @pl.when(kj == 0)
+    @pl.when(kj == 0 if on_grid else flags & _FIRST != 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
 
-    # causal: skip blocks whose first kv index exceeds the last q index
-    # (and, under a window, blocks entirely below the band)
-    active = _block_active(qi, kj, block_q, block_k, causal, window)
-
-    @pl.when(active)
+    # on the grid a tile the mask empties is a step that does nothing; the
+    # schedule lists no such pair
+    @pl.when(_block_active(qi, kj, block_q, block_k, causal, window)
+             if on_grid else True)
     def _update():
         q = q_ref[:]  # [BQ, D] input dtype; dots accumulate in fp32
         ks = k_ref[:]
@@ -154,7 +179,7 @@ def _fwd_kernel(
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(kj == n_kv - 1)
+    @pl.when(kj == n_kv - 1 if on_grid else flags & _LAST != 0)
     def _finalize():
         l = l_scr[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -303,8 +328,9 @@ def _block_active(row_blk, col_blk, block_q: int, block_k: int, causal: bool,
                   window: Optional[int] = None):
     """Does q block `row_blk` intersect kv block `col_blk` under the causal
     (and optional sliding-window band) mask? (Trivially-true traced
-    predicate when not causal, so pl.when always receives a tracer.)
-    Shared by all three kernels."""
+    predicate when not causal, so the backward kernels' pl.when always
+    receives a tracer.) One definition for `flash_schedule` (numpy index
+    arrays) and the three kernels (traced grid indices)."""
     if causal:
         active = col_blk * block_k <= (row_blk + 1) * block_q - 1
         if window:
@@ -318,14 +344,60 @@ def _block_active(row_blk, col_blk, block_q: int, block_k: int, causal: bool,
     return col_blk >= 0
 
 
+# what flash_schedule marks on a pair
+_FIRST, _LAST = 1, 2
+
+
+class FlashSchedule(NamedTuple):
+    """The forward pass's walk over the tiles, from `flash_schedule`."""
+
+    q_tile: np.ndarray   # [visited] int32, the pair's q tile
+    kv_tile: np.ndarray  # [visited] int32, the pair's kv tile
+    flags: np.ndarray    # [visited] int32, _FIRST | _LAST
+    visited: int         # grid steps a (batch, head) pass takes
+    skipped: int         # steps of the rectangular grid that are no pair
+
+
+def flash_schedule(sq: int, skv: int, block_q: int, block_k: int, causal: bool,
+                   window: Optional[int] = None) -> FlashSchedule:
+    """The schedule of the forward pass, a pure function of the shapes
+    (numpy, at trace time): the (q tile, kv tile) pairs `_block_active`
+    admits, a q tile's pairs adjacent and in ascending kv order (the order
+    of the rectangular grid, so every running sum is formed as it is on
+    it), each marked `_FIRST` / `_LAST` of its q tile. Not causal: every
+    pair, nothing skipped.
+
+    Size: three int32 a pair in scalar memory (SMEM), which grows with the
+    square of the tiles a side: 36 pairs at 4,096 / 512 causal, 528 at
+    16,384, 32,896 at 131,072 (386 KiB of the 1 MiB a v5e core has; the
+    chip's compiler refuses 1.5 MiB). Past `_MAX_SCHEDULE_BYTES` (43,690
+    pairs: beyond 151,040 positions at 512 x 512 causal) `_flash_fwd` walks
+    the rectangular grid, whose skipped steps need no list."""
+    n_q, n_kv = sq // block_q, skv // block_k
+    qi, kj = np.meshgrid(np.arange(n_q), np.arange(n_kv), indexing="ij")
+    active = _block_active(qi, kj, block_q, block_k, causal, window)
+    q_tile, kv_tile = (a.astype(np.int32) for a in np.nonzero(active))
+    turns = q_tile[1:] != q_tile[:-1]
+    first = np.concatenate(([True], turns))
+    last = np.concatenate((turns, [True]))
+    flags = (first * _FIRST + last * _LAST).astype(np.int32)
+    return FlashSchedule(q_tile, kv_tile, flags, visited=len(q_tile),
+                         skipped=n_q * n_kv - len(q_tile))
+
+
+# half of the 1 MiB of scalar memory a v5e core has
+_MAX_SCHEDULE_BYTES = 512 * 1024
+
+
 def _kv_index_map(group: int, bq: int, bk: int, causal: bool,
                   window: Optional[int] = None):
-    """KV BlockSpec index map for grids (b, h, i, j). Under the causal mask,
-    masked steps CLAMP their kv index to the last active block (and, under
-    a sliding window, below-band steps clamp UP to the first active
-    block): revisiting the already-resident block elides the DMA, so
-    skipped steps cost neither compute (pl.when in the kernel) nor HBM
-    bandwidth."""
+    """KV BlockSpec index map for a rectangular grid (b, h, i, j): the dQ
+    kernel's, and the forward pass's where it walks no schedule. Under
+    the causal mask, masked steps CLAMP their kv index to the last active
+    block (and, under a sliding window, below-band steps clamp UP to the
+    first active block): revisiting the already-resident block elides the
+    DMA, so skipped steps cost neither compute (pl.when in the kernel) nor
+    HBM bandwidth."""
     if causal:
         def kv_idx(b_, h, i, j, g=group):
             hi = ((i + 1) * bq - 1) // bk
@@ -374,37 +446,61 @@ def _flash_fwd(q, k, v, causal, scale, interpret, blocks=None, window=None):
     group = hq // hkv
     skv = k.shape[2]
     bq, bk = _pick_blocks(sq, *(blocks or (None, None)))
-    n_kv = skv // bk
+    sched = flash_schedule(sq, skv, bq, bk, causal, window)
+    if sched.skipped and 3 * sched.flags.nbytes <= _MAX_SCHEDULE_BYTES:
+        # grid (b, h, t): pair t of the schedule, read from the prefetched lists
+        lists = (sched.q_tile, sched.kv_tile, sched.flags)
+        grid, n_kv = (b, hq, sched.visited), None
+
+        def q_idx(b_, h, t, q_tile, kv_tile, flags):
+            return b_, h, q_tile[t], 0
+
+        def kv_idx(b_, h, t, q_tile, kv_tile, flags):
+            return b_, h // group, kv_tile[t], 0
+    else:
+        # nothing to skip, or more pairs than scalar memory should hold: the
+        # rectangular grid, its empty steps (if any) skipped and clamped
+        lists = ()
+        n_kv = skv // bk
+        grid = (b, hq, sq // bq, n_kv)
+
+        def q_idx(b_, h, i, j):
+            return b_, h, i, 0
+
+        kv_idx = _kv_index_map(group, bq, bk, causal, window)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window, block_q=bq,
         block_k=bk, n_kv=n_kv,
     )
-    kv_idx = _kv_index_map(group, bq, bk, causal, window)
+
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b, hq, sq // bq, n_kv),
-        in_specs=[
-            pl.BlockSpec((None, None, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((None, None, bk, d), kv_idx),
-            pl.BlockSpec((None, None, bk, dv), kv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, bq, dv), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((None, None, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(lists),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((None, None, bq, d), q_idx),
+                pl.BlockSpec((None, None, bk, d), kv_idx),
+                pl.BlockSpec((None, None, bk, dv), kv_idx),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, bq, dv), q_idx),
+                pl.BlockSpec((None, None, bq, 1), q_idx),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, dv), jnp.float32),   # acc
+                pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
+                pltpu.VMEM((bq, 128), jnp.float32),  # running sum (lane-replicated)
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, dv), jnp.float32),   # acc
-            pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
-            pltpu.VMEM((bq, 128), jnp.float32),  # running sum (lane-replicated)
-        ],
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v)
+    )(*lists, q, k, v)
     return out, lse
 
 
@@ -549,10 +645,10 @@ def attention(
 
     window: sliding-window size W (static; requires causal): position i
     attends positions [i-W+1, i] — HF Mistral semantics. In the flash
-    path the band composes with the causal block skip (out-of-band
-    blocks cost neither compute nor DMA), so long-sequence work scales
-    O(S*W) instead of O(S^2). W >= S is a no-op and drops to plain
-    causal.
+    path the band is part of the tile schedule (an out-of-band tile is
+    no grid step of the forward pass and a skipped one of the backward),
+    so long-sequence work scales O(S*W) instead of O(S^2). W >= S is a
+    no-op and drops to plain causal.
     """
     sq, d = q.shape[2], q.shape[3]
     if window is not None:

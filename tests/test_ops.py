@@ -2,6 +2,7 @@
 reference implementations."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from ray_lightning_tpu.ops.attention import attention, reference_attention
@@ -371,3 +372,145 @@ def test_llama_config_flash_blocks_plumbed():
 
     small, _ = lm_loss(params, tokens, replace(cfg, flash_block_q=64, flash_block_k=64))
     assert abs(float(base) - float(small)) < 1e-3
+
+
+# ---------------------------------------------------------------------- #
+# the forward pass's schedule: a pure function of the shapes
+# ---------------------------------------------------------------------- #
+def _kept(s, causal, window):
+    """The mask itself, position by position: [s, s] bool."""
+    rows, cols = np.arange(s)[:, None], np.arange(s)[None, :]
+    if not causal:
+        return np.ones((s, s), bool)
+    keep = rows >= cols
+    if window:
+        keep &= (rows - cols) < window
+    return keep
+
+
+@pytest.mark.parametrize(
+    "s,bq,bk,causal,window",
+    [
+        (512, 64, 64, True, None),
+        (512, 128, 64, True, None),     # bq != bk, both ways
+        (512, 64, 128, True, None),
+        (512, 512, 512, True, None),    # one tile: one pair, nothing to skip
+        (512, 64, 64, False, None),
+        (512, 128, 64, False, None),
+        (1024, 64, 64, True, 256),      # a window of whole tiles
+        (1024, 64, 64, True, 200),      # no multiple of the tile
+        (1024, 64, 64, True, 40),       # both edges of the band in one tile
+        (1024, 64, 64, True, 1),
+        (1024, 128, 64, True, 200),
+        (1024, 64, 128, True, 200),
+        (1024, 128, 128, True, 129),
+    ],
+)
+def test_flash_schedule_is_the_masks_tiles(s, bq, bk, causal, window):
+    """Against the mask written out position by position: the visited
+    pairs are exactly the tiles with a kept score, a q tile's pairs are
+    adjacent and ascending, and first / last mark their ends."""
+    from ray_lightning_tpu.ops.attention import _FIRST, _LAST, flash_schedule
+
+    sched = flash_schedule(s, s, bq, bk, causal, window)
+    tiles = _kept(s, causal, window).reshape(s // bq, bq, s // bk, bk)
+    any_kept = tiles.any(axis=(1, 3))
+    pairs = list(zip(sched.q_tile.tolist(), sched.kv_tile.tolist()))
+    # row-major order IS "a q tile's pairs adjacent, kv ascending"
+    assert pairs == [tuple(p) for p in np.argwhere(any_kept).tolist()]
+    q_tiles = sched.q_tile.tolist()
+    assert ((sched.flags & _FIRST) != 0).tolist() == [
+        t == 0 or q_tiles[t - 1] != q for t, q in enumerate(q_tiles)]
+    assert ((sched.flags & _LAST) != 0).tolist() == [
+        t == len(q_tiles) - 1 or q_tiles[t + 1] != q
+        for t, q in enumerate(q_tiles)]
+    assert sched.visited == len(pairs)
+    assert sched.skipped == any_kept.size - len(pairs)
+    if not causal:
+        assert sched.skipped == 0
+
+
+@pytest.mark.parametrize(
+    "s,window,visited,skipped",
+    [
+        (4096, None, 36, 28),        # train-dense-4k
+        (16384, None, 528, 496),     # the doc cell's full layer
+        (16384, 4096, 252, 772),     # each of its three window layers
+    ],
+    ids=["train_4096", "doc_full_16384", "doc_window_4096"],
+)
+def test_flash_schedule_counts_at_the_cells_shapes(s, window, visited, skipped):
+    """How often the mechanism engages is static: the counts of the cells'
+    shapes at 512 x 512 tiles (docs/performance.md lists them)."""
+    from ray_lightning_tpu.ops.attention import flash_schedule
+
+    sched = flash_schedule(s, s, 512, 512, True, window)
+    assert (sched.visited, sched.skipped) == (visited, skipped)
+
+
+@pytest.mark.parametrize(
+    "s,blocks,causal,window,grid",
+    [
+        (131072, None, True, None, (1, 1, 32896)),   # 386 KiB: rides as it is
+        # past `_MAX_SCHEDULE_BYTES` the rectangular grid, at the tiles asked
+        # for and at any length they divide
+        (151552, None, True, None, (1, 1, 296, 296)),
+        (200192, None, True, None, (1, 1, 391, 391)),
+        (1048576, None, True, None, (1, 1, 2048, 2048)),
+        (1048576, None, True, 4096, (1, 1, 18396)),  # a band grows with s, not s^2
+        # nothing to skip: the schedule is the rectangular grid
+        (512, None, True, None, (1, 1, 1, 1)),       # one tile (the first rungs)
+        (128, (128, 128), True, None, (1, 1, 1, 1)),
+        (256, (128, 128), True, None, (1, 1, 3)),
+        (1024, None, False, None, (1, 1, 2, 2)),     # not causal
+    ],
+)
+def test_flash_fwd_walks_the_schedule_where_it_skips_and_fits(
+        s, blocks, causal, window, grid):
+    """The forward kernel's grid, read off the traced `pallas_call`: the
+    schedule's pairs with its three lists as scalar-prefetch operands, or
+    the rectangular grid and no list where the schedule skips nothing (one
+    tile lowers as before PR 40: a branch on a loaded mark cost 0.2-0.6 us a
+    step on the chip) or would pass `_MAX_SCHEDULE_BYTES` of scalar memory."""
+    from ray_lightning_tpu.ops.attention import _MAX_SCHEDULE_BYTES, _flash_fwd
+
+    x = jax.ShapeDtypeStruct((1, 1, s, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: _flash_fwd(
+        q, k, v, causal, 0.125, True, blocks, window))(x, x, x)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == grid
+    assert mapping.num_index_operands == (3 if len(grid) == 3 else 0)
+    if len(grid) == 3:
+        assert 3 * 4 * grid[2] <= _MAX_SCHEDULE_BYTES
+
+
+@pytest.mark.parametrize(
+    "s,w,bq,bk",
+    [
+        (256, 40, 64, 64),     # the band's lower edge and the diagonal in one tile
+        (256, 1, 64, 64),
+        (512, 200, 64, 64),    # no multiple of the tile, interior tiles between
+        (512, 200, 128, 64),
+        (512, 129, 64, 128),
+    ],
+)
+def test_flash_window_edges_inside_and_across_tiles(s, w, bq, bk):
+    """Windows whose lower edge shares a tile with the diagonal
+    (`window < bk`) and windows that are no multiple of the tile: forward
+    and all three gradients against the masked einsum."""
+    q, k, v = _qkv(1, 4, 2, s, 128)
+    ref = reference_attention(q, k, v, causal=True, window=w)
+    flash = lambda q, k, v: attention(  # noqa: E731
+        q, k, v, causal=True, window=w, impl="flash", interpret=True,
+        block_q=bq, block_k=bk)
+    assert float(jnp.max(jnp.abs(ref - flash(q, k, v)))) < 1e-4
+
+    def loss(fn):
+        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+
+    gr = jax.grad(loss(lambda q, k, v: reference_attention(
+        q, k, v, causal=True, window=w)), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-3

@@ -72,6 +72,14 @@ _Q = ((8, SMALL.n_heads, SMALL.max_seq, SMALL.head_dim), jnp.bfloat16)
 _KV = ((8, SMALL.n_kv_heads, SMALL.max_seq, SMALL.head_dim), jnp.bfloat16)
 
 
+# serve-swa-moe-doc at its 16,384 rung (benchmarks/configs/command-a-plus-d4-e16.json)
+_DOC_QKV = (
+    ((1, 128, 16384, 128), jnp.bfloat16),
+    ((1, 8, 16384, 128), jnp.bfloat16),
+    ((1, 8, 16384, 128), jnp.bfloat16),
+)
+
+
 def _flash(window=None):
     return lambda q, k, v: attention(
         q, k, v, causal=True, impl="flash", interpret=False, window=window
@@ -92,8 +100,18 @@ def _flash(window=None):
         ("window", _flash(window=512), (_Q, _KV, _KV), 1),
         # head_dim 64 is padded to the 128-lane tile inside the op
         ("head_dim64", _flash(), (((8, 12, 512, 64), jnp.bfloat16),) * 3, 1),
+        # the doc cell's longest rung: 128 heads over 8 key/value heads, a
+        # window layer's 252 pairs and the full layer's 528 in scalar memory
+        ("doc_window", _flash(window=4096), _DOC_QKV, 1),
+        ("doc_full", _flash(), _DOC_QKV, 1),
+        # the longest schedule that rides as it is: 32,896 pairs, 386 KiB of
+        # the core's 1 MiB of scalar memory; one q tile more a side and the
+        # forward pass walks the rectangular grid
+        ("pairs_32896", _flash(), (((1, 1, 131072, 128), jnp.bfloat16),) * 3, 1),
+        ("pairs_43956", _flash(), (((1, 1, 151552, 128), jnp.bfloat16),) * 3, 1),
     ],
-    ids=["fwd", "fwd+bwd", "window", "head_dim64"],
+    ids=["fwd", "fwd+bwd", "window", "head_dim64", "doc_window", "doc_full",
+         "pairs_32896", "pairs_43956"],
 )
 def test_flash_attention_compiles(one_chip, name, fn, shapes, kernels):
     assert _custom_calls(fn, *shapes, sharding=one_chip) == kernels
@@ -874,7 +892,12 @@ def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
     recorded the four ``serve_decode`` entries anew: the decode program takes
     the output of the decode program before it and a row's token from there
     where the host's is -1 (one ``select`` ahead of the model's step); every
-    ``serve_prefill@<rung>`` entry stands as it was."""
+    ``serve_prefill@<rung>`` entry stood as it was. PR 40 recorded the eleven
+    ``serve_prefill@<rung>`` entries of 1,024 positions and more anew:
+    ``flash_fwd`` inside them walks ``flash_schedule``'s pairs (a grid of
+    three axes and three scalar-prefetch operands in place of four axes); the
+    eight entries at 256 and 512 (one tile a sequence, nothing to skip, the
+    rectangular grid) and the four ``serve_decode`` entries stand."""
     import json
     import os
 
