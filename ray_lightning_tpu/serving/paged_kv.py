@@ -58,7 +58,10 @@ slot ``i``'s. ``acquire`` zeroes the slot's rows, prefill writes them once
 length), every decode tick updates them in place, and they are given up with
 the slot: whoever held the slot before, by whatever end (finished, expired,
 failed), the next request starts from zero. ``stats()`` reports
-``state.layers``, ``state.bytes_per_slot`` and ``state.slots_used``. Refused
+``state.layers``, ``state.leaves``, ``state.bytes_per_slot`` (all leaves
+together) and ``state.slots_used``. A model may state several state leaves of
+unlike shape and type (a state-space layer's scan state ``[N, C]`` float32 and
+its convolution's tail ``[K - 1, C]`` in the model's type). Refused
 over a state kind, by name, when the engine is built: prefix sharing (a
 shared block says nothing of the state behind it), speculation, KV migration.
 A leaf of the full kind may state any block shape: pooled keys, one a 16
@@ -801,7 +804,10 @@ class PagedKVPool:
                 + block, dtype)
             for name, (layers, block, dtype, kind) in stated.items()
         }
-        self.state_layers = sum(stated[k][0] for k in self.state_leaves)
+        # the layers that keep state: a layer's leaves (a scan state and a
+        # convolution tail) stand beside each other, so the most of any leaf
+        self.state_layers = max(
+            (stated[k][0] for k in self.state_leaves), default=0)
         self.state_bytes_per_slot = sum(
             int(self.cache[k].nbytes) // self.num_slots
             for k in self.state_leaves)
@@ -894,10 +900,14 @@ class PagedKVPool:
         if self._zero_fn is None:
             import jax
 
+            # one program over every state leaf, whatever their shapes and
+            # types: an admission is one dispatch, not one a leaf
             self._zero_fn = jax.jit(
-                lambda leaf, i: leaf.at[:, i].set(0), donate_argnums=(0,))
-        for name in self.state_leaves:
-            self.cache[name] = self._zero_fn(self.cache[name], np.int32(index))
+                lambda leaves, i: {
+                    k: leaf.at[:, i].set(0) for k, leaf in leaves.items()},
+                donate_argnums=(0,))
+        self.cache.update(self._zero_fn(
+            {k: self.cache[k] for k in self.state_leaves}, np.int32(index)))
 
     def release(self, index: int) -> Slot:
         slot = self.slots[index]
@@ -1045,6 +1055,7 @@ class PagedKVPool:
                         for k, v in kind.allocator.stats().items()})
         if self.state_leaves:
             out.update({"state.layers": self.state_layers,
+                        "state.leaves": len(self.state_leaves),
                         "state.bytes_per_slot": self.state_bytes_per_slot,
                         "state.slots_used": self.occupancy})
         return out

@@ -861,6 +861,115 @@ def test_the_sparse_linear_cells_programs_hold_their_kernels_by_name(long_cell_p
 
 
 # ---------------------------------------------------------------------- #
+# the state-space cell (benchmarks/configs/jamba2-3b.json,
+# workloads/serve-ssm-chat.json): two state leaves beside K and V, 256 slots
+# ---------------------------------------------------------------------- #
+SSM_CELL = "serve-ssm-chat"
+SSM_PREFILLS = [f"serve_prefill@{rung}" for rung in prefill_rungs(2048, 64)]
+SCAN_STATES_AT_2048 = 2048 * 5120 * 16 * 4  # one [L, C, N] float32 array: 671 MB
+
+
+@pytest.fixture(scope="module")
+def ssm_cell_programs(topo, one_chip):
+    compiled, leaves, pool_bytes = _compile_cell(SSM_CELL, topo, one_chip)
+    assert sorted(compiled) == sorted(["serve_decode", *SSM_PREFILLS])
+    return compiled, leaves, pool_bytes
+
+
+@pytest.mark.parametrize("program", ["serve_decode", *SSM_PREFILLS])
+def test_the_state_space_cells_programs_compile_and_fit_the_chip(ssm_cell_programs, program):
+    """256 slots, ``max_len`` 3,072, pages of 64: K and V 2 x [2, 12289, 1,
+    64, 128] bf16 (0.81 GB), the scan state [26, 256, 16, 5120] float32 (2.18
+    GB) and the convolution's tail [26, 256, 3 x 5120] bf16 (0.20 GB): 3.19 GB
+    beside 6.06 GB of weights. Every program (the rungs 256 to 2,048 and the
+    decode step) aliases the whole pool, both state leaves among it, copies
+    nothing of a leaf's size, and fits the chip's 16 GB with the weights and
+    its own temporaries. No prefill holds as much as ONE ``[L, 5120, 16]``
+    array of its rung's scan states (671 MB at 2,048): the scan's state
+    stays in the kernel."""
+    compiled, leaves, pool_bytes = ssm_cell_programs
+    assert 3.18e9 < pool_bytes < 3.21e9
+    exe = compiled[program]
+    found = _pool_sized_copies(exe.as_text(), leaves)
+    # prefill writes the slot's state and tail whole where they are, and the
+    # decode step one layer's tails: an update in place of the donated leaf,
+    # which the alias below holds it to
+    found = [f for f in found if not (
+        f.startswith("dynamic-update-slice ")
+        and ("f32[26,256,16,5120]" in f or "bf16[26,256,15360]" in f))]
+    if program == "serve_decode":
+        # the shift itself: one layer's tails (7.9 MB of the leaf's 204) read,
+        # shifted by the rows' inputs and written back where they were
+        found = [f for f in found if not any(
+            one in f for one in ("bf16[1,256,15360]", "bf16[256,15360]"))]
+    assert found == []
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < HBM_BYTES * 0.75
+    if program == "serve_decode":
+        assert mem.temp_size_in_bytes < pool_bytes / 10
+    else:
+        rung = int(program.split("@")[1])
+        assert mem.temp_size_in_bytes < SCAN_STATES_AT_2048 * rung / 2048
+
+
+def test_the_state_space_cells_programs_hold_their_kernels_by_name(ssm_cell_programs):
+    """The decode step moves the scan states on by ``mamba_decode``, once in
+    each of the three scanned runs of Mamba layers, and attends by
+    ``paged_decode_attention``, once an attention layer; prefill scans by
+    ``mamba_scan`` and attends by ``flash_fwd`` at every rung."""
+    compiled, _, _ = ssm_cell_programs
+    decode = compiled["serve_decode"].as_text()
+    assert decode.startswith("HloModule jit_serve_decode")
+    kernels = _kernel_instructions(decode)
+    assert set(kernels) == {"paged_decode_attention", "mamba_decode", "rmsnorm", "fused_argmax"}
+    assert kernels.count("mamba_decode") == 3 and kernels.count("paged_decode_attention") == 2
+    for program in SSM_PREFILLS:
+        text = compiled[program].as_text()
+        assert text.startswith("HloModule jit_serve_prefill")
+        kernels = _kernel_instructions(text)
+        assert set(kernels) == {"flash_fwd", "mamba_scan", "rmsnorm"}
+        assert kernels.count("mamba_scan") == 3 and kernels.count("flash_fwd") == 2
+    temps = [compiled[p].memory_analysis().temp_size_in_bytes for p in SSM_PREFILLS]
+    assert temps == sorted(temps)
+
+
+def test_mamba_decode_aliases_the_stack_of_states_at_the_cells_shapes(one_chip):
+    """The kernel alone at 256 rows of 5,120 channels over the stack of 26
+    layers' states: the stack goes in and comes out as one buffer (2.18 GB
+    aliased, no temporary of its size), the layer a traced scalar."""
+    from ray_lightning_tpu.ops.selective_scan import mamba_decode
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    exe = jax.jit(
+        lambda x, dt, b, c, a, d, states, layer: mamba_decode(
+            x, dt, b, c, a, d, states, layer, kernel=True, interpret=False),
+        donate_argnums=(6,),
+    ).lower(f32(256, 5120), f32(256, 5120), f32(256, 16), f32(256, 16), f32(16, 5120),
+            f32(5120), f32(26, 256, 16, 5120),
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    mem = exe.memory_analysis()
+    stack = 26 * 256 * 16 * 5120 * 4
+    assert mem.alias_size_in_bytes >= stack and mem.temp_size_in_bytes < stack / 100
+    assert _kernel_instructions(exe.as_text()) == ["mamba_decode"]
+
+
+@pytest.mark.parametrize("rung", [256, 2048])
+def test_mamba_scan_compiles_at_the_cells_rungs(one_chip, rung):
+    from ray_lightning_tpu.ops.selective_scan import mamba_scan
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    exe = jax.jit(
+        lambda x, dt, b, c, a, d, n: mamba_scan(
+            x, dt, b, c, a, d, n, kernel=True, interpret=False),
+    ).lower(f32(rung, 5120), f32(rung, 5120), f32(rung, 16), f32(rung, 16), f32(16, 5120),
+            f32(5120), jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    assert _kernel_instructions(exe.as_text()) == ["mamba_scan"]
+
+
+# ---------------------------------------------------------------------- #
 # the accepted serve cells' programs are the parent's
 # ---------------------------------------------------------------------- #
 def _fingerprint(fn, shapes):
