@@ -13,9 +13,11 @@ TPU-first design:
   writes every layer's post-rope (k, v) into the cache;
 - ONE layer body (``_layer``) serves ``prefill``, ``decode_step`` (the
   contiguous cache ``generate`` steps, and the tests' reference), the
-  engine's ``decode_step_paged`` and its speculative ``decode_step_verify``:
-  they differ in the ``attend`` they hand it — what is written to the
-  cache, what is read back and how — and in nothing else;
+  engine's ``decode_step_paged``, its speculative ``decode_step_verify``
+  and ``prefill_decode_step_paged`` (a prompt's positions and the decode
+  rows through the matrix products together: a tick that admits a prompt
+  reads the weights once): they differ in the ``attend`` they hand it — what
+  is written to the cache, what is read back and how — and in nothing else;
 - the decode loop is a single ``lax.scan`` over step index, so the host
   never round-trips per token;
 - attention at decode is a masked matvec over the cache (memory-bound;
@@ -54,7 +56,11 @@ from ray_lightning_tpu.models.llama import LlamaConfig
 from ray_lightning_tpu.ops.attention import attention, flash_supported
 from ray_lightning_tpu.ops.rmsnorm import rmsnorm
 from ray_lightning_tpu.ops.rope import rope_angles, rope_scaling_kind
-from ray_lightning_tpu.parallel.moe import moe_ffn_routed, route_softmax_top_k
+from ray_lightning_tpu.parallel.moe import (
+    moe_ffn_routed,
+    route_softmax_top_k,
+    routed_sizes,
+)
 
 # counters the paged decode step of a configuration with experts returns,
 # summed over its layers: distinct experts chosen, (row, expert) pairs, the
@@ -139,7 +145,8 @@ def _scanned_layers(params):
     return dict(layers, moe=rest), experts
 
 
-def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache, experts=None):
+def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache, experts=None,
+           counted=None):
     """One decoder layer at inference, written once for every serving
     function of the family. x: [..., D] — a prompt ``[B, P, D]``, one
     position a row ``[B, D]`` or K a row ``[B, K, D]``; cos/sin: the rope
@@ -162,7 +169,10 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache, experts=None):
     ``experts``: the expert stacks of all layers (``_scanned_layers``).
 
     Returns (x, cache, sizes): the rows each expert of the stack got from
-    this layer (``moe_ffn_routed``), None for a dense layer."""
+    this layer (``moe_ffn_routed``), None for a dense layer; from the tokens
+    ``counted`` alone (a slice of the rows ``[T]``) where it is given: a step
+    whose rows are a prompt's positions and the decode rows counts the
+    latter, as a decode step does."""
     hd = cfg.head_dim
     lead = x.shape[:-1]
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -182,9 +192,10 @@ def _layer(x, lp, cfg: LlamaConfig, cos, sin, attend, cache, experts=None):
         moe = lp["moe"]
         tokens = h2.reshape(-1, h2.shape[-1])  # [T, D]
         idx, weights = route_softmax_top_k(tokens, moe["router"], cfg.expert_top_k)
-        routed, sizes = moe_ffn_routed(
-            experts, tokens, idx, weights,
-            held=(0, moe["router"].shape[-1], moe["layer"]))
+        held = (0, moe["router"].shape[-1], moe["layer"])
+        routed, sizes = moe_ffn_routed(experts, tokens, idx, weights, held=held)
+        if counted is not None:
+            sizes = routed_sizes(idx[counted], sizes.shape[0], held)
         x = x + routed.reshape(x.shape)
     else:
         gated = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
@@ -257,7 +268,8 @@ cached_attention, flat_pages = _cached_attention, _flat_pages
 gather_pages, write_rows = _gather_pages, _write_rows
 
 
-def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache):
+def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache,
+                            counted=None):
     """``lax.scan`` of a paged decode step's layers with the pool as the
     loop's CARRY, k/v as ``_flat_pages`` lays them;
     ``attend(q, k, v, (k_flat, v_flat), first)`` as ``_layer`` asks,
@@ -266,7 +278,8 @@ def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache
     that. Returns (x, the pool in its own shape, the layers' routing summed
     into ``DECODE_COUNTERS``' [3] int32 or None for a dense configuration:
     over all rows of the step, free slots' dummy rows among them, which is
-    what the step computed). A pool scanned over
+    what the step computed; over the rows ``counted`` where ``_layer`` is
+    given them). A pool scanned over
     instead (an ``xs`` operand taken back as stacked ``ys``) is sliced a
     layer, copied for the kernel and stacked into a second buffer every
     step; carried, and donated by the caller's jit, the buffer that goes in
@@ -280,6 +293,7 @@ def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache
         x, pool, sizes = _layer(
             x, lp, cfg, cos, sin,
             functools.partial(attend, first=layer * n_pages), pool, experts,
+            counted,
         )
         return (x, pool), None if sizes is None else jnp.stack(
             [jnp.sum(sizes > 0), jnp.sum(sizes), jnp.max(sizes)]).astype(jnp.int32)
@@ -292,6 +306,25 @@ def _scan_layers_over_cache(x, params, cfg: LlamaConfig, cos, sin, attend, cache
     cache = {"k": k_flat.reshape(cache["k"].shape),
              "v": v_flat.reshape(cache["v"].shape)}
     return x, cache, None if counters is None else jnp.sum(counters, axis=0)
+
+
+def _prompt_attention(q, k, v, cfg: LlamaConfig) -> jnp.ndarray:
+    """Causal attention of a prompt's positions over themselves, as every
+    prefill of the family computes it. q: [B, H, P, hd]; k/v: [B, Hkv, P, hd],
+    post-rope."""
+    # prompts have arbitrary lengths; a config-pinned impl="flash"
+    # degrades to auto (which falls back to the einsum path) when the
+    # prompt shape is not block-tileable, instead of raising
+    impl = cfg.attn_impl
+    if impl == "flash" and not flash_supported(
+        q.shape, k.shape, cfg.flash_block_q or None,
+        cfg.flash_block_k or None,
+    ):
+        impl = None
+    return attention(q, k, v, causal=True, impl=impl,
+                     block_q=cfg.flash_block_q or None,
+                     block_k=cfg.flash_block_k or None,
+                     window=cfg.sliding_window or None)
 
 
 def prefill(
@@ -315,19 +348,7 @@ def prefill(
 
     def attend(q, k, v, _):
         q, k, v = (a.swapaxes(1, 2) for a in (q, k, v))  # [B, H, P, hd]
-        # prompts have arbitrary lengths; a config-pinned impl="flash"
-        # degrades to auto (which falls back to the einsum path) when the
-        # prompt shape is not block-tileable, instead of raising
-        impl = cfg.attn_impl
-        if impl == "flash" and not flash_supported(
-            q.shape, k.shape, cfg.flash_block_q or None,
-            cfg.flash_block_k or None,
-        ):
-            impl = None
-        att = attention(q, k, v, causal=True, impl=impl,
-                        block_q=cfg.flash_block_q or None,
-                        block_k=cfg.flash_block_k or None,
-                        window=cfg.sliding_window or None)
+        att = _prompt_attention(q, k, v, cfg)
         return att.swapaxes(1, 2), (k, v)  # the rows to cache, post-rope
 
     layers, experts = _scanned_layers(params)
@@ -508,12 +529,17 @@ def decode_step_paged(
     ``DECODE_COUNTERS``, over all B rows of the step and all layers; None
     for a dense one.
     """
-    from ray_lightning_tpu.ops.paged_attention import (
-        paged_decode_attention,
-        paged_kernel_enabled,
-    )
+    bs, (cos, sin) = _paged_step_setup(cfg, cache, block_tables, rope_table)
+    x = params["embed"][token]  # [B, D]
+    attend = _rows_attend(block_tables, pos, bs, kernel)
+    x, cache, counters = _scan_layers_over_cache(
+        x, params, cfg, cos[pos], sin[pos], attend, cache)
+    return _logits(x, params, cfg), cache, counters
 
-    use_kernel = paged_kernel_enabled() if kernel is None else bool(kernel)
+
+def _paged_step_setup(cfg: LlamaConfig, cache, block_tables, rope_table):
+    """What the paged steps of one position a row ask first: (the block
+    size, the rope table), a window refused."""
     if cfg.sliding_window:
         raise ValueError(
             "decode_step_paged requires dense-causal configs: a rolling "
@@ -524,9 +550,22 @@ def decode_step_paged(
     C = block_tables.shape[1] * bs  # logical positions served
     if rope_table is None:
         rope_table = _default_table_or_raise(cfg, max(C, cfg.max_seq))
-    cos, sin = rope_table
-    x = params["embed"][token]  # [B, D]
+    return bs, rope_table
 
+
+def _rows_attend(block_tables, pos, bs: int, kernel: Optional[bool]):
+    """The ``attend`` of rows at one position each (``_layer``,
+    ``_scan_layers_over_cache``): a row's (k, v) is written at ``pos`` through
+    its block table and its query reads positions ``<= pos`` through it, by
+    the kernel or the gather (``decode_step_paged``'s ``kernel``). q:
+    [B, H, hd]; returns (att [B, Hkv, G, hd] float32, the pool)."""
+    from ray_lightning_tpu.ops.paged_attention import (
+        paged_decode_attention,
+        paged_kernel_enabled,
+    )
+
+    use_kernel = paged_kernel_enabled() if kernel is None else bool(kernel)
+    C = block_tables.shape[1] * bs
     phys = jnp.take_along_axis(
         block_tables, (pos // bs)[:, None], axis=1
     )[:, 0]  # [B] physical block holding each row's write position
@@ -562,9 +601,83 @@ def decode_step_paged(
             )
         return att, (k_flat, v_flat)
 
+    return attend
+
+
+def _write_pages(
+    flat: jnp.ndarray, pages: jnp.ndarray, rows: jnp.ndarray
+) -> jnp.ndarray:
+    """Write a prompt's rows ``[Hkv, P, hd]`` (post-rope, in position order)
+    into the pages ``pages`` ``[ceil(P / bs)]`` of ``flat`` (``_flat_pages``;
+    counted through all layers), whole pages: positions past P in the last
+    page take zeros, which no query reads before a decode step has written
+    them. The window is one ``[bs, hd]`` page of a head, contiguous in the
+    row-major stack as ``_write_rows``' row is. Entries that name the same
+    page (the trash block, for a shared prefix and for padding) may land in
+    any order."""
+    hkv, p, hd = rows.shape
+    bs, n = flat.shape[1], pages.shape[0]
+    if n * bs > p:
+        rows = jnp.pad(rows, ((0, 0), (0, n * bs - p), (0, 0)))
+    new = rows.reshape(hkv, n, bs, hd).swapaxes(0, 1).reshape(n * hkv, bs, hd)
+    at = (pages[:, None] * hkv + jnp.arange(hkv, dtype=pages.dtype)).reshape(-1)
+    return flat.at[at].set(new.astype(flat.dtype))
+
+
+def prefill_decode_step_paged(
+    params: Dict[str, Any],
+    cache: Dict[str, jnp.ndarray],
+    prompt: jnp.ndarray,
+    write_table: jnp.ndarray,
+    token: jnp.ndarray,
+    pos: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    cfg: LlamaConfig,
+    rope_table: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+    kernel: Optional[bool] = None,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray], Optional[jnp.ndarray]]:
+    """One prompt's prefill and one ``decode_step_paged`` as ONE pass over the
+    layers: the engine's tick that admits a prompt. prompt: [1, P] int32 (P
+    the rung it was padded to); write_table: [ceil(P / block_size)] int32,
+    the physical block of each of its blocks (the trash block for a shared
+    prefix and for padding); token, pos, block_tables, cache, ``kernel`` and
+    the three results as ``decode_step_paged`` takes and gives them: logits
+    ``[B, V]`` of the decode rows (a prompt's own are never asked for),
+    counters over the decode rows.
+
+    The rows of every matrix product are the prompt's P positions followed by
+    the B decode rows, ``[P + B, D]`` through ``_layer`` as it is, so a
+    weight (an expert's among them) is read ONCE where the two programs read
+    it twice. ``attend`` alone splits: the prompt's rows attend each other
+    causally as ``prefill``'s do and their (k, v) go into the carried pool's
+    pages ``layer * N + write_table`` (``_write_pages``); THEN the decode
+    rows write their row and read through their tables
+    (``_rows_attend``), so the row just admitted, stepping the prompt's last
+    token again at ``P' - 1`` (P' the prompt's own length), reads what this
+    same pass wrote."""
+    bs, (cos, sin) = _paged_step_setup(cfg, cache, block_tables, rope_table)
+    p = prompt.shape[1]
+    x = params["embed"][jnp.concatenate([prompt[0], token])]  # [P + B, D]
+    at = jnp.concatenate([jnp.arange(p, dtype=pos.dtype), pos])
+    rows_attend = _rows_attend(block_tables, pos, bs, kernel)
+
+    def attend(q, k, v, pool, first):
+        qp, kp, vp = (a[:p].swapaxes(0, 1) for a in (q, k, v))  # [H, P, hd]
+        att = _prompt_attention(qp[None], kp[None], vp[None], cfg)[0]
+        pool = tuple(
+            _write_pages(flat, first + write_table, new)
+            for flat, new in zip(pool, (kp, vp))
+        )
+        rows, pool = rows_attend(q[p:], k[p:], v[p:], pool, first)
+        return jnp.concatenate([
+            att.swapaxes(0, 1).reshape(p, -1),
+            rows.reshape(token.shape[0], -1).astype(att.dtype),
+        ]), pool
+
     x, cache, counters = _scan_layers_over_cache(
-        x, params, cfg, cos[pos], sin[pos], attend, cache)
-    return _logits(x, params, cfg), cache, counters
+        x, params, cfg, cos[at], sin[at], attend, cache,
+        counted=slice(p, None))
+    return _logits(x[p:], params, cfg), cache, counters
 
 
 def decode_step_verify(
@@ -690,7 +803,12 @@ class LlamaServing:
 
     A model that speculates also has ``decode_verify(params, cache, tokens,
     pos, tables, table)`` -> (logits, cache); one that does not is refused
-    ``speculate_k`` by name when the engine is built."""
+    ``speculate_k`` by name when the engine is built. One whose prefill can
+    ride its decode step's matrix products has ``prefill_decode_paged(params,
+    cache, prompt_row, write_tables, token, pos, tables, table)`` ->
+    ``decode_paged``'s three results, the prompt's blocks written through
+    ``write_tables`` (``{kind: table}``, the engine's) on the way; the engine
+    then runs a tick that admits a prompt as that one program."""
 
     name = "Llama family (models/llama.py)"
     speculation = True
@@ -732,6 +850,13 @@ class LlamaServing:
     def decode_paged(self, params, cache, token, pos, tables, table):
         return decode_step_paged(
             params, cache, token, pos, tables["full"], self.cfg, table)
+
+    def prefill_decode_paged(
+        self, params, cache, prompt_row, write_tables, token, pos, tables, table
+    ):
+        return prefill_decode_step_paged(
+            params, cache, prompt_row, write_tables["full"], token, pos,
+            tables["full"], self.cfg, table)
 
     def decode_verify(self, params, cache, tokens, pos, tables, table):
         return decode_step_verify(

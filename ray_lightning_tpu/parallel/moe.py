@@ -192,6 +192,30 @@ def held_groups(idx: jnp.ndarray, first: int, count: int, layer=0) -> jnp.ndarra
     return jnp.where((local >= 0) & (local < count), layer * count + local, -1)
 
 
+def _pair_groups(idx: jnp.ndarray, e: int, held: Optional[Tuple[int, ...]]):
+    """(each routed pair's group in stacks of ``e`` experts, pair by pair,
+    with ``e`` for a pair whose expert is not held; which pairs are held)."""
+    if held is not None:
+        idx = held_groups(idx, *held)
+    flat = idx.reshape(-1)
+    here = (flat >= 0) & (flat < e)
+    return jnp.where(here, flat, e), here  # group E: the pairs that leave
+
+
+def _group_sizes(flat: jnp.ndarray, e: int) -> jnp.ndarray:
+    return jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+
+
+def routed_sizes(
+    idx: jnp.ndarray, e: int, held: Optional[Tuple[int, ...]] = None
+) -> jnp.ndarray:
+    """The rows each of ``e`` held experts gets from the pairs ``idx``
+    ``[T, K]``: :func:`moe_ffn_routed`'s ``sizes``, for a caller that counts
+    some of the tokens it routed (the decode rows of a step that carries a
+    prompt's positions too)."""
+    return _group_sizes(_pair_groups(idx, e, held)[0], e)
+
+
 def moe_ffn_routed(
     params: Dict[str, Any],
     xt: jnp.ndarray,
@@ -226,13 +250,9 @@ def moe_ffn_routed(
     held expert got, which is what the serving counters are made of)."""
     t, k = idx.shape
     e = params["w_gate"].shape[0]
-    if held is not None:
-        idx = held_groups(idx, *held)
-    flat = idx.reshape(t * k)
-    here = (flat >= 0) & (flat < e)
-    flat = jnp.where(here, flat, e)  # group E: the pairs that leave
+    flat, here = _pair_groups(idx, e, held)
     order = jnp.argsort(flat, stable=True)  # pair numbers, expert by expert
-    sizes = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)[:e]
+    sizes = _group_sizes(flat, e)
     xs = xt[order // k]  # [T*K, D]: each pair's token
     dt = xt.dtype
     h = (

@@ -2,7 +2,9 @@
 
 Two compiled programs, the first at a few lengths:
 
-- ``_prefill_fn`` — one jitted prefill of one prompt, [1, rung], where
+- ``_prefill_fn`` — one jitted prefill of one prompt, [1, rung] (at the
+  first rung of an engine whose model's prefill rides its decode step, the
+  prompt AND the decode rows: "a tick that admits a prompt", below), where
   the rung is the shortest of a short LADDER of lengths that holds the
   prompt (:func:`prefill_rungs`: doubling from 256 up to
   ``max_prompt_len``, the longest prompt admitted and always the last
@@ -56,6 +58,32 @@ idempotent cache write, same logits as prefill's last position), which
 is what lets prefill skip its logits head and keeps "first token" and
 "every other token" the same compiled program.
 
+A tick that admits a prompt. Run as two programs back to back, such a tick
+reads every weight twice: once for the prompt's rung of positions, once for
+the decode rows. Where the model's serving object provides
+``prefill_decode_paged`` (the Llama family does: the prompt's positions ride
+the decode rows' matrix products through one pass of the layers, its blocks
+written through the write table before the rows read), the engine does not
+speculate and its role is ``"both"``, ``_prefill_fn`` at the FIRST rung
+(``_fused_rung``) IS that step: under the same name ``serve_prefill``, in
+that rung's program's place, taking the prompt row and its write tables and
+then ``_decode_fn``'s own arguments, returning what ``_decode_fn`` returns.
+A tick whose prompt runs at that rung pads it and builds its write tables in
+``rlt.serve.prefill``, prepares the decode rows as ever and dispatches that
+ONE program in ``rlt.serve.decode_dispatch``; everything downstream (the
+tick in flight, the retire, the counters, the prefill traces) sees a decode
+step that happened to carry a prompt. ``stats["fused_prefill_steps"]``
+counts such ticks and ``rlt.serve.tick`` says ``fused=1``. A prompt of that
+rung that is not its tick's last (``max_prefills_per_tick > 1``) and the
+warm-up go through the same program with no row stepped: every slot rides
+as the trash block's padding row a free slot always is. The first rung
+alone: below it a prefill is bound by the weights' bytes, which is what the
+one program saves, most prompts run there, and each fused program costs
+set-up the trace of both its halves; the longer rungs keep the prompt's own
+program and their ticks the two. So does every other engine (another family,
+a speculating engine, a prefill or decode replica): the choice is made once,
+from what the code can see, and nothing sets it.
+
 The order of a tick. The next decode step's tokens are the array the last
 one returned, its positions are ``pos + 1`` whatever those tokens were, and
 the block a position is written to follows from the position alone. So a
@@ -90,6 +118,10 @@ own (plain sums: a reader subtracts the value before its window):
   and the loop thread's time between calls.
 - ``decode_steps`` / ``overlapped_steps``: decode programs dispatched, and
   those dispatched while the one before was unread.
+- ``prefills`` / ``fused_prefill_steps``: prompts prefilled, and the ticks
+  whose prompt and decode rows went out as one program (a decode step among
+  ``decode_steps`` like any other; the prefills at the first rung where a
+  tick admits one prompt, 0 for an engine of two programs a tick).
 - ``starved_steps`` / ``starved_s``: the device STARVES when the tick in
   flight is complete before the next program is enqueued: nothing is queued
   and it idles until the dispatch lands. A call asks the array in flight
@@ -558,6 +590,11 @@ class InferenceEngine:
             # was unread: the host's part of that tick ran under the device's
             "overlapped_steps": 0,
             "prefills": 0,
+            # ticks whose prompt and decode rows went out as ONE program (the
+            # module docstring: where the model's prefill rides its decode
+            # step, prompts of the first rung); 0 for an engine of two
+            # programs a tick
+            "fused_prefill_steps": 0,
             # the sum of the rungs those prefills ran at: what prefill
             # computed, padding and all, and the prompts' own tokens among
             # them (1 - prefill_tokens / prefill_positions is the padded
@@ -775,6 +812,21 @@ class InferenceEngine:
             )
             return sampled_of(logits, key, counters), cache
 
+        def prefill_decode_paged(
+            params, cache, prompt_row, write_table, token, pos, tables, key, prev
+        ):
+            # a tick that admits a prompt, as ONE program: the prompt's rung
+            # of positions rides the decode rows' matrix products (the
+            # model's own step; every weight is read once), its blocks go
+            # where write_table names as prefill_into_paged scatters them,
+            # and the output is decode_paged's
+            token = jnp.where(token < 0, prev[:num_slots], token)
+            logits, cache, counters = model.prefill_decode_paged(
+                params, cache, prompt_row, write_table, token, pos, tables,
+                table
+            )
+            return sampled_of(logits, key, counters), cache
+
         def decode_verify_paged(params, cache, tokens, pos, tables, key):
             # speculative verify: tokens is [num_slots, K] (pending token
             # + K-1 proposals), logits come back [S, K, V] and every
@@ -797,6 +849,41 @@ class InferenceEngine:
         # not one of the two tracked programs (a shape a block count)
         self._install_fn = jax.jit(install_blocks, donate_argnums=(0,))
         decode_fn = decode_verify_paged if spec_k > 0 else decode_paged
+        # Where the model's prefill can ride its decode step, and nothing
+        # stands between a prompt and its first step (a speculating engine
+        # retires a tick before it dispatches the next; a prefill replica
+        # parks the slot), the prefill program of the FIRST rung is that
+        # step, in the first rung's program's place under its name. The
+        # first rung alone: it is where the weights' bytes bound a prefill
+        # (FIRST_PREFILL_RUNG) and where most prompts run, and a fused
+        # program costs set-up the trace of both halves (PERF.md, PR 43: four
+        # of them took the batch cell's setup_s past its bound), so the
+        # longer rungs keep the prompt's own program.
+        self._fused_rung: Optional[int] = self._rungs[0] if (
+            hasattr(model, "prefill_decode_paged")
+            and spec_k == 0 and ecfg.role == "both"
+        ) else None
+
+        def prefill_fn(params, cache, prompt_row, write_table, *rows):
+            # the prompt alone, or with the decode rows behind it: by what
+            # the call hands over, an executable each
+            if not rows:
+                return prefill_into_paged(params, cache, prompt_row, write_table)
+            return prefill_decode_paged(
+                params, cache, prompt_row, write_table, *rows)
+
+        # The decode rows' arguments of the fused program where no row is
+        # stepped with the prompt (the warm-up; a prompt that is not its
+        # tick's last): every slot the padding row a free slot is, token 0 at
+        # position 0 of the trash block. What it samples is not read.
+        self._no_rows = (
+            jnp.zeros((num_slots,), jnp.int32), jnp.zeros((num_slots,), jnp.int32),
+            self._on_device({
+                kind: np.full_like(t, TRASH_BLOCK)
+                for kind, t in self.pool.program_tables().items()
+            }),
+            jax.random.key(0), self._no_previous,
+        ) if self._fused_rung else None
         # One prefill program, specialised by the prompt row's shape: an
         # executable a rung. The pool (argument 1, behind the parameters)
         # is donated to both:
@@ -806,7 +893,7 @@ class InferenceEngine:
         # the donation alone). Undonated, each would copy the whole pool to
         # change a few rows of it, and a tick would hold it two or three times.
         self._prefill_fn = _compile_cache.jit_program(
-            _with_precision(prefill_into_paged), "serve_prefill",
+            _with_precision(prefill_fn), "serve_prefill",
             donate_argnums=(1,)
         )
         self._decode_fn = _compile_cache.jit_program(
@@ -840,17 +927,17 @@ class InferenceEngine:
             token = jnp.zeros((self.pool.num_slots,), jnp.int32)
         pos = jnp.zeros((self.pool.num_slots,), jnp.int32)
         key = jax.random.key(0)
+        rows = (token, pos, self._on_device(self.pool.program_tables()), key,
+                *self._previous_output(None))
         prefills = tuple(
             ("serve_prefill", self._prefill_fn,
              (self.params, cache, jnp.zeros((1, rung), jnp.int32),
-              self._on_device(self._trash_table(rung))))
+              self._on_device(self._trash_table(rung)),
+              *(rows if rung == self._fused_rung else ())))
             for rung in self._rungs
         )
         return prefills + (
-            ("serve_decode", self._decode_fn,
-             (self.params, cache, token, pos,
-              self._on_device(self.pool.program_tables()), key,
-              *self._previous_output(None))),
+            ("serve_decode", self._decode_fn, (self.params, cache, *rows)),
         )
 
     def _previous_output(self, tick: Optional[_Tick]) -> tuple:
@@ -1066,7 +1153,10 @@ class InferenceEngine:
     # ------------------------------------------------------------------ #
     def step(self) -> Dict[str, Any]:
         """Run one scheduler tick: up to N prefills + one batched decode
-        dispatched, and one tick retired: the one dispatched by the call
+        dispatched (the last prefill and the decode as ONE program where the
+        model's prefill rides its decode step and the prompt runs at the
+        first rung: the module docstring, "a tick that admits a prompt"), and
+        one tick retired: the one dispatched by the call
         before this one, whose program the device ran meanwhile (a
         speculating engine retires the tick it just dispatched; see the
         module docstring on the order of a tick).
@@ -1094,7 +1184,9 @@ class InferenceEngine:
         this call enqueued (``rlt.serve.tick``, ``decode_prep``,
         ``decode_dispatch``) or what the retired tick had (``sample_sync``,
         and ``retired_prefills=`` on ``rlt.serve.tick``); ``starved=0|1`` is
-        on the call's first dispatch where a tick was in flight."""
+        on the call's first dispatch where a tick was in flight;
+        ``fused=0|1`` on ``rlt.serve.tick`` says whether this call's prompt
+        and rows went out as one program."""
         t0 = time.perf_counter()
         try:
             with _obs.phase_span("rlt.serve.tick", tick=self._ticks + 1) as span:
@@ -1121,16 +1213,25 @@ class InferenceEngine:
             self.pool.cache = cache
         return out
 
-    def _dispatch_prefill(self, padded: np.ndarray, where) -> None:
+    def _dispatch_prefill(self, padded: np.ndarray, where, rows=None):
         """Enqueue prefill of one padded prompt row [1, rung] into the
         physical blocks ``where`` names (one entry a block of the rung; one
-        such table a kind of leaf where the pool has several)."""
+        such table a kind of leaf where the pool has several). At the rung
+        whose program steps the decode rows too (``_fused_rung``), ``rows``
+        are that step's arguments as ``_decode_fn`` takes them behind the
+        pool, and its output is returned: the sampled tokens, unread.
+        ``rows`` None: no row is stepped (``_no_rows``)."""
         import jax.numpy as jnp
 
         prompt_row, where = jnp.asarray(padded), self._on_device(where)
-        self._update_pool(lambda cache: (
-            self._prefill_fn(self.params, cache, prompt_row, where), None,
-        ))
+        if padded.shape[1] != self._fused_rung:
+            return self._update_pool(lambda cache: (
+                self._prefill_fn(self.params, cache, prompt_row, where), None,
+            ))
+        rows = self._no_rows if rows is None else rows
+        return self._update_pool(lambda cache: self._prefill_fn(
+            self.params, cache, prompt_row, where, *rows
+        )[::-1])
 
     def _dry_since(self, since: Optional[float]) -> Optional[float]:
         """The starvation probe. An engine that overlaps its ticks keeps one
@@ -1187,9 +1288,15 @@ class InferenceEngine:
         # (trace, dispatch start, dispatch end) of this tick's prefills:
         # their duration is known at the tick's sync, not at the enqueue
         prefill_traces: List[tuple] = []
+        # the tick's last prompt where it runs at the fused rung, padded and
+        # with its write tables, until the decode rows it goes out with are
+        # prepared (its own slot is one of them: a request has a token to
+        # give, and an engine with such a rung parks no slot)
+        held: Optional[tuple] = None
         for i, (req, slot) in enumerate(plan.prefills):
             rung = rung_for(self._rungs, req.prompt_len)
-            probing = i == 0 and self._inflight is not None
+            hold = rung == self._fused_rung and i == len(plan.prefills) - 1
+            probing = i == 0 and self._inflight is not None and not hold
             if probing:
                 dry = self._dry_since(dry)
             with _obs.phase_span(
@@ -1207,9 +1314,13 @@ class InferenceEngine:
                 padded[0, : req.prompt_len] = req.tokens
                 tr = req.trace
                 t0 = time.perf_counter() if tr is not None else 0.0
-                self._dispatch_prefill(padded, self.pool.prompt_write_tables(
+                where = self.pool.prompt_write_tables(
                     slot.index, self._blocks_of(rung)
-                ))
+                )
+                if hold:
+                    held = (padded, where)
+                else:
+                    self._dispatch_prefill(padded, where)
                 if probing:
                     self._first_dispatched(span, dry)
                 if tr is not None:
@@ -1340,15 +1451,21 @@ class InferenceEngine:
                     *self._previous_output(self._inflight),
                 )
             # else a prefill was the call's first dispatch
-            probing = not tick.prefills and self._inflight is not None
+            probing = self._inflight is not None and (
+                not tick.prefills or (held is not None and tick.prefills == 1))
             if probing:
                 dry = self._dry_since(dry)
             with _obs.phase_span(
                 "rlt.serve.decode_dispatch", prefills=tick.prefills
             ) as span:
-                tick.sampled = self._update_pool(lambda cache: self._decode_fn(
-                    self.params, cache, *inputs
-                )[::-1])
+                if held is not None:  # the prompt and the rows: one program
+                    tick.sampled = self._dispatch_prefill(*held, rows=inputs)
+                    self.stats["fused_prefill_steps"] += 1
+                else:
+                    tick.sampled = self._update_pool(
+                        lambda cache: self._decode_fn(
+                            self.params, cache, *inputs
+                        )[::-1])
                 if probing:
                     self._first_dispatched(span, dry)
             # the step is on its way: what follows from the positions alone
@@ -1387,7 +1504,8 @@ class InferenceEngine:
                 self._ready_exports.extend(new_exports)
                 self._work.notify_all()
         tick_span.set_metadata(
-            prefills=tick.prefills, retired_prefills=report["prefills"]
+            prefills=tick.prefills, retired_prefills=report["prefills"],
+            fused=int(held is not None),
         )
         return report
 

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from ray_lightning_tpu import observability as obs
+from ray_lightning_tpu.models.generation import LlamaServing
 from ray_lightning_tpu.models.llama import LlamaConfig, init_params
 from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
 
@@ -35,6 +36,16 @@ def model():
     return init_params(jax.random.key(0), cfg), cfg
 
 
+@pytest.fixture(params=["one_program", "two_programs"])
+def fused(request, monkeypatch):
+    """Whether a tick that admits a prompt is one program (the family's
+    prefill rides its decode step) or the two it was: the family's method
+    taken away, which is all an engine looks at."""
+    if request.param == "two_programs":
+        monkeypatch.delattr(LlamaServing, "prefill_decode_paged")
+    return request.param == "one_program"
+
+
 def _engine(model, **kw):
     params, cfg = model
     kw = dict(dict(num_slots=2, max_prompt_len=12, max_len=32, block_size=4), **kw)
@@ -55,15 +66,23 @@ class _Output:
 
 
 def _stub_ready(engine, ready):
-    """Every decode program's output says ``ready`` when asked."""
-    fn = engine._decode_fn
+    """Every decode step's output says ``ready`` when asked, whichever
+    program carried the step: ``_decode_fn``, or ``_prefill_fn`` where a tick
+    that admits a prompt is one program (its last argument is then the
+    output before it, as the decode program's is)."""
 
-    def call(params, cache, token, pos, tables, key, *prev):
-        prev = tuple(getattr(p, "array", p) for p in prev)
-        sampled, cache = fn(params, cache, token, pos, tables, key, *prev)
-        return _Output(sampled, ready), cache
+    def stubbed(fn):
+        def call(params, cache, *rest):
+            if engine._speculate_k == 0:
+                rest = rest[:-1] + (getattr(rest[-1], "array", rest[-1]),)
+            sampled, cache = fn(params, cache, *rest)
+            return _Output(sampled, ready), cache
 
-    engine._decode_fn = call
+        return call
+
+    engine._decode_fn = stubbed(engine._decode_fn)
+    if engine._fused_rung:
+        engine._prefill_fn = stubbed(engine._prefill_fn)
 
 
 def _run(engine, requests=(([1, 2, 3], 6), ([4, 5, 6, 7], 4))):
@@ -83,10 +102,14 @@ def test_a_fresh_engine_has_the_counters_at_zero(model):
     assert {k: stats[k] for k in NEW} == dict.fromkeys(NEW, 0)
 
 
-def test_cycles_are_at_most_the_decode_programs_and_inside_the_calls_time(model):
+def test_cycles_are_at_most_the_decode_programs_and_inside_the_calls_time(model, fused):
+    """A tick whose prompt went out with its rows is one decode step and one
+    cycle, and the cycle is a prefill's."""
     s = _run(_engine(model))
     n = s["decode_steps"]
     assert n == 6  # the first request's six steps; the second rides four of them
+    assert s["fused_prefill_steps"] == (s["prefills"] if fused else 0)
+    assert (s["prefills"], s["overlapped_steps"]) == (2, n - 1)
     # the first retire of a run has no sync before it to start a cycle from
     assert s["decode_cycles"] + s["prefill_cycles"] == n - 1
     # the first tick's prefill is that retire's; the second request's counts
@@ -96,11 +119,13 @@ def test_cycles_are_at_most_the_decode_programs_and_inside_the_calls_time(model)
 
 
 @pytest.mark.parametrize("case", ["ready", "unready", "speculating"])
-def test_the_probe_counts_a_dispatch_whose_tick_in_flight_was_complete(model, case):
+def test_the_probe_counts_a_dispatch_whose_tick_in_flight_was_complete(model, case, fused):
     """Stubbed ready, every overlapped dispatch found the device with
-    nothing queued; stubbed unready, none did; a speculating engine has
-    nothing in flight to ask."""
+    nothing queued (a tick of one program counts once, as one of two does);
+    stubbed unready, none did; a speculating engine has nothing in flight to
+    ask, and runs two programs a tick whatever the family provides."""
     engine = _engine(model, speculate_k=2 if case == "speculating" else 0)
+    assert (engine._fused_rung == 12) == (fused and case != "speculating")
     _stub_ready(engine, case != "unready")
     s = _run(engine, requests=(([5, 9, 5, 9, 5], 6), ([4, 4, 4, 4], 4)))
     if case == "ready":
@@ -145,6 +170,8 @@ def test_a_cycle_across_a_wait_for_work_is_dropped(model):
     engine.run_until_idle()  # compiled, and a sync to start a cycle from
     base = dict(engine.stats)
     engine.start()
+    while engine._cycle_from is not None:  # until the loop waits for work
+        time.sleep(0.01)
     try:
         engine.submit([1, 2, 3, 4], max_new_tokens=5).result(timeout=60)
         time.sleep(0.3)  # the loop waits for work
@@ -173,11 +200,12 @@ def test_a_tick_of_prefills_alone_starts_no_cycle(model):
     assert engine._cycle_from is None
 
 
-def test_the_spans_say_whose_tick_they_are(model):
+def test_the_spans_say_whose_tick_they_are(model, fused):
     """Through the ring recorder: ``prefills=`` on the tick, the prepare and
     the dispatch is what the call enqueued, on the sync what the retired
     tick had; ``starved=`` sits on a call's first dispatch where a tick was
-    in flight, and nowhere else."""
+    in flight, and nowhere else: the prefill's, or the one dispatch of a tick
+    whose prompt and rows are one program, which says ``fused=1``."""
     rec = obs.enable()
     engine = _engine(model)
     _stub_ready(engine, True)
@@ -194,13 +222,15 @@ def test_the_spans_say_whose_tick_they_are(model):
     enqueued = [c["tick"]["prefills"] for c in calls]
     assert enqueued == [1, 0, 1, 0, 0, 0, 0]
     assert [c["tick"]["retired_prefills"] for c in calls] == [0] + enqueued[:-1]
+    assert [c["tick"]["fused"] for c in calls] == [n * fused for n in enqueued]
+    assert engine.stats["fused_prefill_steps"] == 2 * fused
     for i, c in enumerate(calls):
         for phase in ("decode_prep", "decode_dispatch"):
             if phase in c:
                 assert c[phase]["prefills"] == enqueued[i]
         if "sample_sync" in c:
             assert c["sample_sync"]["prefills"] == c["tick"]["retired_prefills"]
-        first = "prefill" if enqueued[i] else "decode_dispatch"
+        first = "prefill" if enqueued[i] and not fused else "decode_dispatch"
         if i == 0 or first not in c:  # nothing in flight, or no dispatch
             assert all("starved" not in args for args in c.values())
         else:

@@ -449,3 +449,104 @@ def test_decode_step_rejects_midsized_cache_under_sliding_window():
     table = rope_angles(16, cfg.head_dim, cfg.rope_theta,
                         scaling=cfg.rope_scaling)
     decode_step(params, bad, token, jnp.int32(0), cfg, rope_table=table)
+
+
+# --------------------------------------------------------------------- #
+# a prompt's prefill riding the decode rows' step (the engine's tick that
+# admits a prompt, as one program)
+# --------------------------------------------------------------------- #
+def _fused_case(case, cfg, rng):
+    """(prompt row [1, rung], its length, its write table, the decode rows'
+    token / pos / tables, what an earlier request left in the pool) over a
+    pool of 24 blocks of 4, block 0 the trash block."""
+    bs, slots, columns = 4, 3, 8
+    rung, own = (10, 9) if case == "ragged-rung" else (8, 6)
+    prompt = np.zeros((1, rung), np.int32)
+    prompt[0, :own] = rng.integers(1, cfg.vocab_size, own)
+    write = np.array([5, 6, 0][: -(-rung // bs)], np.int32)
+    if case == "ragged-rung":
+        write[2] = 7  # the prompt's ninth position is its third block's
+    tables = np.zeros((slots, columns), np.int32)
+    token, pos = np.zeros((slots,), np.int32), np.zeros((slots,), np.int32)
+    before = None
+    if case != "no-live-row":
+        # slot 0 is the one just admitted: its first step feeds the prompt's
+        # last token again at own - 1; slot 1 is eleven positions into
+        # another request; slot 2 is free (the trash block, token 0 at 0)
+        tables[0, : len(write)] = write
+        tables[1, :3] = [9, 10, 11]
+        token[:2], pos[:2] = [prompt[0, own - 1], 11], [own - 1, 10]
+        before = (np.array([9, 10, 11], np.int32), rng.integers(
+            1, cfg.vocab_size, (1, 12)).astype(np.int32))
+    if case == "shared-prefix":
+        # the prompt's first block is an earlier request's, shared: it is
+        # read through the table where that request wrote it (block 12) and
+        # this prompt's copy of it goes to the trash block
+        write[0], tables[0, 0] = 0, 12
+        before = (np.array([12, 0, 0], np.int32), np.concatenate(
+            [prompt[:, :bs], np.zeros((1, 8), np.int32)], axis=1))
+    return prompt, own, write, token, pos, tables, before
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
+@pytest.mark.parametrize(
+    "case", ["rows", "shared-prefix", "no-live-row", "ragged-rung"])
+@pytest.mark.parametrize("kind", ["dense", "experts"])
+def test_prefill_decode_paged_is_prefill_blocks_then_decode_paged(kind, case, kernel):
+    """``LlamaServing.prefill_decode_paged`` (one pass over the layers, the
+    prompt's rung of positions in front of the decode rows) against the two
+    programs it stands for, on one pool: ``prefill_blocks`` scattered through
+    the write table, then ``decode_paged``. The pool's pages but the trash
+    block's are equal, the decode rows' logits are within the paged tests'
+    tolerance and their greedy tokens equal: the row just admitted, whose
+    first step reads what this same pass wrote, a row deep in another
+    request, a free slot; a write table with trash entries for a shared
+    prefix; a call with no live row at all (the first request of a run, the
+    warm-up); a last rung that is no whole number of blocks. The experts'
+    counters are the decode rows' alone, as a decode step's are."""
+    from ray_lightning_tpu.models.generation import (
+        decode_step_paged,
+        prefill_decode_step_paged,
+    )
+
+    cfg, params = _layer_body_model(kind)
+    serving, bs = cfg.serving(), 4
+    rng = np.random.default_rng(11)
+    prompt, own, write, token, pos, tables, before = _fused_case(case, cfg, rng)
+    table = serving.rope_table(32)
+    pool = {
+        name: jnp.asarray(rng.normal(size=(layers, 24) + block), dtype)
+        for name, (layers, block, dtype) in serving.paged_block_leaves(bs).items()
+    }
+
+    def scattered(pool, row, where):
+        blocks = serving.prefill_blocks(
+            params, jnp.asarray(row), len(where), bs, table)
+        return {k: pool[k].at[:, jnp.asarray(where)].set(blocks[k]) for k in pool}
+
+    if before is not None:  # what the rows that go on hold already
+        pool = scattered(pool, before[1], before[0])
+    args = (jnp.asarray(token), jnp.asarray(pos), jnp.asarray(tables), cfg, table)
+    want_logits, want_pool, want_counters = decode_step_paged(
+        params, scattered(pool, prompt, write), *args, kernel=kernel)
+    logits, got_pool, counters = jax.jit(
+        lambda pool, row, where: prefill_decode_step_paged(
+            params, pool, row, where, *args, kernel=kernel)
+    )(pool, jnp.asarray(prompt), jnp.asarray(write))
+
+    for name in pool:  # block 0 takes whatever order its writers land in
+        np.testing.assert_allclose(
+            np.asarray(got_pool[name])[:, 1:], np.asarray(want_pool[name])[:, 1:],
+            atol=1e-5)
+    live = [0, 1] if case != "no-live-row" else []
+    assert np.abs(np.asarray(logits) - np.asarray(want_logits))[live].max(
+        initial=0.0) < 1e-3
+    assert (np.argmax(np.asarray(logits), -1)[live]
+            == np.argmax(np.asarray(want_logits), -1)[live]).all()
+    assert logits.shape == (3, cfg.vocab_size)
+    if kind == "experts":
+        np.testing.assert_array_equal(np.asarray(counters), np.asarray(want_counters))
+        # three rows a layer, each its top-k pairs: none of the prompt's
+        assert int(counters[1]) == 3 * cfg.n_layers * cfg.expert_top_k
+    else:
+        assert counters is None and want_counters is None

@@ -209,22 +209,28 @@ def test_prefill_positions_is_the_sum_of_the_rungs_used(model):
 
 def test_a_wrapper_round_prefill_fn_sees_every_prefill_at_its_rung(model):
     """The serve driver's traced run replaces ``engine._prefill_fn`` with a
-    wrapper: it is still ONE attribute with the call ``(params, cache,
+    wrapper: it is still ONE attribute whose call starts ``(params, cache,
     prompt_row, where)`` (``where``: a write table a kind of leaf), so
-    the wrapper sees every prefill, whatever its rung."""
+    the wrapper sees every prefill, whatever its rung. At the rung whose
+    program steps the decode rows too (PR 43: the first, in an engine of the
+    Llama family), that step's arguments follow, as ``_decode_fn`` takes
+    them; the longer rungs' calls are the prompt's alone."""
     _, params, cfg = model
     engine = InferenceEngine(params, cfg, EngineConfig(**ENGINE))
     engine.warmup()
     inner, seen = engine._prefill_fn, []
 
-    def spanned(params, cache, prompt_row, where):
-        seen.append((prompt_row.shape, where["full"].shape))
-        return inner(params, cache, prompt_row, where)
+    def spanned(params, cache, prompt_row, where, *rows):
+        seen.append((prompt_row.shape, where["full"].shape, len(rows)))
+        return inner(params, cache, prompt_row, where, *rows)
 
     engine._prefill_fn = spanned
     _serve(engine, _prompts(cfg, (5, 300, 900)))
-    assert seen == [((1, 256), (16,)), ((1, 512), (32,)), ((1, 1024), (64,))]
+    with_rows = 5 * (engine._fused_rung == 256)  # token, pos, tables, key, prev
+    assert seen == [((1, 256), (16,), with_rows), ((1, 512), (32,), 0),
+                    ((1, 1024), (64,), 0)]
     assert engine.stats["prefills"] == 3
+    assert engine.stats["fused_prefill_steps"] == (with_rows > 0)
 
 
 def test_a_program_called_at_several_shapes_keeps_an_executable_for_each():

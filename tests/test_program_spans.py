@@ -180,16 +180,22 @@ def test_a_step_that_raises_half_way_leaves_no_span_open(model, tmp_path):
     engine = _engine(model)
     engine.submit([1, 2, 3], max_new_tokens=2)
     engine.run_until_idle()
-    decode = engine._decode_fn
     calls = {"n": 0}
 
-    def failing_decode(*args):
-        calls["n"] += 1
-        if calls["n"] == 2:
-            raise RuntimeError("boom")
-        return decode(*args)
+    def failing(program):
+        def call(*args):
+            calls["n"] += 1
+            if calls["n"] == 2:
+                raise RuntimeError("boom")
+            return program(*args)
 
-    engine._decode_fn = failing_decode
+        return call
+
+    # the first call's prompt and its row go out as one program, the second
+    # call's decode program is the one that raises
+    assert engine._fused_rung
+    engine._prefill_fn = failing(engine._prefill_fn)
+    engine._decode_fn = failing(engine._decode_fn)
     base = engine.stats["ticks"]
 
     def work():

@@ -852,3 +852,130 @@ def test_shutdown_mid_stream_suppresses_late_tokens(model):
     with pytest.raises(EngineClosed):
         engine.submit([1, 2], max_new_tokens=2)
     engine.shutdown(drain=False)  # second shutdown: idempotent no-op
+
+
+# --------------------------------------------------------------------- #
+# a tick that admits a prompt is ONE program where the family's prefill
+# rides its decode step (PR 43): the same streams as the two programs
+# --------------------------------------------------------------------- #
+def _tick_programs_model(kind):
+    base = LlamaConfig.tiny() if kind == "dense" else LlamaConfig.tiny_moe()
+    cfg = dataclasses.replace(base, dtype=jnp.float32)
+    return init_params(jax.random.key(2), cfg), cfg
+
+
+def _staggered_streams(params, cfg, **engine):
+    """Nine requests of mixed lengths behind two shared prefixes through
+    three slots, four before the first tick and the rest while those decode:
+    (the engine, each request's tokens)."""
+    settings = dict(num_slots=3, max_prompt_len=12, max_len=32, block_size=4)
+    engine = InferenceEngine(params, cfg, EngineConfig(**dict(settings, **engine)))
+    rng = np.random.default_rng(5)
+    prefixes = ([7, 3, 9, 4], [2, 8, 6, 5, 1, 1, 3, 2], [])
+    reqs = [
+        (prefixes[i % 3]
+         + [int(t) for t in rng.integers(1, cfg.vocab_size, rng.integers(1, 5))],
+         int(rng.integers(1, 9)))
+        for i in range(9)
+    ]
+    done = [engine.submit(p, max_new_tokens=n) for p, n in reqs[:4]]
+    for _ in range(5):
+        engine.step()
+    done += [engine.submit(p, max_new_tokens=n) for p, n in reqs[4:]]
+    engine.run_until_idle()
+    assert engine._inflight is None and engine.pool.occupancy == 0
+    return engine, reqs, [c.result(timeout=1) for c in done]
+
+
+@pytest.mark.parametrize("prefills_a_tick", [1, 2], ids=["one-a-tick", "two-a-tick"])
+@pytest.mark.parametrize("kind", ["dense", "experts"])
+def test_the_fused_tick_serves_the_two_program_ticks_streams(
+    kind, prefills_a_tick, monkeypatch
+):
+    """Staggered requests, shared prefixes among them, through an engine
+    whose tick that admits a prompt is one program, and through the same
+    engine forced onto two programs a tick (the family's method taken away;
+    ``speculate_k`` and ``role`` untouched): the same token streams,
+    ``generate()``'s. ``fused_prefill_steps`` says how often it engaged:
+    every prefill where a tick admits one prompt, every TICK with prefills
+    where it admits two (the first goes out with no row, the last with the
+    rows); 0 on two programs. Every other counter reads the same."""
+    from ray_lightning_tpu.models.generation import LlamaServing
+
+    params, cfg = _tick_programs_model(kind)
+    fused, reqs, streams = _staggered_streams(
+        params, cfg, max_prefills_per_tick=prefills_a_tick)
+    assert fused._fused_rung == 12  # the one rung of a short ladder
+    monkeypatch.delattr(LlamaServing, "prefill_decode_paged")
+    plain, _, want = _staggered_streams(
+        params, cfg, max_prefills_per_tick=prefills_a_tick)
+    assert plain._fused_rung is None
+    assert streams == want
+    assert streams == [_reference(params, cfg, p, n) for p, n in reqs]
+    s, t = fused.stats, plain.stats
+    assert t["fused_prefill_steps"] == 0 and s["prefills"] == t["prefills"] == 9
+    if prefills_a_tick == 1:
+        assert s["fused_prefill_steps"] == s["prefills"]
+    else:
+        assert 5 <= s["fused_prefill_steps"] < s["prefills"]
+    same = ("decode_steps", "busy_slot_steps", "overlapped_steps", "tokens_out",
+            "prefill_positions", "prefill_tokens", "completed",
+            "dropped_row_steps", *fused._model.counters)
+    assert {k: s[k] for k in same} == {k: t[k] for k in same}
+    assert fused.compile_stats() == plain.compile_stats() == {
+        "prefill_compiles": 1, "decode_compiles": 1}
+    assert fused.pool.stats()["prefix_hits_total"] == plain.pool.stats()[
+        "prefix_hits_total"] > 0
+
+
+@pytest.mark.parametrize("setting", [
+    {"speculate_k": 2}, {"role": "prefill"}, {"role": "decode"}],
+    ids=lambda s: "-".join(f"{k}-{v}" for k, v in s.items()))
+def test_an_engine_that_cannot_fuse_keeps_two_programs_a_tick(model, setting):
+    """A speculating engine reads a tick's tokens before it dispatches the
+    next and a prefill replica parks the slot it filled: their prefill
+    program is the prompt's alone, whatever the family provides. A decode
+    replica's prompts (a migration's fallback) go the same way."""
+    params, cfg = model
+    engine = InferenceEngine(params, cfg, EngineConfig(
+        num_slots=2, max_prompt_len=8, max_len=32, block_size=4, **setting))
+    assert engine._fused_rung is None
+    comp = engine.submit([5, 9, 5, 9, 5], max_new_tokens=4)
+    for _ in range(8):
+        engine.step()
+    assert engine.stats["prefills"] == 1 and engine.stats["fused_prefill_steps"] == 0
+    if setting.get("role") != "prefill":
+        assert comp.result(timeout=1) == _reference(params, cfg, [5, 9, 5, 9, 5], 4)
+
+
+@pytest.mark.parametrize("family", ["deepseek", "cohere"])
+def test_a_family_without_the_fused_step_reads_no_fused_tick(family):
+    """The engine asks the family's serving object and nothing else: a model
+    that provides no ``prefill_decode_paged`` runs its two programs a tick
+    and counts no fused one."""
+    if family == "deepseek":
+        from ray_lightning_tpu.models import deepseek as ds
+
+        cfg = ds.DeepseekConfig(
+            vocab_size=97, dim=64, n_layers=3, n_dense_layers=1, n_heads=4,
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, ffn_dim=96, moe_ffn_dim=32,
+            n_experts=8, expert_top_k=2, max_seq=64, dtype=jnp.float32,
+            remat=False)
+        params, extra = ds.init_params(jax.random.key(0), cfg), {}
+    else:
+        from ray_lightning_tpu.models import cohere as co
+
+        cfg = co.CohereConfig(
+            vocab_size=97, dim=64, n_layers=8, period=4, n_heads=8,
+            n_kv_heads=2, head_dim=16, sliding_window=12, ffn_dim=32,
+            n_experts=16, experts_held=4, first_expert=4, n_shared_experts=2,
+            expert_top_k=4, max_seq=64, dtype=jnp.float32)
+        params, extra = co.init_params(jax.random.key(0), cfg), {"prefix_cache": False}
+    engine = InferenceEngine(params, cfg, EngineConfig(
+        num_slots=3, max_prompt_len=16, max_len=32, block_size=4, **extra))
+    assert engine._fused_rung is None and not hasattr(cfg.serving(), "prefill_decode_paged")
+    done = [engine.submit(p, max_new_tokens=5) for p in ([5, 9, 2, 7, 1], [8, 4])]
+    engine.run_until_idle()
+    assert all(len(c.result(timeout=1)) == 5 for c in done)
+    assert engine.stats["prefills"] == 2 and engine.stats["fused_prefill_steps"] == 0

@@ -96,16 +96,22 @@ class _Noting:
 
 
 class _Recorder:
-    """A stand-in for ``engine._decode_fn``: the real program, with every
-    dispatch and every read of a result noted in order, and what each
-    dispatch fed every occupied slot."""
+    """A stand-in for the engine's decode step in whichever program carries
+    it (``engine._decode_fn``, and ``engine._prefill_fn`` where a tick that
+    admits a prompt is ONE program): the real program, with every dispatch
+    and every read of a result noted in order, and what each dispatch fed
+    every occupied slot."""
 
     def __init__(self, engine, fail_at=None):
         self.engine, self.fn, self.fail_at = engine, engine._decode_fn, fail_at
+        self.with_prompt = engine._prefill_fn
         self.events, self.fed, self.uploads = [], [], []
         engine._decode_fn = self
+        if engine._fused_rung:
+            engine._prefill_fn = lambda params, cache, row, where, *rows: self(
+                params, cache, *rows, prompt=(row, where))
 
-    def __call__(self, params, cache, token, pos, tables, key, *prev):
+    def __call__(self, params, cache, token, pos, tables, key, *prev, prompt=()):
         n = len(self.fed) + 1
         if n == self.fail_at:
             raise RuntimeError("boom")
@@ -120,7 +126,8 @@ class _Recorder:
         ])
         # the output of the program before, as the engine kept it
         prev = tuple(getattr(p, "array", p) for p in prev)
-        sampled, cache = self.fn(params, cache, token, pos, tables, key, *prev)
+        fn = self.with_prompt if prompt else self.fn
+        sampled, cache = fn(params, cache, *prompt, token, pos, tables, key, *prev)
         self.events.append(("dispatch", n))
         return _Noting(sampled, lambda: self.events.append(("read", n))), cache
 

@@ -436,7 +436,9 @@ def test_engine_programs_carry_their_labels_and_kernel_names(
     """The paged engine's two programs compiled for the chip are the modules
     ``jit_serve_prefill`` / ``jit_serve_decode``, and the decode program's
     Mosaic calls are the named kernels: once a layer the paged attention,
-    the norms, the sampler. The engine is built on the CPU with the kernel
+    the norms, the sampler. The Llama family's prefill program steps the
+    decode rows too (PR 43), so it holds those and, where the prompt's shape
+    lets it, ``flash_fwd``. The engine is built on the CPU with the kernel
     knob forced on; the ops ask ``jax.devices()`` at trace time whether to
     interpret, so the test answers with the described chip there."""
     from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
@@ -463,8 +465,8 @@ def test_engine_programs_carry_their_labels_and_kernel_names(
     decode = _kernel_instructions(texts["serve_decode"])
     assert set(decode) == {"paged_decode_attention", "rmsnorm", "fused_argmax"}
     assert "wrapped" not in " ".join(decode)
-    assert set(_kernel_instructions(texts["serve_prefill"])) <= {
-        "flash_fwd", "rmsnorm"}
+    prefill = set(_kernel_instructions(texts["serve_prefill"]))
+    assert set(decode) <= prefill <= set(decode) | {"flash_fwd"}
 
 
 # ---------------------------------------------------------------------- #
@@ -791,6 +793,33 @@ def test_every_rung_of_prefill_is_the_labelled_module_with_its_kernels_inside(
     assert ("gmm" in kernels) == (cell != "serve-dense-chat")
 
 
+def test_the_llama_cells_first_rung_is_one_program_for_the_prompt_and_the_rows(
+    cell_programs
+):
+    """PR 43: in the chat and batch cells ``serve_prefill@256`` is the tick
+    that admits a prompt, whole: the prompt's flash attention AND the decode
+    rows' paged attention and sampler in one module, its arguments the
+    decode program's behind the prompt row and its write table, the pool
+    still aliased whole with nothing of a leaf's or a layer's size copied
+    (the parametrised test above holds every rung to that; this one says
+    what the program is). The longer rungs' programs are the prompt's
+    alone, and so is every rung of the reason cell, whose family provides
+    no such step."""
+    cell, compiled, leaves, pool_bytes = cell_programs
+    exe = compiled["serve_prefill@256"]
+    kernels = set(_kernel_instructions(exe.as_text()))
+    fused = cell != "serve-mla-moe-reason"
+    assert "flash_fwd" in kernels
+    assert ({"paged_decode_attention", "fused_argmax"} <= kernels) == fused
+    if fused:  # the prompt row and its write table ahead of decode's own
+        assert len(jax.tree_util.tree_leaves(exe.input_shardings[0])) == len(
+            jax.tree_util.tree_leaves(compiled["serve_decode"].input_shardings[0])) + 2
+    assert _pool_sized_copies(exe.as_text(), leaves) == []
+    assert exe.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert "paged_decode_attention" not in _kernel_instructions(
+        compiled["serve_prefill@512"].as_text())
+
+
 def test_the_decode_program_holds_the_grouped_matmul_where_there_are_experts(
     cell_programs
 ):
@@ -1042,7 +1071,14 @@ def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
     ``_fwd_tile`` reads off the rung (1,024 x 1,024 where it walked 512 x 512:
     one tile at the 1,024 rung, on the rectangular grid; three pairs at
     2,048); the eight entries at 256 and 512 (one tile, as before) and the
-    four ``serve_decode`` entries stand to the letter."""
+    four ``serve_decode`` entries stand to the letter. PR 43 recorded
+    ``serve_prefill@256`` of ``serve-dense-chat`` and ``serve-moe-batch``
+    anew: in an engine of the Llama family the first rung's program is the
+    tick that admits a prompt, whole (the prompt's positions in front of the
+    decode rows through one pass of the layers, the decode program's
+    arguments behind the prompt's); their three longer rungs, all four
+    ``serve_decode`` entries and every entry of the reason and doc cells
+    stand to the letter."""
     import json
     import os
 
