@@ -5,7 +5,7 @@ dozens of modules, with no single place that says what exists, what the
 defaults are, or which are user-facing versus internal actor-boot
 plumbing. This pass:
 
-1. AST-scans the package (plus ``bench.py`` and ``scripts/``) for every
+1. AST-scans the package (plus ``scripts/``) for every
    ``RLT_*`` read (``os.environ.get`` / ``os.getenv`` / subscript
    loads / any ``.get("RLT_…")``), write (subscript stores,
    ``setdefault``, ``pop``/``del``) and literal mention, with literal

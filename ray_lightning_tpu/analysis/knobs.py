@@ -14,186 +14,6 @@ KNOBS = {
         "modules": ['runtime.api', 'runtime.zygote'],
         "read": False, "set": False,
     },
-    'RLT_BENCH_ARBITRATION_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_ARBITRATION_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_AUTOTUNE': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_COMPILE_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_COMPILE_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_COST': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_DCN_STEPS': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_DCN_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_DCN_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_DISAGG_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_DISAGG_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_DISAGG_TOKENS': {
-        "modules": ['bench'],
-        "defaults": ["'16'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_GOODPUT_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_GOODPUT_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_INPUT_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_INPUT_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_PAGED_KERNEL_STEPS': {
-        "modules": ['bench'],
-        "defaults": ["'20'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_PAGED_KERNEL_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_PAGED_KERNEL_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_PARALLELISM_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_PARALLELISM_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_REMAT_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_REPLAY_DURATION': {
-        "modules": ['bench'],
-        "defaults": ["'8'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_REPLAY_SPEED': {
-        "modules": ['bench'],
-        "defaults": ["'8'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_REPLAY_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_REPLAY_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_SERVE_CHAOS': {
-        "modules": ['bench'],
-        "defaults": ["'0'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SERVE_FAULT': {
-        "modules": ['bench'],
-        "defaults": ["'replica0:crash@every:8'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SERVE_RATES': {
-        "modules": ['bench'],
-        "defaults": ["'4,64,512'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SERVE_REQUESTS': {
-        "modules": ['bench'],
-        "defaults": ["'12'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SERVE_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SERVE_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_SLOW_LOADER': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_SPECULATIVE_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_SPECULATIVE_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_SPECULATIVE_TOKENS': {
-        "modules": ['bench'],
-        "defaults": ["'40'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
-    'RLT_BENCH_ZERO_SWEEP': {
-        "modules": ['bench'],
-        "defaults": ["'1'"],
-        "read": True, "set": False,
-    },
-    'RLT_BENCH_ZERO_TIMEOUT': {
-        "modules": ['bench'],
-        "read": False, "set": False,
-    },
     'RLT_BIND_HOST': {
         "modules": ['runtime.actor', 'runtime.node', 'runtime.queue'],
         "defaults": ["'0.0.0.0'"],
@@ -208,13 +28,13 @@ KNOBS = {
         "read": True, "set": False,
     },
     'RLT_COMPILE_CACHE': {
-        "modules": ['bench', 'runtime.compile_cache'],
+        "modules": ['runtime.compile_cache'],
         "defaults": ["'1'"],
-        "read": True, "set": True,
+        "read": True, "set": False,
     },
     'RLT_COMPILE_CACHE_EXEC': {
-        "modules": ['bench', 'runtime.compile_cache'],
-        "read": True, "set": True,
+        "modules": ['runtime.compile_cache'],
+        "read": True, "set": False,
     },
     'RLT_COST_ANALYSIS': {
         "modules": ['observability.profiler'],
@@ -266,19 +86,11 @@ KNOBS = {
         "read": True, "set": False,
     },
     'RLT_FAULT': {
-        "modules": ['bench', 'runtime.faults', 'workloads.replay'],
-        "read": True, "set": True,
+        "modules": ['runtime.faults', 'workloads.replay'],
+        "read": True, "set": False,
     },
     'RLT_FAULT_FUSE': {
         "modules": ['runtime.faults'],
-        "read": False, "set": False,
-    },
-    'RLT_FLASH_BLOCK_K': {
-        "modules": ['bench', 'ops.attention'],
-        "read": False, "set": False,
-    },
-    'RLT_FLASH_BLOCK_Q': {
-        "modules": ['bench', 'ops.attention'],
         "read": False, "set": False,
     },
     'RLT_GLOBAL_RANK': {
@@ -432,8 +244,8 @@ KNOBS = {
         "read": False, "set": True,
     },
     'RLT_TELEMETRY_DIR': {
-        "modules": ['bench', 'observability.aggregator'],
-        "read": False, "set": True,
+        "modules": ['observability.aggregator'],
+        "read": False, "set": False,
     },
     'RLT_TELEMETRY_RING': {
         "modules": ['observability.trace'],
@@ -444,7 +256,7 @@ KNOBS = {
         "read": False, "set": False,
     },
     'RLT_XLA_CACHE_DIR': {
-        "modules": ['bench', 'runtime.compile_cache', 'strategies.ray_strategies'],
+        "modules": ['runtime.compile_cache', 'strategies.ray_strategies'],
         "read": False, "set": True,
     },
     'RLT_XLA_CACHE_MAX_BYTES': {

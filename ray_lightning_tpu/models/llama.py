@@ -70,7 +70,7 @@ class LlamaConfig:
     # qkv projection bias (Qwen2-family checkpoints); biases shard with
     # the column-parallel output dim under tp, so they stay local
     attn_bias: bool = False
-    # flash block sizes (0 = env/default). Static ints in the traced step,
+    # flash block sizes (0 = each pass's own tile). Static ints in the traced step,
     # so a sweep is one process retracing per config.
     flash_block_q: int = 0
     flash_block_k: int = 0
@@ -177,7 +177,7 @@ class LlamaConfig:
 
     @staticmethod
     def small() -> "LlamaConfig":
-        """~0.9B, seq 2048 — the HBM-sized single-chip bench config
+        """~0.9B, seq 2048 — the HBM-sized single-chip config of `chip_smoke.py`
         (VERDICT r4 weak #3: at mini's ~160M scale vocab/launch overheads
         dominate and single-chip MFU does not transfer to the
         Llama-3-8B/v5p target). bf16 params + adam moments = ~5.3 GB,
@@ -187,7 +187,7 @@ class LlamaConfig:
         return LlamaConfig(loss_chunks=8)  # defaults ARE the 0.9B shape
 
     @staticmethod
-    def mini() -> "LlamaConfig":  # ~160M: the single-chip bench config
+    def mini() -> "LlamaConfig":  # ~160M
         # head_dim 128 (dim/n_heads) so attention takes the pallas flash path
         return LlamaConfig(
             vocab_size=32000, dim=768, n_layers=12, n_heads=6, n_kv_heads=6,

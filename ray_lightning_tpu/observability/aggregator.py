@@ -80,7 +80,7 @@ def step_time_stats(samples_by_rank: Dict[Any, List[float]]) -> Dict[str, float]
     ``step_time_max_skew`` is the spread between the slowest and fastest
     rank's median step time — the quantity that predicts multi-worker
     throughput cliffs. With a single rank it degrades to the in-rank
-    max-min spread so bench rows still capture variance.
+    max-min spread, so a one-rank run still reports its variance.
     """
     pooled: List[float] = []
     medians: List[float] = []

@@ -34,8 +34,8 @@ Design (TPU-first):
   512 x 512. The forward tile waits for the softmax's two cross-lane row
   reductions, which cost once a tile and 8-row group whatever its width;
   the backward kernels read the saved logsumexp, reduce no row, gain less
-  from it and keep 512 x 512 under a window. Explicit `block_q/block_k` and the
-  RLT_FLASH_BLOCK_Q/K pins win for both passes (on-chip sweeps);
+  from it and keep 512 x 512 under a window. A caller's explicit
+  `block_q/block_k` win for both passes; nothing else overrides the choice;
 - `interpret=True` runs the same kernels on CPU for numerical tests.
 
 The reference project has no attention of its own (it wraps user torch
@@ -47,7 +47,6 @@ ring-level custom VJP — einsum block math remains as the off-TPU fallback).
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple, Optional
 
 import jax
@@ -305,17 +304,6 @@ def _bwd_dkv_kernel(
 # --------------------------------------------------------------------- #
 # pallas_call wrappers
 # --------------------------------------------------------------------- #
-def _env_block(name: str, default: int, s: int) -> int:
-    raw = os.environ.get(name, str(default))
-    try:
-        value = int(raw)
-    except ValueError:
-        raise ValueError(f"{name}={raw!r} is not an integer block size")
-    if value <= 0 or value % 8:
-        raise ValueError(f"{name}={value}: block sizes must be positive multiples of 8")
-    return min(value, s)
-
-
 # the tile of all three kernels where no wider one serves
 _TILE = 512
 _WIDE_TILE = 1024
@@ -363,17 +351,13 @@ def _bwd_tile(sq: int, skv: int, row_bytes: int, window: Optional[int]):
 
 def _pick_blocks(s: int, block_q: Optional[int] = None,
                  block_k: Optional[int] = None, default=(_TILE, _TILE)):
-    """Explicit block sizes win; else env (RLT_FLASH_BLOCK_Q/K); else
-    `default`, the pass's own choice (`_fwd_tile`, `_bwd_tile`).
+    """A caller's explicit block sizes win, side by side; else `default`,
+    the pass's own choice (`_fwd_tile`, `_bwd_tile`). Either is clamped to
+    the sequence.
 
-    Explicit args are part of the caller's trace (static python ints), so a
-    single process can sweep block configs by retracing — one device
-    acquisition per sweep instead of one process per config. Env vars
-    remain for whole-run pins but are read at trace time and are NOT jit
-    cache keys."""
-    bq = min(block_q, s) if block_q else _env_block("RLT_FLASH_BLOCK_Q", default[0], s)
-    bk = min(block_k, s) if block_k else _env_block("RLT_FLASH_BLOCK_K", default[1], s)
-    return bq, bk
+    Explicit sizes are static python ints of the caller's trace, so they are
+    jit cache keys and one process can time several tiles by retracing."""
+    return min(block_q or default[0], s), min(block_k or default[1], s)
 
 
 def _block_active(row_blk, col_blk, block_q: int, block_k: int, causal: bool,
@@ -697,7 +681,7 @@ def attention(
     impl: "flash" | "reference" | None (auto: flash when shapes are
     TPU-tileable, reference otherwise). block_q/block_k: explicit flash
     block sizes (static ints, so distinct values retrace — sweepable in
-    one process); default env/512.
+    one process); default the pass's own tile (`_fwd_tile`, `_bwd_tile`).
 
     window: sliding-window size W (static; requires causal): position i
     attends positions [i-W+1, i] — HF Mistral semantics. In the flash

@@ -27,7 +27,7 @@ mean exactly.
 
 Everything here is mesh-agnostic: the collectives bind axis *names* and must
 run inside a ``shard_map`` that maps them (``core/trainer.py``'s compressed
-train step; ``bench.py``'s dcn sweep).
+train step).
 """
 from __future__ import annotations
 

@@ -675,7 +675,7 @@ class ZeroContext:
     def gather_wire_bytes(self) -> int:
         """Wire bytes of one param all-gather as configured (int8 payload
         + bf16 block scales when quantized; the block accounting is the
-        compression layer's, so bench/telemetry ratios stay consistent
+        compression layer's, so the telemetry's ratios stay consistent
         with the dcn-compression path's)."""
         if not self.quantized:
             return self.gather_fp32_bytes()

@@ -50,7 +50,7 @@ After warmup (one prefill compile a rung + one decode compile) the jit
 caches are flat: admission, recycling, mixed prompt lengths, EOS — none
 of it brings a shape warmup has not resolved. ``compile_stats()`` exposes
 the cache sizes so
-tests (and the bench sweep) can assert zero steady-state recompiles.
+tests can assert zero steady-state recompiles.
 
 The first sampled token of a request comes from the first DECODE step
 after its prefill (re-running the last prompt token at position P-1 —
@@ -142,7 +142,7 @@ own (plain sums: a reader subtracts the value before its window):
 
 Threading: ``submit`` is callable from any thread; ``start()`` spawns
 the loop thread, or call ``step()`` yourself for deterministic
-single-threaded driving (tests, bench). ``drain()`` stops admission and
+single-threaded driving (tests). ``drain()`` stops admission and
 finishes in-flight work; ``shutdown(drain=False)`` fails queued work
 immediately.
 """
@@ -608,7 +608,7 @@ class InferenceEngine:
             "dropped_row_steps": 0,
             "completed": 0,
             # speculative accounting: accepted_tokens / spec_row_ticks is
-            # the mean accepted-tokens-per-slot-tick the bench reports
+            # the mean accepted tokens a speculating row's tick
             "accepted_tokens": 0,
             "spec_row_ticks": 0,
             # loop-time sums, in seconds (sums only: a reader subtracts the

@@ -15,8 +15,7 @@ side as a first-class subsystem:
   time, per-tenant SLO attainment, quota conformance, and a bounded
   cross-tenant wait ratio (the zero-starvation check).
 
-Entry points: ``python -m ray_lightning_tpu.cli replay`` and the
-``detail.replay`` bench sweep.
+Entry point: ``python -m ray_lightning_tpu.cli replay``.
 """
 from ray_lightning_tpu.workloads.replay import (  # noqa: F401
     ReplayDriver,

@@ -36,9 +36,7 @@ ANALYSIS = PACKAGE / "analysis"
 ALLOWLIST = ANALYSIS / "allowlist.txt"
 KNOBS = ANALYSIS / "knobs.py"
 DOCS = REPO / "docs"
-KNOB_EXTRA = (REPO / "bench.py",) + tuple(
-    sorted((REPO / "scripts").glob("*.py"))
-)
+KNOB_EXTRA = tuple(sorted((REPO / "scripts").glob("*.py")))
 
 _MODULES = ("core", "lockgraph", "sanitizer", "envknobs", "docs_drift", "invariants")
 

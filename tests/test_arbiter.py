@@ -1098,6 +1098,40 @@ class SimTrain:
         jax.block_until_ready(self.params)
 
 
+def test_forced_cycle_over_a_real_fleet(model, tmp_path):
+    """One forced borrow/return cycle with nothing faked on the serve side:
+    `ChipArbiter` over `FleetServeHandle` over a `LocalReplicaFleet` at
+    capacity, a jitted step on the train side. The borrowed chip boots a
+    second replica that serves the reference's tokens; the return drains it
+    and training takes a step on the regrown devices."""
+    params, cfg = model
+    train = SimTrain(["c0", "c1"])
+    fleet = LocalReplicaFleet(
+        lambda: (params, cfg), engine_kwargs=ENGINE_KW,
+        initial_replicas=1, capacity=1,
+    )
+    try:
+        serve = FleetServeHandle(fleet)
+        arb = _arbiter(tmp_path, train, serve, min_train_devices=1)
+        arb.request_transfer("borrow")
+        assert arb.tick() == "borrowed"
+        assert arb.state == "lent" and fleet.num_replicas == 2
+        assert train.devices() == ["c0"] and list(serve.devices()) == ["c1"]
+        prompts = [[1, 2, 3], [4, 5, 6, 7], [8, 9], [3, 1, 4, 1, 5]]
+        entries = [fleet.submit(p, max_new_tokens=4) for p in prompts]
+        for p, e in zip(prompts, entries):
+            assert e.result(timeout=120) == _reference(params, cfg, p, 4)
+
+        steps = train.steps
+        arb.request_transfer("return")
+        assert arb.tick() == "returned"
+        assert arb.state == "steady" and arb.transfers_completed == 2
+        assert fleet.num_replicas == 1 and train.steps == steps + 1
+        _assert_no_leaks(arb, train, serve, ["c0", "c1"])
+    finally:
+        fleet.shutdown()
+
+
 @pytest.mark.slow
 def test_arbitration_kill_loop_e2e(model, tmp_path):
     """The PR's acceptance bar, end to end:

@@ -396,6 +396,69 @@ def test_fleet_add_replica_warm_starts_from_cache():
         fleet.shutdown()
 
 
+@pytest.mark.serving
+def test_tracked_programs_rebuild_from_either_layer_and_compile_nothing(
+        tmp_path, monkeypatch):
+    """The three tracked programs (``train_step`` and the engine's
+    ``serve_prefill`` / ``serve_decode``): 3 misses cold, then a rebuild from
+    memory (a second engine, a rebuilt step) and one from disk (a relaunched
+    process) are 6 hits and compile nothing. The loader is a stand-in that
+    loads nothing, since this process may deserialize no executable (see the
+    taint note above); the subprocess tests below load real ones."""
+    import optax
+    from jax.experimental import serialize_executable as se
+
+    from ray_lightning_tpu.models.llama import lm_loss
+    from ray_lightning_tpu.serving import EngineConfig, InferenceEngine
+
+    params, cfg = _tiny_model()
+    tx = optax.adamw(3e-4)
+
+    def train_step(p, s, toks):
+        (loss, _), grads = jax.value_and_grad(
+            lambda q: lm_loss(q, toks, cfg), has_aux=True)(p)
+        updates, s = tx.update(grads, s, p)
+        return optax.apply_updates(p, updates), s, loss
+
+    engine = InferenceEngine(
+        params, cfg, EngineConfig(num_slots=2, max_prompt_len=8, max_len=32))
+    programs = [
+        ("train_step", cc.jit_program(train_step, "train_step", donate_argnums=(0, 1)),
+         (params, tx.init(params), jnp.zeros((2, 16), jnp.int32))),
+        *engine._program_specs(),
+    ]
+    names = [name for name, _, _ in programs]
+    assert names == ["train_step", "serve_prefill", "serve_decode"]
+
+    def rebuild():
+        for _, fn, args in programs:
+            fn._compiled.clear()  # a fresh wrapper: lower, hash, look up
+        return [fn.cached_compiled(*args) for _, fn, args in programs]
+
+    cache = cc.get_cache()
+    stats = cache.stats
+    cold = rebuild()
+    assert (stats["misses"], stats["hits"]) == (3, 0)
+    compile_ms = stats["compile_ms_total"]
+    assert len(list((tmp_path / "xla").glob("*.rltx"))) == 3
+
+    warm = rebuild()
+    assert all(a is b for a, b in zip(warm, cold))
+    assert (stats["misses"], stats["memory_hits"], stats["disk_hits"]) == (3, 3, 0)
+
+    loaded = []
+    monkeypatch.setenv("RLT_COMPILE_CACHE_EXEC", "1")
+    monkeypatch.setattr(
+        se, "deserialize_and_load",
+        lambda *payload: loaded.append(payload) or ("loaded", len(loaded)))
+    cache.clear_memory()
+    assert rebuild() == [("loaded", 1), ("loaded", 2), ("loaded", 3)]
+    assert (stats["misses"], stats["hits"], stats["disk_hits"]) == (3, 6, 3)
+    assert stats["corrupt"] == stats["version_skew"] == 0
+    assert stats["compile_ms_total"] == compile_ms
+    assert stats["programs"] == {n: {"hits": 2, "misses": 1} for n in names}
+
+
 # --------------------------------------------------------------------- #
 # disk round-trip in throwaway subprocesses (the only place CPU
 # executables are deserialized)

@@ -614,6 +614,28 @@ def test_new_observability_metrics_have_doc_rows():
         assert name in metrics_mod.HELP, f"{name} missing a HELP entry"
 
 
+def test_local_fit_summary_holds_goodput(tmp_root):
+    """An in-process fit with telemetry on (no launcher, no worker) lands a
+    goodput section in its summary: every second of the fit in one category,
+    some of them productive, the fraction their share."""
+    import ray_lightning_tpu as rlt
+    from tests.utils import BoringModel, get_trainer
+
+    trainer = get_trainer(
+        tmp_root,
+        strategy=rlt.XLAStrategy(devices=1, telemetry=True),
+        limit_train_batches=6,
+    )
+    trainer.fit(BoringModel())
+    gp = agg_mod._read_summary(os.path.join(tmp_root, "telemetry"))["goodput"]
+    seconds = gp["by_category"]
+    assert set(seconds) <= set(goodput_mod.CATEGORIES)
+    assert seconds[goodput_mod.PRODUCTIVE] > 0
+    assert sum(seconds.values()) == pytest.approx(gp["total_s"], rel=0.02)
+    assert gp["fraction"] == pytest.approx(
+        seconds[goodput_mod.PRODUCTIVE] / gp["total_s"], rel=0.02)
+
+
 # --------------------------------------------------------------------- #
 # e2e: chaos run produces goodput + an incident bundle (chaos.sh)
 # --------------------------------------------------------------------- #

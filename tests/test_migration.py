@@ -512,6 +512,30 @@ def test_disaggregated_fleet_token_identical(model):
         fleet.shutdown()
 
 
+def test_same_burst_colocated_and_disaggregated_token_identical(model):
+    """One burst through two replicas either way, two colocated and then one
+    prefill + one decode: the handoff changes no token, every request
+    migrates once and none falls back."""
+    params, cfg = model
+    rng = np.random.default_rng(7)
+    reqs = [[int(t) for t in rng.integers(1, cfg.vocab_size, 6)] for _ in range(8)]
+    streams, stats = {}, {}
+    for prefill in (0, 1):
+        fleet = _disagg_fleet(params, cfg, replicas=2, prefill=prefill)
+        try:
+            assert fleet.disaggregated == bool(prefill)
+            entries = [fleet.submit(p, max_new_tokens=8) for p in reqs]
+            streams[prefill] = [e.result(timeout=180) for e in entries]
+            stats[prefill] = fleet.stats()
+        finally:
+            fleet.shutdown()
+    assert streams[1] == streams[0]
+    assert stats[0]["completed"] == stats[1]["completed"] == 8
+    assert "migration" not in stats[0]
+    m = stats[1]["migration"]
+    assert (m["attempts"], m["migrated"], m["fallbacks"]) == (8, 8, 0)
+
+
 def test_warm_chain_affinity_routes_repeat_prefix_to_same_replica(model):
     params, cfg = model
     fleet = _disagg_fleet(params, cfg, replicas=4, prefill=2)

@@ -304,33 +304,31 @@ def test_longrope_matches_transformers():
         assert abs(float(cos[0, 0]) - ours_att) < 1e-6  # factor on tables
 
 
-def test_flash_multiblock_grid(monkeypatch):
-    """Force small blocks so the grid really iterates (4 q-blocks x 4
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_multiblock_grid(causal):
+    """Small explicit blocks so the grid really iterates (4 q-blocks x 4
     kv-blocks): exercises the scratch-accumulator handoff across grid steps
     that makes VMEM O(block^2) instead of O(S)."""
-    monkeypatch.setenv("RLT_FLASH_BLOCK_Q", "64")
-    monkeypatch.setenv("RLT_FLASH_BLOCK_K", "64")
     q, k, v = _qkv(1, 2, 1, 256, 128)  # GQA group 2 as well
-    for causal in (True, False):
-        ref = reference_attention(q, k, v, causal=causal)
-        out = attention(q, k, v, causal=causal, impl="flash", interpret=True)
-        assert float(jnp.max(jnp.abs(ref - out))) < 1e-4, causal
+
+    def ref(q, k, v):
+        return reference_attention(q, k, v, causal=causal)
+
+    def flash(q, k, v):
+        return attention(q, k, v, causal=causal, impl="flash", interpret=True,
+                         block_q=64, block_k=64)
+
+    assert _traced_grids(flash, q, k, v)["flash_fwd"][-1] > 1
+    assert float(jnp.max(jnp.abs(ref(q, k, v) - flash(q, k, v)))) < 1e-4
 
     def loss(fn):
         return lambda q, k, v: (fn(q, k, v) ** 2).sum()
 
-    for causal in (True, False):
-        g_ref = jax.grad(
-            loss(lambda q, k, v: reference_attention(q, k, v, causal=causal)),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        g_fl = jax.grad(
-            loss(lambda q, k, v: attention(q, k, v, causal=causal, impl="flash", interpret=True)),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-        for a, b in zip(g_ref, g_fl):
-            rel = float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(a)) + 1e-9))
-            assert rel < 1e-4, causal
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    g_fl = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ref, g_fl):
+        rel = float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(a)) + 1e-9))
+        assert rel < 1e-4
 
 
 def test_flash_explicit_block_args():
@@ -457,39 +455,55 @@ _WIDE, _BASE = (1024, 1024), (512, 512)
         (4096, 4 * 256, None, _BASE, _BASE),      # float32 rows of 256 columns
     ],
 )
-def test_each_pass_reads_its_tile_off_the_shapes(
-        s, row_bytes, window, fwd, bwd, monkeypatch):
+def test_each_pass_reads_its_tile_off_the_shapes(s, row_bytes, window, fwd, bwd):
     """`_fwd_tile` and `_bwd_tile` at the cells' shapes (PERF.md section 6,
     PR 42 has the chip's sweeps), clamped to the sequence as `_pick_blocks`
     clamps them; every pick divides the sequence, and so does the 512 x 512
     `flash_supported` asks about."""
     from ray_lightning_tpu.ops.attention import _bwd_tile, _fwd_tile, _pick_blocks
 
-    monkeypatch.delenv("RLT_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("RLT_FLASH_BLOCK_K", raising=False)
     assert _pick_blocks(s, default=_fwd_tile(s, s, row_bytes)) == fwd
     assert _pick_blocks(s, default=_bwd_tile(s, s, row_bytes, window)) == bwd
     assert all(s % side == 0 for side in (*fwd, *bwd, *_pick_blocks(s)))
 
 
-def test_explicit_tiles_and_the_pins_win_over_the_rule(monkeypatch):
-    """The order is the one it was: an explicit block, then the
-    `RLT_FLASH_BLOCK_Q/K` pin, then the pass's own choice; and both passes
-    take the explicit block and the pin alike."""
-    from ray_lightning_tpu.ops.attention import _fwd_tile, _pick_blocks
+# s, block_q, block_k, the pass's own choice, what `_pick_blocks` returns
+_EXPLICIT_TILE_CASES = [
+    (4096, 256, None, _WIDE, (256, 1024)),   # one side asked for, the
+    (4096, None, 128, _WIDE, (1024, 128)),   # other the pass's own
+    (4096, 256, 128, _BASE, (256, 128)),
+    (4096, None, 256, _WIDE, (1024, 256)),
+    (4096, None, 256, _BASE, (512, 256)),
+    (4096, None, 2048, _WIDE, (1024, 2048)),
+    (4096, 64, 256, _WIDE, (64, 256)),
+    (4096, None, None, _WIDE, _WIDE),        # nothing asked for
+    (256, 512, 512, _WIDE, (256, 256)),      # clamped to the sequence
+]
 
-    monkeypatch.delenv("RLT_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("RLT_FLASH_BLOCK_K", raising=False)
-    rule = _fwd_tile(4096, 4096, _ROW)
-    assert _pick_blocks(4096, 256, None, default=rule) == (256, rule[1])
-    assert _pick_blocks(4096, None, 128, default=rule) == (rule[0], 128)
-    assert _pick_blocks(4096, 256, 128) == (256, 128)
-    monkeypatch.setenv("RLT_FLASH_BLOCK_K", "256")
-    assert _pick_blocks(4096, default=rule) == (rule[0], 256)
-    assert _pick_blocks(4096, default=(512, 512)) == (512, 256)
-    assert _pick_blocks(4096, None, 2048, default=rule) == (rule[0], 2048)
+
+@pytest.mark.parametrize("s,block_q,block_k,default,picked", _EXPLICIT_TILE_CASES)
+def test_explicit_tiles_win_over_the_rule(s, block_q, block_k, default, picked):
+    """A tile is the caller's explicit `block_q` / `block_k` or the pass's own
+    choice, side by side, clamped to the sequence."""
+    from ray_lightning_tpu.ops.attention import _pick_blocks
+
+    assert _pick_blocks(s, block_q, block_k, default=default) == picked
+
+
+@pytest.mark.parametrize("s,block_q,block_k,default,picked", _EXPLICIT_TILE_CASES)
+def test_the_bench_knobs_and_the_tile_pins_are_gone(
+        s, block_q, block_k, default, picked, monkeypatch):
+    """PR 44 took `bench.py`'s knobs and the two tile pins only its autotune
+    needed out of the registry; the pins, set, change no pick (they were read
+    at trace time and were no jit cache key)."""
+    from ray_lightning_tpu.analysis.knobs import KNOBS
+    from ray_lightning_tpu.ops.attention import _pick_blocks
+
+    assert not [k for k in KNOBS
+                if k.startswith(("RLT_BENCH_", "RLT_FLASH_BLOCK_"))]
     monkeypatch.setenv("RLT_FLASH_BLOCK_Q", "64")
-    assert _pick_blocks(4096, default=rule) == (64, 256)
+    monkeypatch.setenv("RLT_FLASH_BLOCK_K", "128")
+    assert _pick_blocks(s, block_q, block_k, default=default) == picked
 
 
 def _traced_grids(fn, *args):

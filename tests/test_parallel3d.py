@@ -26,6 +26,7 @@ from ray_lightning_tpu.parallel.sharding import ShardingPolicy
 from ray_lightning_tpu.parallel.zero import PAD_UNIT, ZeroContext
 from ray_lightning_tpu.parallel.mesh import MeshSpec, build_mesh
 from ray_lightning_tpu.strategies.base import XLAStrategy
+from tests.utils import live_bytes
 
 pytestmark = pytest.mark.parallel3d
 
@@ -251,6 +252,27 @@ def test_zero3_tp_matches_ddp(ddp_tp_run):
     # params keep their model-axis placement on device
     w1 = trainer._params["w1"]
     assert w1.sharding.spec == P(None, "tp")
+
+
+def test_tp_holds_less_live_state_than_zero3_alone():
+    """Model-axis sharding shrinks what the four devices hold below
+    data-axis-only ZeRO: under `tp` a leaf the rules claim is held once a
+    data replica, in halves, where ZeRO alone keeps it whole on every
+    device (the explicit step shards moments and masters, not params)."""
+    zero3, _, _, _ = _fit(
+        _TpMLP(tp=False), _loader(64, 16),
+        XLAStrategy(devices=4, sharding_policy=_policy(3)), steps=2,
+    )
+    zero3_tp, _, _, _ = _fit(
+        _TpMLP(tp=True), _loader(64, 16), _tp_strategy(stage=3), steps=2
+    )
+    assert zero3._train_program == zero3_tp._train_program == "zero_train_step"
+    nbytes = {k: 4 * v.size for k, v in zero3._params.items()}
+    assert live_bytes(zero3._params) == 4 * sum(nbytes.values())
+    assert live_bytes(zero3_tp._params) == (
+        2 * (nbytes["w1"] + nbytes["b1"] + nbytes["w2"]) + 4 * nbytes["b2"])
+    assert live_bytes((zero3_tp._params, zero3_tp._opt_state)) < live_bytes(
+        (zero3._params, zero3._opt_state))
 
 
 def test_zero2_tp_matches_ddp(ddp_tp_run):

@@ -389,26 +389,61 @@ def test_deferred_step_logs_reach_csv_in_order(tmp_path):
         float(r["train_loss_step"])  # resolved to a host scalar, not repr junk
 
 
+def _input_microbench(delay_ms, num_workers, prefetch_depth, steps):
+    """Feed a small jitted step through the input pipeline, ``delay_ms`` of
+    sleep in collate standing for a slow host loader. ``num_workers=0,
+    prefetch_depth=0`` is the synchronous feed; anything else goes through
+    AsyncLoader + DevicePrefetcher."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_lightning_tpu.core.data import default_collate
+
+    def collate(items):
+        time.sleep(delay_ms / 1e3)
+        return default_collate(items)
+
+    @jax.jit
+    def step(w, x):
+        for _ in range(8):
+            x = jnp.tanh(x @ w)
+        return w + 1e-4 * jnp.mean(x) * jnp.eye(w.shape[0], dtype=w.dtype), x
+
+    batch, dim = 8, 256
+    dataset = RandomDataset(dim, steps * batch)
+    loader = DataLoader(
+        dataset, batch_size=batch, collate_fn=collate, drop_last=True
+    )
+    w = jnp.eye(dim, dtype=jnp.float32)
+    w, out = step(w, jnp.asarray(dataset.data[:batch]))  # compile outside timing
+    jax.block_until_ready(out)
+
+    src = (
+        AsyncLoader(loader, num_workers=num_workers, prefetch_factor=2)
+        if num_workers > 0
+        else loader
+    )
+    pf = DevicePrefetcher(jax.device_put, depth=prefetch_depth)
+    n = 0
+    t0 = time.perf_counter()
+    for _idx, _host, device_batch in pf.iterate(src):
+        w, out = step(w, device_batch)
+        n += 1
+    jax.block_until_ready(out)
+    dt = time.perf_counter() - t0
+    return {
+        "steps": n,
+        "steps_per_sec": n / max(dt, 1e-9),
+        "input_starved_ms": pf.starved_s * 1e3,
+    }
+
+
 def test_input_microbench_async_beats_sync():
-    """The bench's sweep criterion, in-process: with an emulated slow
-    host loader, 2 workers + depth 2 beat synchronous feeding by >= 25%
-    and shrink the starvation metric."""
-    import importlib.util
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if "bench" in sys.modules:
-        bench = sys.modules["bench"]
-    else:
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(repo, "bench.py")
-        )
-        bench = importlib.util.module_from_spec(spec)
-        sys.modules["bench"] = bench
-        spec.loader.exec_module(bench)
-
-    sync = bench._input_microbench(8.0, num_workers=0, prefetch_depth=0, steps=16)
-    fast = bench._input_microbench(8.0, num_workers=2, prefetch_depth=2, steps=16)
+    """With an emulated slow host loader, 2 workers + depth 2 beat
+    synchronous feeding by >= 25% and shrink the starvation metric."""
+    sync = _input_microbench(8.0, num_workers=0, prefetch_depth=0, steps=16)
+    fast = _input_microbench(8.0, num_workers=2, prefetch_depth=2, steps=16)
+    assert sync["steps"] == fast["steps"] == 16
     assert fast["steps_per_sec"] >= 1.25 * sync["steps_per_sec"], (sync, fast)
     assert fast["input_starved_ms"] < sync["input_starved_ms"]
     assert sync["input_starved_ms"] > 0.0  # the metric moves under load

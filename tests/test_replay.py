@@ -163,21 +163,32 @@ def _fault_env(spec):
         faults._serve_cache = (None, [])
 
 
-def test_replay_driver_verdict_quick(model, tmp_path):
+_QUICK_TRACES = {
+    "diurnal": lambda: diurnal_trace(
+        4.0, 3.0, tenants={"gold": 3.0, "free": 1.0}, seed=2,
+        prompt_len=(2, 6), max_new_tokens=3,
+    ),
+    # a crowd of the best-effort tenant a third of the way in
+    "flash_crowd": lambda: flash_crowd_trace(
+        4.0, 3.0, crowd_tenant="free", crowd_at_s=4.0 / 3,
+        tenants={"gold": 1.0}, seed=2, prompt_len=(2, 6), max_new_tokens=3,
+    ),
+}
+
+
+@pytest.mark.parametrize("generator,replicas", [("diurnal", 1), ("flash_crowd", 2)])
+def test_replay_driver_verdict_quick(model, tmp_path, generator, replicas):
     registry = _registry()
-    fleet = _fleet(model, registry, replicas=1)
+    fleet = _fleet(model, registry, replicas=replicas)
     artifact = str(tmp_path / "verdict.json")
     try:
         # warm the step executable so compile time is not in the TTFTs
         fleet.submit([1, 2], max_new_tokens=2).result(timeout=180)
-        events = diurnal_trace(
-            4.0, 3.0, tenants={"gold": 3.0, "free": 1.0}, seed=2,
-            prompt_len=(2, 6), max_new_tokens=3,
-        )
+        events = _QUICK_TRACES[generator]()
         verdict = ReplayDriver(
             fleet, events, tenants=registry, speed=8.0, seed=2,
             vocab=int(model[1].vocab_size), max_prompt_len=8,
-            artifact_path=artifact, trace_meta={"generator": "diurnal"},
+            artifact_path=artifact, trace_meta={"generator": generator},
         ).run()
     finally:
         fleet.shutdown()

@@ -271,11 +271,42 @@ def test_engine_speculative_token_identity(model):
     assert eng.compile_stats() == {
         "prefill_compiles": 1, "decode_compiles": 1
     }
-    # the accounting the bench's accepted-per-tick number is built on
+    # the accounting accepted tokens per row tick is built on
     assert eng.stats["spec_row_ticks"] > 0
     assert eng.stats["accepted_tokens"] >= eng.stats["spec_row_ticks"]
     # fewer decode ticks than tokens is the whole point
     assert eng.stats["decode_steps"] < eng.stats["tokens_out"]
+
+
+def test_periodic_prompts_take_fewer_ticks_as_k_grows():
+    """The regime prompt-lookup speculation exists for: a small vocabulary
+    and periodic prompts push greedy decoding into loops the n-gram proposer
+    rides. As `speculate_k` goes 0 -> 2 -> 4 the same tokens take fewer
+    decode ticks, and a speculating row lands more than one token a tick."""
+    cfg = _cfg(vocab_size=32)
+    params = init_params(jax.random.key(0), cfg)
+    prompts = [
+        [3, 7, 11, 3, 7, 11, 3, 7], [5, 5, 9, 5, 5, 9, 5, 5],
+        [2, 4, 6, 8, 2, 4, 6, 8], [13, 1, 13, 1, 13, 1, 13, 1],
+        [6, 6, 6, 6, 6, 6, 6, 6], [9, 2, 7, 9, 2, 7, 9, 2],
+    ]
+    streams, ticks, per_row_tick = {}, {}, {}
+    for k in (0, 2, 4):
+        eng = InferenceEngine(params, cfg, engine_config=EngineConfig(
+            num_slots=4, max_prompt_len=8, max_len=64, temperature=0.0,
+            speculate_k=k,
+        ))
+        comps = [eng.submit(p, max_new_tokens=40) for p in prompts]
+        eng.run_until_idle()
+        streams[k] = [c.tokens for c in comps]
+        ticks[k] = eng.stats["decode_steps"]
+        assert eng.stats["tokens_out"] == 6 * 40
+        if k:
+            per_row_tick[k] = (
+                eng.stats["accepted_tokens"] / eng.stats["spec_row_ticks"])
+    assert streams[2] == streams[0] and streams[4] == streams[0]
+    assert ticks[0] > ticks[2] > ticks[4]
+    assert per_row_tick[4] > per_row_tick[2] > 1.2
 
 
 def test_engine_eos_mid_burst_truncates(model):

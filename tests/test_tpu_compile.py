@@ -21,7 +21,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-import bench
 from ray_lightning_tpu.models.llama import (
     LlamaConfig,
     init_params,
@@ -157,12 +156,16 @@ def test_flash_attention_compiles(one_chip, name, fn, shapes, kernels):
     assert _custom_calls(fn, *shapes, sharding=one_chip) == kernels
 
 
+# explicit (block_q, block_k) a caller may pass in place of the pass's own tile
+EXPLICIT_TILES = ((512, 512), (512, 256), (256, 512), (256, 256))
+
+
 @pytest.mark.parametrize(
-    "blocks", bench.FLASH_BLOCK_CANDIDATES, ids=lambda b: f"{b[0]}x{b[1]}"
+    "blocks", EXPLICIT_TILES, ids=lambda b: f"{b[0]}x{b[1]}"
 )
 def test_autotune_block_candidates_compile(one_chip, blocks):
-    """``bench.py`` times fwd+bwd at each of these and a refused one fails
-    its run, so each is compiled here first."""
+    """A caller's explicit tile reaches all three kernels, square or not:
+    the chip's compiler takes forward and backward at each."""
     def loss(q, k, v):
         return attention(
             q, k, v, causal=True, impl="flash", interpret=False,

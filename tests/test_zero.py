@@ -28,7 +28,7 @@ import ray_lightning_tpu as rlt
 from ray_lightning_tpu.parallel.sharding import ShardingPolicy
 from ray_lightning_tpu.parallel.zero import PAD_UNIT, ZeroContext
 from ray_lightning_tpu.strategies.base import XLAStrategy
-from tests.utils import BoringModel
+from tests.utils import BoringModel, live_bytes
 
 pytestmark = pytest.mark.zero
 
@@ -172,6 +172,26 @@ def test_explicit_zero_matches_ddp(ddp_run, stage):
     assert trainer._zero_ctx is not None
     np.testing.assert_allclose(losses, ddp_losses, rtol=1e-4)
     assert _max_abs_diff(params, ddp_params) < 1e-4
+
+
+def test_live_state_bytes_by_stage():
+    """What four devices hold of params and optimizer state after a fit, to
+    the byte. The explicit step keeps params replicated over the data axis at
+    both stages and holds each big leaf's two Adam moments once, padded, where
+    the replicated step holds them four times; stage 3 adds one fp32 master
+    flat a big leaf. So replicated > ZeRO-3 > ZeRO-2: the stage buys the
+    quantizable gather, not memory."""
+    trainers = {stage: _fit(_policy(stage), steps=2)[0] for stage in (0, 2, 3)}
+    live = {stage: live_bytes((t._params, t._opt_state))
+            for stage, t in trainers.items()}
+    big = trainers[3]._zero_ctx.big_leaves
+    assert [b.path for b in big] == [
+        "params/Dense_0/kernel", "params/Dense_1/kernel"]
+    held = 4 * sum(4 * b.size for b in big)       # a replicated moment, 4 devices
+    padded = sum(4 * b.padded for b in big)       # a sharded flat, once
+    assert live[0] - live[2] == 2 * (held - padded)
+    assert live[3] - live[2] == padded
+    assert live[0] > live[3] > live[2]
 
 
 def test_quantized_allgather_close_and_compressed(ddp_run):

@@ -219,3 +219,13 @@ def pallas_calls(jaxpr):
             inner = getattr(param, "jaxpr", param)
             if hasattr(inner, "eqns"):
                 yield from pallas_calls(inner)
+
+
+def live_bytes(tree) -> int:
+    """Bytes the devices hold of a tree of arrays, every addressable shard
+    counted: a replicated leaf counts once a device, a sharded leaf once."""
+    return sum(
+        shard.data.nbytes
+        for leaf in jax.tree_util.tree_leaves(tree)
+        for shard in leaf.addressable_shards
+    )
