@@ -297,6 +297,8 @@ class LightningModule:
 
     def on_train_epoch_start(self) -> None: ...
 
+    def on_train_batch_end(self, outputs, batch, batch_idx) -> None: ...
+
     def on_train_epoch_end(self) -> None: ...
 
     def on_validation_epoch_start(self) -> None: ...

@@ -2243,6 +2243,9 @@ class Trainer:
                 self._record_train_logs(logs, aggregator, batch_size)
             with obs.phase_span("rlt.train.callbacks", hook="batch_end"):
                 self._cb("on_train_batch_end", logs, batch, batch_idx)
+                # the module's own hook behind the callbacks': one of them
+                # may have read the step's outputs, and it can tell
+                self._module.on_train_batch_end(logs, batch, batch_idx)
             self.global_step += 1
             n_batches += 1
             if rec is not None or prof is not None:

@@ -21,6 +21,8 @@ an eighth full, and a Pallas operand is taken in the order it is declared.
   in_{t - (K - 1) + j})`` over a prompt, and for one position of every slot
   from the slot's TAIL, its last ``K - 1`` inputs, which it shifts. Plain
   ``jax.numpy``: elementwise work XLA fuses, on a leaf it updates in place.
+  :func:`causal_conv` also runs without the bias and without the ``silu``
+  (the gated short convolution of ``models/lfm2.py``).
 - :func:`mamba_scan` (``name="mamba_scan"``): the scan over one prompt. A grid
   over channel tiles and chunks of positions; a tile's state stays in VMEM
   from chunk to chunk and only ``y`` and the last state leave: no ``[L, C,
@@ -87,20 +89,23 @@ def _lanes(coef):
 # --------------------------------------------------------------------- #
 # the convolution in front of the scan
 # --------------------------------------------------------------------- #
-def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, n_valid=None
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def causal_conv(x: jnp.ndarray, w: jnp.ndarray, b: Optional[jnp.ndarray], n_valid=None,
+                activation: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: [L, C] of one sequence; w: [K, C] (``w[K - 1]`` meets the
-    position's own input); b: [C]. Returns (``silu(conv)`` [L, C] float32,
-    the tail [K - 1, C] in x's type: the inputs of positions ``n_valid - K +
-    1 .. n_valid - 1`` (None: the last), zeros before the sequence's start)."""
+    position's own input); b: [C], or None for a convolution published
+    without a bias. Returns (``silu(conv)`` [L, C] float32, or ``conv``
+    itself with ``activation=False``: a gated short convolution has neither
+    bias nor activation; the tail [K - 1, C] in x's type: the inputs of
+    positions ``n_valid - K + 1 .. n_valid - 1`` (None: the last), zeros
+    before the sequence's start)."""
     n, k = x.shape[0], w.shape[0]
     padded = jnp.pad(x, ((k - 1, 0), (0, 0)))
-    acc = b.astype(jnp.float32)[None, :]
+    acc = 0.0 if b is None else b.astype(jnp.float32)[None, :]
     for j in range(k):
         acc = acc + w[j].astype(jnp.float32)[None, :] * padded[j: j + n].astype(jnp.float32)
     n_valid = jnp.asarray(n if n_valid is None else n_valid, jnp.int32)
     tail = jax.lax.dynamic_slice_in_dim(padded, jnp.clip(n_valid, 0, n), k - 1, axis=0)
-    return jax.nn.silu(acc), tail
+    return (jax.nn.silu(acc) if activation else acc), tail
 
 
 def conv_step(x: jnp.ndarray, tails: jnp.ndarray, layer, w: jnp.ndarray, b: jnp.ndarray

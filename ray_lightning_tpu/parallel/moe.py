@@ -5,7 +5,8 @@ combine einsums over an expert-sharded weight stack — XLA partitions the
 [tokens, experts, capacity] dispatch tensors into all-to-alls over the 'ep'
 axis (Switch-Transformer style). No scatter/gather, fully static shapes.
 
-Inference has one no-drop path. :func:`moe_ffn_routed` computes only the
+There is one no-drop path, inference's and, for a model that trains
+without a capacity, training's. :func:`moe_ffn_routed` computes only the
 routed (token, expert) pairs: the pairs sorted by expert, one grouped matmul
 a weight stack, no capacity, so no pair is ever dropped however uneven the
 routing. It takes the choice and the weights from its caller, so any router
@@ -13,13 +14,20 @@ feeds it: :func:`route_softmax_top_k` (softmax over all experts, the top k
 renormalised: the handful of large experts of the Llama family) and
 :func:`route_sigmoid_bias` (sigmoid scores, a selection bias that does not
 enter the weights, renormalised and scaled: the one hundreds of small
-experts are published with).
+experts are published with). A caller that trains says
+``differentiable=True``: the path then differentiates in the rows, the
+weights and the three stacks, on the chip as off it: :func:`grouped_matmul`'s
+kernel has its backward (the rows' gradient by the same kernel over the
+transposed stack, the stack's by its sibling that sums over a group's rows),
+and the sort's gathers go back as gathers, never as a scatter. Without it
+the program is inference's, to the letter.
 
 The reference has no MoE (SURVEY §2c: EP absent); this is part of the
 framework's first-class parallelism surface.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -147,9 +155,59 @@ def _gmm_tiles(k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
     return _GMM_ROWS, max((r for r in _lane_divisors(k) if fits(r, tn)), default=k), tn
 
 
+def _gmm_kernel(xs, w, sizes, interpret):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(xs, w, sizes, jnp.float32,
+               _gmm_tiles(xs.shape[1], w.shape[2], w.dtype.itemsize), interpret=interpret)
+
+
+_gmm_vjp = jax.custom_vjp(_gmm_kernel, nondiff_argnums=(3,))
+
+
+def _in_a_group(rows: int, sizes: jnp.ndarray) -> jnp.ndarray:
+    """[rows, 1]: whether a row lies in a group or behind the last one."""
+    return jnp.arange(rows, dtype=jnp.int32)[:, None] < jnp.sum(sizes)
+
+
+def _gmm_kernel_fwd(xs, w, sizes, interpret):
+    """Under ``jax.grad`` the rows of no group, which the kernel leaves
+    unwritten, read zero: what lies in them would otherwise be multiplied
+    into the gradients of whatever scales the product."""
+    out = _gmm_kernel(xs, w, sizes, interpret)
+    return jnp.where(_in_a_group(xs.shape[0], sizes), out, 0.0), (xs, w, sizes)
+
+
+def _gmm_kernel_bwd(interpret, saved, dy):
+    """``dxs = dy @ w[g]^T`` a group by the forward's kernel over the stack
+    transposed in its index map, ``dw[g] = xs_g^T @ dy_g`` by ``tgmm``, which
+    visits an empty group to write its zeros. Both take ``dy`` in the
+    stack's type (the forward's products are of that type too) and
+    accumulate in float32. Rows behind the last group are written by
+    neither: their ``dxs`` is set to zero here, and ``tgmm`` reads none of
+    them. ``sizes`` gets no gradient."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    xs, w, sizes = saved
+    k, n = w.shape[1:]
+    dy = dy.astype(w.dtype)
+    dxs = gmm(dy, w, sizes, xs.dtype, _gmm_tiles(n, k, w.dtype.itemsize),
+              transpose_rhs=True, interpret=interpret)
+    # tgmm keeps a [K, N] tile as a float32 accumulator from a group's first
+    # row tile to its last, beside the tile it writes out: _gmm_tiles' rule
+    # at four bytes an element (2048 x 1792 -> 512 x 1792)
+    dw = tgmm(xs.swapaxes(0, 1), dy, sizes, w.dtype, _gmm_tiles(k, n, 4),
+              interpret=interpret)
+    return jnp.where(_in_a_group(xs.shape[0], sizes), dxs, 0), dw, None
+
+
+_gmm_vjp.defvjp(_gmm_kernel_fwd, _gmm_kernel_bwd)
+
+
 def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
                    kernel: Optional[bool] = None,
-                   interpret: Optional[bool] = None) -> jnp.ndarray:
+                   interpret: Optional[bool] = None,
+                   differentiable: bool = False) -> jnp.ndarray:
     """``xs[start_g : start_g + sizes[g]] @ w[g]`` for every group g, the
     rows of ``xs`` [M, K] lying group after group; w: [G, K, N]; returns
     float32 [M, N]. An empty group costs nothing: its weights are not read.
@@ -160,26 +218,27 @@ def grouped_matmul(xs: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
     (group, row tile) pairs that hold rows and takes a ``[K, N]`` tile
     (:func:`_gmm_tiles`) a step, over whole row tiles: M is padded up to
     one with rows of no group (64 pairs of a 32-row decode tick with two
-    experts a token). Elsewhere ``lax.ragged_dot``, which differentiates.
-    ``kernel=True`` off the TPU interprets the kernel (the tests)."""
+    experts a token). ``differentiable=True`` gives the kernel its backward
+    under ``jax.grad`` (:func:`_gmm_kernel_bwd`: ``gmm`` over the transposed
+    stack and ``tgmm``; a row of no group gets a zero gradient and reads no
+    weight); the forward is the same call either way. Elsewhere
+    ``lax.ragged_dot``, which differentiates by itself. ``kernel=True`` off
+    the TPU interprets the kernels (the tests)."""
     on_tpu = jax.devices()[0].platform == "tpu"
     if kernel is None:
         kernel = on_tpu
     if interpret is None:
         interpret = not on_tpu
-    m, k = xs.shape
-    n = w.shape[2]
+    m = xs.shape[0]
     if not kernel:
         return jax.lax.ragged_dot(
             xs, w, sizes.astype(jnp.int32),
             preferred_element_type=jnp.float32)
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-
     ragged = -m % _GMM_ROWS
     if ragged:
         xs = jnp.pad(xs, ((0, ragged), (0, 0)))
-    out = gmm(xs, w, sizes.astype(jnp.int32), jnp.float32,
-              _gmm_tiles(k, n, w.dtype.itemsize), interpret=interpret)
+    product = _gmm_vjp if differentiable else _gmm_kernel
+    out = product(xs, w, sizes.astype(jnp.int32), interpret)
     return out[:m] if ragged else out
 
 
@@ -216,6 +275,33 @@ def routed_sizes(
     return _group_sizes(_pair_groups(idx, e, held)[0], e)
 
 
+@jax.custom_vjp
+def _permute(x: jnp.ndarray, perm: jnp.ndarray, inverse: jnp.ndarray) -> jnp.ndarray:
+    """``x[perm]`` for a permutation and its inverse: the gradient is the
+    gather ``g[inverse]``, where XLA, which cannot know that no index comes
+    twice, would scatter row by row."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
+                lambda inverse, g: (g[inverse], None, None))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _pair_rows(xt: jnp.ndarray, order: jnp.ndarray, inverse: jnp.ndarray, k: int):
+    """``xt[order // k]``: the token of every pair, pairs in sorted order
+    (``order`` a permutation of the ``T * k`` pairs). A token's gradient is
+    the sum over its ``k`` pairs, adjacent once gathered back into pair
+    order."""
+    return xt[order // k]
+
+
+_pair_rows.defvjp(
+    lambda xt, order, inverse, k: (xt[order // k], inverse),
+    lambda k, inverse, g: (g[inverse].reshape(-1, k, g.shape[1]).sum(
+        axis=1, dtype=jnp.float32).astype(g.dtype), None, None))
+
+
 def moe_ffn_routed(
     params: Dict[str, Any],
     xt: jnp.ndarray,
@@ -223,6 +309,7 @@ def moe_ffn_routed(
     weights: jnp.ndarray,
     kernel: Optional[bool] = None,
     held: Optional[Tuple[int, ...]] = None,
+    differentiable: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """No-drop MoE evaluation that computes only the routed pairs.
 
@@ -246,6 +333,10 @@ def moe_ffn_routed(
     ``sizes`` does not count it. What it would have added is another
     holder's to compute; nothing here stands in for that exchange.
 
+    ``differentiable=True`` is for a caller under ``jax.grad``: the grouped
+    products carry their backward and the sort's gathers go back as gathers
+    (:func:`_pair_rows`, :func:`_permute`); the values are the same.
+
     Returns (out [T, D] in xt's dtype, sizes [E] int32: the rows each
     held expert got, which is what the serving counters are made of)."""
     t, k = idx.shape
@@ -253,21 +344,31 @@ def moe_ffn_routed(
     flat, here = _pair_groups(idx, e, held)
     order = jnp.argsort(flat, stable=True)  # pair numbers, expert by expert
     sizes = _group_sizes(flat, e)
-    xs = xt[order // k]  # [T*K, D]: each pair's token
+
+    def inverse_of_order():
+        return jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+
+    if differentiable:
+        inverse = inverse_of_order()
+        pair_rows = lambda: _pair_rows(xt, order, inverse, k)
+        sort = lambda a: _permute(a, order, inverse)
+        unsort = lambda a: _permute(a, inverse, order)
+    else:
+        pair_rows = lambda: xt[order // k]
+        sort = lambda a: a[order]
+        unsort = lambda a: a[inverse_of_order()]
+    mm = functools.partial(grouped_matmul, sizes=sizes, kernel=kernel,
+                           differentiable=differentiable)
+    xs = pair_rows()  # [T*K, D]: each pair's token
     dt = xt.dtype
-    h = (
-        jax.nn.silu(grouped_matmul(xs, params["w_gate"], sizes, kernel))
-        * grouped_matmul(xs, params["w_up"], sizes, kernel)
-    ).astype(dt)
-    y = grouped_matmul(h, params["w_down"], sizes, kernel)  # [T*K, D] f32
+    h = (jax.nn.silu(mm(xs, params["w_gate"])) * mm(xs, params["w_up"])).astype(dt)
+    y = mm(h, params["w_down"])  # [T*K, D] f32
     # rows behind the last group were never written: selected, not scaled
-    y = jnp.where(here[order][:, None],
-                  y * weights.reshape(t * k)[order][:, None], 0.0)
+    y = jnp.where(here[order][:, None], y * sort(weights.reshape(t * k))[:, None], 0.0)
     # back to pair order by a gather (the inverse permutation), then a
     # token's K rows are adjacent
-    inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
-        jnp.arange(t * k, dtype=jnp.int32))
-    out = y[inverse].reshape(t, k, -1).sum(axis=1)
+    out = unsort(y).reshape(t, k, -1).sum(axis=1)
     return out.astype(dt), sizes
 
 

@@ -1081,7 +1081,10 @@ def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
     decode rows through one pass of the layers, the decode program's
     arguments behind the prompt's); their three longer rungs, all four
     ``serve_decode`` entries and every entry of the reason and doc cells
-    stand to the letter."""
+    stand to the letter. PR 46 recorded none: the routed path's backward
+    (``grouped_matmul``'s ``custom_vjp``, the sort's gathers that go back as
+    gathers) sits behind ``differentiable=True``, which the model that trains
+    sets and no serving family does, so all 23 entries stand."""
     import json
     import os
 
@@ -1094,3 +1097,97 @@ def test_the_accepted_serve_cells_programs_lower_to_the_recorded_text(
     want = {k: v for k, v in recorded.items() if k.startswith(cell + "/")}
     assert sorted(got) == sorted(want) and len(want) >= 5
     assert [k for k in want if got[k] != want[k]] == []
+
+
+# ---------------------------------------------------------------------- #
+# the expert model's train step: the grouped products under jax.grad
+# ---------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def moe_train_step(topo, one_chip):
+    """``train-moe-8k``'s step as ``Trainer`` builds it, less the trainer:
+    the family's module at the cell's widths, the loss and its gradient, the
+    module's own AdamW; parameters and optimizer state donated, everything
+    as shapes on the described chip."""
+    import optax
+
+    from benchmarks import loader
+    from ray_lightning_tpu.models import lfm2
+
+    cell = loader.Manifest().cell("train-moe-8k")
+    sizes, job, program = cell.config, cell.traffic, cell.family.program
+    cfg = program.model_config(sizes, max_seq=job["seq_len"], **cell.settings["model"])
+    tx = program.make_module(cfg, sizes, 1, job["optimizer"]).configure_optimizers()
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda: cell.family.weights.make_params(sizes, cell.family.weights.seed_keys(sizes, 1))))
+    state = on_chip(jax.eval_shape(tx.init, params))
+    tokens = jax.ShapeDtypeStruct(
+        (job["rows_per_chip"], job["seq_len"]), jnp.int32, sharding=one_chip)
+
+    def step(params, state, tokens):
+        (loss, logs), grads = jax.value_and_grad(
+            lambda p: lfm2.lm_loss(p, tokens, cfg), has_aux=True)(params)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, loss, logs["moe_sizes"]
+
+    mp = pytest.MonkeyPatch()
+    try:
+        mp.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        exe = jax.jit(step, donate_argnums=(0, 1)).lower(params, state, tokens).compile()
+    finally:
+        mp.undo()
+    held = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params))
+    return exe, held
+
+
+def test_the_expert_train_step_fits_the_chip_with_its_state_in_place(moe_train_step):
+    """Weights and two moments of 893.7 M parameters go in and come out as
+    the same buffers (6 bytes each); beside them the gradients and a layer's
+    activations under remat: under 16 GB, and over the quarter of the chip a
+    cell has to fill."""
+    exe, held = moe_train_step
+    mem = exe.memory_analysis()
+    assert held == 893_696_256
+    assert mem.argument_size_in_bytes >= 6 * held and mem.alias_size_in_bytes >= 6 * held
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0.25 * 16e9 < total < 11e9  # 9.73 GB when this was written
+
+
+def test_the_expert_train_step_differentiates_through_the_grouped_kernels(moe_train_step):
+    """Four expert layers x three stacks: ``gmm`` forward, again under remat
+    and once more for the rows' gradient, ``tgmm`` for the stacks'; the names
+    the benchmark finds them by; no ``ragged_dot`` and no dispatch array of
+    ``[tokens, experts, capacity]``; the attention layer's three flash
+    kernels beside them."""
+    from collections import Counter
+
+    text = moe_train_step[0].as_text()
+    kernels = Counter(_kernel_instructions(text))
+    assert kernels["gmm"] == 4 * 3 * 3 and kernels["tgmm"] == 4 * 3
+    assert kernels["flash_fwd"] == 2 and kernels["flash_bwd_dq"] == kernels["flash_bwd_dkv"] == 1
+    assert "ragged" not in text
+    assert "[16384,32," not in text and "[16384,16," not in text
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1792), (1792, 2048)], ids=["gate-up", "down"])
+def test_the_grouped_kernels_gradients_compile_at_the_cells_shapes(one_chip, k, n):
+    """One stack of 16 held experts under 65,536 sorted pairs: the product,
+    the rows' gradient (the same kernel over the stack transposed) and the
+    stack's (``tgmm``, its float32 accumulator a ``_gmm_tiles`` tile at four
+    bytes an element)."""
+    from ray_lightning_tpu.parallel.moe import grouped_matmul
+
+    def grads(xs, w, sizes):
+        return jax.grad(
+            lambda xs, w: grouped_matmul(
+                xs, w, sizes, kernel=True, interpret=False, differentiable=True).sum(),
+            argnums=(0, 1))(xs, w)
+
+    text = _compiled_text(
+        grads, ((65536, k), jnp.bfloat16), ((16, k, n), jnp.bfloat16), ((16,), jnp.int32),
+        sharding=one_chip)
+    # the unused forward is dropped; straight under the outer jit the compiler
+    # names a call after ``jit(gmm)`` whole, inside a step's scopes after ``gmm``
+    assert [k.removeprefix("jit_") for k in _kernel_instructions(text)] == ["gmm", "tgmm"]
